@@ -52,12 +52,11 @@ def _hash_pids(vals, sel, n_out: int, traced: bool) -> jnp.ndarray:
         and vals[0].dict is None
         and str(vals[0].values.dtype) == "int64"
     ):
-        from auron_tpu.ops.pallas_kernels import (
-            partition_ids_pallas,
-            use_pallas,
-        )
+        from auron_tpu.jaxenv import is_tpu
 
-        if use_pallas():
+        if is_tpu():
+            from auron_tpu.ops.pallas_kernels import partition_ids_pallas
+
             pids = partition_ids_pallas(vals[0].values, n_out)
             null_pid = pmod(
                 jnp.full(cap, jnp.uint32(42)).view(jnp.int32), n_out

@@ -57,13 +57,10 @@ def _auto_budget() -> int:
     HBM-sized 8GB; on the CPU backend device arrays live in host RAM, so
     half the physical memory is the faithful analog of the reference's
     executor-memory-derived budget."""
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() != "cpu":
-            return 8 << 30
-    except Exception:  # noqa: BLE001  # auronlint: disable=R12 -- backend probe: an unprobeable jax means the CPU sizing below, which IS the documented fallback
-        pass
+    if jax.default_backend() != "cpu":
+        return 8 << 30
     try:
         phys = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
         return phys // 2  # the documented behavior, no floor: a small

@@ -52,8 +52,8 @@ from typing import Optional
 
 import numpy as np
 
-if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-    # standalone runs land on a 1-device CPU host; the mesh wants
+if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS") == "cpu":
+    # a CPU run lands on a 1-device host; the mesh wants
     # sql.shuffle.partitions devices (same bootstrap as models/sqlgate)
     from auron_tpu.jaxenv import force_cpu_backend
     from auron_tpu.utils.config import Configuration, SQL_SHUFFLE_PARTITIONS

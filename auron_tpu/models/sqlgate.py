@@ -36,11 +36,11 @@ from typing import Callable, Optional
 import numpy as np
 import pandas as pd
 
-if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-    # Standalone runs land on a 1-device CPU host but the mesh needs
-    # sql.shuffle.partitions devices — virtualize BEFORE the engine imports
-    # below initialize the backend. A live accelerator run sets
-    # JAX_PLATFORMS=tpu and skips this.
+if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS") == "cpu":
+    # A CPU run (JAX_PLATFORMS=cpu, as the Makefile targets set it) lands
+    # on a 1-device host but the mesh needs sql.shuffle.partitions devices
+    # — virtualize BEFORE the engine imports below initialize the backend.
+    # With the variable unset the run takes the devices JAX gives it.
     from auron_tpu.jaxenv import force_cpu_backend
     from auron_tpu.utils.config import Configuration, SQL_SHUFFLE_PARTITIONS
 

@@ -37,7 +37,7 @@ import time
 
 import numpy as np
 
-if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
+if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS") == "cpu":
     from auron_tpu.jaxenv import force_cpu_backend
 
     force_cpu_backend(2)

@@ -1,9 +1,11 @@
 """ctypes bindings for the native runtime helpers (native/auron_native.cpp).
 
-Loads ``native/libauron_native.so`` (built by ``make native``); every entry
-has a numpy fallback so the engine runs without the library (mirrors the
-reference's is_jni_bridge_inited() branching that lets kernels run without
-a JVM, spill.rs:90-101).
+Loads ``native/libauron_native.so``, building it from the source beside it
+on first use. Every entry has a numpy twin so the engine runs where the
+library is not packaged at all (mirrors the reference's
+is_jni_bridge_inited() branching that lets kernels run without a JVM,
+spill.rs:90-101) — but a source that does not build, or a library that
+does not load, raises: which twin ran is never a silent matter.
 """
 
 from __future__ import annotations
@@ -26,21 +28,17 @@ def _lib():
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     so = os.path.join(here, "native", "libauron_native.so")
     if not os.path.exists(so):
-        src = os.path.join(here, "native", "auron_native.cpp")
-        if os.path.exists(src):
-            try:
-                subprocess.run(
-                    ["make", "-C", os.path.join(here, "native")],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
-    if not os.path.exists(so):
-        return None
-    try:
-        lib = ctypes.CDLL(so)
-    except OSError:
-        return None
+        if not os.path.exists(os.path.join(here, "native", "auron_native.cpp")):
+            return None  # not packaged: the numpy twins are the engine
+        r = subprocess.run(
+            ["make", "-C", os.path.join(here, "native"), "libauron_native.so"],
+            capture_output=True, text=True, timeout=120,
+        )
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"native helper library failed to build:\n{r.stderr[-2000:]}"
+            )
+    lib = ctypes.CDLL(so)
     # one literal `lib.<sym>.argtypes/.restype =` statement per export —
     # auronlint R15 cross-checks these bindings against the C signatures
     # in native/auron_native.cpp, so they must stay statically visible

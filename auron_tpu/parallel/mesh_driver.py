@@ -501,34 +501,17 @@ class MeshQueryDriver:
         )
 
     def _routing_counts(self, batches: list[Batch], pids: list[jnp.ndarray]) -> np.ndarray:
-        """Exact [P_src, P_dst] live-row routing matrix (one host sync).
-
-        On TPU the histogram runs as a pallas kernel and only n_parts ints
-        cross to the host per shard; elsewhere the pid vector transfers
-        and numpy bincounts."""
-        from auron_tpu.ops.pallas_kernels import (
-            partition_histogram_pallas,
-            use_pallas,
-        )
-
-        counts = np.zeros((len(batches), self.n_parts), dtype=np.int64)
-        on_tpu = use_pallas()
-        for src, (b, pid) in enumerate(zip(batches, pids)):
-            if on_tpu:
-                live_pid = jnp.where(b.device.sel, pid.astype(jnp.int32), -1)
-                counts[src] = np.asarray(
-                    jax.device_get(  # auronlint: sync-point(4/task) -- routing histogram read at the exchange stage boundary
-                        partition_histogram_pallas(live_pid, self.n_parts)
-                    )
-                )
-                continue
-            # auronlint: sync-point(4/task) -- exchange routing histogram read at the stage boundary; one batched transfer
-            sel_d, pid_d = jax.device_get((b.device.sel, pid))
-            sel = np.asarray(sel_d)
-            pid_h = np.asarray(pid_d)[sel]
-            if pid_h.size:
-                counts[src] = np.bincount(pid_h, minlength=self.n_parts)
-        return counts
+        """Exact [P_src, P_dst] live-row routing matrix (one host sync):
+        the histogram runs on the device and only n_parts ints per shard
+        cross to the host."""
+        if not batches:
+            return np.zeros((0, self.n_parts), dtype=np.int64)
+        # auronlint: sync-point(4/task) -- exchange routing histogram read at the stage boundary; one batched transfer
+        got = jax.device_get([
+            _live_pid_counts(b.device.sel, pid, n_parts=self.n_parts)
+            for b, pid in zip(batches, pids)
+        ])
+        return np.stack(got).astype(np.int64)
 
     def _allgather_counts(
         self, local: np.ndarray, local_cap: int
@@ -930,6 +913,16 @@ def _local_shard(arr: jax.Array, p: int):
         if (idx.start or 0) == p:
             return s.data[0]
     raise KeyError(f"partition {p} not addressable on this process")
+
+
+@partial(jax.jit, static_argnames=("n_parts",))
+def _live_pid_counts(sel: jnp.ndarray, pid: jnp.ndarray, n_parts: int) -> jnp.ndarray:
+    """int32[n_parts] live rows per destination: a compare-and-reduce per
+    destination (XLA fuses it into one pass; no scatter). Ids outside
+    [0, n_parts) and dead rows fall out of every bucket."""
+    dst = jnp.arange(n_parts, dtype=jnp.int32)[:, None]
+    hit = (pid.astype(jnp.int32)[None, :] == dst) & sel[None, :]
+    return jnp.sum(hit, axis=1, dtype=jnp.int32)
 
 
 def _row_width_bytes(schema: T.Schema) -> int:

@@ -13,28 +13,6 @@ from jax import lax
 
 from auron_tpu.ops import bitonic
 
-_pallas_state: list = []
-
-
-def _skip_unless_pallas(impl):
-    """Interpret-mode Pallas needs a jaxlib with TPU lowering registries;
-    this CPU-only build raises NotImplementedError (same skip as
-    test_native.py's kernel tests). Probe once."""
-    if impl != "pallas":
-        return
-    if not _pallas_state:
-        probe = (
-            jnp.zeros(8, jnp.uint64),
-            jnp.arange(8, dtype=jnp.int32),
-        )
-        try:
-            bitonic.bitonic_sort(probe, impl="pallas", interpret=True)
-            _pallas_state.append(None)
-        except NotImplementedError as e:
-            _pallas_state.append(str(e))
-    if _pallas_state[0] is not None:
-        pytest.skip(f"pallas unavailable on this jaxlib build: {_pallas_state[0]}")
-
 
 def _operands(cap, n_words, n_distinct, seed, dead_frac=0.0):
     rng = np.random.default_rng(seed)
@@ -64,7 +42,6 @@ def _operands(cap, n_words, n_distinct, seed, dead_frac=0.0):
     ],
 )
 def test_matches_stable_lax_sort(impl, cap, n_words, n_distinct, dead_frac):
-    _skip_unless_pallas(impl)
     ops = _operands(cap, n_words, n_distinct, seed=cap + n_words, dead_frac=dead_frac)
     want = lax.sort(ops, num_keys=len(ops) - 1)
     got = bitonic.bitonic_sort(ops, impl=impl, interpret=True)
@@ -75,7 +52,6 @@ def test_matches_stable_lax_sort(impl, cap, n_words, n_distinct, dead_frac):
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_signed_operands_match_lax(impl):
     """int64/int32 key operands compare signed (sign-biased planes)."""
-    _skip_unless_pallas(impl)
     rng = np.random.default_rng(21)
     cap = 1024
     k = jnp.asarray(rng.integers(-(2**62), 2**62, cap).astype(np.int64))
@@ -91,7 +67,6 @@ def test_signed_operands_match_lax(impl):
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_narrow_planes_match(impl):
     """narrow=True operands (statically-zero hi words) sort identically."""
-    _skip_unless_pallas(impl)
     ops = _operands(2048, 2, 100, seed=9, dead_frac=0.25)
     # dead key (0/1) and second word masked to 32 bits -> narrowable
     ops = (ops[0], ops[1], ops[2] & jnp.uint64(0xFFFFFFFF), ops[3])
@@ -105,7 +80,6 @@ def test_narrow_planes_match(impl):
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_segment_by_keys_device_impl(impl):
-    _skip_unless_pallas(impl)
     from auron_tpu.exprs.eval import ColumnVal
     from auron_tpu import types as T
     from auron_tpu.ops import segments as S
@@ -129,7 +103,6 @@ def test_segment_by_keys_device_impl(impl):
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_agg_end_to_end_with_bitonic(impl):
     """A grouped aggregation with the bitonic sort forced stays exact."""
-    _skip_unless_pallas(impl)
     import pandas as pd
     import pyarrow as pa
 
@@ -185,7 +158,6 @@ def test_agg_end_to_end_with_bitonic(impl):
 def test_order_by_and_window_with_bitonic(impl):
     """The ORDER BY and window paths produce identical results with the
     network forced (exec/sort_exec.py + exec/window_exec.py wiring)."""
-    _skip_unless_pallas(impl)
     import pandas as pd
     import pyarrow as pa
 
@@ -240,7 +212,8 @@ def test_sort_impl_for_gates():
     # explicit override wins regardless of backend
     with conf_scope(Configuration().set(DEVICE_SORT_IMPL, "jnp")):
         assert bitonic.sort_impl_for(2, 1 << 16) == "jnp"
-    # auto on the CPU test backend -> lax (hostsort owns CPU)
+    # auto -> lax on every backend (the kernel is opt-in until a chip
+    # run has compared the two)
     with conf_scope(Configuration().set(DEVICE_SORT_IMPL, "auto")):
         assert bitonic.sort_impl_for(2, 1 << 16) == "lax"
 
@@ -300,7 +273,6 @@ def test_tiled_sort_block_boundary_values():
 def test_tiled_sort_pallas_matches_lax_sort():
     from auron_tpu.ops import bitonic as BT
 
-    _skip_unless_pallas("pallas")  # same probe/skip as the other kernel tests
     old_gate = BT._VMEM_GATE_BYTES
     BT._VMEM_GATE_BYTES = 64 << 10
     try:
