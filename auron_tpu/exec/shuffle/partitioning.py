@@ -54,7 +54,10 @@ def _hash_pids(vals, sel, n_out: int, traced: bool) -> jnp.ndarray:
     ):
         from auron_tpu.jaxenv import is_tpu
 
-        if is_tpu():
+        # one device only: a batch downstream of a mesh exchange is
+        # replicated over the mesh, and Mosaic kernels cannot be
+        # partitioned automatically — the jnp hash below can
+        if is_tpu() and len(vals[0].values.sharding.device_set) == 1:
             from auron_tpu.ops.pallas_kernels import partition_ids_pallas
 
             pids = partition_ids_pallas(vals[0].values, n_out)

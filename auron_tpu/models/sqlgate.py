@@ -150,14 +150,17 @@ def build_resources(lq: LoweredQuery, frames: dict, cache: dict) -> dict:
 
 
 def execute(lq: LoweredQuery, frames: dict, mesh, conf=None,
-            cache: Optional[dict] = None) -> pd.DataFrame:
+            cache: Optional[dict] = None, driver=None) -> pd.DataFrame:
     """Run one lowered query: distributed stage on the mesh, optional
-    single-task collect stage over the gathered output."""
+    single-task collect stage over the gathered output. ``driver``: a
+    caller-built MeshQueryDriver (to read its exchange stats afterwards)
+    instead of a fresh one over ``mesh``/``conf``."""
     from auron_tpu.parallel.mesh_driver import MeshQueryDriver
 
     cache = cache if cache is not None else {}
     resources = build_resources(lq, frames, cache)
-    driver = MeshQueryDriver(mesh, conf=conf or Configuration())
+    if driver is None:
+        driver = MeshQueryDriver(mesh, conf=conf or Configuration())
     outs = driver.run(lq.distributed, resources)
     batches = [b for part in outs for b in part]
     if lq.collect is None:

@@ -74,7 +74,9 @@ def partition_ids_pallas(
     u = jnp.pad(values_i64, (0, rows * _LANES - n)).view(jnp.uint64)
     lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32).reshape(rows, _LANES)
     hi = (u >> jnp.uint64(32)).astype(jnp.uint32).reshape(rows, _LANES)
-    spec = pl.BlockSpec((block, _LANES), lambda i: (i, 0))
+    # x64 is on globally and Mosaic refuses 64-bit values: a bare Python 0
+    # in the index map would be traced as an int64 constant
+    spec = pl.BlockSpec((block, _LANES), lambda i: (i, jnp.int32(0)))
     out = pl.pallas_call(
         partial(_murmur3_pmod_kernel, seed=seed, n_parts=n_parts),
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),

@@ -21,7 +21,7 @@ shuffle, so a mesh exchange and a file shuffle route rows identically.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
@@ -29,10 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - jax 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from auron_tpu.ops import hashing as H
 from auron_tpu.parallel.mesh import PARTITION_AXIS
@@ -163,8 +160,11 @@ def batch_exchange_step(mesh: Mesh, slot_cap: int, n_hash_cols: int = 1):
     return jax.jit(fn)
 
 
+@lru_cache(maxsize=64)
 def pid_exchange_step(mesh: Mesh, slot_cap: int):
-    """Mesh repartitioner routed by PRECOMPUTED partition ids.
+    """Mesh repartitioner routed by PRECOMPUTED partition ids. Memoized
+    per (mesh, slot_cap): a fresh jax.jit per exchange would retrace and
+    re-lower the collective on every query a server replays.
 
     The planned-query driver computes pids host-side with the same
     ``Partitioning`` code the file shuffle writer uses (spark-exact murmur3

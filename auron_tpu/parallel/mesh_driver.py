@@ -81,6 +81,8 @@ class ExchangeStats:
     coalesced_groups: list | None = None  # AQE partition grouping, if applied
     #: AQE skew-split task table, if applied: [(pid, map_lo, map_hi|None)]
     skew_tasks: list | None = None
+    #: mesh transport: how many devices the exchanged arrays live on
+    n_devices: int = 0
 
     def partition_sizes(self) -> np.ndarray:
         return self.rows.sum(axis=0)
@@ -665,6 +667,7 @@ class MeshQueryDriver:
             place(pid),
         )
         assert int(jax.device_get(overflow)) == 0, "sized from exact counts"  # auronlint: sync-point(4/task) -- one-scalar overflow invariant check per exchange
+        self.stats[-1].n_devices = len(rsel.sharding.device_set)
 
         # expose the addressable partitions (all of them single-process;
         # only this process's shards in SPMD) as a partition-keyed mapping

@@ -1,0 +1,174 @@
+"""Ask the chip's compiler, without the chip: the Pallas kernels and the main
+path's stage programs compiled for a DESCRIBED TPU v5e at the shapes the
+main path gives them (on-chip-measurement guide, section 2).
+
+What interpret mode cannot show, this does: Mosaic refuses 64-bit values
+inside a kernel, a block that outgrows VMEM, a program that cannot be
+partitioned. Nothing runs, so nothing here is a result or a time on the
+device — only "the chip's compiler accepts it, quickly".
+
+The topology is described inside a module-scoped fixture, never at import
+(only one process may hold the TPU library; every xdist worker imports
+every test file). Keep every such test in THIS file: a second file could
+go to another worker, whose fixture would then skip.
+"""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+#: a kernel compiles in a second or two and the flagship stage in about six;
+#: the bound only has to catch a compile that has grown to minutes (the
+#: fully-unrolled sort network did), with room for six busy xdist workers
+MAX_COMPILE_S = 60.0
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = no compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip (the next one warns): keep
+    # these out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from auron_tpu.parallel.mesh import PARTITION_AXIS
+
+    return Mesh(np.array(topo.devices[:4]), (PARTITION_AXIS,))
+
+
+def _compile(fn, *args, **static):
+    """Lower + compile for the described device; (compiled, seconds)."""
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args, **static).compile()
+    secs = time.perf_counter() - t0
+    assert secs < MAX_COMPILE_S, f"compiled in {secs:.1f}s (bound {MAX_COMPILE_S}s)"
+    return compiled, secs
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows", [1000, 1 << 16, 1 << 20, 1 << 22])
+def test_partition_ids_kernel_compiles(one_chip, rows):
+    """The shuffle pid kernel up to bench.py's accelerator batch (1<<22
+    rows): the row-block grid keeps VMEM use independent of the batch."""
+    from auron_tpu.ops.pallas_kernels import partition_ids_pallas
+
+    compiled, _ = _compile(
+        partition_ids_pallas, _sds((rows,), jnp.int64, one_chip), n_parts=200)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("planes,P_", [(4, 2048), (3, 4096), (6, 2048)])
+def test_bitonic_sort_kernel_compiles(one_chip, planes, P_):
+    """The opt-in (exec.device.sort.impl=pallas) single-block network."""
+    from auron_tpu.ops import bitonic
+
+    x = _sds((planes, P_ // 128, 128), jnp.uint32, one_chip)
+    compiled, _ = _compile(bitonic._run_pallas, x, P=P_, interpret=False)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bitonic_merge_kernel_compiles(one_chip):
+    """The tiled path's merge-split kernel over one block pair."""
+    from auron_tpu.ops import bitonic
+
+    x = _sds((4, 8192 // 128, 128), jnp.uint32, one_chip)
+    compiled, _ = _compile(bitonic._run_pallas_merge, x, P=8192, interpret=False)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cap", [1 << 11, 1 << 17, 1 << 20, 1 << 22])
+def test_auto_sort_impl_is_lax_on_tpu(monkeypatch, cap):
+    """No Pallas sort shape is ``auto``'s pick: the unrolled network's
+    compile runs to minutes from P = 131072 up, so on a TPU too ``auto``
+    is lax.sort until a chip run has compared them (ROADMAP S8)."""
+    from auron_tpu.ops import bitonic
+    from auron_tpu.utils.config import DEVICE_SORT_IMPL, Configuration
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    conf = Configuration().set(DEVICE_SORT_IMPL, "auto")
+    assert bitonic.sort_impl_for(2, cap, conf=conf) == "lax"
+
+
+# ---------------------------------------------------------------------------
+# the main path's jitted programs
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows,n_parts", [(1 << 20, 2), (1 << 22, 8)])
+def test_routing_counts_compile(one_chip, rows, n_parts):
+    """The exchange's on-device routing histogram (plain jnp, int32)."""
+    from auron_tpu.parallel.mesh_driver import _live_pid_counts
+
+    compiled, _ = _compile(
+        _live_pid_counts, _sds((rows,), jnp.bool_, one_chip),
+        _sds((rows,), jnp.int32, one_chip), n_parts=n_parts)
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (n_parts,) and out.dtype == jnp.int32
+
+
+def test_flagship_stage_program_compiles(one_chip):
+    """``__graft_entry__.entry()``'s fused filter + project + group
+    aggregation (a 3-operand lax.sort inside) at its own example shapes."""
+    from auron_tpu.models.flagship import example_args, fused_filter_agg_step
+
+    args = [_sds(a.shape, a.dtype, one_chip) for a in example_args(cap=8192)]
+    compiled, _ = _compile(jax.jit(fused_filter_agg_step), *args)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
+def test_exchange_steps_compile_for_four_chips(mesh4):
+    """The ICI shuffle as ONE program across four chips: both mesh
+    programs must partition, and the collective must really be there."""
+    from auron_tpu.parallel.exchange import (
+        pid_exchange_step,
+        sharded_agg_exchange_step,
+    )
+
+    rows = NamedSharding(mesh4, P("p"))
+    cap = 1 << 17
+    cols = (_sds((4, cap), jnp.int64, rows), _sds((4, cap), jnp.float64, rows))
+    masks = (_sds((4, cap), jnp.bool_, rows),) * 2
+    compiled, _ = _compile(
+        pid_exchange_step(mesh4, slot_cap=1 << 16), (cols, masks),
+        _sds((4, cap), jnp.bool_, rows), _sds((4, cap), jnp.int32, rows))
+    assert "all-to-all" in compiled.as_text()
+
+    compiled, _ = _compile(
+        sharded_agg_exchange_step(mesh4, slot_cap=128),
+        _sds((4, 128), jnp.int64, rows), _sds((4, 128), jnp.float64, rows),
+        _sds((4, 128), jnp.bool_, rows))
+    assert "all-to-all" in compiled.as_text()

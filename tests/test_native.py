@@ -252,15 +252,22 @@ def test_live_pid_counts_matches_bincount():
     assert got.dtype == np.int32 and (got == want).all()
 
 
-def test_pallas_pid_path_matches_generic(monkeypatch):
+@pytest.mark.parametrize("replicated", [False, True])
+def test_pallas_pid_path_matches_generic(monkeypatch, replicated):
     """Force the gated pallas pid path (interpret mode) through the real
-    HashPartitioning entry and compare with the generic jnp path."""
+    HashPartitioning entry and compare with the generic jnp path. A batch
+    replicated over several devices (what a mesh exchange hands the next
+    stage) must NOT reach the kernel: Mosaic kernels cannot be partitioned
+    automatically."""
+    import jax
     import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     import auron_tpu.exec.shuffle.partitioning as P
     import auron_tpu.ops.pallas_kernels as PK
     from auron_tpu import types as T
     from auron_tpu.columnar import Batch
+    from auron_tpu.columnar.batch import DeviceBatch
     from auron_tpu.exprs.ir import col
 
     rng = np.random.default_rng(10)
@@ -268,14 +275,23 @@ def test_pallas_pid_path_matches_generic(monkeypatch):
         {"k": rng.integers(-(2**60), 2**60, 2000).tolist()},
         schema=T.Schema.of(T.Field("k", T.INT64)),
     )
+    if replicated:
+        everywhere = NamedSharding(
+            Mesh(np.array(jax.devices()[:4]), ("p",)), PartitionSpec())
+        b = Batch(b.schema, jax.device_put(b.device, everywhere), b.dicts)
+        assert isinstance(b.device, DeviceBatch)
     hp = P.HashPartitioning([col(0)], 16)
     want = np.asarray(hp.partition_ids(b, None))
 
     monkeypatch.setattr("auron_tpu.jaxenv.is_tpu", lambda: True)
     orig = PK.partition_ids_pallas
-    monkeypatch.setattr(
-        PK, "partition_ids_pallas",
-        lambda v, n, seed=42: orig(v, n, seed=seed, interpret=True),
-    )
+    calls = []
+
+    def kernel(v, n, seed=42):
+        calls.append(v.shape)
+        return orig(v, n, seed=seed, interpret=True)
+
+    monkeypatch.setattr(PK, "partition_ids_pallas", kernel)
     got = np.asarray(hp.partition_ids(b, None))
     assert (got == want).all()
+    assert bool(calls) != replicated
