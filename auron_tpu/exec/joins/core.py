@@ -40,6 +40,7 @@ from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exprs import Evaluator, ir
 from auron_tpu.exprs.eval import ColumnVal
 from auron_tpu.ops import binsearch
+from auron_tpu.ops.floatbits import f64_equality_word
 from auron_tpu.ops import segments as S
 
 INNER = "inner"
@@ -581,7 +582,7 @@ def _canon_words_traced(key_vals, key_masks, key_kinds):
             f = v.astype(jnp.float64)
             f = jnp.where(f == 0, jnp.float64(0), f)
             f = jnp.where(jnp.isnan(f), jnp.float64(jnp.nan), f)
-            w = f.view(jnp.uint64)
+            w = f64_equality_word(f)
         else:  # ints / date / timestamp / decimal64 / dict codes
             w = v.astype(jnp.int64).view(jnp.uint64)
         words.append(jnp.where(m, w, jnp.uint64(0)))

@@ -43,6 +43,7 @@ from auron_tpu import types as T
 # import inside a jitted function would CREATE them under the trace and
 # leak dead tracers into the module cache
 from auron_tpu.ops import binsearch, hashing
+from auron_tpu.ops.floatbits import f64_equality_word
 from auron_tpu.exprs.eval import ColumnVal
 
 
@@ -82,7 +83,7 @@ def _canonical_word(cv: ColumnVal) -> jnp.ndarray:
         f = v.astype(jnp.float64)
         f = jnp.where(f == 0, jnp.float64(0), f)
         f = jnp.where(jnp.isnan(f), jnp.float64(jnp.nan), f)
-        return f.view(jnp.uint64)
+        return f64_equality_word(f)
     if dt.is_dict_encoded:
         # codes are equality keys within a unified-dictionary context
         return v.astype(jnp.int64).view(jnp.uint64)

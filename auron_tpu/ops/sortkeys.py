@@ -23,6 +23,7 @@ import numpy as np
 
 from auron_tpu import types as T
 from auron_tpu.exprs.eval import ColumnVal
+from auron_tpu.ops.floatbits import f64_orderable_word
 
 
 @dataclass(frozen=True)
@@ -55,9 +56,7 @@ def orderable_word(cv: ColumnVal) -> jnp.ndarray:
         f = v.astype(jnp.float64)
         f = jnp.where(f == 0, jnp.float64(0), f)
         f = jnp.where(jnp.isnan(f), jnp.float64(jnp.nan), f)
-        b = f.view(jnp.uint64)
-        neg = (b & sign) != 0
-        return jnp.where(neg, ~b, b | sign)
+        return f64_orderable_word(f)
     raise TypeError(f"unsortable type {dt}")
 
 

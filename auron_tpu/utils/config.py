@@ -260,8 +260,8 @@ DEVICE_SORT_IMPL = str_conf(
     "exec.device.sort.impl", "auto", "exec",
     "cluster-sort implementation when sorting on-device (host sort off): "
     "lax = multi-operand lax.sort; jnp = jitted bitonic merge network; "
-    "pallas = VMEM-resident bitonic Pallas kernel; auto = pallas on TPU "
-    "when the problem fits the VMEM gate, else lax (ops/bitonic.py)",
+    "pallas = VMEM-resident bitonic Pallas kernel; auto = lax on every "
+    "backend until a chip run has compared them (ops/bitonic.py)",
 )
 # auronlint: disable=R14 -- upstream-parity surface (conf.rs:53): SMJ fallback is not implemented in this engine yet; the key must exist so ported configs round-trip
 SMJ_FALLBACK_ENABLE = bool_conf(

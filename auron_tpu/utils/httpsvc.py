@@ -281,7 +281,18 @@ class _Handler(BaseHTTPRequestHandler):
             # conservative: after an arbitrary handler failure the
             # request-stream position is not trustworthy for reuse
             self.close_connection = True
-            self._send(f"error: {e}\n".encode(), "text/plain", 500)
+            # a task failure wraps its cause ("task ... failed" from err):
+            # answer with the whole chain and log the traceback, or the
+            # reason is lost to client and operator alike
+            import logging
+
+            logging.getLogger("auron_tpu").exception("POST %s failed", self.path)
+            chain, cur = [], e
+            while cur is not None:
+                chain.append(f"{type(cur).__name__}: {cur}")
+                cur = cur.__cause__
+            self._send(("error: " + " <- ".join(chain) + "\n").encode(),
+                       "text/plain", 500)
 
     def _post_stream(self, raw: bytes) -> None:
         srv = _stream_server

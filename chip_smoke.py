@@ -62,6 +62,12 @@ def say(**rec) -> None:
     print(json.dumps(rec), flush=True)
 
 
+def check(ok: bool, why) -> None:
+    """The script's comparisons: not ``assert``, which ``python -O`` drops."""
+    if not ok:
+        raise AssertionError(why)
+
+
 class CompileClock:
     """Seconds JAX spent tracing, lowering and compiling (or fetching from
     the persistent cache), the cache's hit/miss counts and the slowest
@@ -82,7 +88,7 @@ class CompileClock:
         import jax.monitoring as mon
 
         self._tot = dict.fromkeys([*self._DUR.values(), *self._CNT.values()], 0)
-        self._tot["programs"] = 0
+        self._tot["programs"] = self._tot["pallas_programs"] = 0
         self._slow: list = []
         mon.register_event_duration_secs_listener(self._on_duration)
         mon.register_event_listener(self._on_event)
@@ -95,6 +101,7 @@ class CompileClock:
             if key == "compile_s":
                 self._tot["programs"] += 1
                 self._slow.append((round(secs, 2), fun_name))
+                self._tot["pallas_programs"] += "pallas" in fun_name
 
     def _on_event(self, event: str, **_kw) -> None:
         key = self._CNT.get(event)
@@ -195,12 +202,12 @@ def phase_batch(sf: float, batch_rows: int, n_parts: int, seed: int,
                          "seconds": round(time.perf_counter() - t0, 3),
                          **clock.take()})
 
-    assert len(want) > 0, "oracle produced no rows: the comparison would be vacuous"
-    assert len(got) == len(want), (len(got), len(want))
-    assert got["d_year"].tolist() == want["d_year"].tolist()
-    assert got["i_brand_id"].tolist() == want["i_brand_id"].tolist()
+    check(len(want) > 0, "oracle produced no rows: the comparison would be vacuous")
+    check(len(got) == len(want), (len(got), len(want)))
+    for c in ("d_year", "i_brand_id"):
+        check(got[c].tolist() == want[c].tolist(), f"batch: column {c} differs")
     for g, w in zip(got["s"], want["s"]):
-        assert abs(float(g) - float(w)) <= 1e-6 * max(1.0, abs(float(w))), (g, w)
+        check(abs(float(g) - float(w)) <= 1e-6 * max(1.0, abs(float(w))), (g, w))
 
     peak = jax.devices()[0].memory_stats() or {}
     say(phase="batch", sf=sf, fact_rows=n_rows, fact_bytes=n_bytes,
@@ -223,9 +230,9 @@ def _post_sql(port: int, body: dict, timeout: float) -> dict:
         ) from None
 
 
-def _check_rows(name: str, resp: dict, frames: dict, float_rel: float):
+def _check_rows(name: str, resp: dict, frames: dict, float_rel: float) -> None:
     """An HTTP /sql answer against the text's oracle: the same comparison
-    ``sqlgate.run_case`` makes, over the JSON rows. Returns the frame."""
+    ``sqlgate.run_case`` makes, over the JSON rows."""
     import pandas as pd
 
     from auron_tpu.models import sqlgate
@@ -234,11 +241,9 @@ def _check_rows(name: str, resp: dict, frames: dict, float_rel: float):
     case = sqlgate.case_by_name(name)
     got = pd.DataFrame(resp["rows"], columns=resp["columns"])
     want = sqlgate.oracle_head(case.oracle(frames), case)
-    assert len(want) > 0, f"{name}: oracle produced no rows"
-    got.columns = list(want.columns)
+    check(len(want) > 0, f"{name}: oracle produced no rows")
     err = compare_frames(got, want, float_rel, sorted_rows=True)
-    assert err is None, f"{name}: {err}"
-    return got
+    check(err is None, f"{name}: {err}")
 
 
 def phase_sql(sf: float, n_parts: int, names: tuple, seed: int,
@@ -271,8 +276,8 @@ def phase_sql(sf: float, n_parts: int, names: tuple, seed: int,
             t0 = time.perf_counter()
             again = _post_sql(port, {"sql": case.sql, "tenant": "smoke"}, timeout)
             again_s = time.perf_counter() - t0
-            assert again["cache_hit"] is True, f"{name}: plan cache missed"
-            assert again["rows"] == first["rows"], f"{name}: replay diverged"
+            check(again["cache_hit"] is True, f"{name}: plan cache missed")
+            check(again["rows"] == first["rows"], f"{name}: replay diverged")
             texts.append({"text": name, "rows": len(first["rows"]),
                           "first_s": round(first_s, 3),
                           "again_s": round(again_s, 3), **compiled,
@@ -309,7 +314,7 @@ def phase_exchange(n_chips: int, sf: float, name: str, seed: int,
     frames = build_tables(tpcds.generate(sf=sf, seed=seed), seed=seed)
     case = sqlgate.case_by_name(name)
     want = sqlgate.oracle_head(case.oracle(frames), case)
-    assert len(want) > 0, f"{name}: oracle produced no rows"
+    check(len(want) > 0, f"{name}: oracle produced no rows")
     float_rel = C.SQL_GATE_FLOAT_REL.get(C.Configuration())
     mesh = make_mesh(n_chips)
     lq = compile_text(case.sql, sqlgate.gate_catalog(), n_parts=n_chips)
@@ -321,15 +326,13 @@ def phase_exchange(n_chips: int, sf: float, name: str, seed: int,
         df = sqlgate.execute(lq, frames, mesh, driver=driver)
         secs = time.perf_counter() - t0
         err = compare_frames(df, want, float_rel, sorted_rows=True)
-        assert err is None, f"{name} under exchange.mode={mode}: {err}"
-        assert driver.stats, f"{name}: no exchange ran"
-        assert all(s.mode == mode for s in driver.stats), [
-            (s.exchange_id, s.mode) for s in driver.stats]
-        if mode == "mesh":
-            for s in driver.stats:
-                assert s.n_devices == n_chips, (
-                    f"exchange {s.exchange_id}: exchanged arrays live on "
-                    f"{s.n_devices} device(s), not {n_chips}")
+        check(err is None, f"{name} under exchange.mode={mode}: {err}")
+        check(bool(driver.stats), f"{name}: no exchange ran")
+        for s in driver.stats:
+            check(s.mode == mode, f"exchange {s.exchange_id} took {s.mode}")
+            check(mode == "file" or s.n_devices == n_chips,
+                  f"exchange {s.exchange_id}: exchanged arrays live on "
+                  f"{s.n_devices} device(s), not {n_chips}")
         got[mode] = df
         say(phase="exchange.sql", text=name, mode=mode, n_parts=n_chips,
             seconds=round(secs, 3), rows=len(df),
@@ -338,7 +341,7 @@ def phase_exchange(n_chips: int, sf: float, name: str, seed: int,
                        for s in driver.stats],
             **clock.take(), matches_oracle=True)
     err = compare_frames(got["mesh"], got["file"], float_rel, sorted_rows=True)
-    assert err is None, f"{name}: mesh and file transports disagree: {err}"
+    check(err is None, f"{name}: mesh and file transports disagree: {err}")
 
 
 def main() -> None:

@@ -140,6 +140,22 @@ def test_routing_counts_compile(one_chip, rows, n_parts):
     assert out.shape == (n_parts,) and out.dtype == jnp.int32
 
 
+def test_float64_key_words_compile(monkeypatch, one_chip):
+    """A TPU carries float64 as a float32 pair and refuses every bitcast
+    between float64 and 64-bit integers: the IEEE-bits words are refused,
+    the pair-built words of ops/floatbits.py compile."""
+    from auron_tpu.ops import floatbits
+
+    f = _sds((1 << 20,), jnp.float64, one_chip)
+    with pytest.raises(Exception, match="X64 element types"):
+        jax.jit(lambda v: v.view(jnp.uint64)).lower(f).compile()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for word in (floatbits.f64_orderable_word, floatbits.f64_equality_word):
+        compiled, _ = _compile(jax.jit(word), f)
+        (out,) = jax.tree.leaves(compiled.out_info)
+        assert out.dtype == jnp.uint64
+
+
 def test_flagship_stage_program_compiles(one_chip):
     """``__graft_entry__.entry()``'s fused filter + project + group
     aggregation (a 3-operand lax.sort inside) at its own example shapes."""

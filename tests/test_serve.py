@@ -450,6 +450,31 @@ def test_post_sql_404_without_server():
         httpsvc.stop()
 
 
+def test_post_sql_500_names_the_cause():
+    """A task failure reaches the handler as "task ... failed" FROM its
+    cause: the 500 must carry the whole chain, or the reason is lost (the
+    first SQL run on a chip answered a bare "task stage=0 partition=0
+    failed")."""
+    from auron_tpu.utils import httpsvc
+
+    class Failing:
+        def execute_json(self, body):
+            try:
+                raise ValueError("no such kernel")
+            except ValueError as e:
+                raise RuntimeError("task stage=0 partition=0 failed") from e
+
+    port = httpsvc.start(0)
+    httpsvc.install_sql_server(Failing())
+    try:
+        code, resp = _post(port, {"sql": "select 1"})
+        assert code == 500
+        assert "task stage=0 partition=0 failed" in resp["error"]
+        assert "ValueError: no such kernel" in resp["error"]
+    finally:
+        httpsvc.stop()
+
+
 # ---------------------------------------------------------------------------
 # the concurrency differential gate, toy scale
 # ---------------------------------------------------------------------------

@@ -150,3 +150,37 @@ def test_negative_nan_bits_sort_greatest():
     order = np.argsort(w)
     # ascending: -inf, 1.0, inf, NaN (greatest) — even for negative-bit NaN
     assert order.tolist() == [2, 0, 3, 1]
+
+
+@pytest.mark.parametrize("on_tpu", [False, True])
+def test_f64_key_words_order_and_equality(monkeypatch, on_tpu):
+    """ops/floatbits: the uint64 words of a float64 key order like the
+    values and are equal iff the values are — with the IEEE bitcast, and
+    with the float32-pair construction a TPU needs (it has no float64
+    bitcast). The pair holds ~48 mantissa bits, so the TPU leg uses values
+    a TPU can hold: float32 pairs."""
+    import jax
+    import jax.numpy as jnp
+
+    from auron_tpu.ops import floatbits
+
+    if on_tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.default_rng(5)
+    hi = rng.normal(scale=1e6, size=4000).astype(np.float32)
+    lo = (hi * np.float32(2.0**-26) * rng.random(4000).astype(np.float32))
+    vals = hi.astype(np.float64) + lo.astype(np.float64)
+    vals = np.concatenate([
+        vals, vals[:500], -vals[:500], [0.0, 1.0, -1.0, 0.01, 123456.78],
+        [np.inf, -np.inf, np.nan, 3.4e38, -3.4e38, 1e-30, -1e-30]])
+    f = jnp.asarray(vals)
+    order = np.asarray(floatbits.f64_orderable_word(f))
+    equal = np.asarray(floatbits.f64_equality_word(f))
+    want = np.argsort(vals, kind="stable")  # numpy sorts NaN last, like SQL
+    got = np.argsort(order, kind="stable")
+    assert np.array_equal(vals[got], vals[want], equal_nan=True)
+    for words in (order, equal):
+        same_word = words[:, None] == words[None, :]
+        same_val = (vals[:, None] == vals[None, :]) | (
+            np.isnan(vals)[:, None] & np.isnan(vals)[None, :])
+        assert np.array_equal(same_word, same_val)
