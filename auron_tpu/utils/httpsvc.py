@@ -53,6 +53,7 @@ response (keep-alive framing), bounded by ``_MAX_BODY``.
 from __future__ import annotations
 
 import json
+import logging
 import threading
 import traceback
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -284,8 +285,6 @@ class _Handler(BaseHTTPRequestHandler):
             # a task failure wraps its cause ("task ... failed" from err):
             # answer with the whole chain and log the traceback, or the
             # reason is lost to client and operator alike
-            import logging
-
             logging.getLogger("auron_tpu").exception("POST %s failed", self.path)
             chain, cur = [], e
             while cur is not None:
