@@ -1,0 +1,271 @@
+"""The harness: what every run does, whatever its cell.
+
+Finds a cell's files by the names in ``BENCHMARK.json`` (its configuration
+under ``configs/``, its traffic mix under ``traffic/``, its driver under
+``drivers/``, its queries under ``queries/``, a reader for each per-layer
+metric under ``metrics/``), and drives one run: set-up, window, peak memory,
+the comparison with the reference, the metrics. ``run.py`` is the command;
+tests call ``run_cell`` with the devices they have.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import importlib.util
+import json
+import os
+import shutil
+import statistics
+import sys
+import tempfile
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+
+
+def say(**rec) -> None:
+    """An evidence line: one JSON object, before the result line."""
+    print(json.dumps(rec, default=str), flush=True)
+
+
+def load_json(*parts: str) -> dict:
+    with open(os.path.join(HERE, *parts)) as f:
+        return json.load(f)
+
+
+def load_module(kind: str, name: str):
+    """``benchmark/<kind>/<name>.py``, found by name. A metric that is split
+    by path (``x.batch``, ``x.sql``) shares the reader ``metrics/x.py``."""
+    for stem in (name, name.split(".", 1)[0]):
+        path = os.path.join(HERE, kind, stem + ".py")
+        if os.path.exists(path):
+            mod_name = f"benchmark_{kind}_{stem.replace('.', '_')}"
+            if mod_name in sys.modules:
+                return sys.modules[mod_name]
+            spec = importlib.util.spec_from_file_location(mod_name, path)
+            mod = importlib.util.module_from_spec(spec)
+            sys.modules[mod_name] = mod
+            spec.loader.exec_module(mod)
+            return mod
+    raise FileNotFoundError(f"no benchmark/{kind}/{name}.py")
+
+
+def load_cell(workload: str, spec: dict | None = None) -> dict:
+    """The cell's entry of ``BENCHMARK.json`` with its configuration and its
+    traffic mix read from their own files."""
+    if spec is None:
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            spec = json.load(f)
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if workload not in cells:
+        raise SystemExit(f"run.py: no workload {workload!r} in BENCHMARK.json; "
+                         f"it has {sorted(cells)}")
+    cell = dict(cells[workload])
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    with open(os.path.join(ROOT, files[cell["config"]])) as f:
+        cell["config_file"] = json.load(f)
+    cell["traffic_file"] = load_json("traffic", cell["traffic"] + ".json")
+
+    def reports(m: dict) -> bool:
+        return "workloads" not in m or workload in m["workloads"]
+
+    cell["end_to_end"] = [m for m in spec["end_to_end"] if reports(m)]
+    e2e = {m["name"] for m in cell["end_to_end"]}
+    cell["per_layer"] = [m for m in spec["per_layer"]
+                         if reports(m) and m["moves"] in e2e]
+    return cell
+
+
+def require_tpu(n_chips: int):
+    """The devices JAX gives this process, or exit: there is no CPU mode."""
+    import jax
+
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        sys.exit(f"run.py: JAX found no TPU (platform={devs[0].platform!r}); "
+                 "the benchmark has no CPU mode")
+    if len(devs) < n_chips:
+        sys.exit(f"run.py: the cell needs {n_chips} chips, JAX reports {len(devs)}")
+    return devs[:n_chips]
+
+
+def chip_peaks(device_kind: str) -> dict:
+    table = load_json("peaks.json")
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r} in "
+                       "benchmark/peaks.json: add a row with its source")
+    return table[device_kind]
+
+
+def span(name: str):
+    """A host span on the profiler's clock; costs next to nothing while no
+    trace is being taken."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+class Tracer:
+    """The profiler around a steady sub-window, started and stopped by the
+    driver at the points its traffic file names. Off in a ``--trace 0`` run:
+    every method is then a no-op."""
+
+    def __init__(self, on: bool) -> None:
+        self.on = on
+        self.dir = None
+        self.t_start = self.t_stop = None
+        self._stack = contextlib.ExitStack()
+
+    def start(self) -> None:
+        if not self.on or self.t_start is not None:
+            return
+        import jax
+
+        self.dir = tempfile.mkdtemp(prefix="bench_trace_")
+        jax.profiler.start_trace(self.dir)
+        self._stack.enter_context(span("bench:window"))
+        self.t_start = time.perf_counter()
+
+    def stop(self) -> None:
+        if not self.on or self.t_start is None or self.t_stop is not None:
+            return
+        import jax
+
+        self.t_stop = time.perf_counter()
+        self._stack.close()
+        jax.profiler.stop_trace()
+
+    def reduce(self) -> dict | None:
+        """The trace's reduction; the trace's files are removed (a run
+        writes little to disk) unless BENCH_KEEP_TRACE names a directory."""
+        if self.dir is None or self.t_stop is None:
+            return None
+        from benchmark import trace_reduce
+
+        try:
+            path = trace_reduce.find_xplane(self.dir)
+            keep = os.environ.get("BENCH_KEEP_TRACE")
+            if keep:
+                os.makedirs(keep, exist_ok=True)
+                shutil.copy(path, keep)
+            out = trace_reduce.reduce_file(path)
+            if out is not None:
+                out["trace_bytes"] = os.path.getsize(path)
+            return out
+        finally:
+            shutil.rmtree(self.dir, ignore_errors=True)
+
+
+def memory_peak_bytes(devs) -> int:
+    """The peak on the fullest chip, as the backend reports it."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devs]
+    return int(max(peaks))
+
+
+def percentile(values: list, q: float) -> float:
+    """Linear interpolation between closest ranks (numpy's default)."""
+    xs = sorted(values)
+    if len(xs) == 1:
+        return float(xs[0])
+    k = (len(xs) - 1) * q
+    lo = int(k)
+    hi = min(lo + 1, len(xs) - 1)
+    return float(xs[lo] + (xs[hi] - xs[lo]) * (k - lo))
+
+
+def end_to_end(name: str, records: list, window_s: float, setup_s: float):
+    """The end-to-end metrics, each over all the work and all the time of the
+    window. None where the cell's records hold nothing for it."""
+    done = [r for r in records if r["ok"]]
+    if name == "setup_s":
+        return setup_s
+    if name == "batch_query_s":
+        return window_s / len(done) if done else None
+    if name == "sql_queries_per_s":
+        return len(done) / window_s if done else None
+    if name == "sql_latency_p95_s":
+        return percentile([r["t1"] - r["t0"] for r in records], 0.95) \
+            if records else None
+    raise KeyError(f"run.py has no definition for end-to-end metric {name!r}")
+
+
+def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
+             t_start: float) -> dict:
+    """Everything of a run after the look for a chip: set-up, window, the
+    comparison, the metrics. Returns the result line as a dict."""
+    from benchmark.compile_clock import CompileClock
+
+    config, traffic = cell["config_file"], cell["traffic_file"]
+    driver = load_module("drivers", config["driver"])
+    clock = CompileClock()
+    counters = None
+    if trace:
+        # patches ArrayImpl._value for the whole process: never on in the
+        # run that reports the end-to-end metrics
+        from auron_tpu.utils.profiling import EngineCounters
+
+        counters = EngineCounters.install()
+    tracer = Tracer(trace)
+    kind = devs[0].device_kind
+    peaks = chip_peaks(kind) if devs[0].platform == "tpu" else None
+
+    state = driver.setup(config, traffic, seed, span=span, say=say)
+    setup_compile = clock.take()
+    syncs0 = counters.syncs if counters else 0
+    batches0 = counters.batches if counters else 0
+    setup_s = time.perf_counter() - t_start
+    try:
+        records, window_s = driver.window(state, seconds, tracer)
+    finally:
+        tracer.stop()
+    window_compile = clock.take()
+    syncs = counters.syncs - syncs0 if counters else None
+    batches = counters.batches - batches0 if counters else None
+    peak = memory_peak_bytes(devs)
+    driver.finish(state)           # the program's state is freed here
+    reduction = tracer.reduce()
+    compared = driver.check(state, records, config["limits"])
+
+    failed = sum(1 for r in records if not r["ok"])
+    compared = {"failed": {"value": failed, "limit": config["limits"]["failed"]},
+                **compared}
+    correct = all(c["value"] <= c["limit"] for c in compared.values())
+
+    say(setup={"seconds": setup_s, **setup_compile},
+        window={"seconds": window_s, "requests": len(records),
+                "compiles": window_compile, "host_syncs": syncs,
+                "batches": batches},
+        latency_s={"n": len(records),
+                   "median": statistics.median(r["t1"] - r["t0"] for r in records)
+                   if records else None,
+                   "max": max((r["t1"] - r["t0"] for r in records), default=None)})
+
+    facts = {"records": records, "window_s": window_s, "trace": reduction,
+             "traced": [tracer.t_start, tracer.t_stop],
+             "compiles_in_window": window_compile["programs"],
+             "host_syncs": syncs, "batches": batches, "peak_bytes": peak,
+             "peaks": peaks, "scan_bytes": state.get("scan_bytes", {})}
+    metrics = {}
+    if trace:
+        for m in cell["per_layer"]:
+            value = load_module("metrics", m["name"]).read(facts)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    else:
+        for m in cell["end_to_end"]:
+            value = end_to_end(m["name"], records, window_s, setup_s)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+
+    device = {"platform": devs[0].platform, "kind": kind, "count": len(devs),
+              "memory_peak_bytes": peak}
+    result = {"correct": correct, "attempted": len(records), "failed": failed,
+              "metrics": metrics, "device": device}
+    if trace and reduction is not None:
+        device["busy_s"] = reduction["busy_s"]
+        device["window_s"] = reduction["window_s"]
+        result["breakdown"] = {"device_ops": reduction["device_ops"],
+                               "idle_gaps": reduction["idle_gaps"]}
+    result["compared"] = compared
+    return result
