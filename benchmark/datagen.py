@@ -30,6 +30,25 @@ WEEK_SEQ_BASE = 5112
 
 
 
+def make(config: dict, seed: int) -> dict:
+    """The frames a configuration's ``data`` entry names, from the seed."""
+    return globals()[config["data"]["generator"]](config["data"]["sf"], seed)
+
+
+def column_bytes(frames: dict, columns: dict) -> int:
+    """Bytes of ``{table: [column, ...]}``: fixed-width columns at their
+    width, strings at their length. What a query's text must read once."""
+    total = 0
+    for table, cols in columns.items():
+        for c in cols:
+            s = frames[table][c]
+            if pd.api.types.is_numeric_dtype(s.dtype):
+                total += int(s.dtype.itemsize) * len(s)
+            else:
+                total += int(s.str.len().sum())
+    return total
+
+
 def _n_stores(sf: float) -> int:
     return max(3, int(12 * min(sf, 1.0)) or 3)
 

@@ -18,30 +18,13 @@ import gc
 import tempfile
 import time
 
-import pandas as pd
-
 from benchmark import compare, datagen
 from benchmark.harness import load_module
 
 
-def scan_bytes(frames: dict, columns: dict) -> int:
-    """Bytes of the columns a query's text must read once: fixed-width
-    columns at their width, strings at their UTF-8 length."""
-    total = 0
-    for table, cols in columns.items():
-        for c in cols:
-            s = frames[table][c]
-            if pd.api.types.is_numeric_dtype(s.dtype):
-                total += int(s.dtype.itemsize) * len(s)
-            else:
-                total += int(s.str.len().sum())
-    return total
-
-
 def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
     t0 = time.perf_counter()
-    frames = getattr(datagen, config["data"]["generator"])(
-        config["data"]["sf"], seed)
+    frames = datagen.make(config, seed)
     gen_s = time.perf_counter() - t0
     (name,) = traffic["queries"]
     query = load_module("queries", name)
@@ -51,7 +34,7 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
     ingest_s = time.perf_counter() - t0
     state = {"frames": frames, "query": query, "name": name, "params": params,
              "resident": resident, "span": span, "traffic": traffic,
-             "scan_bytes": {name: scan_bytes(frames, query.SCAN_COLUMNS)}}
+             "scan_bytes": {name: datagen.column_bytes(frames, query.SCAN_COLUMNS)}}
     t0 = time.perf_counter()
     with tempfile.TemporaryDirectory(prefix="bench_q_") as wd:
         query.run(resident, params, wd, span)
@@ -117,8 +100,7 @@ def check(state: dict, records: list, limits: dict) -> dict:
 def control(config: dict, traffic: dict, seed: int) -> tuple:
     """The reference put in the program's place and computed in float32:
     ``(state, records)`` for ``check``, which has to find it not correct."""
-    frames = getattr(datagen, config["data"]["generator"])(
-        config["data"]["sf"], seed)
+    frames = datagen.make(config, seed)
     (name,) = traffic["queries"]
     query = load_module("queries", name)
     params = {**config["sizes"], **traffic["params"]}

@@ -25,7 +25,6 @@ import urllib.request
 import pandas as pd
 
 from benchmark import compare, datagen
-from benchmark.drivers.batch_class import scan_bytes
 from benchmark.harness import load_module
 
 
@@ -48,8 +47,7 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
     from auron_tpu.utils.config import Configuration
 
     t0 = time.perf_counter()
-    frames = getattr(datagen, config["data"]["generator"])(
-        config["data"]["sf"], seed)
+    frames = datagen.make(config, seed)
     for table, df in frames.items():
         want = [n for n, _, _ in TABLES[table]]
         if list(df.columns) != want:
@@ -69,7 +67,7 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
         raise KeyError(f"the traffic names texts the configuration lacks: {unknown}")
     state = {"frames": frames, "server": server, "port": port, "texts": texts,
              "traffic": traffic, "span": span, "first": {},
-             "scan_bytes": {n: scan_bytes(frames, q.SCAN_COLUMNS)
+             "scan_bytes": {n: datagen.column_bytes(frames, q.SCAN_COLUMNS)
                             for n, q in texts.items()}}
     warm = {}
     try:
@@ -178,8 +176,7 @@ def check(state: dict, records: list, limits: dict) -> dict:
 def control(config: dict, traffic: dict, seed: int) -> tuple:
     """The references put in the program's place and computed in float32:
     ``(state, records)`` for ``check``, which has to find them not correct."""
-    frames = getattr(datagen, config["data"]["generator"])(
-        config["data"]["sf"], seed)
+    frames = datagen.make(config, seed)
     low_frames = compare.to_float32(frames)
     texts = {n: load_module("queries", n) for n in traffic["queries"]}
     state = {"frames": frames, "texts": texts, "first": {}}

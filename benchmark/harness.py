@@ -149,10 +149,7 @@ class Tracer:
             if keep:
                 os.makedirs(keep, exist_ok=True)
                 shutil.copy(path, keep)
-            out = trace_reduce.reduce_file(path)
-            if out is not None:
-                out["trace_bytes"] = os.path.getsize(path)
-            return out
+            return trace_reduce.reduce_file(path)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -161,33 +158,6 @@ def memory_peak_bytes(devs) -> int:
     """The peak on the fullest chip, as the backend reports it."""
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devs]
     return int(max(peaks))
-
-
-def percentile(values: list, q: float) -> float:
-    """Linear interpolation between closest ranks (numpy's default)."""
-    xs = sorted(values)
-    if len(xs) == 1:
-        return float(xs[0])
-    k = (len(xs) - 1) * q
-    lo = int(k)
-    hi = min(lo + 1, len(xs) - 1)
-    return float(xs[lo] + (xs[hi] - xs[lo]) * (k - lo))
-
-
-def end_to_end(name: str, records: list, window_s: float, setup_s: float):
-    """The end-to-end metrics, each over all the work and all the time of the
-    window. None where the cell's records hold nothing for it."""
-    done = [r for r in records if r["ok"]]
-    if name == "setup_s":
-        return setup_s
-    if name == "batch_query_s":
-        return window_s / len(done) if done else None
-    if name == "sql_queries_per_s":
-        return len(done) / window_s if done else None
-    if name == "sql_latency_p95_s":
-        return percentile([r["t1"] - r["t0"] for r in records], 0.95) \
-            if records else None
-    raise KeyError(f"run.py has no definition for end-to-end metric {name!r}")
 
 
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
@@ -245,18 +215,15 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
              "traced": [tracer.t_start, tracer.t_stop],
              "compiles_in_window": window_compile["programs"],
              "host_syncs": syncs, "batches": batches, "peak_bytes": peak,
-             "peaks": peaks, "scan_bytes": state.get("scan_bytes", {})}
+             "setup_s": setup_s, "peaks": peaks,
+             "scan_bytes": state.get("scan_bytes", {})}
     metrics = {}
-    if trace:
-        for m in cell["per_layer"]:
-            value = load_module("metrics", m["name"]).read(facts)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    else:
-        for m in cell["end_to_end"]:
-            value = end_to_end(m["name"], records, window_s, setup_s)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    for m in cell["per_layer"] if trace else cell["end_to_end"]:
+        # a reader that finds nothing to read returns None: the metric is
+        # then left out of the line, never reported as 0
+        value = load_module("metrics", m["name"]).read(facts)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     device = {"platform": devs[0].platform, "kind": kind, "count": len(devs),
               "memory_peak_bytes": peak}

@@ -21,16 +21,16 @@ stretch of the window in which no device op ran, and is labelled by the
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import sys
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench:"
 WINDOW_SPAN = "bench:window"
-#: gaps shorter than this are not listed one by one (they still count as idle)
-MIN_GAP_NS = 1_000
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -92,6 +92,25 @@ def label_gap(gap: list, spans: list) -> str:
     return best
 
 
+def short_op(module: str, op: str) -> str:
+    """``<program>/<op>``: the program's name without its fingerprint and
+    the op's own name without the HLO text behind it."""
+    return f"{module.split('(', 1)[0]}/{op.split(' = ', 1)[0].strip()}"[:160]
+
+
+def name_ops(ops: list, modules: list) -> list:
+    """Each op event named by the program whose event on the modules line
+    holds its start."""
+    modules = sorted(modules, key=lambda m: m[1])
+    starts = [m[1] for m in modules]
+    out = []
+    for name, s, e in ops:
+        i = bisect.bisect_right(starts, s) - 1
+        inside = i >= 0 and s < modules[i][2]
+        out.append((short_op(modules[i][0] if inside else "?", name), s, e))
+    return out
+
+
 def extract(profile) -> dict:
     """Plain lists out of a ``ProfileData``: per device plane the op events
     ``(name, start_ns, end_ns)``, and the benchmark's spans from every other
@@ -99,11 +118,14 @@ def extract(profile) -> dict:
     devices, spans = {}, []
     for plane in profile.planes:
         if plane.name.startswith(DEVICE_PREFIX):
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    devices.setdefault(plane.name, []).extend(
-                        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
-                        for ev in line.events)
+            by_line = {line.name: [(ev.name, ev.start_ns,
+                                    ev.start_ns + ev.duration_ns)
+                                   for ev in line.events]
+                       for line in plane.lines
+                       if line.name in (OPS_LINE, MODULES_LINE)}
+            if by_line.get(OPS_LINE):
+                devices[plane.name] = name_ops(by_line[OPS_LINE],
+                                               by_line.get(MODULES_LINE, []))
         else:
             for line in plane.lines:
                 for ev in line.events:
@@ -131,8 +153,6 @@ def reduce_events(devices: dict, spans: list, top: int = 10) -> dict | None:
             if d > 0:
                 op_ns[name] = op_ns.get(name, 0) + d
         for g in gaps(merged, lo, hi):
-            if g[1] - g[0] < MIN_GAP_NS:
-                continue
             name = label_gap(g, inner)
             gap_ns[name] = gap_ns.get(name, 0) + (g[1] - g[0])
             longest.append((g[1] - g[0], name))
