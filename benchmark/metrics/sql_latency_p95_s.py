@@ -1,7 +1,10 @@
-"""End to end, SQL cells: the 95th percentile of POST-to-last-byte latency over
-ALL requests of the window, failed ones with the time they took to fail
+"""Entry layer, SQL cells: the 95th percentile of POST-to-last-byte latency
+over ALL requests of the window, failed ones with the time they took to fail
 (linear interpolation between closest ranks, as numpy's default). The sample
-count is on the evidence line ``latency_s``."""
+count is on the evidence line ``latency_s``. A per-layer metric
+(``sql_latency_p95_s.layer``): a closed loop that keeps the device busy is at
+capacity, where the rate is the end-to-end metric and the tail swings with the
+order in which the streams' requests meet (PERF.md section 2)."""
 
 
 def read(facts: dict):

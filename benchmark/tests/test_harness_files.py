@@ -70,8 +70,7 @@ def test_every_per_layer_metric_has_a_reader(spec):
 def test_every_end_to_end_metric_has_a_reader(spec):
     facts = {"records": [{"ok": True, "t0": 0.0, "t1": 2.0}], "window_s": 4.0,
              "setup_s": 9.0}
-    want = {"setup_s": 9.0, "batch_query_s": 4.0, "sql_queries_per_s": 0.25,
-            "sql_latency_p95_s": 2.0}
+    want = {"setup_s": 9.0, "batch_query_s": 4.0, "sql_queries_per_s": 0.25}
     for m in spec["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
@@ -80,6 +79,14 @@ def test_every_end_to_end_metric_has_a_reader(spec):
         empty = dict(facts, records=[])
         if m["name"] != "setup_s":
             assert harness.load_module("metrics", m["name"]).read(empty) is None
+
+
+def test_latency_percentile_interpolates():
+    read = harness.load_module("metrics", "sql_latency_p95_s.layer").read
+    recs = [{"ok": True, "t0": 0.0, "t1": float(i)} for i in range(1, 22)]
+    assert read({"records": recs}) == 20.0
+    assert read({"records": recs[:2]}) == pytest.approx(1.95)
+    assert read({"records": []}) is None
 
 
 def test_names_units_and_lengths(spec):
