@@ -6,9 +6,9 @@ Two numbers come out of every comparison, each held to a limit of its own:
   from the reference's, a missing column or a different row count counted as
   every row. The configurations promise exact results: the limit is 0.
 - ``float_gap``: the widest gap of a float cell, ``|got - want| / max(1,
-  |want|)`` (the repo's own ``compare_frames`` measure, as a number and not a
-  verdict). Its limit stands between what sound runs of the program read and
-  what the reference computed in float32 reads (PERF.md section 2).
+  |want|)``. A configuration whose answers hold no float (DECIMAL sums are
+  exact, and compared as exact cells) states no limit for it and it is not
+  reported.
 
 ``in_order`` compares row i with row i (an ORDER BY whose order the driver
 itself produces); otherwise both frames are first put into one total order by
@@ -93,30 +93,51 @@ class TieError(AssertionError):
 
 
 def head(df: pd.DataFrame, order: tuple, ascending: tuple, limit) -> pd.DataFrame:
-    """The reference's rows under ORDER BY ... LIMIT (copy of the repo's
-    ``sqlgate.oracle_head``)."""
-    if limit is None or len(df) <= limit:
+    """The reference's rows under ORDER BY ... LIMIT, NULLs ordered as Spark
+    orders them: first where a key ascends, last where it descends."""
+    if not order:
         return df.reset_index(drop=True)
-    by = list(order)
-    if df[by].isna().any().any():
-        raise TieError("NULL in ORDER BY keys under an effective LIMIT")
-    full = df.sort_values(by, ascending=list(ascending),
-                          kind="mergesort").reset_index(drop=True)
-    boundary = full.iloc[limit - 1][by]
-    if (full.iloc[limit][by] == boundary).all():
-        tie = full[(full[by] == boundary).all(axis=1)]
-        if len(tie.drop_duplicates()) > 1:
+    rows = list(zip(*[df[c].tolist() for c in order]))
+
+    def key(i: int) -> tuple:
+        out = []
+        for v, asc in zip(rows[i], ascending):
+            null = is_null(v)
+            rank = (0 if null else 1) if asc else (1 if null else 0)
+            num = 0 if null else v           # keys are numbers, Decimal among them
+            out.append((rank, num if asc else -num))
+        return tuple(out)
+
+    idx = sorted(range(len(df)), key=key)
+    if limit is None or len(df) <= limit:
+        return df.iloc[idx].reset_index(drop=True)
+    if key(idx[limit]) == key(idx[limit - 1]):
+        tie = df.iloc[[i for i in idx if key(i) == key(idx[limit])]]
+        if len(tie.astype(str).drop_duplicates()) > 1:
             raise TieError("non-identical rows tie at the LIMIT boundary")
-    return full.iloc[:limit].reset_index(drop=True)
+    return df.iloc[idx[:limit]].reset_index(drop=True)
 
 
-def to_float32(frames: dict) -> dict:
-    """The control's data: every float64 column rounded to float32, so that
-    the reference run over it computes in float32, the nearest precision
-    below the float64 the configurations state."""
+def money_in(precision: str, frames: dict, schemas: dict) -> dict:
+    """The control's data: every DECIMAL column (whole cents in the frames)
+    rounded to ``float32`` or ``bfloat16`` and kept as float32, so that the
+    reference run over it sums money in that precision where the
+    configuration promises exact DECIMAL sums."""
+    def low(s: pd.Series) -> pd.Series:
+        f = s.astype("Float32")
+        if precision == "bfloat16":
+            u = f.array._data.view(np.uint32)          # round to nearest even
+            u = (u + 0x7FFF + ((u >> 16) & 1)) & np.uint32(0xFFFF0000)
+            f = pd.Series(pd.arrays.FloatingArray(u.view(np.float32), f.array._mask),
+                          index=s.index)
+        elif precision != "float32":
+            raise ValueError(f"no control in {precision!r}")
+        return f
+
     out = {}
     for name, df in frames.items():
-        cols = {c: (df[c].astype(np.float32) if df[c].dtype == np.float64 else df[c])
-                for c in df.columns}
-        out[name] = pd.DataFrame(cols)
+        money = {c for c, t, _ in schemas[name] if t.startswith("decimal")}
+        out[name] = pd.DataFrame(
+            {c: (low(df[c]) if c in money else df[c]) for c in df.columns},
+            copy=False)
     return out

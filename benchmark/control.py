@@ -2,11 +2,12 @@
 
     python3 benchmark/control.py --workload <cell> --seeds 1 2 3
 
-The control is the plain reference put in the program's place and computed
-in float32, the nearest precision below the float64 that the configurations
-state. Its answers go through the same ``check`` as the window's answers.
-It needs no chip and the benchmark's own runs do not run it; PERF.md section 2
-gives the readings that each limit was set from.
+The control is the plain reference put in the program's place with money in
+the lower precision that the cell's traffic file names (``control_money``),
+where the configuration promises exact DECIMAL sums. Its answers go through
+the same ``check`` as the window's answers. It needs no chip and the
+benchmark's own runs do not run it; PERF.md section 2 gives the readings that
+each limit was set from.
 """
 
 from __future__ import annotations
@@ -47,7 +48,7 @@ def main() -> None:
         passed.append(out["correct"])
     if any(passed):
         sys.exit("control.py: a control came out correct: the comparison "
-                 "cannot tell float32 from float64")
+                 "cannot tell the lower precision from the exact sums")
 
 
 if __name__ == "__main__":

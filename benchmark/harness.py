@@ -111,8 +111,9 @@ class Tracer:
     driver at the points its traffic file names. Off in a ``--trace 0`` run:
     every method is then a no-op."""
 
-    def __init__(self, on: bool) -> None:
+    def __init__(self, on: bool, keep: str | None = None) -> None:
         self.on = on
+        self.keep = keep
         self.dir = None
         self.t_start = self.t_stop = None
         self._stack = contextlib.ExitStack()
@@ -138,17 +139,17 @@ class Tracer:
 
     def reduce(self) -> dict | None:
         """The trace's reduction; the trace's files are removed (a run
-        writes little to disk) unless BENCH_KEEP_TRACE names a directory."""
+        writes little to disk), the ``.xplane.pb`` first copied to the
+        directory that ``--keep-trace`` names, if it names one."""
         if self.dir is None or self.t_stop is None:
             return None
         from benchmark import trace_reduce
 
         try:
             path = trace_reduce.find_xplane(self.dir)
-            keep = os.environ.get("BENCH_KEEP_TRACE")
-            if keep:
-                os.makedirs(keep, exist_ok=True)
-                shutil.copy(path, keep)
+            if self.keep:
+                os.makedirs(self.keep, exist_ok=True)
+                shutil.copy(path, self.keep)
             return trace_reduce.reduce_file(path)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
@@ -161,7 +162,7 @@ def memory_peak_bytes(devs) -> int:
 
 
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
-             t_start: float) -> dict:
+             t_start: float, keep_trace: str | None = None) -> dict:
     """Everything of a run after the look for a chip: set-up, window, the
     comparison, the metrics. Returns the result line as a dict."""
     from benchmark.compile_clock import CompileClock
@@ -176,7 +177,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
         from auron_tpu.utils.profiling import EngineCounters
 
         counters = EngineCounters.install()
-    tracer = Tracer(trace)
+    tracer = Tracer(trace, keep_trace)
     kind = devs[0].device_kind
     peaks = chip_peaks(kind) if devs[0].platform == "tpu" else None
 

@@ -1,0 +1,67 @@
+"""TPC-DS query 42 with the specification's qualification parameters
+(MONTH 11, YEAR 2000, MANAGER 1), as the two Spark stages of ``benchmark/star_plan.py``:
+
+    select dt.d_year, item.i_category_id, item.i_category,
+           sum(ss_ext_sales_price)
+    from date_dim dt, store_sales, item
+    where dt.d_date_sk = store_sales.ss_sold_date_sk
+      and store_sales.ss_item_sk = item.i_item_sk
+      and item.i_manager_id = 1 and dt.d_moy = 11 and dt.d_year = 2000
+    group by dt.d_year, item.i_category_id, item.i_category
+    order by sum(ss_ext_sales_price) desc, dt.d_year, item.i_category_id,
+             item.i_category
+    limit 100
+
+The reference is plain pandas over whole cents; it imports nothing of the
+program.
+"""
+
+from __future__ import annotations
+
+import decimal
+import functools
+
+import pandas as pd
+
+from benchmark import star_plan
+
+PLAN = {
+    "name": "q42",
+    "date_filter": {"d_moy": 11, "d_year": 2000},
+    "item_filter": {"i_manager_id": 1},
+    "keys": [("date_dim", "d_year", "d_year"),
+             ("item", "i_category_id", "i_category_id"),
+             ("item", "i_category", "i_category")],
+    "sum": ("ss_ext_sales_price", "sum_sales"),
+    "output": ["d_year", "i_category_id", "i_category", "sum_sales"],
+    "order": [("sum_sales", False), ("d_year", True), ("i_category_id", True),
+              ("i_category", True)],
+    "limit": 100,
+}
+ORDER = tuple(c for c, _ in PLAN["order"])
+ASCENDING = tuple(a for _, a in PLAN["order"])
+LIMIT = PLAN["limit"]
+#: the answer's rows come in the ORDER BY's order, made by the driver's side
+IN_ORDER = True
+#: what the query's text must read once, whatever plan the engine builds
+SCAN_COLUMNS = {
+    "store_sales": ["ss_sold_date_sk", "ss_item_sk", "ss_ext_sales_price"],
+    "date_dim": ["d_date_sk", "d_year", "d_moy"],
+    "item": ["i_item_sk", "i_category_id", "i_category", "i_manager_id"],
+}
+ingest = star_plan.ingest
+run = functools.partial(star_plan.run, PLAN)
+
+
+def reference(frames: dict, params: dict | None = None) -> pd.DataFrame:
+    """The answer's rows before ORDER BY and LIMIT, sums as exact decimals."""
+    dd, it = frames["date_dim"], frames["item"]
+    m = dd[(dd.d_moy == 11) & (dd.d_year == 2000)].merge(
+        frames["store_sales"].dropna(subset=["ss_sold_date_sk"]),
+        left_on="d_date_sk", right_on="ss_sold_date_sk")
+    m = m.merge(it[it.i_manager_id == 1], left_on="ss_item_sk", right_on="i_item_sk")
+    g = (m.groupby(["d_year", "i_category_id", "i_category"], as_index=False, dropna=False)
+          .agg(cents=("ss_ext_sales_price", lambda s: s.sum(min_count=1))))
+    g["sum_sales"] = [None if pd.isna(c) else decimal.Decimal(int(round(c))).scaleb(-2)
+                  for c in g.pop("cents")]
+    return g

@@ -1,14 +1,14 @@
-"""TPC-DS query 3 with the specification's qualification parameters (MANUFACT
-128, MONTH 11), as the two Spark stages of ``benchmark/star_plan.py``:
+"""TPC-DS query 52 with the specification's qualification parameters
+(MONTH 11, YEAR 2000, MANAGER 1), as the two Spark stages of ``benchmark/star_plan.py``:
 
     select dt.d_year, item.i_brand_id brand_id, item.i_brand brand,
-           sum(ss_ext_sales_price) sum_agg
+           sum(ss_ext_sales_price) ext_price
     from date_dim dt, store_sales, item
     where dt.d_date_sk = store_sales.ss_sold_date_sk
       and store_sales.ss_item_sk = item.i_item_sk
-      and item.i_manufact_id = 128 and dt.d_moy = 11
+      and item.i_manager_id = 1 and dt.d_moy = 11 and dt.d_year = 2000
     group by dt.d_year, item.i_brand, item.i_brand_id
-    order by dt.d_year, sum_agg desc, brand_id
+    order by dt.d_year, ext_price desc, brand_id
     limit 100
 
 The reference is plain pandas over whole cents; it imports nothing of the
@@ -25,14 +25,14 @@ import pandas as pd
 from benchmark import star_plan
 
 PLAN = {
-    "name": "q3",
-    "date_filter": {"d_moy": 11},
-    "item_filter": {"i_manufact_id": 128},
+    "name": "q52",
+    "date_filter": {"d_moy": 11, "d_year": 2000},
+    "item_filter": {"i_manager_id": 1},
     "keys": [("date_dim", "d_year", "d_year"), ("item", "i_brand", "brand"),
              ("item", "i_brand_id", "brand_id")],
-    "sum": ("ss_ext_sales_price", "sum_agg"),
-    "output": ["d_year", "brand_id", "brand", "sum_agg"],
-    "order": [("d_year", True), ("sum_agg", False), ("brand_id", True)],
+    "sum": ("ss_ext_sales_price", "ext_price"),
+    "output": ["d_year", "brand_id", "brand", "ext_price"],
+    "order": [("d_year", True), ("ext_price", False), ("brand_id", True)],
     "limit": 100,
 }
 ORDER = tuple(c for c, _ in PLAN["order"])
@@ -44,7 +44,7 @@ IN_ORDER = True
 SCAN_COLUMNS = {
     "store_sales": ["ss_sold_date_sk", "ss_item_sk", "ss_ext_sales_price"],
     "date_dim": ["d_date_sk", "d_year", "d_moy"],
-    "item": ["i_item_sk", "i_brand_id", "i_brand", "i_manufact_id"],
+    "item": ["i_item_sk", "i_brand_id", "i_brand", "i_manager_id"],
 }
 ingest = star_plan.ingest
 run = functools.partial(star_plan.run, PLAN)
@@ -53,14 +53,12 @@ run = functools.partial(star_plan.run, PLAN)
 def reference(frames: dict, params: dict | None = None) -> pd.DataFrame:
     """The answer's rows before ORDER BY and LIMIT, sums as exact decimals."""
     dd, it = frames["date_dim"], frames["item"]
-    m = dd[dd.d_moy == 11].merge(
+    m = dd[(dd.d_moy == 11) & (dd.d_year == 2000)].merge(
         frames["store_sales"].dropna(subset=["ss_sold_date_sk"]),
         left_on="d_date_sk", right_on="ss_sold_date_sk")
-    m = m.merge(it[it.i_manufact_id == 128], left_on="ss_item_sk",
-                right_on="i_item_sk")
-    g = (m.groupby(["d_year", "i_brand_id", "i_brand"], as_index=False,
-                   dropna=False)
+    m = m.merge(it[it.i_manager_id == 1], left_on="ss_item_sk", right_on="i_item_sk")
+    g = (m.groupby(["d_year", "i_brand_id", "i_brand"], as_index=False, dropna=False)
           .agg(cents=("ss_ext_sales_price", lambda s: s.sum(min_count=1))))
-    g["sum_agg"] = [None if pd.isna(c) else decimal.Decimal(int(round(c))).scaleb(-2)
-                    for c in g.pop("cents")]
+    g["ext_price"] = [None if pd.isna(c) else decimal.Decimal(int(round(c))).scaleb(-2)
+                  for c in g.pop("cents")]
     return g.rename(columns={"i_brand_id": "brand_id", "i_brand": "brand"})

@@ -37,6 +37,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--keep-trace", metavar="DIR", default=None,
+                    help="with --trace 1: keep the profiler's .xplane.pb in DIR "
+                         "(to read by hand, or with trace_reduce.py)")
     args = ap.parse_args()
 
     from benchmark import harness
@@ -46,7 +49,7 @@ def main() -> None:
 
     devs = harness.require_tpu(cell["chips"])
     result = harness.run_cell(cell, args.seed, args.seconds, bool(args.trace),
-                              devs, T_START)
+                              devs, T_START, args.keep_trace)
     for name, c in result["compared"].items():
         print(f"compared {name}: {c['value']!r} (limit {c['limit']!r})",
               file=sys.stderr)
