@@ -2,7 +2,7 @@
 
 - The control: the references put in the program's place with money summed in
   float32 have to come out not correct where the sums outgrow float32's 24
-  bits (the four-query mix at a fifth of the cell's rows and up), and the
+  bits (the four-query mix at a quarter of the cell's rows), and the
   exact references in the same place correct.
 - The faults: a whole run is driven at a tiny size on the CPU (everything of
   ``run_cell``; only the look for a chip is skipped) with the timed path
