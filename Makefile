@@ -37,8 +37,8 @@ perfcheck:
 # tiny q3-class pipeline in no-obs / obs-off / flight-recorder subprocess
 # configurations and fails when obs-off exceeds 2% or the always-on
 # flight recorder exceeds 5% wall over the no-obs baseline; also
-# sanity-checks a full-trace run's Perfetto artifact + the span-vs-
-# metrics op-seconds cross-check (tools/obscheck.py).
+# sanity-checks a full-trace run's Perfetto artifact and that
+# obs.window_summary of the replay is complete (tools/obscheck.py).
 obscheck:
 	JAX_PLATFORMS=cpu python tools/obscheck.py
 

@@ -17,6 +17,7 @@ from typing import Any
 
 import pyarrow as pa
 
+from auron_tpu import obs
 from auron_tpu.runtime.task import TaskRuntime
 
 _lock = threading.Lock()
@@ -63,11 +64,12 @@ def next_batch_c(handle: int, array_ptr: int, schema_ptr: int) -> int:
     (release callbacks transfer ownership per the C data interface spec).
     Returns 1 on a batch, 0 at end of stream. The batch's buffers are
     handed off by reference — the serde-free twin of next_batch_ipc."""
-    rb = next_batch(handle)
-    if rb is None:
-        return 0
-    rb._export_to_c(int(array_ptr), int(schema_ptr))
-    return 1
+    with obs.span("next_batch_c", cat="entry"):
+        rb = next_batch(handle)
+        if rb is None:
+            return 0
+        rb._export_to_c(int(array_ptr), int(schema_ptr))
+        return 1
 
 
 def put_resource_shuffle(key: str, manifest: bytes) -> None:
@@ -121,32 +123,33 @@ def call_native(task_bytes: bytes, extra_resources: dict | None = None) -> int:
     in-process serving path's isolation primitive: two concurrent queries
     each hand their own stage output under the same rid without racing on
     put_resource/remove_resource (the C ABI keeps using the global map)."""
-    with _lock:
-        resources = dict(_resources)
-    if extra_resources:
-        resources.update(extra_resources)
-    # session-set obs knobs apply inside TaskRuntime.__init__, BEFORE its
-    # pump thread starts (a post-start apply would race the task's own
-    # span installation); only the HTTP service starts lazily here
-    rt = TaskRuntime(task_bytes, resources=resources, shared=_resources)
-    try:
-        # conf-gated observability service (auron/src/http analog)
-        from auron_tpu.utils.httpsvc import maybe_start_from_conf
-
-        maybe_start_from_conf(rt.ctx.conf)
-        h = next(_next_handle)
+    with obs.span("call_native", cat="entry"):
         with _lock:
-            _runtimes[h] = rt
-    except BaseException:
-        # the runtime's pump thread is already running: a failure before
-        # the handle is published must cancel/join it, or it leaks for
-        # the life of the process (R11 task-runtime protocol)
+            resources = dict(_resources)
+        if extra_resources:
+            resources.update(extra_resources)
+        # session-set obs knobs apply inside TaskRuntime.__init__, BEFORE its
+        # pump thread starts (a post-start apply would race the task's own
+        # span installation); only the HTTP service starts lazily here
+        rt = TaskRuntime(task_bytes, resources=resources, shared=_resources)
         try:
-            rt.finalize()
-        except Exception:  # noqa: BLE001  # auronlint: disable=R12 -- unwind: the original failure is the error; finalize's own is secondary
-            pass
-        raise
-    return h
+            # conf-gated observability service (auron/src/http analog)
+            from auron_tpu.utils.httpsvc import maybe_start_from_conf
+
+            maybe_start_from_conf(rt.ctx.conf)
+            h = next(_next_handle)
+            with _lock:
+                _runtimes[h] = rt
+        except BaseException:
+            # the runtime's pump thread is already running: a failure before
+            # the handle is published must cancel/join it, or it leaks for
+            # the life of the process (R11 task-runtime protocol)
+            try:
+                rt.finalize()
+            except Exception:  # noqa: BLE001  # auronlint: disable=R12 -- unwind: the original failure is the error; finalize's own is secondary
+                pass
+            raise
+        return h
 
 
 def native_task(task_bytes: bytes, extra_resources: dict | None = None):
@@ -190,21 +193,26 @@ class _NativeTask:
 
 
 def next_batch(handle: int) -> pa.RecordBatch | None:
+    """The Arrow materialisation is entry work: the span's self time is
+    what the host spends on it once the queue wait and the device reads
+    inside it are taken out."""
     rt = _runtimes[handle]
-    return rt.next_arrow()
+    with obs.span("next_batch", cat="entry"):
+        return rt.next_arrow()
 
 
 def next_batch_ipc(handle: int) -> bytes | None:
     """IPC-serialized variant for out-of-process hosts."""
-    rb = next_batch(handle)
-    if rb is None:
-        return None
     import io
 
-    sink = io.BytesIO()
-    with pa.ipc.new_stream(sink, rb.schema) as w:
-        w.write_batch(rb)
-    return sink.getvalue()
+    with obs.span("next_batch_ipc", cat="entry"):
+        rb = next_batch(handle)
+        if rb is None:
+            return None
+        sink = io.BytesIO()
+        with pa.ipc.new_stream(sink, rb.schema) as w:
+            w.write_batch(rb)
+        return sink.getvalue()
 
 
 _metrics_sink = None
@@ -225,7 +233,8 @@ def finalize_native(handle: int) -> dict:
         rt = _runtimes.pop(handle, None)
     if rt is None:
         return {}
-    snap = rt.finalize()
+    with obs.span("finalize_native", cat="entry"):
+        snap = rt.finalize()
     if _metrics_sink is not None:
         try:
             _metrics_sink(snap)

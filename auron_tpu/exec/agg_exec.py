@@ -1688,9 +1688,12 @@ def _reduce_columns(sel, keys, agg_cols, raw, cfg, collect_cb=None, agg_aux=None
         )
     else:
         if words is None:
-            words = S.key_words(keys)
+            with jax.named_scope("auron.agg.key_words"):
+                words = S.key_words(keys)
         if merge_cap_a is not None:
-            seg = S.segment_merged(list(words), sel, merge_cap_a, fp_bits, fp)
+            with jax.named_scope("auron.agg.merge"):
+                seg = S.segment_merged(list(words), sel, merge_cap_a,
+                                       fp_bits, fp)
         else:
             seg = S.segment_by_keys(
                 list(words), sel, order, fp, host_sort=host_sort,
@@ -1700,24 +1703,27 @@ def _reduce_columns(sel, keys, agg_cols, raw, cfg, collect_cb=None, agg_aux=None
     order = seg.order
 
     out_vals: list[ColumnVal] = []
-    slot = jnp.clip(seg.group_of_slot, 0, cap - 1)
-    group_valid = jnp.arange(cap, dtype=jnp.int32) < seg.num_groups
-    if n_keys == 0:
-        # a global agg always yields exactly one group, even over 0 rows
-        group_valid = jnp.zeros(cap, bool).at[0].set(True)
-    for kv in keys:
-        sorted_vals = kv.values[order]
-        sorted_mask = kv.validity[order]
-        out_vals.append(
-            ColumnVal(sorted_vals[slot], sorted_mask[slot] & group_valid, kv.dtype, kv.dict)
-        )
+    with jax.named_scope("auron.agg.key_gather"):
+        slot = jnp.clip(seg.group_of_slot, 0, cap - 1)
+        group_valid = jnp.arange(cap, dtype=jnp.int32) < seg.num_groups
+        if n_keys == 0:
+            # a global agg always yields exactly one group, even over 0 rows
+            group_valid = jnp.zeros(cap, bool).at[0].set(True)
+        for kv in keys:
+            sorted_vals = kv.values[order]
+            sorted_mask = kv.validity[order]
+            out_vals.append(
+                ColumnVal(sorted_vals[slot], sorted_mask[slot] & group_valid,
+                          kv.dtype, kv.dict)
+            )
     if agg_aux is None:
         agg_aux = (None,) * len(agg_specs)
     for (a, in_t), cols, aux in zip(agg_specs, agg_cols, agg_aux):
-        out_vals.extend(
-            _reduce_one(a, in_t, cols, order, seg, cap, raw, group_valid,
-                        collect_cb, aux)
-        )
+        with jax.named_scope(f"auron.agg.reduce.{a.func}"):
+            out_vals.extend(
+                _reduce_one(a, in_t, cols, order, seg, cap, raw, group_valid,
+                            collect_cb, aux)
+            )
     return out_vals, group_valid, seg
 
 
@@ -1917,10 +1923,12 @@ def _reduce_arrays_impl(sel, key_v, key_m, agg_v, agg_m, agg_aux, order, words,
         # sentinel): cached on the state batch so steady-state probing
         # never re-hashes the invariant state keys
         cap = sel.shape[0]
-        slot = jnp.clip(seg.group_of_slot, 0, cap - 1)
-        group_fp = jnp.where(
-            group_valid, seg.fp_sorted[slot], jnp.uint64(0xFFFFFFFFFFFFFFFF)
-        )
+        with jax.named_scope("auron.agg.group_fp"):
+            slot = jnp.clip(seg.group_of_slot, 0, cap - 1)
+            group_fp = jnp.where(
+                group_valid, seg.fp_sorted[slot],
+                jnp.uint64(0xFFFFFFFFFFFFFFFF)
+            )
     else:
         group_fp = None
     return (

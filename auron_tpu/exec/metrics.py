@@ -63,11 +63,13 @@ class MetricNode:
         per-batch multiplicities (sync-budget checks divide site counts by
         these), not just totals.
 
-        The SAME dt is handed to the span timeline (obs.note_op): the
-        flight recorder's per-operator compute segments and this metric
-        tree are two renderings of one measurement, which is what lets
-        bench/perf_gate cross-check span-derived op totals against the
-        MetricNode rollup without tolerance games."""
+        The SAME dt is handed to the flight recorder (obs.note_op) as an
+        ``op`` event: the recorder's per-operator segments and this
+        metric tree are two renderings of one measurement. That
+        measurement is DISPATCH time, and a timer that stays open across
+        a ``yield`` also bills the consumer's time to the producer
+        (docs/observability.md): where the host's time went is read from
+        the regions, ``obs.window_summary``."""
         t0 = time.perf_counter_ns()
         try:
             yield

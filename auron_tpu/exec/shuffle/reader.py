@@ -26,6 +26,7 @@ from typing import Iterator
 import numpy as np
 import pyarrow as pa
 
+from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import (
     Batch,
@@ -43,6 +44,11 @@ from auron_tpu.exec.shuffle.format import (
     is_v2_payload,
     shuffle_encoding_enabled,
 )
+
+
+#: the argument of every ``exchange:read`` span around an emit (shared:
+#: nothing writes to it)
+_EMIT = {"phase": "emit"}
 
 
 class IpcReaderExec(ExecOperator):
@@ -82,7 +88,10 @@ class IpcReaderExec(ExecOperator):
         for payload in payload_iter:
             ctx.check_cancelled()
             ctx.metrics.add("shuffle_bytes_read", len(payload))
-            with ctx.metrics.timer("decode_time"):
+            with ctx.metrics.timer("decode_time"), \
+                    obs.span("read", cat="exchange") as sp:
+                if sp is not None:
+                    sp.arg = {"phase": "decode", "bytes": len(payload)}
                 if is_v2_payload(payload):
                     asm.add_v2(decode_block_v2(payload))
                 else:
@@ -92,12 +101,14 @@ class IpcReaderExec(ExecOperator):
                         for rb in r:
                             asm.add_arrow(rb)
             if asm.rows >= target:
-                with ctx.metrics.timer("decode_time"):
+                with ctx.metrics.timer("decode_time"), \
+                        obs.span("read", cat="exchange", arg=_EMIT):
                     b = asm.emit()
                 if b is not None:
                     yield b
         if asm.rows:
-            with ctx.metrics.timer("decode_time"):
+            with ctx.metrics.timer("decode_time"), \
+                    obs.span("read", cat="exchange", arg=_EMIT):
                 b = asm.emit()
             if b is not None:
                 yield b

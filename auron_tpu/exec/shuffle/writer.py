@@ -22,6 +22,7 @@ import numpy as np
 import pyarrow as pa
 from jax import lax
 
+from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import Batch, DeviceBatch
 from auron_tpu.exec.base import ExecOperator, ExecutionContext
@@ -88,7 +89,8 @@ class ShuffleWriterExec(ExecOperator):
                 staging.add_all(parts)
 
             offsets = [0]
-            with ctx.metrics.timer("write_time"):
+            with ctx.metrics.timer("write_time"), \
+                    obs.span("write", cat="exchange") as sp:
                 # task-attempt isolation: a speculative duplicate or a
                 # zombie attempt surviving an executor-loss retry may run
                 # CONCURRENTLY with this one against the same deterministic
@@ -121,6 +123,8 @@ class ShuffleWriterExec(ExecOperator):
                     _os.replace(tmp_data, self.data_file)
                     _os.replace(tmp_index, self.index_file)
                     committed = True
+                    if sp is not None:
+                        sp.arg = {"phase": "write", "bytes": offsets[-1]}
                 finally:
                     if not committed:  # don't leak .attempt-* temps
                         for p in (tmp_data, tmp_index):
@@ -174,12 +178,15 @@ class _ShuffleStaging:
     def _flush(self, pid: int) -> None:
         if not self.staged[pid]:
             return
-        with self.ctx.metrics.timer("compress_time"):
+        with self.ctx.metrics.timer("compress_time"), \
+                obs.span("write", cat="exchange") as sp:
             # conf threaded: spill() runs on the requesting task's thread
             blk = encode_shuffle_block(
                 align_dict_batches(self.staged[pid]),
                 conf=self.ctx.conf, metrics=self.ctx.metrics,
             )
+            if sp is not None:
+                sp.arg = {"phase": "compress", "bytes": len(blk)}
         self.ctx.metrics.add("shuffle_bytes_raw",
                              self.staged_bytes[pid])
         self.ctx.metrics.add("shuffle_bytes_written", len(blk))
@@ -340,14 +347,20 @@ class RssShuffleWriterExec(ExecOperator):
 
         def flush(pid: int):
             if staged[pid]:
-                with ctx.metrics.timer("compress_time"):
+                with ctx.metrics.timer("compress_time"), \
+                        obs.span("write", cat="exchange") as sp:
                     blk = encode_shuffle_block(
                         align_dict_batches(staged[pid]),
                         conf=ctx.conf, metrics=ctx.metrics,
                     )
+                    if sp is not None:
+                        sp.arg = {"phase": "compress", "bytes": len(blk)}
                 ctx.metrics.add("shuffle_bytes_raw", staged_bytes[pid])
                 ctx.metrics.add("shuffle_bytes_written", len(blk))
-                with ctx.metrics.timer("push_time"):
+                with ctx.metrics.timer("push_time"), \
+                        obs.span("write", cat="exchange") as sp:
+                    if sp is not None:
+                        sp.arg = {"phase": "push", "bytes": len(blk)}
                     push(pid, blk)
                 ctx.metrics.add("data_size", len(blk))
                 staged[pid].clear()
@@ -470,6 +483,12 @@ def finish_partition_batch(
     return out
 
 
+def _repart_arg(parts) -> dict:
+    """A repartition span's argument: the Arrow bytes it handed on."""
+    return {"phase": "repart",
+            "bytes": sum(rb.nbytes for _, rb in parts or ())}
+
+
 def partitioned_stream(child_iter, partitioning: Partitioning, ctx):
     """One-deep stage/finish pipeline over a batch stream: batch i's
     device->host transfer rides behind batch i+1's dispatch, so the
@@ -477,16 +496,21 @@ def partitioned_stream(child_iter, partitioning: Partitioning, ctx):
     pending = None
     for b in child_iter:
         ctx.check_cancelled()
-        with ctx.metrics.timer("repart_time", count=True):
+        with ctx.metrics.timer("repart_time", count=True), \
+                obs.span("write", cat="exchange") as sp:
             cur = stage_partition_batch(b, partitioning, ctx)
-            parts = (
-                finish_partition_batch(pending, partitioning, ctx)
-                if pending is not None else None
-            )
+            parts = None
+            if pending is not None:
+                parts = finish_partition_batch(pending, partitioning, ctx)
+            if sp is not None:
+                sp.arg = _repart_arg(parts)
         pending = cur
         if parts is not None:
             yield parts
     if pending is not None:
-        with ctx.metrics.timer("repart_time"):
+        with ctx.metrics.timer("repart_time"), \
+                obs.span("write", cat="exchange") as sp:
             parts = finish_partition_batch(pending, partitioning, ctx)
+            if sp is not None:
+                sp.arg = _repart_arg(parts)
         yield parts

@@ -193,16 +193,19 @@ class MemManager:
         NEVER against the executing thread's ambient span."""
         if obs.core._mode == obs.MODE_OFF:  # keep the no-obs path bare
             return consumer.spill()
-        sp = self._owner_spans.get(id(consumer))
+        owner = self._owner_spans.get(id(consumer))
         t0 = time.perf_counter_ns()
-        with obs.use_span(sp):
+        # parent=owner (or None) is EXPLICIT: the spill's own span is what
+        # rides the executing thread while consumer.spill() runs
+        arg = {"consumer": consumer.name}
+        with obs.span("spill", cat="spill", parent=owner, arg=arg):
             freed = consumer.spill()
-        if freed:
-            # freed==0 attempts are not spills: num_spills skips them,
-            # and the two exported counts must agree (/metrics.prom vs
-            # /queries)
-            obs.note_spill(consumer.name, "spill",
-                           time.perf_counter_ns() - t0, freed, sp=sp)
+            arg["bytes"] = int(freed)
+        # freed==0 attempts are not spills: num_spills skips them, and the
+        # two exported counts must agree (/metrics.prom vs /queries)
+        trace = owner.trace if owner is not None else None
+        if freed and trace is not None and obs.core._mode == obs.MODE_TRACE:
+            trace.note_spill(time.perf_counter_ns() - t0, freed)
         return freed
 
     def update_mem_used(self, consumer: MemConsumer, old_used: int, new_used: int) -> None:
@@ -305,6 +308,13 @@ def _conf_trace_id(conf) -> int:
         return 0
 
 
+def _container_span(what: str, container: str, conf):
+    """A spill container's ``spill:<what>`` span, attributed to the OWNING
+    trace by the conf-carried id (the trace itself may have closed)."""
+    return obs.span(what, cat="spill", arg={"consumer": container},
+                    parent=None, trace_id=_conf_trace_id(conf))
+
+
 class DiskSpill:
     """Disk tier: zstd-compressed Arrow IPC blocks in a temp file (analog of
     the reference's compressed file spills, spill.rs:40-56).
@@ -324,15 +334,13 @@ class DiskSpill:
     def write_table(self, tbl) -> None:
         from auron_tpu.exec.shuffle.format import encode_block
 
-        obs_on = obs.core._mode != obs.MODE_OFF
-        t0 = time.perf_counter_ns() if obs_on else 0
-        blk = encode_block(tbl, conf=self._conf)
-        with open(self.path, "ab") as f:
-            f.write(blk)
-        self._offsets.append(self._offsets[-1] + len(blk))
-        if obs_on:
-            obs.note_spill("DiskSpill", "write", time.perf_counter_ns() - t0,
-                           len(blk), trace_id=_conf_trace_id(self._conf))
+        with _container_span("write", "DiskSpill", self._conf) as sp:
+            blk = encode_block(tbl, conf=self._conf)
+            with open(self.path, "ab") as f:
+                f.write(blk)
+            self._offsets.append(self._offsets[-1] + len(blk))
+            if sp is not None:
+                sp.arg["bytes"] = len(blk)
 
     def read_tables(self):
         from auron_tpu.exec.shuffle.format import decode_blocks
@@ -419,53 +427,50 @@ class HostSpill:
     def write_table(self, tbl) -> None:
         from auron_tpu.exec.shuffle.format import encode_block
 
-        obs_on = obs.core._mode != obs.MODE_OFF
-        t0 = time.perf_counter_ns() if obs_on else 0
-        blk = encode_block(tbl, conf=self._conf)
-        with self._lock:
-            if self._disk is not None:
-                with open(self._disk.path, "ab") as f:
-                    f.write(blk)
-                return
-            self._blocks.append(blk)
-            self._nbytes += len(blk)
-            self._admitted += len(blk)
-            # admission under OUR lock: a concurrent demotion of this spill
-            # must take this lock first, so it always sees these bytes and
-            # forgets exactly _admitted — the ledger can't drift (ADVICE r4:
-            # the post-release admit re-added bytes a demotion had already
-            # forgotten and re-inserted a demoted spill as resident)
-            victims = _host_ledger.admit(self, len(blk), conf=self._conf)
-        if obs_on:
-            obs.note_spill("HostSpill", "write", time.perf_counter_ns() - t0,
-                           len(blk), trace_id=_conf_trace_id(self._conf))
+        with _container_span("write", "HostSpill", self._conf) as sp:
+            blk = encode_block(tbl, conf=self._conf)
+            if sp is not None:
+                sp.arg["bytes"] = len(blk)
+            with self._lock:
+                if self._disk is not None:
+                    with open(self._disk.path, "ab") as f:
+                        f.write(blk)
+                    return
+                self._blocks.append(blk)
+                self._nbytes += len(blk)
+                self._admitted += len(blk)
+                # admission under OUR lock: a concurrent demotion of this
+                # spill must take this lock first, so it always sees these
+                # bytes and forgets exactly _admitted — the ledger can't
+                # drift (ADVICE r4: the post-release admit re-added bytes a
+                # demotion had already forgotten and re-inserted a demoted
+                # spill as resident)
+                victims = _host_ledger.admit(self, len(blk), conf=self._conf)
         for v in victims:  # demote OUTSIDE our lock (lock order spill->ledger)
             v._demote()
 
     def _demote(self) -> None:  # auronlint: thread-root(foreign) -- ledger pressure demotes victims on whichever thread admitted the last block
         """Move resident blocks to disk (ledger pressure)."""
-        obs_on = obs.core._mode != obs.MODE_OFF
-        t0 = time.perf_counter_ns() if obs_on else 0
-        with self._lock:
-            if self._disk is not None or self._blocks is None:
-                return
-            disk = DiskSpill(self._spill_dir, conf=self._conf)
-            try:
-                with open(disk.path, "ab") as f:
-                    for blk in self._blocks:
-                        f.write(blk)
-            except BaseException:
-                # a failed demotion write (disk full) must not leak the
-                # temp file; the blocks stay resident in RAM (R11)
-                disk.release()
-                raise
-            freed = self._admitted
-            self._blocks, self._nbytes, self._admitted = [], 0, 0
-            self._disk = disk
-        _host_ledger.forget(self, freed)
-        if obs_on:
-            obs.note_spill("HostSpill", "demote", time.perf_counter_ns() - t0,
-                           freed, trace_id=_conf_trace_id(self._conf))
+        with _container_span("demote", "HostSpill", self._conf) as sp:
+            with self._lock:
+                if self._disk is not None or self._blocks is None:
+                    return
+                disk = DiskSpill(self._spill_dir, conf=self._conf)
+                try:
+                    with open(disk.path, "ab") as f:
+                        for blk in self._blocks:
+                            f.write(blk)
+                except BaseException:
+                    # a failed demotion write (disk full) must not leak the
+                    # temp file; the blocks stay resident in RAM (R11)
+                    disk.release()
+                    raise
+                freed = self._admitted
+                self._blocks, self._nbytes, self._admitted = [], 0, 0
+                self._disk = disk
+            _host_ledger.forget(self, freed)
+            if sp is not None:
+                sp.arg["bytes"] = freed
 
     @property
     def demoted(self) -> bool:

@@ -10,12 +10,14 @@ Public surface:
 
 - ``span`` / ``use_span`` / ``current_span`` / ``query_trace`` — the
   span model (obs/span.py); spans cross thread hops EXPLICITLY, like
-  conf (R7).
-- ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_spill`` /
-  ``note_harvest`` / ``note_transfer_start`` / ``note_pump_batch`` —
-  the instrumentation facade the engine calls (MetricNode.timer,
-  EngineCounters hooks, memmgr, transfer window, task pump). Each
-  checks ``core._mode`` first; in mode off a call is one flag test.
+  conf (R7). A span's ``cat`` is its layer (``LAYERS``), and every span
+  is also a region ``auron:<layer>:<name>`` on the profiler's clock.
+- ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` —
+  the instrumentation facade behind MetricNode.timer, the EngineCounters
+  hooks and the task pump. Each checks ``core._mode`` first; in mode off
+  a call is one flag test.
+- ``window_summary(t0_s, t1_s)`` — where the host's time went between
+  two readings of ``time.perf_counter()``, by layer (obs/export.py).
 - exporters in ``auron_tpu.obs.export`` (Chrome/Perfetto JSON,
   Prometheus text), served by utils/httpsvc at ``/trace``,
   ``/metrics.prom``, ``/queries``.
@@ -35,7 +37,9 @@ from auron_tpu.obs.core import (  # noqa: F401  (re-exported)
     mode_name,
     set_mode,
 )
+from auron_tpu.obs.export import window_summary  # noqa: F401  (re-exported)
 from auron_tpu.obs.span import (  # noqa: F401  (re-exported)
+    LAYERS,
     Span,
     Trace,
     _span_var,
@@ -53,8 +57,8 @@ OBS_MODE = str_conf(
     "obs.mode", "recorder", "observability",
     "recording mode: off (instrumentation short-circuits) | recorder "
     "(always-on bounded flight recorder, <=5% overhead by the obscheck "
-    "gate) | trace (full tracing: per-query summaries + span/metric "
-    "cross-check). Applied process-wide when a task's conf sets it "
+    "gate) | trace (full tracing: per-query summaries with event "
+    "counters). Applied process-wide when a task's conf sets it "
     "explicitly (bridge/api.py); AURON_TPU_OBS_MODE sets the start mode",
 )
 OBS_TRACE_ID = int_conf(
@@ -99,90 +103,48 @@ def apply_conf(conf) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _span_ids():
-    sp = _span_var.get()
-    if sp is None:
-        return None, 0, 0
-    return sp.trace, sp.trace_id, sp.span_id
-
-
 def note_op(op: str, metric: str, dur_ns: int) -> None:
-    """One MetricNode.timer interval (exec/metrics.py): the span
-    timeline's per-operator compute segments. The SAME dt lands in the
-    metric tree, so span-derived and metric-derived per-op totals agree
-    by construction. Per-event Trace accumulation (the span_op_ns side
-    of the cross-check) is TRACE-mode only — recorder mode pays for ring
-    appends, never a per-event lock."""
+    """One MetricNode.timer interval (exec/metrics.py), as an ``op`` event
+    of the flight recorder. The SAME dt lands in the metric tree. It is
+    dispatch time, and a timer may stay open across a ``yield``: the
+    event is NOT a region (no layer) and no reader sums it by layer."""
     if core._mode == MODE_OFF:
         return
-    trace, tid, sid = _span_ids()
+    sp = _span_var.get()
+    tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
     core.record("op", metric, dur_ns, tid, sid, 0, op.partition(".")[0])
-    if trace is not None and core._mode == MODE_TRACE:
-        trace.note_op(op, metric, dur_ns)
 
 
 def note_sync(dur_ns: int, is_async: bool) -> None:
     """One device->host read observed by EngineCounters (blocking sync or
-    async-window harvest), attributed to the calling thread's span."""
-    if core._mode == MODE_OFF:
+    async-window harvest), into the trace counters of the calling
+    thread's span. Its region and ring event are the hook's own
+    ``span(<site>, cat="sync")``."""
+    if core._mode != MODE_TRACE:
         return
-    trace, tid, sid = _span_ids()
-    core.record("async" if is_async else "sync",
-                "async_read" if is_async else "host_sync",
-                dur_ns, tid, sid, 0, None)
-    if trace is not None and core._mode == MODE_TRACE:
+    trace = current_trace()
+    if trace is not None:
         trace.note_sync(dur_ns, is_async)
 
 
 def note_compile(dur_ns: int) -> None:
-    if core._mode == MODE_OFF:
+    """One backend compile (or fetch from the persistent cache), into
+    the trace counters; its region is the hook's ``span(<program>,
+    cat="compile")``, named by the XLA module."""
+    if core._mode != MODE_TRACE:
         return
-    trace, tid, sid = _span_ids()
-    core.record("compile", "xla_compile", dur_ns, tid, sid, 0, None)
-    if trace is not None and core._mode == MODE_TRACE:
+    trace = current_trace()
+    if trace is not None:
         trace.note_compile(dur_ns)
 
 
-def note_spill(consumer: str, what: str, dur_ns: int, nbytes: int,
-               sp: "Span | None" = None, trace_id: int = 0) -> None:
-    """A spill-path event. Attribution is EXPLICIT only: the owner's span
-    (memmgr's registration-captured one) or the owning conf's trace id
-    (spill containers carry conf) — never the executing thread's ambient
-    span, which during a cross-thread spill belongs to a FOREIGN task."""
-    if core._mode == MODE_OFF:
-        return
-    if sp is not None:
-        trace, tid, sid = sp.trace, sp.trace_id, sp.span_id
-    else:
-        trace, tid, sid = get_trace(trace_id), int(trace_id), 0
-    core.record("spill", what, dur_ns, tid, sid, 0,
-                {"consumer": consumer, "bytes": int(nbytes)})
-    if trace is not None and what == "spill" and core._mode == MODE_TRACE:
-        trace.note_spill(dur_ns, nbytes)
-
-
-def note_harvest(n: int, dur_ns: int) -> None:
-    """One async-transfer window harvest (runtime/transfer.py)."""
-    if core._mode == MODE_OFF:
-        return
-    _, tid, sid = _span_ids()
-    core.record("transfer", "harvest", dur_ns, tid, sid, 0, {"n": n})
-
-
-def note_transfer_start(n: int) -> None:
-    if core._mode == MODE_OFF:
-        return
-    _, tid, sid = _span_ids()
-    core.record("transfer", "start", 0, tid, sid, 0, {"n": n})
-
-
 def note_pump_batch() -> None:
-    """One batch through a task pump (runtime/task.py)."""
-    if core._mode == MODE_OFF:
+    """One batch through a task pump (runtime/task.py): the trace's
+    batch count (its interval is the pump's ``pump:batch`` span)."""
+    if core._mode != MODE_TRACE:
         return
-    trace, tid, sid = _span_ids()
-    core.record("pump", "batch", 0, tid, sid, 0, None)
-    if trace is not None and core._mode == MODE_TRACE:
+    trace = current_trace()
+    if trace is not None:
         trace.note_batch()
 
 
@@ -190,6 +152,5 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
     def _noop(*a, **k) -> None:
         return None
 
-    note_op = note_sync = note_compile = note_spill = _noop  # noqa: F811
-    note_harvest = note_transfer_start = note_pump_batch = _noop  # noqa: F811
+    note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

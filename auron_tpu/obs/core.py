@@ -12,8 +12,8 @@ PAPERS.md) without having had tracing "on". Three modes:
                  ring-full of events per thread is always retrievable
                  (``/trace?last=...`` on the HTTP service).
 - ``trace``    — full tracing: same rings, plus per-query ``Trace``
-                 accumulators feed exportable per-query summaries and the
-                 span-vs-metrics cross-check (obs/span.py).
+                 accumulators feed exportable per-query summaries
+                 (obs/span.py).
 
 ``AURON_TPU_OBS_KILL=1`` is the obscheck *baseline* switch: at import the
 public facade in ``auron_tpu.obs`` is rebound to true no-ops, so a replay
@@ -110,6 +110,9 @@ _tls = threading.local()
 _reg_lock = threading.Lock()
 _rings: list[_Ring] = []
 _ring_seq = itertools.count(1)
+#: newest event of any ring that left the registry (evicted or pruned):
+#: a reader of a window that starts before it cannot claim completeness
+_lost_until_ns = 0
 
 
 def _live_idents() -> set:
@@ -128,7 +131,7 @@ def _make_ring() -> _Ring:
             live = _live_idents()
             dead = [r for r in _rings if r.ident not in live]
             if dead:
-                _rings.remove(min(dead, key=lambda r: r.last_ns))
+                _drop_locked(min(dead, key=lambda r: r.last_ns))
         r = _Ring(next(_ring_seq), _ring_capacity)
         _rings.append(r)
     _tls.ring = r  # auronlint: disable=R7 -- per-THREAD ring is the recorder's design: events buffer by executing thread; TASK attribution rides in the event's trace/span fields, never in this local
@@ -136,39 +139,53 @@ def _make_ring() -> _Ring:
 
 
 def record(kind: str, name: str, dur_ns: int, trace_id: int,
-           span_id: int, parent_id: int, arg=None) -> None:
+           span_id: int, parent_id: int, arg=None, layer: str = "",
+           end_ns: int = 0) -> None:
     """Append one event to the calling thread's ring. Callers MUST have
     checked ``core._mode`` already — this function does not re-check.
     Event layout (a plain tuple, cheapest thing Python has):
-    ``(ts_start_ns, dur_ns, kind, name, trace_id, span_id, parent_id, arg)``.
+    ``(ts_start_ns, dur_ns, kind, name, trace_id, span_id, parent_id, arg,
+    layer)``. ``layer`` is set on REGIONS only — intervals that nest on
+    their thread and are also on the profiler's clock as
+    ``auron:<layer>:<name>`` (spans, host reads, compiles, spills); an
+    ``op`` timer interval may stay open across a ``yield`` and has none.
+    The event ends now, or at ``end_ns`` where the caller read the clock
+    itself (a span: its interval then nests exactly inside its parent's).
     """
     r = getattr(_tls, "ring", None)  # auronlint: disable=R7 -- per-THREAD ring is the recorder's design: events buffer by executing thread; TASK attribution rides in the event's trace/span fields, never in this local
     if r is None:
         r = _make_ring()
-    now = time.perf_counter_ns()
+    now = end_ns or time.perf_counter_ns()
     i = r.idx
     r.buf[i % r.cap] = (now - dur_ns, dur_ns, kind, name,
-                        trace_id, span_id, parent_id, arg)
+                        trace_id, span_id, parent_id, arg, layer)
     r.idx = i + 1
     r.last_ns = now
 
 
+def _drop_locked(r: _Ring) -> None:
+    global _lost_until_ns
+    _rings.remove(r)
+    if r.idx:
+        _lost_until_ns = max(_lost_until_ns, r.last_ns)
+
+
 def _prune_locked(now_ns: int) -> None:
     live = _live_idents()
-    _rings[:] = [
-        r for r in _rings
-        if r.ident in live or now_ns - r.last_ns < _RETENTION_NS
-    ]
+    for r in [r for r in _rings
+              if r.ident not in live and now_ns - r.last_ns >= _RETENTION_NS]:
+        _drop_locked(r)
 
 
 def snapshot_events(last_s: float | None = None,
                     trace_id: int | None = None) -> list[tuple[dict, list]]:
     """Best-effort copy of every ring's events, oldest-first per ring,
     optionally limited to the last ``last_s`` seconds and/or one trace.
-    Returns ``[(ring_info, [event, ...]), ...]``. Concurrent writers may
-    overwrite a slot mid-copy; the copy simply reflects whichever event
-    won — the recorder trades a perfectly consistent snapshot for a
-    lock-free hot path."""
+    Returns ``[(ring_info, [event, ...]), ...]``; ``ring_info["wrapped"]``
+    says that the ring has overwritten events older than its first one.
+    Concurrent writers may overwrite a slot mid-copy; the copy simply
+    reflects whichever event won — the recorder trades a perfectly
+    consistent snapshot for a lock-free hot path."""
     now = time.perf_counter_ns()
     cut = None if last_s is None else now - int(float(last_s) * 1e9)
     with _reg_lock:
@@ -190,14 +207,22 @@ def snapshot_events(last_s: float | None = None,
             and (trace_id is None or ev[4] == trace_id)
         ]
         if evs:
-            out.append(({"tid": r.tid, "name": r.tname}, evs))
+            out.append(({"tid": r.tid, "name": r.tname,
+                         "wrapped": idx > cap}, evs))
     return out
+
+
+def lost_until_ns() -> int:
+    """End of the newest event that left with an evicted or pruned ring."""
+    return _lost_until_ns
 
 
 def reset_for_tests() -> None:
     """Drop all rings (test isolation only — not part of the API)."""
+    global _lost_until_ns
     with _reg_lock:
         _rings.clear()
+        _lost_until_ns = 0
     # each thread's _tls.ring is dropped lazily: a stale thread-local ring
     # keeps recording but is no longer exported
     if getattr(_tls, "ring", None) is not None:

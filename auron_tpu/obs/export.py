@@ -1,6 +1,7 @@
-"""Exporters: Chrome/Perfetto trace JSON, Prometheus text, query ring.
+"""Exporters: Chrome/Perfetto trace JSON, Prometheus text, query ring,
+and the one reader over the rings by layer (``window_summary``).
 
-Three views of the same recorded state (docs/observability.md):
+Views of the same recorded state (docs/observability.md):
 
 - ``chrome_trace()``   — the flight recorder's rings as Chrome
   trace-event JSON (loadable in Perfetto / chrome://tracing): one
@@ -12,6 +13,9 @@ Three views of the same recorded state (docs/observability.md):
   text exposition with task/stage/partition/operator labels
   (``/metrics.prom``).
 - the recent-queries ring (obs/span.py) served at ``/queries``.
+- ``window_summary()`` — where the host's time went between two readings
+  of the clock, by layer: count, seconds and SELF seconds of the regions
+  (the benchmark's per-layer metrics read it).
 """
 
 from __future__ import annotations
@@ -33,7 +37,7 @@ def chrome_trace(last_s: float | None = None,
     named: set = set()
     for ring, evs in groups:
         tid = ring["tid"]
-        for (ts, dur, kind, name, tr, sp, parent, arg) in evs:
+        for (ts, dur, kind, name, tr, sp, parent, arg, layer) in evs:
             if (tr, tid) not in named:
                 named.add((tr, tid))
                 events.append({
@@ -57,7 +61,8 @@ def chrome_trace(last_s: float | None = None,
             events.append({
                 "ph": "X",
                 "name": f"{arg}.{name}" if kind == "op" and arg else name,
-                "cat": kind,
+                # a region renders under its layer, an op timer as "op"
+                "cat": layer or kind,
                 "ts": ts / 1e3,        # trace-event time unit is us
                 "dur": max(dur / 1e3, 0.001),
                 "pid": tr,
@@ -70,6 +75,86 @@ def chrome_trace(last_s: float | None = None,
             "args": {"name": tr_name},
         })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
+# ---------------------------------------------------------------------------
+# by layer: where the host's time went in a window
+# ---------------------------------------------------------------------------
+
+
+def self_ns(regions: list) -> list:
+    """Self nanoseconds of one thread's regions ``(start, end, ...)``,
+    already clipped: each region's duration minus what the regions nested
+    directly inside it cover. Regions of one thread nest properly (they
+    are ``with`` blocks), so one pass over them by start with a stack of
+    the open ones is exact. Returns ``[(region, self_ns), ...]``. Also the
+    one implementation behind ``benchmark/trace_scopes.py``'s tables."""
+    out, stack = [], []        # stack of [region, covered_by_children_ns]
+    def close(upto):
+        while stack and stack[-1][0][1] <= upto:
+            reg, covered = stack.pop()
+            out.append((reg, reg[1] - reg[0] - covered))
+    for reg in sorted(regions, key=lambda r: (r[0], -r[1])):
+        close(reg[0])
+        if stack:
+            stack[-1][1] += min(reg[1], stack[-1][0][1]) - reg[0]
+        stack.append([reg, 0])
+    close(float("inf"))
+    return out
+
+
+def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
+    """Where the host's time went between ``t0_s`` and ``t1_s`` (readings
+    of ``time.perf_counter()``, the clock the rings are stamped with).
+
+    From the REGION events (those with a layer: spans, host reads,
+    compiles) whose interval meets the window, clipped to it: per layer
+    the count ``n``, the summed duration ``total_s`` and the self time
+    ``self_s`` (duration minus the part its same-thread child regions
+    cover), under ``layers``; the same three per ``<layer>:<name>`` under
+    ``spans`` (host reads excepted: their names are sites). Thread-seconds,
+    not wall: two map pumps run at once. ``d2h_bytes`` sums the bytes of
+    the host reads; ``sync_sites`` ranks them by seconds as ``[site, n,
+    seconds]``. ``complete`` is False where
+    a ring that may hold events of the window has wrapped, or left the
+    registry with events newer than the window's start: the sums are then
+    a lower bound and a metric reader reports nothing."""
+    lo, hi = int(t0_s * 1e9), int(t1_s * 1e9)
+    complete = core.lost_until_ns() <= lo
+    layers: dict[str, dict] = {}
+    spans: dict[str, dict] = {}
+    sites: dict[str, list] = {}
+    d2h = 0
+
+    def book(table: dict, key: str, dur_ns: int, own_ns: int) -> None:
+        ent = table.setdefault(key, {"n": 0, "total_s": 0.0, "self_s": 0.0})
+        ent["n"] += 1
+        ent["total_s"] += dur_ns / 1e9
+        ent["self_s"] += own_ns / 1e9
+
+    for ring, evs in core.snapshot_events():
+        # a wrapped ring has lost only events that ended before its
+        # oldest one did
+        if ring["wrapped"] and evs[0][0] + evs[0][1] > lo:
+            complete = False
+        regions = [
+            (max(ts, lo), min(ts + dur, hi), layer, name, arg)
+            for (ts, dur, _k, name, _t, _s, _p, arg, layer) in evs
+            if layer and ts < hi and ts + dur > lo
+        ]
+        for (s, e, layer, name, arg), own in self_ns(regions):
+            book(layers, layer, e - s, own)
+            if layer == "sync":
+                d2h += arg.get("bytes", 0) if isinstance(arg, dict) else 0
+                site = sites.setdefault(name, [0, 0.0])
+                site[0] += 1
+                site[1] += (e - s) / 1e9
+            else:
+                book(spans, f"{layer}:{name}", e - s, own)
+    ranked = sorted(sites.items(), key=lambda kv: -kv[1][1])[:top]
+    return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
+            "layers": layers, "spans": spans, "d2h_bytes": d2h,
+            "sync_sites": [[k, n, secs] for k, (n, secs) in ranked]}
 
 
 def _trace_names() -> list[tuple[int, str]]:

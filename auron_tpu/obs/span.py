@@ -21,6 +21,14 @@ crossing a thread hop requires an explicit hand-off:
 - spill containers                   -> carry the owning conf, and with
   it ``obs.trace.id`` (conf-id attribution, no live Span needed)
 
+Layers: a span's ``cat`` is its LAYER, one of the closed set ``LAYERS``
+(the rows of PERF.md section 3). Every span is also a region on the
+profiler's clock — a ``jax.profiler.TraceAnnotation`` named
+``auron:<layer>:<name>`` — so the host timeline these rings keep and the
+``/host:CPU`` plane of a ``jax.profiler`` trace can be laid over each
+other. A region nests on its thread: a span NEVER stays open across a
+``yield``.
+
 Every accumulator mutation on a ``Trace`` takes the trace's own lock:
 events arrive from pump threads, spill threads and harvest threads
 concurrently (the R8 contract; the lesson of the ``sync_sites`` race
@@ -40,6 +48,17 @@ from auron_tpu.obs import core
 _span_var: contextvars.ContextVar = contextvars.ContextVar(
     "auron_obs_span", default=None
 )
+
+#: the closed set of layers (docs/observability.md). ``sync`` and
+#: ``compile`` spans are opened by the EngineCounters hooks
+#: (utils/profiling.py) around each host read and each compile
+LAYERS = frozenset({
+    "entry", "plan", "pump", "exchange", "wait", "sync", "compile", "spill",
+    "sql", "stream", "task", "query",
+})
+
+#: ``jax.profiler.TraceAnnotation``, imported when the first span opens
+_annotation = None
 
 _id_seq = itertools.count(1)
 _span_seq = itertools.count(1)
@@ -69,13 +88,18 @@ def recent_queries() -> list[dict]:
 
 
 class Span:
-    __slots__ = ("trace", "trace_id", "span_id", "parent_id",
-                 "name", "cat", "t0_ns")
+    """``arg`` may be set until the region closes (bytes known only at
+    the end of a write): it lands on the ring event and, as metadata, on
+    the profiler's region."""
 
-    def __init__(self, name: str, cat: str, trace: "Trace | None",
+    __slots__ = ("trace", "trace_id", "span_id", "parent_id",
+                 "name", "cat", "arg", "t0_ns")
+
+    def __init__(self, name: str, cat: str, arg, trace: "Trace | None",
                  trace_id: int, parent_id: int):
         self.name = name
         self.cat = cat
+        self.arg = arg
         self.trace = trace
         self.trace_id = trace_id
         self.span_id = next(_span_seq)
@@ -84,29 +108,20 @@ class Span:
 
 
 class Trace:
-    """Per-query accumulator. Two independent per-operator accountings
-    live here ON PURPOSE (the cross-check the q5 misattribution needed):
-
-    - ``op_totals``   — MetricNode snapshot rollups folded in at task
-      finalize (the engine's existing accounting);
-    - ``span_op_ns``  — the same timers, accumulated from the live timer
-      *events* as they happen (the span timeline's accounting).
-
-    ``bench.py``/``perf_gate.py``/tests compare the two through
-    ``op_seconds_skew``; they agree exactly when every thread hop was
-    threaded, so divergence means a hop lost its span.
-
-    Per-EVENT accumulation (span_op_ns, sync/compile/spill/batch
-    counters) happens only in TRACE mode — recorder mode never takes
-    this lock on a hot path; its summaries carry the per-task side
-    (wall, tasks, op_seconds from finalize rollups) with the event
-    counters at zero."""
+    """Per-query accumulator: per-operator metric totals folded in at
+    task finalize (``op_totals``, the MetricNode rollup) plus event
+    counters. Per-EVENT accumulation (sync/compile/spill/batch counters)
+    happens only in TRACE mode — recorder mode never takes this lock on a
+    hot path; its summaries carry the per-task side (wall, tasks,
+    op_seconds from finalize rollups) with the event counters at zero.
+    Where the host's time went by layer is not kept here: it is read from
+    the rings (``obs.window_summary``)."""
 
     __slots__ = ("id", "name", "kind", "t0_ns", "_lock",
                  "syncs", "sync_ns", "async_reads", "async_ns",
                  "compiles", "compile_ns",
                  "spills", "spill_ns", "spill_bytes",
-                 "batches", "tasks", "op_totals", "span_op_ns", "closed")
+                 "batches", "tasks", "op_totals")
 
     def __init__(self, name: str, kind: str = "query"):
         self.id = next(_id_seq)
@@ -126,8 +141,6 @@ class Trace:
         self.batches = 0
         self.tasks = 0
         self.op_totals: dict[str, dict[str, int]] = {}
-        self.span_op_ns: dict[str, dict[str, int]] = {}
-        self.closed = False
 
     # -- accumulators (all cross-thread; every write under self._lock) --
 
@@ -155,12 +168,6 @@ class Trace:
         with self._lock:
             self.batches += 1
 
-    def note_op(self, op: str, metric: str, dur_ns: int) -> None:
-        op = op.partition(".")[0] or "<node>"
-        with self._lock:
-            tot = self.span_op_ns.setdefault(op, {})
-            tot[metric] = tot.get(metric, 0) + dur_ns
-
     def add_task_metrics(self, snapshot: dict) -> None:
         from auron_tpu.exec.metrics import MetricNode
 
@@ -178,34 +185,6 @@ class Trace:
         with self._lock:
             return {op: MetricNode.op_seconds(tot)
                     for op, tot in self.op_totals.items()}
-
-    def span_op_seconds(self) -> dict[str, float]:
-        """Per-op timer seconds re-derived from span-timeline events."""
-        from auron_tpu.exec.metrics import MetricNode
-
-        with self._lock:
-            return {op: MetricNode.op_seconds(tot)
-                    for op, tot in self.span_op_ns.items()}
-
-    def op_seconds_skew(self, min_s: float = 0.05) -> dict:
-        """Cross-check the two accountings: max relative divergence over
-        operators with at least ``min_s`` of metric time."""
-        metric = self.metric_op_seconds()
-        span = self.span_op_seconds()
-        worst = 0.0
-        worst_op = None
-        compared = 0
-        for op, ms in metric.items():
-            if ms < min_s:
-                continue
-            compared += 1
-            skew = abs(span.get(op, 0.0) - ms) / ms
-            if skew > worst:
-                worst, worst_op = skew, op
-        # ``compared`` lets gate consumers reject a VACUOUS pass (nothing
-        # crossed min_s) — worst_op alone is also None on exact agreement
-        return {"max_skew_pct": round(100.0 * worst, 2), "op": worst_op,
-                "compared": compared, "ok": worst <= 0.05}
 
     def summary(self) -> dict:
         wall_ns = time.perf_counter_ns() - self.t0_ns
@@ -254,47 +233,71 @@ _UNSET = object()
 
 
 class span:
-    """Open a child span for a ``with`` region. ``parent`` defaults to the
-    calling thread's current span; pass ``parent=``/``trace=`` explicitly
-    when opening on a new thread (the task pump). No-ops in mode off."""
+    """Open a child span for a ``with`` region of layer ``cat``. ``parent``
+    defaults to the calling thread's current span; pass ``parent=``/
+    ``trace=`` explicitly when opening on a new thread (the task pump) or
+    for an owner that is not the executing thread's (spills);
+    ``trace_id`` alone attributes to a trace that is known only by its
+    conf-threaded id and may have closed (spill containers). No-ops in
+    mode off."""
 
-    __slots__ = ("name", "cat", "arg", "sp", "_tok")
+    __slots__ = ("name", "cat", "arg", "sp", "_tok", "_region")
 
-    def __init__(self, name: str, cat: str = "", arg=None,
-                 parent=_UNSET, trace: Trace | None = None):
+    def __init__(self, name: str, cat: str, arg=None,
+                 parent=_UNSET, trace: Trace | None = None,
+                 trace_id: int = 0):
+        self.sp = None
+        if core._mode == core.MODE_OFF:
+            return
+        if cat not in LAYERS:
+            raise ValueError(f"unknown obs layer {cat!r}")
         self.name = name
         self.cat = cat
         self.arg = arg
         if parent is _UNSET:
-            parent = None if core._mode == core.MODE_OFF else _span_var.get()
+            parent = _span_var.get()
         if trace is None and parent is not None:
             trace = parent.trace
-        self.sp = (parent, trace)
-        self._tok = None
+        self.sp = (parent, trace, int(trace_id))
 
     def __enter__(self) -> Span | None:
-        if core._mode == core.MODE_OFF:
+        global _annotation
+        if self.sp is None or core._mode == core.MODE_OFF:
             self.sp = None
             return None
-        parent, trace = self.sp
-        tid = trace.id if trace is not None else (
-            parent.trace_id if parent is not None else 0
-        )
-        sp = Span(self.name, self.cat, trace, tid,
+        parent, trace, tid = self.sp
+        if trace is not None:
+            tid = trace.id
+        elif parent is not None:
+            tid = parent.trace_id
+        sp = Span(self.name, self.cat, self.arg, trace, tid,
                   parent.span_id if parent is not None else 0)
         self.sp = sp
         self._tok = _span_var.set(sp)
+        # the profiler-clock half: with no profiler session an enter and
+        # exit is a flag test (0.7 us measured with two arguments)
+        if _annotation is None:
+            import jax
+
+            _annotation = jax.profiler.TraceAnnotation
+        self._region = _annotation(f"auron:{sp.cat}:{sp.name}",
+                                   span=sp.span_id, parent=sp.parent_id,
+                                   trace=tid)
+        self._region.__enter__()
         return sp
 
     def __exit__(self, *exc):
         sp = self.sp
         if sp is None:
             return False
-        if self._tok is not None:
-            _span_var.reset(self._tok)
+        if isinstance(sp.arg, dict):
+            self._region.set_metadata(**sp.arg)
+        self._region.__exit__(*exc)
+        _span_var.reset(self._tok)
         if core._mode != core.MODE_OFF:
-            core.record("span", sp.name, time.perf_counter_ns() - sp.t0_ns,
-                        sp.trace_id, sp.span_id, sp.parent_id, self.arg)
+            end = time.perf_counter_ns()
+            core.record("span", sp.name, end - sp.t0_ns, sp.trace_id,
+                        sp.span_id, sp.parent_id, sp.arg, sp.cat, end)
         return False
 
 
@@ -371,7 +374,6 @@ class query_trace:
         self._cs.__exit__(exc_type, exc, tb)
         with _traces_lock:
             _traces.pop(self.trace.id, None)
-        self.trace.closed = True
         self.summary = self.trace.summary()
         # a query that died must not masquerade as a fast success in the
         # /queries ring — operators triage from these entries

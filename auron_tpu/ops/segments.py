@@ -189,58 +189,70 @@ def segment_by_keys(
     cap = sel.shape[0]
     dead_first_key = jnp.where(sel, jnp.uint64(0), jnp.uint64(1))
     iota = jnp.arange(cap, dtype=jnp.int32)
+    # scope names (``auron.agg.*``, HLO metadata only) are what a device
+    # trace names this program's operations by
     if fingerprint:
         if fp is None:
             # host-sort callers pass the fp they already computed for the
             # eager lexsort (host_order_fp) — hashing twice per batch would
             # cancel the narrower sort's savings
-            fp = hashing.fingerprint64(words, fp_bits)
-        if host_sort:
-            if order is None:
-                order = hostsort.order_by_words((dead_first_key, fp))
-            sel_sorted = sel[order]
-            fp_sorted = fp[order]
-        else:
-            # iota is a KEY (num_keys=3): ties resolve in batch order, the
-            # same stable semantics as the host lexsort — `first` and
-            # staged-run layouts stay identical across backends
-            # auronlint: sort-payload -- fixed 3-operand fingerprint sort (the payload-thin form)
-            s_dead, fp_sorted, order = lax.sort(
-                (dead_first_key, fp, iota), num_keys=3
+            with jax.named_scope("auron.agg.fingerprint"):
+                fp = hashing.fingerprint64(words, fp_bits)
+        with jax.named_scope("auron.agg.sort"):
+            if host_sort:
+                if order is None:
+                    order = hostsort.order_by_words((dead_first_key, fp))
+                sel_sorted = sel[order]
+                fp_sorted = fp[order]
+            else:
+                # iota is a KEY (num_keys=3): ties resolve in batch order,
+                # the same stable semantics as the host lexsort — `first`
+                # and staged-run layouts stay identical across backends
+                # auronlint: sort-payload -- fixed 3-operand fingerprint sort (the payload-thin form)
+                s_dead, fp_sorted, order = lax.sort(
+                    (dead_first_key, fp, iota), num_keys=3
+                )
+                # the sort already emitted the sorted planes — no re-gather
+                sel_sorted = s_dead == 0
+        with jax.named_scope("auron.agg.key_gather"):
+            sorted_words = tuple(w[order] for w in words)
+        with jax.named_scope("auron.agg.boundaries"):
+            return _finish_segmentation(
+                order, sorted_words, sel_sorted, cap, fp_sorted=fp_sorted
             )
-            # the sort already emitted the sorted planes — no re-gather
-            sel_sorted = s_dead == 0
-        sorted_words = tuple(w[order] for w in words)
-        return _finish_segmentation(
-            order, sorted_words, sel_sorted, cap, fp_sorted=fp_sorted
-        )
     if host_sort:
         if order is None:
-            order = hostsort.order_by_words((dead_first_key, *words))
-        sel_sorted = sel[order]
-        sorted_words = tuple(w[order] for w in words)
+            with jax.named_scope("auron.agg.sort"):
+                order = hostsort.order_by_words((dead_first_key, *words))
+        with jax.named_scope("auron.agg.key_gather"):
+            sel_sorted = sel[order]
+            sorted_words = tuple(w[order] for w in words)
     else:
         operands = [dead_first_key, *words, iota]
-        if device_impl in ("jnp", "pallas"):
-            from auron_tpu.ops import bitonic
+        with jax.named_scope("auron.agg.sort"):
+            if device_impl in ("jnp", "pallas"):
+                from auron_tpu.ops import bitonic
 
-            # statically-zero hi planes skip the network: the 0/1 dead key
-            # always; the null-bits word (last, by key_words construction)
-            # when <= 32 key columns set bits in its low half only
-            narrow = [True] + [False] * len(words) + [False]
-            if 0 < n_key_cols <= 32 and len(words) == n_key_cols + 1:
-                narrow[len(words)] = True
-            # auronlint: sort-payload -- legacy full-word grouping sort: the operand list scales with key columns by design; the fingerprint path above is the thin form
-            sorted_ops = bitonic.bitonic_sort(
-                tuple(operands), impl=device_impl, narrow=tuple(narrow)
-            )
-        else:
-            # auronlint: sort-payload -- legacy full-word grouping sort (collision-free exact fallback for the fingerprint path)
-            sorted_ops = lax.sort(tuple(operands), num_keys=len(operands) - 1)
-        sel_sorted = sorted_ops[0] == 0
-        sorted_words = sorted_ops[1:-1]
-        order = sorted_ops[-1]
-    return _finish_segmentation(order, sorted_words, sel_sorted, cap)
+                # statically-zero hi planes skip the network: the 0/1 dead
+                # key always; the null-bits word (last, by key_words
+                # construction) when <= 32 key columns set bits in its low
+                # half only
+                narrow = [True] + [False] * len(words) + [False]
+                if 0 < n_key_cols <= 32 and len(words) == n_key_cols + 1:
+                    narrow[len(words)] = True
+                # auronlint: sort-payload -- legacy full-word grouping sort: the operand list scales with key columns by design; the fingerprint path above is the thin form
+                sorted_ops = bitonic.bitonic_sort(
+                    tuple(operands), impl=device_impl, narrow=tuple(narrow)
+                )
+            else:
+                # auronlint: sort-payload -- legacy full-word grouping sort (collision-free exact fallback for the fingerprint path)
+                sorted_ops = lax.sort(tuple(operands),
+                                      num_keys=len(operands) - 1)
+            sel_sorted = sorted_ops[0] == 0
+            sorted_words = sorted_ops[1:-1]
+            order = sorted_ops[-1]
+    with jax.named_scope("auron.agg.boundaries"):
+        return _finish_segmentation(order, sorted_words, sel_sorted, cap)
 
 
 def host_order(words: list[jnp.ndarray], sel: jnp.ndarray) -> jnp.ndarray:

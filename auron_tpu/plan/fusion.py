@@ -40,7 +40,7 @@ Invariants the fused stage preserves (docs/fusion.md):
 - Metric attribution: fused-program wall time is split back into
   per-operator MetricNode children (proportional to the cost model's
   per-operator weights), and the SAME split nanos are handed to the obs
-  span timeline — ``top_ops`` and the <=5% span/metric cross-check see
+  flight recorder as ``op`` events — ``top_ops`` sees
   FilterExec/ProjectExec/HashAggExec, never one opaque stage.
 """
 
@@ -122,25 +122,28 @@ def _trace_steps(dev: DeviceBatch, steps: tuple):
     eager path's. Returns (sel, values, validity, final projection's
     ColumnVals or None). The common-subexpression memo is shared across
     consecutive steps over the same input columns and reset at every
-    projection (which replaces the column planes)."""
+    projection (which replaces the column planes). Each step's operations
+    carry the scope name ``auron.stage.step<i>.<filter|project>`` (HLO
+    metadata only: what a device trace names them by)."""
     sel = dev.sel
     values, validity = dev.values, dev.validity
     outs = None
     memo: dict = {}
-    for step in steps:
+    for i, step in enumerate(steps):
         kind, schema, exprs = step
-        b = Batch(schema, DeviceBatch(sel, values, validity),
-                  (None,) * len(schema.fields))
-        ev = Evaluator(schema, partition_id=0, row_offset=0, resources={})
-        if kind == "filter":
-            for p in exprs:
-                cv = ev._eval(p, b, memo)
-                sel = sel & cv.validity & cv.values.astype(bool)
-        else:
-            outs = [ev._eval(e, b, memo) for e in exprs]
-            values = tuple(cv.values for cv in outs)
-            validity = tuple(cv.validity for cv in outs)
-            memo = {}
+        with jax.named_scope(f"auron.stage.step{i}.{kind}"):
+            b = Batch(schema, DeviceBatch(sel, values, validity),
+                      (None,) * len(schema.fields))
+            ev = Evaluator(schema, partition_id=0, row_offset=0, resources={})
+            if kind == "filter":
+                for p in exprs:
+                    cv = ev._eval(p, b, memo)
+                    sel = sel & cv.validity & cv.values.astype(bool)
+            else:
+                outs = [ev._eval(e, b, memo) for e in exprs]
+                values = tuple(cv.values for cv in outs)
+                validity = tuple(cv.validity for cv in outs)
+                memo = {}
     return sel, values, validity, outs
 
 
@@ -251,56 +254,63 @@ def _stage_program_probe(dev, lut, lut_base, bwords, n_live, pack_args,
     sel, values, validity, _ = _trace_steps(dev, steps)
     (key_exprs, key_schema, kinds, use_lut, probe_outer, bcap, packed,
      pcol_ids, take) = probe
-    b = Batch(key_schema, DeviceBatch(sel, values, validity),
-              (None,) * len(key_schema.fields))
-    ev = Evaluator(key_schema, partition_id=0, row_offset=0, resources={})
-    memo: dict = {}
-    kcvs = [ev._eval(e, b, memo) for e in key_exprs]
-    if packed:
-        # multi-key packing with the build's ranges (driver: _pack_probe_jit
-        # then a single synthetic INT64 key column)
-        w0, v0 = jcore._canon_words(kcvs)
-        mins, maxs, shifts = pack_args
-        pw, pv = jcore._pack_probe_words_jit(tuple(w0), v0, mins, maxs, shifts)
-        probe_words = [jnp.where(pv, pw, jnp.uint64(0))]
-        pvalid = pv
-    else:
-        probe_words, pvalid = jcore._canon_words_traced(
-            tuple(cv.values for cv in kcvs),
-            tuple(cv.validity for cv in kcvs), kinds,
-        )
-    ok_base = sel & (pvalid if pvalid is not None else jnp.ones_like(sel))
+    with jax.named_scope("auron.probe.pack"):
+        b = Batch(key_schema, DeviceBatch(sel, values, validity),
+                  (None,) * len(key_schema.fields))
+        ev = Evaluator(key_schema, partition_id=0, row_offset=0, resources={})
+        memo: dict = {}
+        kcvs = [ev._eval(e, b, memo) for e in key_exprs]
+        if packed:
+            # multi-key packing with the build's ranges (driver:
+            # _pack_probe_jit then a single synthetic INT64 key column)
+            w0, v0 = jcore._canon_words(kcvs)
+            mins, maxs, shifts = pack_args
+            pw, pv = jcore._pack_probe_words_jit(tuple(w0), v0, mins, maxs,
+                                                 shifts)
+            probe_words = [jnp.where(pv, pw, jnp.uint64(0))]
+            pvalid = pv
+        else:
+            probe_words, pvalid = jcore._canon_words_traced(
+                tuple(cv.values for cv in kcvs),
+                tuple(cv.validity for cv in kcvs), kinds,
+            )
+        ok_base = sel & (pvalid if pvalid is not None else jnp.ones_like(sel))
     if take[0] == "exists":
         # duplicate-tolerant existence LUT (driver: _probe_exists_jit)
-        size = exists_lut.shape[0]
-        eidx = probe_words[0].view(jnp.int64) - lut_base
-        in_range = (eidx >= 0) & (eidx < size)
-        hit = exists_lut[jnp.clip(eidx, 0, size - 1).astype(jnp.int32)]
-        out = (ok_base & in_range & hit,)
+        with jax.named_scope("auron.probe.lookup"):
+            size = exists_lut.shape[0]
+            eidx = probe_words[0].view(jnp.int64) - lut_base
+            in_range = (eidx >= 0) & (eidx < size)
+            hit = exists_lut[jnp.clip(eidx, 0, size - 1).astype(jnp.int32)]
+            out = (ok_base & in_range & hit,)
         if emit == "cols":
             return sel, values, validity, out
         return sel, out
-    bi, ok = jcore._probe_unique_ops(
-        probe_words, ok_base, lut if use_lut else None, lut_base,
-        list(bwords), n_live, bcap,
-    )
-    sel_out = sel if probe_outer else (sel & ok)
-    live = jnp.sum(sel_out.astype(jnp.int32))
+    with jax.named_scope("auron.probe.lookup"):
+        bi, ok = jcore._probe_unique_ops(
+            probe_words, ok_base, lut if use_lut else None, lut_base,
+            list(bwords), n_live, bcap,
+        )
+        sel_out = sel if probe_outer else (sel & ok)
+        live = jnp.sum(sel_out.astype(jnp.int32))
     if take[0] == "probe":
         out = (bi, ok, sel_out, live)
     elif take[0] == "gather":
-        bv = tuple(v[bi] for v in bvals)
-        bm = tuple(m[bi] & ok for m in bmasks)
+        with jax.named_scope("auron.probe.gather"):
+            bv = tuple(v[bi] for v in bvals)
+            bm = tuple(m[bi] & ok for m in bmasks)
         out = (bi, ok, sel_out, live, bv, bm)
     else:  # ("compact", out_cap) — the predicted sync-free take
         out_cap = take[1]
-        idx, new_sel = compaction_index(sel_out, out_cap)
-        c_pvals = tuple(values[c][idx] for c in pcol_ids)
-        c_pmasks = tuple(validity[c][idx] & new_sel for c in pcol_ids)
-        c_bi = bi[idx]
-        c_ok = ok[idx] & new_sel
-        out_bvals = tuple(v[c_bi] for v in bvals)
-        out_bmasks = tuple(m[c_bi] & c_ok for m in bmasks)
+        with jax.named_scope("auron.probe.compact"):
+            idx, new_sel = compaction_index(sel_out, out_cap)
+            c_pvals = tuple(values[c][idx] for c in pcol_ids)
+            c_pmasks = tuple(validity[c][idx] & new_sel for c in pcol_ids)
+            c_bi = bi[idx]
+            c_ok = ok[idx] & new_sel
+        with jax.named_scope("auron.probe.gather"):
+            out_bvals = tuple(v[c_bi] for v in bvals)
+            out_bmasks = tuple(m[c_bi] & c_ok for m in bmasks)
         out = (bi, ok, sel_out, live,
                (c_pvals, c_pmasks, out_bvals, out_bmasks, new_sel))
     if emit == "cols":
@@ -325,16 +335,17 @@ def _stage_program_shuffle(dev, rr_start, *, steps: tuple, emit: str,
 
     sel, values, validity, _ = _trace_steps(dev, steps)
     spec, schema, n_out, mode = shuffle
-    pids = partition_ids_traced(
-        spec, schema, n_out, sel, values, validity, rr_start
-    )
-    if mode == "host":
-        extra = (pids,)
-    else:
-        out_dev, counts = cluster_rows(
-            DeviceBatch(sel, values, validity), pids, n_out
+    with jax.named_scope("auron.shuffle.partition"):
+        pids = partition_ids_traced(
+            spec, schema, n_out, sel, values, validity, rr_start
         )
-        extra = (out_dev, counts)
+        if mode == "host":
+            extra = (pids,)
+        else:
+            out_dev, counts = cluster_rows(
+                DeviceBatch(sel, values, validity), pids, n_out
+            )
+            extra = (out_dev, counts)
     if emit == "cols":
         return sel, values, validity, extra
     return sel, extra
@@ -770,8 +781,7 @@ class FusedStageExec(ExecOperator):
             dt = time.perf_counter_ns() - t0
             node.add("fused_batches", 1)
             # split the stage's wall nanos back into per-operator timers,
-            # handing the SAME split to the span timeline (obs.note_op) so
-            # the <=5% span/metric cross-check holds through fusion
+            # handing the SAME split to the flight recorder (obs.note_op)
             spent = 0
             for i, ((nm, w), cnode) in enumerate(zip(shares, attr)):
                 dt_i = dt - spent if i == len(shares) - 1 else dt * w // total_w

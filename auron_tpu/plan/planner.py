@@ -10,6 +10,7 @@ drives the root operator (runtime/task.py).
 
 from __future__ import annotations
 
+from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.exec.base import ExecOperator, ExecutionContext
 from auron_tpu.exprs import ir
@@ -522,16 +523,20 @@ def task_from_proto(task: pb.TaskDefinition):
     from auron_tpu.plan.fusion import fuse_exec_tree
     from auron_tpu.plan.optimizer import elide_smj_input_sorts, prune_columns
 
-    _resolve_shuffle_templates(task)
-    conf = Configuration(dict(task.conf))
-    mode = dict(task.conf).get("auron.smj.elide.sorts", "build")
-    # column pruning runs on EVERY task (idempotent): join pair-gather
-    # bytes scale with emitted column count, the dominant join cost
-    proto = prune_columns(elide_smj_input_sorts(task.plan, mode=mode))
-    # whole-stage fusion rewrites the EXEC tree (protos/goldens untouched):
-    # pipeline segments between blocking boundaries compile into single
-    # XLA programs where the cost model says fusion wins (plan/fusion.py)
-    plan = fuse_exec_tree(plan_from_proto(proto), conf)
+    with obs.span("task", cat="plan"):
+        _resolve_shuffle_templates(task)
+        conf = Configuration(dict(task.conf))
+        mode = dict(task.conf).get("auron.smj.elide.sorts", "build")
+        # column pruning runs on EVERY task (idempotent): join pair-gather
+        # bytes scale with emitted column count, the dominant join cost
+        proto = prune_columns(elide_smj_input_sorts(task.plan, mode=mode))
+        plan = plan_from_proto(proto)
+        # whole-stage fusion rewrites the EXEC tree (protos/goldens
+        # untouched): pipeline segments between blocking boundaries compile
+        # into single XLA programs where the cost model says fusion wins
+        # (plan/fusion.py)
+        with obs.span("fusion", cat="plan"):
+            plan = fuse_exec_tree(plan, conf)
     return plan, task.stage_id, task.partition_id, conf
 
 

@@ -107,15 +107,28 @@ class TaskRuntime:
                 from auron_tpu.utils.profiling import EngineCounters
 
                 counters = EngineCounters._installed
-                for batch in self.plan.execute(self.ctx.partition_id, self.ctx):
+                # one pump:batch span per PULL of the operator tree: the
+                # loop drives the iterator with next() inside the span, so
+                # the region holds the tree's host work for one batch and
+                # never spans a yield (a region must nest on its thread).
+                # The last pull, which ends the stream, is a span too: a
+                # shuffle-writing task does its writes there
+                batches = iter(self.plan.execute(self.ctx.partition_id,
+                                                 self.ctx))
+                while True:
+                    with obs.span("batch", cat="pump"):
+                        batch = next(batches, _END)
+                        if batch is not _END and self._host_prefetch:
+                            batch.prefetch_host()
+                    if batch is _END:
+                        break
                     if counters is not None:
                         # per-batch denominator for sync-budget checks
                         # (tools/perfcheck.py); no-op unless profiling is on
                         counters.note_batch()
                     obs.note_pump_batch()
-                    if self._host_prefetch:
-                        batch.prefetch_host()
-                    self._queue.put(batch)
+                    with obs.span("queue_put", cat="wait"):
+                        self._queue.put(batch)
         except TaskCancelled:
             pass
         except BaseException as e:  # noqa: BLE001 — relayed to the consumer
@@ -138,7 +151,8 @@ class TaskRuntime:
         """Next device batch, or None at end of stream."""
         if self._finalized:
             return None
-        item = self._queue.get()
+        with obs.span("queue_get", cat="wait"):
+            item = self._queue.get()
         if item is _END:
             self._check_error()
             return None
@@ -175,7 +189,6 @@ class TaskRuntime:
         snap = self.ctx.metrics.snapshot()
         if self._obs_trace is not None:
             # fold this task's metric rollup into the owning query trace
-            # (the metric half of the span-vs-metrics cross-check)
             self._obs_trace.add_task_metrics(snap)
         return snap
 

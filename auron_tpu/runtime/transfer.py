@@ -22,7 +22,6 @@ pipeline (docs/pipeline.md); the prediction half lives in
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Any, Iterator
 
@@ -37,7 +36,6 @@ def start_host_transfer(*arrays) -> None:
     array types without ``copy_to_host_async`` (numpy scalars, tracers in
     tests) simply skip — the later harvest then pays the transfer, which
     is exactly the pre-window behavior."""
-    obs.note_transfer_start(len(arrays))
     for a in arrays:
         copy = getattr(a, "copy_to_host_async", None)
         if copy is not None:
@@ -54,15 +52,12 @@ def harvest(*arrays) -> tuple[np.ndarray, ...]:  # auronlint: thread-root(foreig
     profiling hook — the C++ ``__array__`` fast path bypasses it."""
     import jax
 
-    obs_on = obs.core._mode != obs.MODE_OFF
-    t0 = time.perf_counter_ns() if obs_on else 0
-    with async_read_scope():
-        out = tuple(
+    with obs.span("harvest", cat="wait") as sp, async_read_scope():
+        if sp is not None:
+            sp.arg = {"n": len(arrays)}
+        return tuple(
             np.asarray(x) for x in jax.device_get(arrays)  # auronlint: sync-point(1/batch) -- async-window harvest: transfer started k batches earlier, accounted as async_reads
         )
-    if obs_on:
-        obs.note_harvest(len(arrays), time.perf_counter_ns() - t0)
-    return out
 
 
 class TransferWindow:

@@ -9,9 +9,15 @@ new program shapes and (b) device->host syncs (every ``device_get`` /
 version-dependent, so every hook degrades to "counter absent" rather than
 failing the run.
 
-Every observed compile/sync also lands in the active span's trace and the
-flight recorder (auron_tpu/obs) — the time-correlated record that turns
-"host_sync_s grew" into "the syncs happened HERE, during THAT query".
+Every observed compile/sync is also an ``obs.span`` (auron_tpu/obs): an
+event of the flight recorder under the active span's trace — the
+time-correlated record that turns "host_sync_s grew" into "the syncs
+happened HERE, during THAT query" — and a region on the profiler's
+clock: ``auron:sync:<file:line>`` (``auron:sync:async:<site>`` for a
+window harvest) with the waiting operator's class and the bytes read as
+its arguments, and ``auron:compile:<program>`` named by the XLA module.
+The bytes read back are counted there and nowhere else
+(``obs.window_summary``'s ``d2h_bytes``).
 
 Thread safety: syncs arrive from task pumps, spill threads and transfer
 harvests concurrently. All counter state is guarded by one lock — the
@@ -50,6 +56,18 @@ def async_read_scope():
         _async_ctx.on = prev
 
 
+def _module_name(args, kwargs) -> str:
+    """The XLA module's name (``jit__reduce_arrays_impl``) out of the
+    compile entry's arguments: the MLIR module is the one with an
+    ``operation`` that carries ``sym_name``. Best effort, like the hook
+    itself: "?" where this jaxlib hands the module over otherwise."""
+    for a in (*args, *kwargs.values()):
+        attrs = getattr(getattr(a, "operation", None), "attributes", None)
+        if attrs is not None and "sym_name" in attrs:
+            return str(attrs["sym_name"]).strip('"')
+    return "?"
+
+
 class EngineCounters:
     """Process-wide compile/sync counters. install() is idempotent per
     process; read the totals from .snapshot()."""
@@ -71,8 +89,9 @@ class EngineCounters:
         # batches pumped through task runtimes — the per-batch denominator
         # for sync-budget checks (tools/perfcheck.py)
         self.batches = 0
-        # per-call-site sync attribution (engine frame nearest the sync);
-        # cheap enough to keep always-on: one stack walk per *blocking* sync
+        # per-call-site sync attribution (engine frame nearest the sync):
+        # one stack walk per read, BEFORE it (the site names the read's
+        # region); the table keeps stalls, or every read on request
         self.sync_sites: dict[str, list] = {}
         # per-OPERATOR sync-wait attribution: the innermost live ExecOperator
         # frame at the moment of the stall. Generator suspension makes this
@@ -100,7 +119,7 @@ class EngineCounters:
             _EO = None
         site = None
         op = None
-        f = _sys._getframe(2)
+        f = _sys._getframe(1)
         while f is not None:
             fn = f.f_code.co_filename
             if "auron_tpu" in fn and "utils/profiling" not in fn:
@@ -115,8 +134,7 @@ class EngineCounters:
             f = f.f_back
         return site or "?", op
 
-    def _record_site(self, dt: float) -> None:
-        site, op = self._find_site()
+    def _record_site(self, site: str, op: str | None, dt: float) -> None:
         with self._lock:
             ent = self.sync_sites.setdefault(site, [0, 0.0])
             ent[0] += 1
@@ -145,7 +163,8 @@ class EngineCounters:
                 def counted_compile(*a, **kw):
                     t0 = time.perf_counter()
                     try:
-                        return orig_compile(*a, **kw)
+                        with obs.span(_module_name(a, kw), cat="compile"):
+                            return orig_compile(*a, **kw)
                     finally:
                         dt = time.perf_counter() - t0
                         with self._lock:
@@ -163,12 +182,18 @@ class EngineCounters:
 
             @property
             def counted_value(arr):
+                site, op = self._find_site()
+                is_async = getattr(_async_ctx, "on", False)
                 t0 = time.perf_counter()
                 try:
-                    return orig_value.fget(arr)
+                    with obs.span(f"async:{site}" if is_async else site,
+                                  cat="sync") as sp:
+                        if sp is not None:
+                            sp.arg = {"op": op or "",
+                                      "bytes": int(getattr(arr, "nbytes", 0))}
+                        return orig_value.fget(arr)
                 finally:
                     dt = time.perf_counter() - t0
-                    is_async = getattr(_async_ctx, "on", False)
                     with self._lock:
                         if is_async:
                             self.async_reads += 1
@@ -177,13 +202,10 @@ class EngineCounters:
                             self.syncs += 1
                             self.sync_s += dt
                         all_sites = self.record_all_sites
-                    if is_async:
-                        if dt > _STALL_S:
-                            # the window was too shallow: the harvest still
-                            # blocked — keep it visible in the site table
-                            self._record_site(dt)
-                    elif dt > _STALL_S or all_sites:
-                        self._record_site(dt)
+                    # an async harvest that still blocked (the window was
+                    # too shallow) stays visible in the site table
+                    if dt > _STALL_S or (all_sites and not is_async):
+                        self._record_site(site, op, dt)
                     obs.note_sync(int(dt * 1e9), is_async)
 
             _ja.ArrayImpl._value = counted_value

@@ -36,14 +36,9 @@ GB/s at sf=8) and a correct run whose ingest throughput falls below
 held the same way query speedups are.
 
 ``--trace-out=PATH`` (or AURON_TRACE_OUT) raises obs to full-trace mode
-and writes the timed runs' span timeline as Chrome/Perfetto JSON; the
-record then also carries ``top_ops_span`` (per-op seconds re-derived
-from span events) and ``span_check`` — the cross-check that the span
-timeline and the MetricNode rollup tell the same per-operator story
+and writes the timed runs' span timeline as Chrome/Perfetto JSON
 (docs/observability.md). Without the flag the runs still execute under
-a query trace (ring attribution + /queries summary), but span-event
-accumulation — and therefore the cross-check — exists only in full
-trace mode.
+a query trace (ring attribution + /queries summary).
 """
 
 import json
@@ -197,16 +192,6 @@ def main() -> None:
         shuf = shuffle_breakdown(flat_totals)
     if shuf is not None:
         record["shuffle"] = shuf
-    if qt.trace is not None and qt.trace.span_op_ns:
-        # the SAME ranking re-derived from span-timeline events, plus the
-        # agreement check — the two accountings can't silently diverge.
-        # Span data exists only under full trace mode (--trace-out).
-        span_ops = qt.trace.span_op_seconds()
-        record["top_ops_span"] = {
-            k: round(v, 3)
-            for k, v in sorted(span_ops.items(), key=lambda kv: -kv[1])[:5]
-        }
-        record["span_check"] = qt.trace.op_seconds_skew()
     if trace_out:
         if qt.trace is not None:
             from auron_tpu.obs import export

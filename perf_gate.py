@@ -32,13 +32,9 @@ PERF_GATE_MIN_SPEEDUP (default 0.5; q3/q18/q93/q14 default 1.0).
 
 ``--trace-out=DIR`` (or PERF_GATE_TRACE_OUT=DIR) raises children to
 full-trace mode and writes one Chrome/Perfetto span-timeline artifact
-per class (``trace_<class>_sf<N>.json``); under it the breakdown line
-also carries ``top_ops_span`` (per-op seconds re-derived from span
-events) and ``span_check`` — the agreement gate between the span
-timeline and the MetricNode rollup (docs/observability.md). Without
-the flag each class still runs under a query trace (ring attribution),
-but span-event accumulation is trace-mode only, so those keys are
-absent. Trace-mode runs skip the ratchet (enforcement AND persistence):
+per class (``trace_<class>_sf<N>.json``; docs/observability.md).
+Without the flag each class still runs under a query trace (ring
+attribution). Trace-mode runs skip the ratchet (enforcement AND persistence):
 the accounting overhead inside the timed dispatch must neither fail a
 class hovering at 0.9×best nor pollute the recorded bests.
 
@@ -250,17 +246,6 @@ def run_one(name: str, ws: str) -> None:
         # data-plane visibility (ISSUE 11): throughputs, bytes and the
         # per-block encoding histogram ride every gate run
         brk["shuffle"] = shuf
-    if qt.trace is not None and qt.trace.span_op_ns:
-        # the same top_ops re-derived from the span timeline, and the
-        # agreement check against the metric rollup above — a hop that
-        # lost its span (misattribution!) shows here, not rounds later.
-        # Span data exists only under full trace mode (--trace-out).
-        span_ops = qt.trace.span_op_seconds()
-        brk["top_ops_span"] = {
-            k: round(v, 3)
-            for k, v in sorted(span_ops.items(), key=lambda kv: -kv[1])[:5]
-        }
-        brk["span_check"] = qt.trace.op_seconds_skew()
     print(json.dumps(brk), flush=True)
 
 

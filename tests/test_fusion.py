@@ -345,8 +345,8 @@ def test_replay_adds_no_compiles():
 def test_metric_attribution_splits_per_operator():
     """Fused-program time lands on the CONSTITUENT operators' metric
     nodes (top_ops must see FilterExec/ProjectExec, not one opaque
-    stage), the span timeline receives the same nanos (the <=5%
-    span/metric cross-check relies on it), and the residual stage
+    stage), the flight recorder receives the same nanos as op events,
+    and the residual stage
     overhead NOT covered by the per-constituent split lands on the STAGE
     node — metric conservation: program splits + stage residual ==
     measured stage wall, exactly (a stage that reports 0.0 in top_ops
@@ -582,3 +582,74 @@ def test_writer_stage_counted_and_byte_identical(tmp_path):
         # the trailing 16 bytes carry a random attempt pair tag
         assert on_data[:-16] == off_data[:-16], name
         assert len(on_idx) == len(off_idx), name
+
+
+# ---------------------------------------------------------------------------
+# scope names inside the stage programs (what a device trace names ops by)
+# ---------------------------------------------------------------------------
+
+
+def _first_call(monkeypatch, program: str, run):
+    """Run ``run()`` with ``fusion.<program>`` wrapped to keep its first
+    call: ``(the jitted program, args, kwargs)``."""
+    jitted = getattr(fusion, program)
+    calls = []
+
+    def capture(*args, **kw):
+        calls.append((args, kw))
+        return jitted(*args, **kw)
+
+    monkeypatch.setattr(fusion, program, capture)
+    run()
+    assert calls, f"{program} never dispatched"
+    return (jitted, *calls[0])
+
+
+def test_probe_stage_program_carries_its_scope_names(monkeypatch):
+    """The lowered text of the probe stage program holds every
+    ``auron.stage.*`` / ``auron.probe.*`` scope name (metadata only)."""
+    from auron_tpu.exec.base import ExecutionContext
+
+    dim = pd.DataFrame({"id": np.arange(1, 101, dtype=np.int64),
+                        "b": np.arange(1, 101) * 2.0})
+    dim_b = [Batch.from_pandas(dim)]
+
+    def run():
+        pb = _probe_frame(3)
+        scan = MemoryScanExec([pb], pb[0].schema)
+        flt = FilterExec(scan, [BinaryOp(
+            "gt", Column(1, "v"), Literal(-10.0, T.FLOAT64))])
+        join = BroadcastHashJoinExec(
+            flt, MemoryScanExec([list(dim_b)], dim_b[0].schema),
+            [Column(0, "k")], [Column(0, "id")], "inner", build_side="right")
+        list(fuse_exec_tree(join, ON).execute(0, ExecutionContext()))
+
+    jitted, args, kw = _first_call(monkeypatch, "_stage_program_probe", run)
+    # the predicted compact-take is the variant that runs every step
+    compact = dict(kw, probe=kw["probe"][:-1] + (("compact", 256),))
+    text = jitted.lower(*args, **compact).as_text(debug_info=True)
+    for scope in ("auron.stage.step0.filter", "auron.probe.pack",
+                  "auron.probe.lookup", "auron.probe.compact",
+                  "auron.probe.gather"):
+        assert scope in text, scope
+
+
+def test_shuffle_stage_program_carries_its_scope_names(monkeypatch, tmp_path):
+    from auron_tpu.exec.base import ExecutionContext
+    from auron_tpu.exec.shuffle.partitioning import HashPartitioning
+    from auron_tpu.exec.shuffle.writer import ShuffleWriterExec
+
+    frames = [_frame(2000, s) for s in (1, 2)]
+
+    def run():
+        scan = MemoryScanExec([list(frames)], frames[0].schema)
+        twice = BinaryOp("mul", Column(1, "v"), Literal(2.0, T.FLOAT64))
+        prj = ProjectExec(scan, [Column(0, "k"), twice], ["k", "v2"])
+        w = ShuffleWriterExec(prj, HashPartitioning([Column(0, "k")], 3),
+                              str(tmp_path / "x.data"), str(tmp_path / "x.index"))
+        list(fuse_exec_tree(w, ON).execute(0, ExecutionContext()))
+
+    jitted, args, kw = _first_call(monkeypatch, "_stage_program_shuffle", run)
+    text = jitted.lower(*args, **kw).as_text(debug_info=True)
+    for scope in ("auron.stage.step0.project", "auron.shuffle.partition"):
+        assert scope in text, scope

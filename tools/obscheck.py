@@ -16,9 +16,9 @@ pipeline tools/perfcheck.py uses, in three subprocess configurations:
 
 A ``trace``-mode run also executes (full tracing + per-query summary):
 its wall is REPORTED, and its exported artifact is sanity-checked —
-Chrome-trace JSON loads, carries op/sync/compile event kinds, and the
-span-derived per-operator seconds agree with the MetricNode rollup
-within 5%% (the accounting cross-check of docs/observability.md).
+Chrome-trace JSON loads, carries op events and the regions of the task,
+pump and sync layers, and ``obs.window_summary`` of the timed replay is
+complete and holds the batch path's layers (docs/observability.md).
 
 Methodology: each mode runs OBSCHECK_REPS times interleaved and the
 MINIMUM wall is compared — min-of-N measures the systematic cost, not
@@ -71,9 +71,7 @@ def child(trace_out: str | None) -> None:
         rec["wall_s"] = round(time.perf_counter() - t0, 4)
         export.write_chrome_trace(trace_out, trace_id=qt.trace.id)
         rec["trace_out"] = trace_out
-        # min_s low enough that the tiny replay's top ops still qualify —
-        # a threshold nothing crosses would pass the cross-check vacuously
-        rec["skew"] = qt.trace.op_seconds_skew(min_s=0.005)
+        rec["window"] = obs.window_summary(t0, time.perf_counter())
         # whether the version-dependent EngineCounters sync hook is live:
         # the artifact check requires sync events only when it is
         rec["host_syncs"] = EngineCounters._installed.snapshot()["host_syncs"]
@@ -115,11 +113,12 @@ def _check_trace_artifact(path: str, rec: dict) -> list[str]:
         return [f"trace artifact unreadable: {e!r}"]
     xs = [e for e in ct.get("traceEvents", []) if e.get("ph") == "X"]
     kinds = {e.get("cat") for e in xs}
-    # op/span events come from our own instrumentation and must exist;
-    # sync events depend on the version-sensitive EngineCounters hook
-    # (profiling.py degrades to "counter absent" by design) — require
-    # them only when the child actually observed syncs
-    required = ["op", "span"]
+    # op events and the task/pump regions come from our own
+    # instrumentation and must exist; sync regions depend on the
+    # version-sensitive EngineCounters hook (profiling.py degrades to
+    # "counter absent" by design) — require them only when the child
+    # actually observed syncs
+    required = ["op", "task", "pump"]
     if rec.get("host_syncs", 0) > 0:
         required.append("sync")
     for want in required:
@@ -129,15 +128,13 @@ def _check_trace_artifact(path: str, rec: dict) -> list[str]:
         isinstance(e.get("ts"), (int, float)) and "name" in e for e in xs
     ):
         problems.append("trace artifact has malformed X events")
-    skew = rec.get("skew") or {}
-    if not skew.get("ok", False):
-        problems.append(f"span/metric op-seconds diverge: {skew}")
-    elif skew.get("compared", 0) == 0:
-        # ok=true with nothing compared is a vacuous pass, not a pass
-        problems.append(
-            "span/metric cross-check compared no operator (all below "
-            "min_s) — raise OBSCHECK_SF so the check has teeth"
-        )
+    window = rec.get("window") or {}
+    if not window.get("complete", False):
+        problems.append("window_summary of the timed replay is incomplete")
+    missing = {"entry", "plan", "task", "pump", "wait"} - set(
+        window.get("layers", {}))
+    if missing:
+        problems.append(f"window_summary lacks layers {sorted(missing)}")
     return problems
 
 
@@ -159,7 +156,8 @@ def main() -> int:
                               "trace.json")
     trec = _run_child({"AURON_TPU_OBS_MODE": "trace"}, trace_out=trace_file)
     print(json.dumps({"mode": "trace", **{k: v for k, v in trec.items()
-                                          if k != "summary"}}), flush=True)
+                                          if k not in ("summary", "window")}}),
+          flush=True)
 
     base = min(walls["base"])
     failures = list(_check_trace_artifact(trace_file, trec))
