@@ -37,6 +37,7 @@ import numpy as np
 import pyarrow as pa
 from jax import lax
 
+from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import (
     Batch,
@@ -587,16 +588,37 @@ class HashAggExec(ExecOperator):
                 SelectivityPredictor(conf) if predictor_enabled(conf) else None
             )
 
+        def fold_deferred(bb, in_capacity):
+            """One PARTIAL raw fold of the deferred arm (dispatch and
+            mispredict repair alike), noted in the rings with the capacity
+            the reduce runs at beside the capacity the batch came in with
+            (an event, not a region: the pump's self time keeps its
+            meaning)."""
+            obs.note_agg_fold(bb.capacity, in_capacity)
+            with ctx.metrics.timer("elapsed_compute"):
+                return self._to_intermediate(bb, ctx)
+
         def dispatch_deferred(b):
             """Dispatch half: device work only — predicted compaction +
             the grouped reduce; the (live count, group count, collision
-            flag) scalars ride the window host-ward."""
+            flag) scalars ride the window host-ward. A stream's FIRST
+            batch has no history to predict from: its live count is read
+            here, once a stream, and seeds the predictor, so compaction
+            engages from batch 1 and not from the first harvest (depth + 1
+            batches in — past the end of a short stream)."""
             from auron_tpu.columnar.batch import compact_batch, compaction_bucket
 
-            pred_cap = (
-                defer_pred.predict(b.capacity)
-                if defer_pred is not None else None
-            )
+            pred_cap = None
+            seeded = False
+            if defer_pred is not None:
+                pred_cap = defer_pred.predict(b.capacity)
+                if pred_cap is None:
+                    # auronlint: disable=R9 -- first-batch-only branch: predict() is None exactly once per stream (the observe below seeds it)
+                    n_seed = int(jax.device_get(b.device.num_rows()))  # auronlint: sync-point(4/task) -- deferred-agg seed: the stream's first live count, read before its reduce is dispatched
+                    defer_pred.observe(n_seed)
+                    ctx.metrics.add("sel_seed_reads", 1)
+                    seeded = True
+                    pred_cap = defer_pred.predict(b.capacity)
             used_cap = None
             bb = b
             if pred_cap is not None:
@@ -606,22 +628,24 @@ class HashAggExec(ExecOperator):
                     # detects n > used_cap and recomputes from ``b``
                     bb = compact_batch(b, out_cap)
                     used_cap = out_cap
-            with ctx.metrics.timer("elapsed_compute"):
-                inter = self._to_intermediate(bb, ctx)
+                    ctx.metrics.add("agg_compacted_batches", 1)
+            inter = fold_deferred(bb, b.capacity)
             coll = getattr(inter, "_fp_collision", None)
             scalars = [b.device.num_rows(), inter.device.num_rows()]
             if coll is not None:
                 scalars.append(coll)
-            return tuple(scalars), (b, inter, used_cap, coll is not None)
+            return tuple(scalars), (b, inter, used_cap, coll is not None, seeded)
 
         def resolve_deferred(resolved, state):
             """Harvest half, k batches behind dispatch: exact (n, g) land
             together — no pending_g carry — and the intermediate stages at
             its exact group bucket."""
             nonlocal seen_rows, seen_groups, skipping
-            b, inter, used_cap, has_coll = state
+            b, inter, used_cap, has_coll, seeded = state
             n, g = int(resolved[0]), int(resolved[1])
-            if defer_pred is not None:
+            if defer_pred is not None and not seeded:
+                # the seed batch was observed at dispatch: the EWMA and the
+                # shrink streak count batches, never one twice
                 defer_pred.observe(n, predicted=used_cap)
             if n == 0:
                 return
@@ -634,8 +658,7 @@ class HashAggExec(ExecOperator):
                 bb = b
                 if 4 * n <= b.capacity:
                     bb = compact_batch(b, bucket_capacity(n))
-                with ctx.metrics.timer("elapsed_compute"):
-                    inter = self._to_intermediate(bb, ctx)
+                inter = fold_deferred(bb, b.capacity)
                 coll = getattr(inter, "_fp_collision", None)
                 scalars = [inter.device.num_rows()]
                 if coll is not None:

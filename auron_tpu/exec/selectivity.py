@@ -21,7 +21,10 @@ autocorrelated across batches of one stream, so the bucket is *predictable*:
   device state — results are bit-identical to the blocking path.
 
 The first batch of a stream has no history and takes the classic blocking
-path (one sync per stream, not per batch).
+path (one sync per stream, not per batch): every consumer reads that
+batch's live count itself and ``observe``s it before its first ``predict``
+— waiting for the first harvest instead leaves a stream no longer than
+the transfer window unseeded to its end.
 """
 
 from __future__ import annotations

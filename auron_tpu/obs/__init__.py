@@ -12,10 +12,10 @@ Public surface:
   span model (obs/span.py); spans cross thread hops EXPLICITLY, like
   conf (R7). A span's ``cat`` is its layer (``LAYERS``), and every span
   is also a region ``auron:<layer>:<name>`` on the profiler's clock.
-- ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` —
-  the instrumentation facade behind MetricNode.timer, the EngineCounters
-  hooks and the task pump. Each checks ``core._mode`` first; in mode off
-  a call is one flag test.
+- ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` /
+  ``note_agg_fold`` — the instrumentation facade behind MetricNode.timer,
+  the EngineCounters hooks, the task pump and the partial aggregate.
+  Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``window_summary(t0_s, t1_s)`` — where the host's time went between
   two readings of ``time.perf_counter()``, by layer (obs/export.py).
 - exporters in ``auron_tpu.obs.export`` (Chrome/Perfetto JSON,
@@ -115,6 +115,20 @@ def note_op(op: str, metric: str, dur_ns: int) -> None:
     core.record("op", metric, dur_ns, tid, sid, 0, op.partition(".")[0])
 
 
+def note_agg_fold(rows: int, in_rows: int) -> None:
+    """One PARTIAL raw fold of the deferred aggregate (exec/agg_exec.py):
+    the capacity its grouped reduce runs at, beside the capacity the batch
+    came in with. A ``fold`` event of no duration and no layer (NOT a
+    region: it takes nothing out of ``pump:batch``'s self time);
+    ``window_summary`` sums the first as ``agg_fold_rows``."""
+    if core._mode == MODE_OFF:
+        return
+    sp = _span_var.get()
+    tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
+    core.record("fold", "agg.partial", 0, tid, sid, 0,
+                {"rows": rows, "in_rows": in_rows})
+
+
 def note_sync(dur_ns: int, is_async: bool) -> None:
     """One device->host read observed by EngineCounters (blocking sync or
     async-window harvest), into the trace counters of the calling
@@ -153,4 +167,5 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
         return None
 
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
+    note_agg_fold = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

@@ -115,7 +115,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ``spans`` (host reads excepted: their names are sites). Thread-seconds,
     not wall: two map pumps run at once. ``d2h_bytes`` sums the bytes of
     the host reads; ``sync_sites`` ranks them by seconds as ``[site, n,
-    seconds]``. ``complete`` is False where
+    seconds]``. ``agg_fold_rows`` sums the capacities the partial
+    aggregate's raw folds ran at (the ``fold`` events that began in the
+    window, ``obs.note_agg_fold``). ``complete`` is False where
     a ring that may hold events of the window has wrapped, or left the
     registry with events newer than the window's start: the sums are then
     a lower bound and a metric reader reports nothing."""
@@ -124,7 +126,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     layers: dict[str, dict] = {}
     spans: dict[str, dict] = {}
     sites: dict[str, list] = {}
-    d2h = 0
+    d2h = fold_rows = 0
 
     def book(table: dict, key: str, dur_ns: int, own_ns: int) -> None:
         ent = table.setdefault(key, {"n": 0, "total_s": 0.0, "self_s": 0.0})
@@ -137,6 +139,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
         # oldest one did
         if ring["wrapped"] and evs[0][0] + evs[0][1] > lo:
             complete = False
+        fold_rows += sum(ev[7]["rows"] for ev in evs
+                         if ev[2] == "fold" and lo <= ev[0] < hi)
         regions = [
             (max(ts, lo), min(ts + dur, hi), layer, name, arg)
             for (ts, dur, _k, name, _t, _s, _p, arg, layer) in evs
@@ -154,6 +158,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ranked = sorted(sites.items(), key=lambda kv: -kv[1][1])[:top]
     return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
+            "agg_fold_rows": fold_rows,
             "sync_sites": [[k, n, secs] for k, (n, secs) in ranked]}
 
 

@@ -922,3 +922,123 @@ def test_deferred_partial_counts_k_deep_interleaved_mispredicts():
     finally:
         conf.set(TRANSFER_WINDOW_DEPTH, saved_depth)
         conf.set(AGG_PARTIAL_DEFER, saved_defer)
+
+
+# live rows of each of the stream's three 4096-row batches; None = default
+# predictor knob. ``want``: seed reads, mispredict repairs, batches
+# compacted at dispatch, and the capacities the raw folds ran at, in order
+# (a repair's fold comes at the drain, after the stream's three).
+_SEED_CASES = {
+    "sparse": dict(live=(100, 90, 110), want=(1, 0, 3, [256, 256, 256])),
+    "dense": dict(live=(3000, 2900, 3100), want=(1, 0, 0, [4096, 4096, 4096])),
+    "growing": dict(live=(60, 600, 60), want=(1, 1, 3, [128, 128, 128, 1024])),
+    "empty_first": dict(live=(0, 1000, 0), want=(1, 1, 3, [128, 128, 128, 1024])),
+    "predictor_off": dict(live=(100, 90, 110), predictor="off",
+                          want=(0, 0, 0, [4096, 4096, 4096])),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SEED_CASES))
+def test_deferred_partial_seeds_the_predictor_on_a_streams_first_batch(case):
+    """A stream shorter than the transfer window (3 batches, depth 4) never
+    harvests before its drain, so the deferred arm reads the FIRST batch's
+    live count (one blocking read a stream) and compacts from batch 1 on:
+    the grouped reduce folds live rows, not batch capacity. Row- and
+    count-exact against exec.agg.partial.defer=off in every case."""
+    import os
+    import time
+
+    import auron_tpu
+    from auron_tpu import obs
+    from auron_tpu.exec.basic import FilterExec
+    from auron_tpu.exprs.ir import IsNotNull
+    from auron_tpu.obs import core
+    from auron_tpu.utils.config import (
+        AGG_PARTIAL_DEFER, SELECTIVITY_PREDICTOR_ENABLE,
+        TRANSFER_WINDOW_DEPTH, active_conf,
+    )
+    from auron_tpu.utils.profiling import EngineCounters
+
+    spec = _SEED_CASES[case]
+    cap = 4096
+    rng = np.random.default_rng(27)
+    frames, rows = [], []
+    for n_live in spec["live"]:
+        ks = [f"brand#{int(k)}" for k in rng.integers(0, 37, cap)]
+        alive = np.zeros(cap, dtype=bool)
+        alive[rng.choice(cap, n_live, replace=False)] = True
+        vs = rng.integers(1, 10_000, cap)
+        frames.append(Batch.from_pydict({
+            "k": ks,
+            "v": [int(v) for v in vs],
+            "live": [1 if a else None for a in alive],
+        }))
+        rows += [(k, int(v)) for k, v, a in zip(ks, vs, alive) if a]
+    want = (
+        pd.DataFrame(rows, columns=["k", "v"])
+        .groupby("k").agg(c=("v", "size"), s=("v", "sum")).reset_index()
+        .sort_values("k").reset_index(drop=True)
+    )
+
+    EngineCounters.install()
+    conf = active_conf()
+    saved = {k: conf.get(k) for k in (
+        AGG_PARTIAL_DEFER, SELECTIVITY_PREDICTOR_ENABLE, TRANSFER_WINDOW_DEPTH)}
+    saved_mode = obs.mode()
+
+    def run(defer):
+        conf.set(AGG_PARTIAL_DEFER, defer)
+        scan = MemoryScanExec.single(
+            [Batch(b.schema, b.device, b.dicts) for b in frames])
+        flt = FilterExec(scan, [IsNotNull(col(2))])
+        aggs = [(AggExpr("count_star", None), "c"), (AggExpr("sum", col(1)), "s")]
+        p = HashAggExec(flt, [(col(0), "k")], aggs, PARTIAL)
+        f = HashAggExec(p, [(col(0), "k")], aggs, FINAL)
+        ctx = ExecutionContext()
+        ctx.metrics.name = f.name
+        t0 = time.perf_counter()
+        out = f.collect(ctx=ctx).to_pandas().sort_values("k").reset_index(drop=True)
+        return out, ctx.metrics, (t0, time.perf_counter())
+
+    try:
+        obs.set_mode("recorder")
+        conf.set(TRANSFER_WINDOW_DEPTH, 4)
+        conf.set(SELECTIVITY_PREDICTOR_ENABLE, spec.get("predictor", "auto"))
+        got, metrics, (t0, t1) = run("on")
+        off, _, _ = run("off")
+    finally:
+        for k, v in saved.items():
+            conf.set(k, v)
+        obs.set_mode(saved_mode)
+
+    for df in (got, off):
+        assert df["k"].tolist() == want["k"].tolist()
+        assert df["c"].tolist() == want["c"].tolist()
+        assert df["s"].tolist() == want["s"].tolist()
+
+    seeds, repairs, compacted, fold_caps = spec["want"]
+    assert metrics.total("sel_seed_reads") == seeds
+    assert metrics.total("sel_mispredicts") == repairs
+    assert metrics.total("agg_compacted_batches") == compacted
+    lo, hi = int(t0 * 1e9), int(t1 * 1e9)
+    events = sorted((ev for _ring, evs in core.snapshot_events() for ev in evs
+                     if lo <= ev[0] < hi), key=lambda ev: ev[0])
+    folds = [ev[7] for ev in events if ev[2] == "fold"]
+    assert [f["rows"] for f in folds] == fold_caps
+    assert all(f["in_rows"] == cap for f in folds)
+    ws = obs.window_summary(t0, t1)
+    assert ws["agg_fold_rows"] == sum(fold_caps)
+    # the arm's blocking reads, by the sync-point lines the hook names:
+    # one seed read a stream, one more for each repair, and no other
+    src = os.path.join(os.path.dirname(auron_tpu.__file__), "exec/agg_exec.py")
+    with open(src) as f:
+        lines = f.readlines()
+    arm_reads = [
+        lines[int(ev[3].rsplit(":", 1)[1]) - 1].split("sync-point", 1)[1]
+        for ev in events
+        if ev[8] == "sync" and ev[3].startswith("exec/agg_exec.py:")
+        and "deferred-agg" in lines[int(ev[3].rsplit(":", 1)[1]) - 1]
+    ]
+    assert sum("deferred-agg seed" in r for r in arm_reads) == seeds
+    assert sum("mispredict repair" in r for r in arm_reads) == repairs
+    assert len(arm_reads) == seeds + repairs

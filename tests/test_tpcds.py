@@ -65,12 +65,21 @@ def test_windowed_query_matches_oracle(data):
         assert g == pytest.approx(w, rel=1e-9)
 
 
-def test_q3_concurrent_maps_with_spills():
+def test_q3_concurrent_maps_with_spills(monkeypatch):
     """Map tasks run concurrently; a tiny memory budget forces cross-thread
     spill cascades through MemManager — results must stay exact (regression
     for the per-consumer locking added in round 2)."""
+    from auron_tpu.exec import agg_exec
     from auron_tpu.memory.memmgr import MemManager
 
+    # the plan's integer keys would fold in the dense table, which is
+    # unspillable and stages nothing until its final drain: a spill then
+    # needs two tasks to hold memory at the same instant, and under a
+    # loaded host none did (num_spills == 0, the one tier-1 failure of PR
+    # 25-26's runs). With the dense table refusing, every batch stages an
+    # intermediate through the generic path (the deferred window, the
+    # table's own spills), so each map task spills whatever the timing.
+    monkeypatch.setattr(agg_exec._DenseAggState, "LIMIT", 0)
     data = tpcds.generate(sf=0.05, seed=9)
     MemManager.init(budget_bytes=4096)  # tiny: every staged inter spills
     orig = tpcds.to_batches
