@@ -501,16 +501,9 @@ def _arrow_to_host(arr: pa.Array, dtype: T.DataType, cap: int,
         # unscaled magnitude exceeds int64 (possible for p>18) become NULL —
         # matching Spark's non-ANSI overflow-to-null behavior rather than
         # crashing ingestion (documented decimal64 limitation, types.py).
-        unscaled = arr.cast(pa.decimal128(38, dtype.scale))
-        ints = np.zeros(n, dtype=np.int64)
-        for j, x in enumerate(unscaled):
-            if not x.is_valid:
-                continue
-            u = int(x.as_py().scaleb(dtype.scale))
-            if -(2**63) <= u < 2**63:
-                ints[j] = u
-            else:
-                mask_np[j] = False
+        ints, fits = _decimal_unscaled(arr, dtype.scale)
+        if not fits.all():
+            np.logical_and(mask_np[:n], fits, out=mask_np[:n])
         vals_np = _pad_to_cap(ints, cap, phys, zc=zc)
     elif dtype.kind == T.TypeKind.TIMESTAMP:
         a = arr.cast(pa.timestamp("us"))
@@ -535,6 +528,29 @@ def _arrow_to_host(arr: pa.Array, dtype: T.DataType, cap: int,
             a = a.fill_null(T.numpy_zero(dtype))
         vals_np = _pad_to_cap(a.to_numpy(zero_copy_only=False), cap, phys, zc=zc)
     return vals_np, mask_np, d
+
+
+def _decimal_unscaled(arr: pa.Array, scale: int):
+    """(unscaled int64[n], fits bool[n]) of a decimal Arrow array at
+    ``scale``, in one pass over the Decimal128 buffer: a value is two
+    little-endian 64-bit words, and it fits int64 exactly when the high
+    word is the sign extension of the low one. NULL slots and values that
+    do not fit read 0; ``fits`` is False for the latter alone."""
+    wide = arr.cast(pa.decimal128(38, scale))
+    n = len(wide)
+    buf = wide.buffers()[1]
+    if n == 0 or buf is None:
+        return np.zeros(n, dtype=np.int64), np.ones(n, dtype=bool)
+    words = np.frombuffer(buf, dtype="<i8", count=2 * n,
+                          offset=16 * wide.offset).reshape(n, 2)
+    lo, hi = words[:, 0], words[:, 1]
+    fits = hi == (lo >> 63)
+    keep = fits
+    if wide.null_count:
+        valid = pc.is_valid(wide).to_numpy(zero_copy_only=False)
+        keep = fits & valid
+        fits = fits | ~valid
+    return np.where(keep, lo, 0), fits
 
 
 def _decimal_from_unscaled(vals: np.ndarray, mask: np.ndarray, dtype: T.DataType) -> pa.Array:

@@ -117,7 +117,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     the host reads; ``sync_sites`` ranks them by seconds as ``[site, n,
     seconds]``. ``agg_fold_rows`` sums the capacities the partial
     aggregate's raw folds ran at (the ``fold`` events that began in the
-    window, ``obs.note_agg_fold``). ``complete`` is False where
+    window, ``obs.note_agg_fold``). ``plan_cache_hits`` and
+    ``plan_cache_misses`` count the ``serve:plan`` spans that began in the
+    window by their ``cache_hit`` argument. ``complete`` is False where
     a ring that may hold events of the window has wrapped, or left the
     registry with events newer than the window's start: the sums are then
     a lower bound and a metric reader reports nothing."""
@@ -127,6 +129,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     spans: dict[str, dict] = {}
     sites: dict[str, list] = {}
     d2h = fold_rows = 0
+    plans = [0, 0]          # serve:plan spans: [misses, hits]
 
     def book(table: dict, key: str, dur_ns: int, own_ns: int) -> None:
         ent = table.setdefault(key, {"n": 0, "total_s": 0.0, "self_s": 0.0})
@@ -141,6 +144,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             complete = False
         fold_rows += sum(ev[7]["rows"] for ev in evs
                          if ev[2] == "fold" and lo <= ev[0] < hi)
+        for ev in evs:
+            if ev[8] == "serve" and ev[3] == "plan" and lo <= ev[0] < hi:
+                plans[bool(ev[7]["cache_hit"])] += 1
         regions = [
             (max(ts, lo), min(ts + dur, hi), layer, name, arg)
             for (ts, dur, _k, name, _t, _s, _p, arg, layer) in evs
@@ -159,6 +165,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
             "agg_fold_rows": fold_rows,
+            "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
             "sync_sites": [[k, n, secs] for k, (n, secs) in ranked]}
 
 

@@ -54,7 +54,7 @@ _span_var: contextvars.ContextVar = contextvars.ContextVar(
 #: (utils/profiling.py) around each host read and each compile
 LAYERS = frozenset({
     "entry", "plan", "pump", "exchange", "wait", "sync", "compile", "spill",
-    "sql", "stream", "task", "query",
+    "sql", "stream", "task", "query", "serve",
 })
 
 #: ``jax.profiler.TraceAnnotation``, imported when the first span opens

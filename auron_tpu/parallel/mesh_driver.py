@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import (
     Batch,
@@ -213,22 +214,29 @@ class MeshQueryDriver:
                 else range(self._reduce_parts or self.n_parts)
             )
             for p in parts:
-                # whole-stage fusion applies to driver-executed stages
-                # exactly as task_from_proto applies it to bridge tasks
-                # (plan/fusion.py; protos untouched, bit-identical by the
-                # PR-7 contract). Before the serving work this path ran
-                # every SQL-lowered mesh stage EAGER — per-batch python
-                # dispatch the fused programs remove, which under
-                # concurrent queries was pure GIL serialization
-                from auron_tpu.plan.fusion import fuse_exec_tree
-
-                op = fuse_exec_tree(plan_from_proto(resolved), self.conf)
+                op = self._plan_stage(resolved)
                 ctx = ExecutionContext(partition_id=p, conf=self.conf.copy(),
                                        resources=resources)
-                outs[p] = list(op.execute(p, ctx))
+                outs[p] = _pump(op, p, ctx)
             return outs
         finally:
             self._cleanup_tmp()
+
+    def _plan_stage(self, proto: pb.PhysicalPlanNode):
+        """A driver-executed stage's exec tree, under the spans
+        ``task_from_proto`` opens for a bridge task (``plan:task``,
+        ``plan:fusion`` inside it). Whole-stage fusion applies here exactly
+        as there (plan/fusion.py; protos untouched, bit-identical by the
+        PR-7 contract): before the serving work this path ran every
+        SQL-lowered mesh stage EAGER — per-batch python dispatch the fused
+        programs remove, which under concurrent queries was pure GIL
+        serialization."""
+        from auron_tpu.plan.fusion import fuse_exec_tree
+
+        with obs.span("task", cat="plan"):
+            plan = plan_from_proto(proto)
+            with obs.span("fusion", cat="plan"):
+                return fuse_exec_tree(plan, self.conf)
 
     @staticmethod
     def _collect_sources(plan: pb.PhysicalPlanNode) -> list[tuple[str, str]]:
@@ -432,20 +440,41 @@ class MeshQueryDriver:
         n_src = self._maybe_coalesce_inputs(child, resources)
         if n_src == self.n_parts and not self.spmd:
             n_src = self._maybe_split_skew(child, resources)
-        from auron_tpu.plan.fusion import fuse_exec_tree
-
-        op = fuse_exec_tree(plan_from_proto(child), self.conf)
+        op = self._plan_stage(child)
         schema = op.schema
         shard_batches: list[Batch] = []
-        pids: list[jnp.ndarray] = []
         map_parts = self.local_parts if self.spmd else range(n_src)
         for p in map_parts:
             ctx = ExecutionContext(partition_id=p, conf=self.conf.copy(),
                                    resources=resources)
-            got = list(op.execute(p, ctx))
-            b = device_concat(got) if got else Batch.empty(schema)
-            shard_batches.append(b)
-            pids.append(part.partition_ids(b, ctx))
+            got = _pump(op, p, ctx)
+            shard_batches.append(
+                device_concat(got) if got else Batch.empty(schema))
+        with obs.span("write", cat="exchange") as sp:
+            out = self._route(spec, part, schema, shard_batches, n_src,
+                              ex_id, resources)
+            if sp is not None:
+                st = self.stats[-1]
+                sp.arg = {"mode": st.mode, "rows": int(st.rows.sum()),
+                          "bytes": int(st.rows.sum()) * _row_width_bytes(schema)}
+        if isinstance(out, pb.PhysicalPlanNode):
+            return out          # file transport: its readers are IpcReaders
+        with obs.span("read", cat="exchange"):
+            return self._mesh_receive(schema, ex_id, resources, *out)
+
+    def _route(self, spec, part, schema: T.Schema, shard_batches: list[Batch],
+               n_src: int, ex_id: str, resources: dict):
+        """The exchange's write side, one ``exchange:write`` region:
+        destination ids, the routing matrix, the transport decision and
+        the send. File transport: the spliced reader node (the writers
+        open their own ``exchange:write`` regions inside this one). Mesh:
+        what ``_mesh_receive`` takes, the collective dispatched."""
+        pids: list[jnp.ndarray] = [
+            part.partition_ids(b, ExecutionContext(
+                partition_id=p, conf=self.conf.copy(), resources=resources))
+            for p, b in zip(self.local_parts if self.spmd else range(n_src),
+                            shard_batches)
+        ]
 
         # ---- statistics + transport decision
         counts = self._routing_counts(shard_batches, pids)
@@ -497,10 +526,8 @@ class MeshQueryDriver:
 
         if mode == "file":
             return self._file_exchange(spec, schema, shard_batches, ex_id, resources)
-        return self._mesh_exchange(
-            schema, shard_batches, pids, counts, ex_id, resources,
-            spmd_cap=spmd_cap,
-        )
+        return self._mesh_send(schema, shard_batches, pids, counts,
+                               spmd_cap=spmd_cap)
 
     def _routing_counts(self, batches: list[Batch], pids: list[jnp.ndarray]) -> np.ndarray:
         """Exact [P_src, P_dst] live-row routing matrix (one host sync):
@@ -599,16 +626,14 @@ class MeshQueryDriver:
 
     # ---- ICI transport ------------------------------------------------
 
-    def _mesh_exchange(
+    def _mesh_send(
         self,
         schema: T.Schema,
         batches: list[Batch],
         pids: list[jnp.ndarray],
         counts: np.ndarray,
-        ex_id: str,
-        resources: dict,
         spmd_cap: int | None = None,
-    ) -> pb.PhysicalPlanNode:
+    ) -> tuple:
         ncols = len(schema)
         # unify dictionaries so codes are meaningful across shards
         dicts: list = [None] * ncols
@@ -666,6 +691,15 @@ class MeshQueryDriver:
             place(sel),
             place(pid),
         )
+        return tuple(dicts), rvals, rmasks, rsel, overflow
+
+    def _mesh_receive(self, schema: T.Schema, ex_id: str, resources: dict,
+                      dicts: tuple, rvals, rmasks, rsel,
+                      overflow) -> pb.PhysicalPlanNode:
+        """The exchange's read side, one ``exchange:read`` region: the wait
+        for the collective (the overflow check reads one scalar of its
+        result) and each partition's received rows taken out of the
+        exchanged arrays."""
         assert int(jax.device_get(overflow)) == 0, "sized from exact counts"  # auronlint: sync-point(4/task) -- one-scalar overflow invariant check per exchange
         self.stats[-1].n_devices = len(rsel.sharding.device_set)
 
@@ -680,7 +714,7 @@ class MeshQueryDriver:
                 tuple(shard(v, p) for v in rvals),
                 tuple(shard(m, p) for m in rmasks),
             )
-            out_parts[p] = [Batch(schema, dev, tuple(dicts))]
+            out_parts[p] = [Batch(schema, dev, dicts)]
         resources[ex_id] = out_parts
         return pb.PhysicalPlanNode(
             memory_scan=pb.MemoryScanNode(
@@ -803,6 +837,28 @@ class MeshQueryDriver:
                 schema=schema_to_proto(schema), resource_id=ex_id
             )
         )
+
+
+def _pump(op, partition: int, ctx: ExecutionContext) -> list[Batch]:
+    """Drain one partition of a stage's operator tree: one ``pump:batch``
+    region per PULL, as runtime/task.py's pump has it (the last pull,
+    which ends the stream, included), so the readers of the task pump's
+    regions read driver-executed stages too. Never open across a yield:
+    the loop drives the iterator with next() inside the region."""
+    from auron_tpu.utils.profiling import EngineCounters
+
+    counters = EngineCounters._installed
+    out: list[Batch] = []
+    batches = iter(op.execute(partition, ctx))
+    while True:
+        with obs.span("batch", cat="pump"):
+            b = next(batches, None)
+        if b is None:
+            return out
+        if counters is not None:
+            counters.note_batch()
+        obs.note_pump_batch()
+        out.append(b)
 
 
 def _partition_scoped(which: str, inner) -> bool:

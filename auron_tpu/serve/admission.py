@@ -26,6 +26,7 @@ from __future__ import annotations
 import threading
 import time
 
+from auron_tpu import obs
 from auron_tpu.utils.config import (
     SERVE_ADMIT_MEM_FRACTION,
     SERVE_MAX_CONCURRENT,
@@ -143,7 +144,10 @@ class _Admit:
         self.wait_s = 0.0
 
     def __enter__(self) -> "_Admit":
-        self.wait_s = self._ctl._acquire()
+        with obs.span("admit", cat="serve") as sp:
+            self.wait_s = self._ctl._acquire()
+            if sp is not None:
+                sp.arg = {"queue_wait_s": self.wait_s}
         return self
 
     def __exit__(self, *exc) -> bool:

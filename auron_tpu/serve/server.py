@@ -18,20 +18,27 @@ One instance serves many concurrent queries (docs/serving.md):
   output under the shared ``sql:__stage__`` rid without racing on the
   global resource map.
 
-The server owns the table frames (a catalog's worth of pandas frames,
-as built by sql/catalog.build_tables) and uploads each scanned view
-once per (rid, mesh width) — the Flare compile-once/serve-many shape,
-applied to data residency too.
+The server owns the tables as COLUMNAR batches of the catalog's declared
+schemas, resident on the device from construction: a table is handed
+over as a list of ``Batch`` (a host engine's materialised segments), or
+as a pandas frame that is converted once, here, through the same ingest
+(``Batch.from_pandas`` under the catalog's schema). A scanned view — the
+partitioned ``sql:<table>`` or the replicated ``sql:<table>:all`` — is a
+regrouping of those same batches, so every view of every width shares
+one upload: the Flare compile-once/serve-many shape, applied to data
+residency too.
 """
 
 from __future__ import annotations
 
+import decimal
 import threading
 import time
 from typing import Optional
 
 import pandas as pd
 
+from auron_tpu import obs
 from auron_tpu.serve.admission import AdmissionController
 from auron_tpu.serve.cache import PlanCache, plan_cache_key
 from auron_tpu.utils.config import (
@@ -66,13 +73,42 @@ def _default_base_conf(conf: Optional[Configuration]) -> Configuration:
     return conf
 
 
+#: rows a batch of a converted frame holds at most (the scan's unit)
+TABLE_BATCH_ROWS = 1 << 20
+
+
+def _columnar(name: str, table, schema, n_parts: int) -> list:
+    """One table as the list of batches the server keeps: batches pass
+    through (their schema must be the catalog's), a pandas frame is cut
+    into equal row ranges — at most TABLE_BATCH_ROWS each, the same
+    number for every partition of the default mesh width — and ingested
+    under the catalog's schema."""
+    from auron_tpu.columnar.batch import Batch
+
+    if isinstance(table, pd.DataFrame):
+        n = len(table)
+        per_part = max(1, -(-n // (n_parts * TABLE_BATCH_ROWS)))  # batches
+        rows = max(1, -(-n // (n_parts * per_part)))
+        # an empty frame is one empty batch
+        return [Batch.from_pandas(table.iloc[i:i + rows], schema=schema)
+                for i in range(0, max(n, 1), rows)]
+    batches = list(table)
+    want = [(f.name, f.dtype) for f in schema]
+    for b in batches:
+        got = [(f.name, f.dtype) for f in b.schema]
+        if got != want:
+            raise ValueError(
+                f"table {name!r}: a batch's columns {got} are not the "
+                f"catalog's {want}")
+    return batches
+
+
 class SqlServer:
     """In-process SQL serving front end (POST /sql's implementation)."""
 
-    def __init__(self, catalog, frames: dict, conf: Configuration | None = None,
+    def __init__(self, catalog, tables: dict, conf: Configuration | None = None,
                  n_parts: int | None = None, mesh=None):
         self.catalog = catalog
-        self.frames = frames
         self.conf = _default_base_conf(conf)
         self.n_parts = (n_parts if n_parts is not None
                         else self.conf.get(SQL_SHUFFLE_PARTITIONS))
@@ -90,13 +126,14 @@ class SqlServer:
         self.mesh = self._mesh_for(self.n_parts)
         self.plan_cache = PlanCache(self.conf.get(SERVE_PLAN_CACHE_ENTRIES))
         self.admission = AdmissionController(self.conf)
-        # uploaded table views, (rid, n_parts) -> per-partition batch
-        # lists; one upload per scanned view across ALL queries/tenants.
-        # The lock guards only the dict — uploads run OUTSIDE it behind a
-        # per-key in-flight event, so a first-touch staging of one large
-        # table never serializes unrelated concurrent queries
-        self._res_lock = threading.Lock()
-        self._res_cache: dict = {}
+        # table -> its batches in row order, immutable after this line
+        # (views regroup them; nothing re-uploads). A table the catalog
+        # does not name can never be scanned and is not kept
+        self.tables: dict[str, list] = {
+            name: _columnar(name, t, catalog.schema(name), self.n_parts)
+            for name, t in tables.items()
+            if catalog.schema(name) is not None
+        }
         self._stats_lock = threading.Lock()
         self.queries_ok = 0
         self.queries_err = 0
@@ -129,14 +166,17 @@ class SqlServer:
         door: a hit skips parse/bind/lower entirely."""
         from auron_tpu.sql import compile_text
 
-        key = plan_cache_key(sql, conf)
-        lq = self.plan_cache.lookup(key)
-        if lq is not None:
-            return lq, key, True
-        lq = compile_text(sql, self.catalog,
-                          n_parts=conf.get(SQL_SHUFFLE_PARTITIONS))
-        self.plan_cache.insert(key, lq)
-        return lq, key, False
+        with obs.span("plan", cat="serve", arg={"cache_hit": True}) as sp:
+            key = plan_cache_key(sql, conf)
+            lq = self.plan_cache.lookup(key)
+            if lq is not None:
+                return lq, key, True
+            if sp is not None:
+                sp.arg["cache_hit"] = False
+            lq = compile_text(sql, self.catalog,
+                              n_parts=conf.get(SQL_SHUFFLE_PARTITIONS))
+            self.plan_cache.insert(key, lq)
+            return lq, key, False
 
     # ------------------------------------------------------------------
     # execution
@@ -162,63 +202,43 @@ class SqlServer:
             return mesh
 
     def _build_resources(self, lq) -> dict:
-        """Batch lists for every table the plan scans, uploaded once per
-        (rid, width). Two first-queries of one table serialize on that
-        table's in-flight event only; queries over already-resident (or
-        different) tables proceed without waiting."""
-        return {use.rid: self._table_view(use, lq.n_parts)
-                for use in lq.tables}
-
-    def _table_view(self, use, n_parts: int):
-        from auron_tpu.models.tpcds import to_batches
-
-        key = (use.rid, n_parts)
-        with self._res_lock:
-            ent = self._res_cache.get(key)
-            if ent is None:
-                ent = self._res_cache[key] = {
-                    "done": threading.Event(), "val": None}
-                builder = True
+        """Batch lists for every table the plan scans: per partition a
+        contiguous run of the table's batches, or all of them on every
+        partition for a replicated (build-side) view."""
+        out = {}
+        for use in lq.tables:
+            batches = self.tables[use.table]
+            if use.replicated:
+                out[use.rid] = [batches] * lq.n_parts
             else:
-                builder = False
-        if builder:
-            try:
-                df = self.frames[use.table]
-                if use.replicated:
-                    val = [to_batches(df, 1)[0]] * n_parts
-                else:
-                    val = to_batches(df, n_parts)
-                ent["val"] = val
-            except BaseException:
-                # failed upload must not wedge waiters or poison the
-                # cache: drop the entry, release waiters (they re-raise)
-                with self._res_lock:
-                    self._res_cache.pop(key, None)
-                raise
-            finally:
-                ent["done"].set()
-            return val
-        ent["done"].wait()
-        if ent["val"] is None:
-            raise RuntimeError(
-                f"concurrent upload of {use.rid} failed; retry the query")
-        return ent["val"]
+                per = -(-len(batches) // lq.n_parts)
+                out[use.rid] = [batches[p * per:(p + 1) * per]
+                                for p in range(lq.n_parts)]
+        return out
 
     def _execute(self, lq, conf: Configuration) -> pd.DataFrame:
         """Run one lowered query under ``conf``: distributed stage on the
         shared mesh (fresh driver per query — drivers carry per-run
         state), then the optional collect stage as an isolated task."""
+        from auron_tpu.parallel.mesh_driver import MeshQueryDriver
+
+        with obs.span("execute", cat="serve"):
+            resources = self._build_resources(lq)
+            driver = MeshQueryDriver(self._mesh_for(lq.n_parts), conf=conf)
+            outs = driver.run(lq.distributed, resources)
+        batches = [b for part in outs for b in part]
+        with obs.span("collect", cat="serve"):
+            return self._collect(lq, conf, batches)
+
+    def _collect(self, lq, conf: Configuration, batches: list) -> pd.DataFrame:
+        """The distributed stage's output as one frame: through the collect
+        task where the plan has one (global merge, ORDER BY, LIMIT)."""
         import jax
 
         from auron_tpu.bridge import api
-        from auron_tpu.parallel.mesh_driver import MeshQueryDriver
         from auron_tpu.plan import builders as B
         from auron_tpu.sql.lowering import STAGE_RID
 
-        resources = self._build_resources(lq)
-        driver = MeshQueryDriver(self._mesh_for(lq.n_parts), conf=conf)
-        outs = driver.run(lq.distributed, resources)
-        batches = [b for part in outs for b in part]
         if lq.collect is None:
             dfs = [b.to_pandas() for b in batches]
         else:
@@ -264,8 +284,6 @@ class SqlServer:
                tenant: str | None = None) -> tuple[pd.DataFrame, dict]:
         """Plan (or cache-hit) + admit + execute one query. Returns the
         result frame and a record (digest, cache_hit, timings, trace)."""
-        from auron_tpu import obs
-
         t_arrive = time.perf_counter()
         try:
             # inside the try: a refused conf key (QueryError) and an
@@ -317,8 +335,11 @@ class SqlServer:
                                   tenant=body.get("tenant"))
         except SqlDiagnostic as e:
             raise QueryError(str(e)) from None
-        rec["columns"] = list(df.columns)
-        rec["rows"] = _json_rows(df)
+        # serve:encode, first half: cells to JSON-safe values (the second,
+        # json.dumps, is the HTTP handler's: utils/httpsvc.py)
+        with obs.span("encode", cat="serve"):
+            rec["columns"] = list(df.columns)
+            rec["rows"] = _json_rows(df)
         return rec
 
     def stats(self) -> dict:
@@ -331,21 +352,27 @@ class SqlServer:
             "queries_err": err,
             "plan_cache": self.plan_cache.stats(),
             "admission": self.admission.stats(),
-            "tables_resident": len(self._res_cache),
+            "tables_resident": len(self.tables),
         }
 
 
 def _json_rows(df: pd.DataFrame) -> list[list]:
     """JSON-safe row materialization: numpy scalars -> python, NaN/NaT ->
-    null. Deterministic (shortest-roundtrip float repr), so two identical
-    result frames serialize byte-identically — the property the
-    concurrency differential gate's HTTP leg compares on."""
+    null, a DECIMAL cell -> its exact decimal string at the column's scale
+    (``"1234.50"``, never through a float: docs/serving.md). Deterministic
+    (shortest-roundtrip float repr), so two identical result frames
+    serialize byte-identically — the property the concurrency differential
+    gate's HTTP leg compares on."""
     out = []
     for row in df.itertuples(index=False, name=None):
         vals = []
         for v in row:
             if v is None or (isinstance(v, float) and v != v) or pd.isna(v):
                 vals.append(None)
+            elif isinstance(v, decimal.Decimal):
+                # Arrow hands a DECIMAL(p,s) cell over with exponent -s:
+                # fixed-point notation keeps every digit of the scale
+                vals.append(format(v, "f"))
             elif hasattr(v, "isoformat"):
                 # datetime-like (pd.Timestamp, date): BEFORE .item() —
                 # Timestamp.item does not exist and a raw Timestamp is

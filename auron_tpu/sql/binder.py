@@ -246,9 +246,10 @@ class ExprBinder:
             v = int(t)
             dt = T.INT32 if _fits_int32(v) else T.INT64
             return Bound(ir.Literal(v, dt), dt, t)
-        # '.'-form and exponent-form numbers bind as float64: the catalog
-        # carries float64 money columns (no decimal columns), so a decimal
-        # literal would only force casts the engine immediately folds away
+        # '.'-form and exponent-form numbers bind as float64, also beside
+        # a DECIMAL column (catalogs declare DECIMAL money): the pair then
+        # compares in float64 by ir.numeric_common_type. An exact decimal
+        # literal is what CAST(<number> AS DECIMAL(p,s)) is for
         return Bound(ir.Literal(float(t), T.FLOAT64), T.FLOAT64, t)
 
     def _bind_StringLit(self, e: A.StringLit) -> Bound:

@@ -1,4 +1,6 @@
-"""TPC-DS catalog for the SQL frontend.
+"""Catalogs for the SQL frontend: :class:`Catalog` (declared schemas and
+row counts, ``Catalog.declared`` for a deployment's own tables) and the
+synthetic TPC-DS-named catalog the gates bind against (``TABLES``).
 
 The synthetic star schema the plan-builder classes use (models/tpcds.py)
 carries only the columns those hand-built pipelines touch. Real TPC-DS
@@ -156,17 +158,35 @@ def schema_of(table: str) -> T.Schema:
 
 @dataclass(frozen=True)
 class Catalog:
-    """Binder-side view: table -> schema + row-count estimate (the
-    estimate only drives hash-join build-side selection)."""
+    """Binder-side view: table -> schema + row count (the count only
+    drives join ordering: the largest relation seeds the probe side)."""
 
     schemas: dict[str, T.Schema]
     row_counts: dict[str, int]
 
+    @classmethod
+    def declared(cls, schemas: dict[str, T.Schema],
+                 row_counts: dict[str, int]) -> "Catalog":
+        """A catalog of tables as their source declares them — column
+        types (DECIMAL money among them) and nullability — with the
+        tables' REAL row counts. A table without a count refuses: a
+        guessed cardinality picks the probe side silently."""
+        missing = sorted(set(schemas) - set(row_counts))
+        if missing:
+            raise ValueError(f"no row count for table(s) {missing}")
+        return cls({t.lower(): s for t, s in schemas.items()},
+                   {t.lower(): int(n) for t, n in row_counts.items()})
+
     def schema(self, name: str) -> T.Schema | None:
         return self.schemas.get(name.lower())
 
-    def rows(self, name: str) -> int:
-        return self.row_counts.get(name.lower(), 1000)
+    def rows(self, name: str, default: int | None = None) -> int:
+        """The table's row count; for a table the catalog holds no count
+        of, ``default`` where the caller gives one, else KeyError."""
+        n = self.row_counts.get(name.lower(), default)
+        if n is None:
+            raise KeyError(f"catalog has no row count for {name!r}")
+        return n
 
 
 def tpcds_catalog(n_fact: int = 1 << 20) -> Catalog:

@@ -58,6 +58,7 @@ import threading
 import traceback
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from auron_tpu import obs
 from auron_tpu.utils.config import bool_conf, int_conf
 
 HTTP_SERVICE_ENABLE = bool_conf(
@@ -239,45 +240,17 @@ class _Handler(BaseHTTPRequestHandler):
                 self._send(b"bad request body: unacceptable "
                            b"Content-Length\n", "text/plain", 400)
                 return
-            raw = self.rfile.read(n)
             path = self.path.split("?", 1)[0]
+            if path == "/sql":
+                # serve:request — body read to last byte written
+                with obs.span("request", cat="serve"):
+                    self._post_sql(self.rfile.read(n))
+                return
+            raw = self.rfile.read(n)
             if path == "/stream":
                 self._post_stream(raw)
-                return
-            if path != "/sql":
+            else:
                 self._send(b"not found\n", "text/plain", 404)
-                return
-            srv = _sql_server
-            if srv is None:
-                self._send(b"no sql server installed\n", "text/plain", 404)
-                return
-            # serve imports AFTER the 404 checks and INSIDE the try: a
-            # stray POST to an observability-only service must not pay
-            # (or crash the handler on) the pandas-heavy serve import —
-            # the contract is "a handler exception answers 500"
-            from auron_tpu.serve.admission import AdmissionTimeout
-            from auron_tpu.serve.server import QueryError
-
-            try:
-                body = json.loads(raw or b"{}")
-            except (ValueError, TypeError) as e:
-                self._send(f"bad request body: {e}\n".encode(),
-                           "text/plain", 400)
-                return
-            try:
-                payload = srv.execute_json(body)
-            except QueryError as e:
-                self._send(
-                    json.dumps({"error": str(e)}).encode(),
-                    "application/json", 400)
-                return
-            except AdmissionTimeout as e:
-                # queue-don't-die's bound: busy, retry later
-                self._send(
-                    json.dumps({"error": str(e)}).encode(),
-                    "application/json", 503)
-                return
-            self._send(json.dumps(payload).encode(), "application/json")
         except Exception as e:  # noqa: BLE001 — the service must survive
             # conservative: after an arbitrary handler failure the
             # request-stream position is not trustworthy for reuse
@@ -292,6 +265,44 @@ class _Handler(BaseHTTPRequestHandler):
                 cur = cur.__cause__
             self._send(("error: " + " <- ".join(chain) + "\n").encode(),
                        "text/plain", 500)
+
+    def _post_sql(self, raw: bytes) -> None:
+        srv = _sql_server
+        if srv is None:
+            self._send(b"no sql server installed\n", "text/plain", 404)
+            return
+        # serve imports AFTER the 404 checks and inside do_POST's try: a
+        # stray POST to an observability-only service must not pay
+        # (or crash the handler on) the pandas-heavy serve import —
+        # the contract is "a handler exception answers 500"
+        from auron_tpu.serve.admission import AdmissionTimeout
+        from auron_tpu.serve.server import QueryError
+
+        try:
+            body = json.loads(raw or b"{}")
+        except (ValueError, TypeError) as e:
+            self._send(f"bad request body: {e}\n".encode(),
+                       "text/plain", 400)
+            return
+        try:
+            payload = srv.execute_json(body)
+        except QueryError as e:
+            self._send(
+                json.dumps({"error": str(e)}).encode(),
+                "application/json", 400)
+            return
+        except AdmissionTimeout as e:
+            # queue-don't-die's bound: busy, retry later
+            self._send(
+                json.dumps({"error": str(e)}).encode(),
+                "application/json", 503)
+            return
+        # serve:encode, second half (the first is _json_rows)
+        with obs.span("encode", cat="serve") as sp:
+            out = json.dumps(payload).encode()
+            if sp is not None:
+                sp.arg = {"bytes": len(out)}
+        self._send(out, "application/json")
 
     def _post_stream(self, raw: bytes) -> None:
         srv = _stream_server

@@ -53,13 +53,13 @@ def frames():
 def server(frames):
     srv = SqlServer(sqlgate.gate_catalog(), frames, n_parts=2)
     yield srv
-    # in-flight upload events: every entry that is still resident must
-    # have released its waiters (a cleared event after the builder
-    # returned = the PR-12 stuck-waiter shape)
-    with srv._res_lock:
-        stuck = [k for k, ent in srv._res_cache.items()
-                 if not ent["done"].is_set() or ent["val"] is None]
-    assert not stuck, f"resource-map entries with unreleased waiters: {stuck}"
+    # the frames were converted once, at construction: every table is
+    # resident as batches of the catalog's schema, and no query replaced
+    # or re-uploaded them
+    assert set(srv.tables) == set(frames)
+    for name, batches in srv.tables.items():
+        assert batches and all(
+            b.schema == srv.catalog.schema(name) for b in batches), name
 
 
 def _sql(name):
