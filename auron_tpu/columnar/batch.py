@@ -115,17 +115,49 @@ def bucket_capacity(n: int) -> int:
     return c
 
 
-def compaction_bucket(n_live: int, in_capacity: int) -> int | None:
+def compaction_bucket(
+    n_live: int, in_capacity: int,
+    dense_planes: int | None = None, taken_planes: int = 0,
+) -> int | None:
     """THE compaction policy shared by every sparse-output boundary (join
-    chain, BHJ unique-compact, selectivity predictor): the capacity bucket
-    to compact ``n_live`` rows into, or None when compaction would not pay
-    and the batch should stay dense at ``in_capacity``. The 4x threshold is
-    the measured break-even of one extra gather of every output column
-    against the smaller downstream batches."""
+    chain, BHJ unique-compact and its fused stage twin, the partial
+    aggregate's deferred arm): the capacity bucket to compact ``n_live``
+    rows into, or None when compaction would not pay and the batch should
+    stay dense at ``in_capacity``. A rule over shapes alone.
+
+    A join says what each side of its boundary gathers: ``dense_planes``
+    arrays (values and validities of its build columns) at ``in_capacity``
+    where the batch stays dense, ``taken_planes`` arrays (those, the probe
+    columns and the build index) at the bucket where it compacts. On the
+    TPU a gathered element costs 7-10 ns whatever the table's size and
+    the gather's width (an int64 plane twice that), and
+    ``compaction_index`` is a pass over the mask plus one such gather at
+    the bucket's width for every bit of ``in_capacity`` (PERF.md section
+    5, "unit costs": measured on the v5e), so the rule there counts
+    gathered elements: compact iff
+    ``bucket * (index passes + taken_planes) <= in_capacity * dense_planes``
+    — at two to four build planes a bucket of a sixteenth of capacity or
+    less. XLA:CPU keeps the break-even measured there, a quarter of
+    capacity. A caller that names no ``dense_planes`` (the aggregate, for
+    which staying dense means a sort-segmented reduce at capacity, tens of
+    gathers a row) keeps the quarter rule on both back ends."""
     cap = bucket_capacity(max(n_live, 1))
+    if dense_planes is not None and _gather_bound():
+        passes = max(in_capacity - 1, 1).bit_length()
+        if cap * (passes + taken_planes) > in_capacity * dense_planes:
+            return None
+        return cap
     if cap * 4 > in_capacity:
         return None
     return cap
+
+
+def _gather_bound() -> bool:
+    """Is this a back end whose gathers cost by the output element (the
+    TPU), so that compaction_bucket counts elements?"""
+    from auron_tpu.jaxenv import is_tpu
+
+    return is_tpu()
 
 
 class DeviceBatch(NamedTuple):
@@ -714,13 +746,32 @@ def device_concat(batches: Sequence[Batch]) -> Batch:
 from functools import partial as _partial
 
 
+#: row length of _running_count's first level
+_COUNT_BLOCK = 1024
+
+
+def _running_count(sel: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running count of the mask (int32), in two levels: within
+    rows of 1,024, then across the rows' totals. The same integers as one
+    flat cumsum, which the TPU's compiler takes 25 s over at 1,048,576
+    elements (12 s at 524,288, 4 s at 4,194,304) in every program that
+    holds a compaction index; this form compiles in half a second
+    (PERF.md, PR 29)."""
+    ones = sel.astype(jnp.int32)
+    if ones.shape[0] <= _COUNT_BLOCK:  # buckets are powers of two
+        return jnp.cumsum(ones)
+    rows = jnp.cumsum(ones.reshape(-1, _COUNT_BLOCK), axis=1)
+    totals = rows[:, -1]
+    return (rows + (jnp.cumsum(totals) - totals)[:, None]).reshape(-1)
+
+
 def compaction_index(sel: jnp.ndarray, out_cap: int):
     """(idx[out_cap], sel_out[out_cap]): positions of the live rows, via
     cumsum + branchless binary search. Gather-based on purpose — XLA:CPU
     lowers scatters to serial loops (the platform even advertises
     prefer-no-scatter), while the log2(cap) searchsorted passes vectorize."""
     cap = sel.shape[0]
-    pos = jnp.cumsum(sel.astype(jnp.int32))
+    pos = _running_count(sel)
     idx = jnp.searchsorted(
         pos, jnp.arange(1, out_cap + 1, dtype=jnp.int32), side="left"
     ).astype(jnp.int32)

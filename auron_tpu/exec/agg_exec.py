@@ -652,12 +652,13 @@ class HashAggExec(ExecOperator):
             if used_cap is not None and n > used_cap:
                 # predicted bucket truncated live rows: recompute from the
                 # still-held original batch at the exact bucket
-                from auron_tpu.columnar.batch import compact_batch
+                from auron_tpu.columnar.batch import (
+                    compact_batch, compaction_bucket,
+                )
 
                 ctx.metrics.add("sel_mispredicts", 1)
-                bb = b
-                if 4 * n <= b.capacity:
-                    bb = compact_batch(b, bucket_capacity(n))
+                out_cap = compaction_bucket(n, b.capacity)
+                bb = b if out_cap is None else compact_batch(b, out_cap)
                 inter = fold_deferred(bb, b.capacity)
                 coll = getattr(inter, "_fp_collision", None)
                 scalars = [inter.device.num_rows()]

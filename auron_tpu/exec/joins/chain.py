@@ -7,10 +7,13 @@ Executing the stack operator-at-a-time materializes an intermediate batch
 per level; fused, the chain costs
 
     one probe program per level (key canon + LUT/binsearch, no gathers)
-    one combined selection + ONE compaction of the bottom probe stream
-    one gather program materializing every projected column at the
-    compacted width (probe columns at idx, each level's build columns at
-    bi_level[idx])
+    ONE probe program for every level (key canon + LUT/binsearch, the
+    combined selection and its live count; no gathers)
+    ONE take program: the compaction index of the bottom probe stream and
+    every projected column gathered at the compacted width (probe columns
+    at idx, each level's build columns at bi_level[idx]) — or, where
+    ``compaction_bucket``'s rule says the bucket is too wide to pay, the
+    build columns alone gathered at the batch's capacity
 
 which is the minimum memory traffic for the whole subtree (the reference's
 column-pruned multi-BHJ pipelines approximate this with its fused
@@ -28,8 +31,8 @@ from typing import Iterator
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from auron_tpu import obs
 from auron_tpu.columnar.batch import Batch, compaction_bucket, compaction_index
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exec.selectivity import SelectivityPredictor, predictor_enabled
@@ -59,8 +62,8 @@ def try_fused_chain(top, partition: int, ctx) -> Iterator[Batch] | None:
     caller then runs the ordinary per-operator path)."""
     from auron_tpu.exec.joins.bhj import BroadcastHashJoinExec
 
-    # on accelerators (compact off) the chain still fuses — it just emits
-    # dense outputs with NO host sync; on CPU hosts it compacts per batch
+    # compact off: the chain still fuses — it emits dense outputs with NO
+    # host read at all
     compact_mode = _compact_join_output_enabled()
 
     # collect the stack of fusable links, top-down
@@ -236,13 +239,24 @@ def _run_chain(
     # steady-state pipeline state: EWMA selectivity predictor picks the
     # compaction bucket ahead of time; the k-deep transfer window carries
     # each batch's actual live count host-ward while later batches compute
-    # (docs/pipeline.md). First batch seeds the EWMA via the blocking path.
+    # (docs/pipeline.md). The first batch seeds the EWMA from its live
+    # count, read once (eight bytes).
     pred = (
         SelectivityPredictor(ctx.conf)
         if compact_mode and predictor_enabled(ctx.conf)
         else None
     )
     window = TransferWindow(ctx.conf.get(TRANSFER_WINDOW_DEPTH))
+    n_levels = len(links)
+    build_planes = 2 * sum(len(cs) for cs in bcols_per_level)
+    # compacting also takes the probe columns and every level's bi
+    taken_planes = 2 * len(probe_cols) + build_planes + n_levels
+
+    def bucket_of(n_live: int, capacity: int) -> int | None:
+        return compaction_bucket(
+            n_live, capacity, dense_planes=build_planes,
+            taken_planes=taken_planes,
+        )
 
     def assemble(pb, c_p, c_pm, c_b, c_bm, new_sel) -> Batch:
         """Output batch from gathered arrays; c_p None = probe columns
@@ -268,9 +282,19 @@ def _run_chain(
         out = batch_from_columns(out_cols, out_schema.names, new_sel)
         return Batch(out_schema, out.device, out.dicts)
 
-    def take_at(pb, sel_out, bis, out_cap: int):
-        """Device-side compaction into a static bucket: index, gather and
-        live count in ONE program — no host round-trip."""
+    def take(mode: str, pb, sel_out, bis, out_cap: int | None):
+        """One take on the device, noted in the rings: compaction index
+        and every gather at the static bucket ``out_cap`` in ONE program,
+        or (None) the build columns alone gathered at the batch's width.
+        Returns (c_p, c_pm, c_b, c_bm, new_sel)."""
+        obs.note_join_take(
+            mode, (out_cap or pb.capacity) * n_levels, pb.capacity
+        )
+        if out_cap is None:
+            c_b, c_bm = _chain_take_dense_jit(
+                bvals_all, bmasks_all, tuple(bis), sel_out
+            )
+            return None, None, c_b, c_bm, sel_out
         return _chain_take_pred_jit(
             tuple(pb.col_values(c) for c in probe_cols),
             tuple(pb.col_validity(c) for c in probe_cols),
@@ -279,12 +303,13 @@ def _run_chain(
         )
 
     def dispatch(pb):
-        """Async half: ALL levels' canon + probe + selection AND as ONE
-        program (single pass over the probe keys), then the compacted (or
-        dense) gather at the PREDICTED bucket. No host sync here — the live
-        count rides the transfer window and is harvested k batches later,
-        overlapping device compute (and, on remote accelerators, hiding
-        link latency). Returns (async-arrays, finish-state)."""
+        """Async half: ALL levels' canon + probe + selection AND + live
+        count as ONE program (single pass over the probe keys), then the
+        compacted (or dense) take at the PREDICTED bucket. No host sync
+        here past a stream's seed — the live count rides the transfer
+        window and is harvested k batches later, overlapping device
+        compute (and, on remote accelerators, hiding link latency).
+        Returns (async-arrays, finish-state)."""
         kv_all = tuple(
             tuple(pb.col_values(c) for c in key_cols)
             for key_cols in key_cols_per_level
@@ -293,106 +318,76 @@ def _run_chain(
             tuple(pb.col_validity(c) for c in key_cols)
             for key_cols in key_cols_per_level
         )
-        sel_out, bis = _chain_probe_all_jit(
+        sel_out, bis, live = _chain_probe_all_jit(
             kv_all, km_all, pb.device.sel,
             luts, lut_bases, bwords_all, n_lives,
             cfgs=level_cfgs,
         )
-        bis = list(bis)
         if not compact_mode:
-            return (), ("dense", pb, sel_out, bis, None)
-        pred_cap = pred.predict(pb.capacity) if pred is not None else None
+            return (), ("done", pb, take("dense", pb, sel_out, bis, None))
+        if pred is None:
+            # predictor off, compaction on: the live count rides the
+            # window (so the per-batch read still overlaps k batches of
+            # compute) and the take waits for it
+            return (live,), ("count", pb, sel_out, bis)
+        pred_cap = pred.predict(pb.capacity)
         if pred_cap is None:
-            if pred is None:
-                # predictor off, compaction on: ship the selection MASK
-                # through the window so the per-batch read still overlaps
-                # k batches of compute (the pre-predictor 1-deep pipeline,
-                # deepened and async-accounted)
-                return (sel_out,), ("sync", pb, sel_out, bis, None)
-            # no history yet: classic blocking seed path (eager, once)
-            return (), ("sync", pb, sel_out, bis, None)
-        if compaction_bucket(pred_cap, pb.capacity) is None:
-            # predicted survival too high for compaction to pay: dense
-            # emit, still sync-free (live count observed asynchronously)
-            n_live_dev = _sel_count_jit(sel_out)
-            return (n_live_dev,), ("pdense", pb, sel_out, bis, None)
-        taken = take_at(pb, sel_out, bis, pred_cap)
-        return (taken[-1],), ("pred", pb, sel_out, bis, (taken, pred_cap))
+            # seed: no history yet. Read this batch's live count — one
+            # scalar, once a stream — and take at its own bucket: exact,
+            # so it never repairs and need not ride the (empty) window
+            # auronlint: disable=R9 -- first batch of a stream only: the predictor takes over afterwards (seed read)
+            n_seed = int(jax.device_get(live))  # auronlint: sync-point(2/task) -- chain compaction seed read: the first batch's live count
+            pred.observe(n_seed)
+            out_cap = bucket_of(n_seed, pb.capacity)
+            return (), ("done", pb, take("seed", pb, sel_out, bis, out_cap))
+        out_cap = bucket_of(pred_cap, pb.capacity)
+        if out_cap is None:
+            # predicted too wide to pay. A wrong "dense" costs a whole
+            # capacity of gathers for a batch that may hold nothing (the
+            # batches behind a burst, while the predictor's bucket waits
+            # out its shrink patience), and the batch stays in the window
+            # until its count lands anyway: the count itself decides there
+            return (live,), ("count", pb, sel_out, bis)
+        taken = take("compact", pb, sel_out, bis, out_cap)
+        return (live,), ("pred", pb, sel_out, bis, taken, out_cap)
 
     def finish(resolved, state) -> Batch:
-        mode, pb, sel_out, bis, extra = state
-        if mode == "dense":
-            # accelerator mode: dense output, ZERO host syncs in the chain
-            c_b, c_bm = _chain_take_dense_jit(
-                bvals_all, bmasks_all, tuple(bis), sel_out
-            )
-            return assemble(pb, None, None, c_b, c_bm, sel_out)
-        if mode == "sync":
-            if resolved:
-                sel_np = resolved[0]  # windowed mask (predictor off)
-            else:
-                # auronlint: disable=R9 -- first batch of a stream only: the predictor takes over afterwards (seed read)
-                sel_np = np.asarray(jax.device_get(sel_out))  # auronlint: sync-point(2/task) -- chain compaction seed read: first batch of a stream
-            idx_np = np.flatnonzero(sel_np)
-            n_live = int(idx_np.size)
+        mode, pb = state[:2]
+        if mode == "done":
+            return assemble(pb, *state[2])
+        n_live = int(resolved[0])
+        if mode == "count":
+            _, _, sel_out, bis = state
             if pred is not None:
                 pred.observe(n_live)
-            out_cap = compaction_bucket(n_live, pb.capacity)
-            if out_cap is None:
-                c_b, c_bm = _chain_take_dense_jit(
-                    bvals_all, bmasks_all, tuple(bis), sel_out
-                )
-                return assemble(pb, None, None, c_b, c_bm, sel_out)
-            idx_pad = np.zeros(out_cap, dtype=np.int32)
-            idx_pad[:n_live] = idx_np
-            c_p, c_pm, c_b, c_bm, new_sel = _chain_take_jit(
-                tuple(pb.col_values(c) for c in probe_cols),
-                tuple(pb.col_validity(c) for c in probe_cols),
-                bvals_all, bmasks_all,
-                tuple(bis),
-                jnp.asarray(idx_pad), jnp.int32(n_live),
-            )
-            return assemble(pb, c_p, c_pm, c_b, c_bm, new_sel)
-        # predicted modes: the live count was harvested from the window
-        n_live = int(resolved[0])
-        if mode == "pdense":
-            pred.observe(n_live)
-            c_b, c_bm = _chain_take_dense_jit(
-                bvals_all, bmasks_all, tuple(bis), sel_out
-            )
-            return assemble(pb, None, None, c_b, c_bm, sel_out)
-        taken, pred_cap = extra
-        pred.observe(n_live, predicted=pred_cap)
-        if n_live > pred_cap:
+            out_cap = bucket_of(n_live, pb.capacity)
+            return assemble(pb, *take(
+                "dense" if out_cap is None else "compact",
+                pb, sel_out, bis, out_cap,
+            ))
+        # predicted: the live count was harvested from the window
+        _, _, sel_out, bis, taken, out_cap = state
+        pred.observe(n_live, predicted=out_cap)
+        if n_live > out_cap:
             # mispredict: the compacted gather truncated rows. Repair from
             # the still-held device state at the CORRECT bucket — pure
             # recompute, no extra sync (n_live is already host-side).
             ctx.metrics.add("sel_mispredicts", 1)
-            out_cap = compaction_bucket(n_live, pb.capacity)
-            if out_cap is None:
-                c_b, c_bm = _chain_take_dense_jit(
-                    bvals_all, bmasks_all, tuple(bis), sel_out
-                )
-                return assemble(pb, None, None, c_b, c_bm, sel_out)
-            taken = take_at(pb, sel_out, bis, out_cap)
-        c_p, c_pm, c_b, c_bm, new_sel, _ = taken
-        return assemble(pb, c_p, c_pm, c_b, c_bm, new_sel)
+            taken = take(
+                "repair", pb, sel_out, bis, bucket_of(n_live, pb.capacity)
+            )
+        return assemble(pb, *taken)
 
     # k-deep software pipeline: batch i's live count is harvested while
-    # batches i+1..i+k compute; emission order stays FIFO. Seed-path
-    # batches ("sync": no prediction yet) finish EAGERLY so the first
-    # batch's observation unblocks prediction for the second — they only
-    # occur as a stream prefix, while the window is still empty.
+    # batches i+1..i+k compute; emission order stays FIFO. A batch whose
+    # take is already settled ("done": compaction off, or the seed, which
+    # only occurs while the window is still empty) is emitted at once
+    # instead of pinning k batches of probe/build-index state.
     for pb in probe_child_stream:
         ctx.check_cancelled()
         with ctx.metrics.timer("probe_time", count=True):
             arrays, state = dispatch(pb)
-            if state[0] == "dense" or (
-                pred is not None and state[0] == "sync" and not len(window)
-            ):
-                # dense (accelerator) mode has no host read to overlap —
-                # emit immediately instead of pinning k batches of probe/
-                # build-index state in the window
+            if state[0] == "done":
                 ready = [finish((), state)]
             else:
                 ready = [
@@ -414,9 +409,9 @@ from functools import partial
 @partial(jax.jit, static_argnames=("cfgs",))
 def _chain_probe_all_jit(kv_all, km_all, psel, luts, lut_bases, bwords_all, n_lives, cfgs):
     """Every level's key canonicalization + unique probe + the combined
-    selection AND in ONE program: XLA fuses the per-level LUT gathers into a
-    single pass over the probe stream, and no per-level ok/live-count
-    intermediates are materialized."""
+    selection AND (with its live count) in ONE program: XLA fuses the
+    per-level LUT gathers into a single pass over the probe stream, and no
+    per-level ok/live-count intermediates are materialized."""
     sel = psel
     bis = []
     for kv, km, lut, lb, bw, nl, (bcap, use_lut, kinds) in zip(
@@ -429,7 +424,7 @@ def _chain_probe_all_jit(kv_all, km_all, psel, luts, lut_bases, bwords_all, n_li
         )
         bis.append(bi)
         sel = sel & ok
-    return sel, tuple(bis)
+    return sel, tuple(bis), jnp.sum(sel.astype(jnp.int32))
 
 
 @jax.jit
@@ -451,40 +446,17 @@ def _and_all(sel, oks):
     return sel
 
 
-@jax.jit
-def _sel_count_jit(sel):
-    return jnp.sum(sel.astype(jnp.int32))
-
-
 @partial(jax.jit, static_argnames=("out_cap",))
 def _chain_take_pred_jit(
     probe_vals, probe_masks, build_vals, build_masks, bis, sel, out_cap: int
 ):
-    """Sync-free variant of _chain_take_jit: the compaction index is
-    computed ON DEVICE from the selection mask at a *predicted* static
-    bucket, and the actual live count is returned for asynchronous
-    harvest — if it exceeds out_cap the caller repairs by re-taking at
-    the correct bucket (rows beyond out_cap are truncated here)."""
+    """One program: the compaction index computed ON DEVICE from the
+    selection mask at a static bucket (predicted, or a seed's or a
+    repair's exact one), the bottom probe columns taken at it and every
+    level's build columns gathered at the compacted width. Rows beyond
+    out_cap are truncated: the caller harvests the true live count
+    asynchronously and repairs by re-taking at the correct bucket."""
     idx, new_sel = compaction_index(sel, out_cap)
-    n_live = jnp.sum(sel.astype(jnp.int32))
-    c_p = tuple(v[idx] for v in probe_vals)
-    c_pm = tuple(m[idx] & new_sel for m in probe_masks)
-    c_b = []
-    c_bm = []
-    for lv_vals, lv_masks, bi in zip(build_vals, build_masks, bis):
-        c_bi = bi[idx]
-        c_b.append(tuple(v[c_bi] for v in lv_vals))
-        c_bm.append(tuple(m[c_bi] & new_sel for m in lv_masks))
-    return c_p, c_pm, tuple(c_b), tuple(c_bm), new_sel, n_live
-
-
-@jax.jit
-def _chain_take_jit(
-    probe_vals, probe_masks, build_vals, build_masks, bis, idx, n_live
-):
-    """One program: compact the bottom probe columns and gather every
-    level's build columns at the compacted width."""
-    new_sel = jnp.arange(idx.shape[0], dtype=jnp.int32) < n_live
     c_p = tuple(v[idx] for v in probe_vals)
     c_pm = tuple(m[idx] & new_sel for m in probe_masks)
     c_b = []

@@ -618,23 +618,6 @@ def _unique_probe_jit(
 
 
 @jax.jit
-def _unique_compact_take_jit(
-    probe_vals, probe_masks, bi, ok, build_vals, build_masks, idx, n_live
-):
-    """Compaction with a HOST-computed row index (np.flatnonzero of the
-    selection — on CPU hosts that's a memcpy + linear scan, far cheaper
-    than a device cumsum+searchsorted chain)."""
-    new_sel = jnp.arange(idx.shape[0], dtype=jnp.int32) < n_live
-    c_pvals = tuple(v[idx] for v in probe_vals)
-    c_pmasks = tuple(m[idx] & new_sel for m in probe_masks)
-    c_bi = bi[idx]
-    c_ok = ok[idx] & new_sel
-    out_bvals = tuple(v[c_bi] for v in build_vals)
-    out_bmasks = tuple(m[c_bi] & c_ok for m in build_masks)
-    return c_pvals, c_pmasks, out_bvals, out_bmasks, new_sel
-
-
-@jax.jit
 def _gather_build_jit(build_vals, build_masks, bi, ok):
     """Build-column gathers at probe capacity (dense-output fallback)."""
     return (

@@ -10,11 +10,12 @@ from __future__ import annotations
 
 from typing import Callable, Iterator
 
+import jax
 import jax.numpy as jnp
-import numpy as np
 
+from auron_tpu import obs
 from auron_tpu import types as T
-from auron_tpu.columnar.batch import Batch
+from auron_tpu.columnar.batch import Batch, compaction_bucket
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exprs import Evaluator, ir
 from auron_tpu.exprs.eval import ColumnVal
@@ -28,6 +29,7 @@ from auron_tpu.exec.joins.core import (
 
 def _compact_join_output_enabled() -> bool:
     from auron_tpu.exec.base import current_context
+    from auron_tpu.exec.selectivity import predictor_enabled
     from auron_tpu.jaxenv import is_tpu
     from auron_tpu.utils.config import (
         JOIN_COMPACT_OUTPUT, active_conf, resolve_tri,
@@ -35,8 +37,15 @@ def _compact_join_output_enabled() -> bool:
 
     ctx = current_context()
     conf = ctx.conf if ctx is not None else active_conf()
-    # auto: syncs are cheap on CPU, costly on the link
-    return resolve_tri(conf.get(JOIN_COMPACT_OUTPUT), not is_tpu())
+    # auto: on wherever the boundary costs no blocking read a batch. With
+    # the predictor a stream reads eight bytes once (its seed) and the rest
+    # rides the transfer window, on any back end; without it every batch
+    # blocks on its live count, which a CPU host can afford and the link
+    # to an accelerator cannot. WHETHER a given batch compacts is then
+    # compaction_bucket's rule over its shapes.
+    return resolve_tri(
+        conf.get(JOIN_COMPACT_OUTPUT), predictor_enabled(conf) or not is_tpu()
+    )
 
 
 class UniqueProbePipeline:
@@ -87,6 +96,7 @@ class EquiJoinDriver:
         self.build_side = build_side
         self.condition = condition
         self._cond_reduced = None  # lazy (schema, expr, assemble) cache
+        self._take_planes = None  # lazy (dense, taken) of take_bucket
         self.exists_col = exists_col
         full_schema = core.join_output_schema(
             left_schema, right_schema, join_type, exists_col
@@ -196,6 +206,7 @@ class EquiJoinDriver:
             build=build,
             kind=kind,
             compact=compact,
+            take_bucket=self.take_bucket,
             pipe=pipe,
             bcap=bb.capacity,
             use_lut=build.lut is not None,
@@ -366,9 +377,10 @@ class EquiJoinDriver:
         proj, _, bcol_ids = self._unique_probe_cfg()
         import jax.numpy as _jnp
 
-        # sparse-output compaction: densify BEFORE gathering build columns
-        # (one host sync per batch — worth it on CPU hosts, off on
-        # accelerators where the round-trip dominates)
+        # sparse-output compaction: densify BEFORE gathering build columns,
+        # wherever take_bucket's rule says the gathers saved outweigh the
+        # compaction (an outer probe side or a residual condition needs
+        # every probe row: always dense)
         compact_ok = (
             self.wants_pairs
             and self.condition is None
@@ -380,6 +392,8 @@ class EquiJoinDriver:
             )
             return
 
+        if bcol_ids:
+            obs.note_join_take("dense", pb.capacity, pb.capacity)
         if prep is not None and prep.take == "gather":
             bi, ok, sel_out = prep.bi, prep.ok, prep.sel_out
             bvals, bmasks = prep.bvals, prep.bmasks
@@ -444,15 +458,50 @@ class EquiJoinDriver:
             else:  # existence
                 yield self._emit_probe_exists(pb, ok & pb.device.sel)
 
+    def take_bucket(self, n_live: int, capacity: int) -> int | None:
+        """``compaction_bucket`` at this join's output boundary: the bucket
+        a probe batch of ``capacity`` rows with ``n_live`` survivors (or a
+        predicted bucket of them) compacts into, or None where it stays
+        dense. The ONE decision the eager driver, its mispredict repair
+        and the fused probe stage (through the published anchor) share."""
+        if self._take_planes is None:
+            _, pcol_ids, bcol_ids = self._unique_probe_cfg()
+            build_planes = 2 * len(bcol_ids)  # a column: values + validity
+            # compacting also takes the probe columns, bi and ok
+            self._take_planes = (
+                build_planes, 2 * len(pcol_ids) + build_planes + 2
+            )
+        dense_planes, taken_planes = self._take_planes
+        return compaction_bucket(
+            n_live, capacity, dense_planes=dense_planes,
+            taken_planes=taken_planes,
+        )
+
+    def _take_unique(self, mode, build, pb, pcol_ids, bcol_ids, bi, ok,
+                     sel_out, out_cap):
+        """One take of the boundary on the device, noted in the rings:
+        the build columns gathered at the batch's capacity (``out_cap``
+        None: probe columns stay views) or everything taken at the bucket
+        ``out_cap``, its index computed in the same program. Returns
+        _unique_compact_take_pred_jit's layout."""
+        bb = build.batch
+        bvals = tuple(bb.col_values(c) for c in bcol_ids)
+        bmasks = tuple(bb.col_validity(c) for c in bcol_ids)
+        obs.note_join_take(mode, out_cap or pb.capacity, pb.capacity)
+        if out_cap is None:
+            bv, bm = core._gather_build_jit(bvals, bmasks, bi, ok)
+            return (None, None, bv, bm, sel_out)
+        return core._unique_compact_take_pred_jit(
+            tuple(pb.col_values(c) for c in pcol_ids),
+            tuple(pb.col_validity(c) for c in pcol_ids),
+            bi, ok, bvals, bmasks, sel_out, out_cap=out_cap,
+        )
+
     def _emit_unique_compacted(
         self, build: PreparedBuild, pb: Batch, pvals, bcol_ids, proj,
         pipe: "UniqueProbePipeline | None" = None,
         prep=None,
     ) -> Iterator[Batch]:
-        import jax
-
-        from auron_tpu.columnar.batch import compaction_bucket
-
         bb = build.batch
         nl = len(self.left_schema)
         if prep is not None:
@@ -486,75 +535,48 @@ class EquiJoinDriver:
             else (pred.predict(pb.capacity) if pred is not None else None)
         )
         if pred_cap is None:
-            # seed/fallback path: ONE transfer — the selection mask itself
-            # (it was going to sync for the live count anyway; the mask is
-            # 1 byte/row and yields the compaction index host-side via
-            # flatnonzero). Steady state replaces this with the predicted
-            # bucket below: first batch of a stream only.
+            # seed: a stream's first batch has no prediction (nor has any
+            # batch with the predictor off). The probe program already
+            # counted the survivors: read that scalar — eight bytes, not
+            # the mask — seed the predictor and take on the device at the
+            # count's own bucket. Exact, so it never repairs and need not
+            # ride the window (which is still empty: order stays FIFO).
             # auronlint: disable=R9 -- first batch of a stream (and predictor-off fallback): pred_cap is None only before the first observation
-            sel_np = np.asarray(jax.device_get(sel_out))  # auronlint: sync-point(2/task) -- unique-join compaction seed read: first batch of a stream (and predictor-off fallback)
-            idx_np = np.flatnonzero(sel_np)
-            n_live = int(idx_np.size)
+            n_live = int(jax.device_get(n_live_dev))  # auronlint: sync-point(2/task) -- unique-join compaction seed read: the first batch's live count (and predictor-off fallback)
             if pred is not None:
                 pred.observe(n_live)
-            out_cap = compaction_bucket(n_live, pb.capacity)
-            if out_cap is None:
-                # dense output: compaction wouldn't pay — plain gathers
-                bvals, bmasks = core._gather_build_jit(
-                    tuple(bb.col_values(c) for c in bcol_ids),
-                    tuple(bb.col_validity(c) for c in bcol_ids),
-                    bi, ok,
-                )
-                c_pvals = c_pmasks = None
-                new_sel = sel_out
-            else:
-                idx_pad = np.zeros(out_cap, dtype=np.int32)
-                idx_pad[:n_live] = idx_np
-                c_pvals, c_pmasks, bvals, bmasks, new_sel = core._unique_compact_take_jit(
-                    tuple(pb.col_values(c) for c in pcol_ids),
-                    tuple(pb.col_validity(c) for c in pcol_ids),
-                    bi, ok,
-                    tuple(bb.col_values(c) for c in bcol_ids),
-                    tuple(bb.col_validity(c) for c in bcol_ids),
-                    jnp.asarray(idx_pad), jnp.int32(n_live),
-                )
+            taken = self._take_unique(
+                "seed", build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
+                self.take_bucket(n_live, pb.capacity),
+            )
             yield self._unique_out_batch(
-                pb, bb, proj, pcol_ids, bcol_ids,
-                c_pvals, c_pmasks, bvals, bmasks, new_sel,
+                pb, bb, proj, pcol_ids, bcol_ids, *taken
             )
             return
         # predicted path: compaction index computed ON DEVICE at the
-        # predicted bucket (or dense when prediction says compaction won't
-        # pay) — no host sync; the actual live count is harvested from the
-        # transfer window k batches later and mispredicts repair there.
-        # With a stage payload the gather/take already happened inside the
-        # fused program — reuse its outputs, push the same window state.
-        if compaction_bucket(pred_cap, pb.capacity) is None:
-            if prep is not None and prep.take == "gather_pred":
-                bvals, bmasks = prep.bvals, prep.bmasks
-            else:
-                bvals, bmasks = core._gather_build_jit(
-                    tuple(bb.col_values(c) for c in bcol_ids),
-                    tuple(bb.col_validity(c) for c in bcol_ids),
-                    bi, ok,
-                )
-            taken = (None, None, bvals, bmasks, sel_out)
-            state = (pb, bb, proj, pcol_ids, bcol_ids, taken,
-                     None, bi, ok, sel_out)
+        # predicted bucket — no host sync; the actual live count is
+        # harvested from the transfer window k batches later and
+        # mispredicts repair there. With a stage payload the take already
+        # happened inside the fused program — reuse its outputs, push the
+        # same window state. Where the rule says the predicted bucket is
+        # too wide to pay, nothing is taken yet: a wrong "dense" costs a
+        # whole capacity of gathers for a batch that may hold nothing (the
+        # batches behind a burst, while the predictor's bucket waits out
+        # its shrink patience), and the batch stays in the window until
+        # its count lands anyway, so the count itself decides there.
+        out_cap = self.take_bucket(pred_cap, pb.capacity)
+        if out_cap is None:
+            taken = None
+        elif prep is not None and prep.take == "compact":
+            obs.note_join_take("compact", out_cap, pb.capacity)
+            taken = prep.taken
         else:
-            if prep is not None and prep.take == "compact":
-                taken = prep.taken
-            else:
-                taken = core._unique_compact_take_pred_jit(
-                    tuple(pb.col_values(c) for c in pcol_ids),
-                    tuple(pb.col_validity(c) for c in pcol_ids),
-                    bi, ok,
-                    tuple(bb.col_values(c) for c in bcol_ids),
-                    tuple(bb.col_validity(c) for c in bcol_ids),
-                    sel_out, out_cap=pred_cap,
-                )
-            state = (pb, bb, proj, pcol_ids, bcol_ids, taken,
-                     pred_cap, bi, ok, sel_out)
+            taken = self._take_unique(
+                "compact", build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
+                out_cap,
+            )
+        state = (build, pb, proj, pcol_ids, bcol_ids, taken,
+                 out_cap, bi, ok, sel_out)
         for resolved, st in pipe.window.push((n_live_dev,), state):
             yield self._finish_unique_compacted(resolved, st, pred)
 
@@ -568,40 +590,33 @@ class EquiJoinDriver:
 
     def _finish_unique_compacted(self, resolved, state, pred) -> Batch:
         """Harvest half of the predicted compaction: observe the actual
-        live count, repair a too-small bucket by re-taking from the
-        still-held device state (pure recompute — no extra sync)."""
-        from auron_tpu.columnar.batch import compaction_bucket
+        live count, take what the dispatch left to it, repair a too-small
+        bucket by re-taking from the still-held device state (pure
+        recompute — no extra sync)."""
         from auron_tpu.exec.base import current_context
 
-        pb, bb, proj, pcol_ids, bcol_ids, taken, pred_cap, bi, ok, sel_out = state
+        (build, pb, proj, pcol_ids, bcol_ids, taken, pred_cap, bi, ok,
+         sel_out) = state
         n_live = int(resolved[0])
         if pred is not None:
             pred.observe(n_live, predicted=pred_cap)
-        if pred_cap is not None and n_live > pred_cap:
+        if taken is None:
+            # predicted too wide to pay: take at the count's own bucket
+            out_cap = self.take_bucket(n_live, pb.capacity)
+            taken = self._take_unique(
+                "dense" if out_cap is None else "compact",
+                build, pb, pcol_ids, bcol_ids, bi, ok, sel_out, out_cap,
+            )
+        elif n_live > pred_cap:
             ctx = current_context()
             if ctx is not None:
                 ctx.metrics.add("sel_mispredicts", 1)
-            out_cap = compaction_bucket(n_live, pb.capacity)
-            if out_cap is None:
-                bvals, bmasks = core._gather_build_jit(
-                    tuple(bb.col_values(c) for c in bcol_ids),
-                    tuple(bb.col_validity(c) for c in bcol_ids),
-                    bi, ok,
-                )
-                taken = (None, None, bvals, bmasks, sel_out)
-            else:
-                taken = core._unique_compact_take_pred_jit(
-                    tuple(pb.col_values(c) for c in pcol_ids),
-                    tuple(pb.col_validity(c) for c in pcol_ids),
-                    bi, ok,
-                    tuple(bb.col_values(c) for c in bcol_ids),
-                    tuple(bb.col_validity(c) for c in bcol_ids),
-                    sel_out, out_cap=out_cap,
-                )
-        c_pvals, c_pmasks, bvals, bmasks, new_sel = taken
+            taken = self._take_unique(
+                "repair", build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
+                self.take_bucket(n_live, pb.capacity),
+            )
         return self._unique_out_batch(
-            pb, bb, proj, pcol_ids, bcol_ids,
-            c_pvals, c_pmasks, bvals, bmasks, new_sel,
+            pb, build.batch, proj, pcol_ids, bcol_ids, *taken
         )
 
     def _unique_out_batch(

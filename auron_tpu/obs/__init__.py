@@ -13,8 +13,9 @@ Public surface:
   conf (R7). A span's ``cat`` is its layer (``LAYERS``), and every span
   is also a region ``auron:<layer>:<name>`` on the profiler's clock.
 - ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` /
-  ``note_agg_fold`` — the instrumentation facade behind MetricNode.timer,
-  the EngineCounters hooks, the task pump and the partial aggregate.
+  ``note_agg_fold`` / ``note_join_take`` — the instrumentation facade
+  behind MetricNode.timer, the EngineCounters hooks, the task pump, the
+  partial aggregate and the unique-build join's output boundary.
   Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``window_summary(t0_s, t1_s)`` — where the host's time went between
   two readings of ``time.perf_counter()``, by layer (obs/export.py).
@@ -129,6 +130,25 @@ def note_agg_fold(rows: int, in_rows: int) -> None:
                 {"rows": rows, "in_rows": in_rows})
 
 
+def note_join_take(mode: str, rows: int, in_rows: int) -> None:
+    """One take of the unique-build join's output boundary (the BHJ
+    driver, its fused stage twin, the star-join chain): ``mode`` is
+    ``compact`` (the predicted bucket), ``dense`` (the batch's capacity),
+    ``seed`` (a stream's first batch, taken at the bucket of the live
+    count just read) or ``repair`` (a mispredict's second take); ``rows``
+    the rows of capacity the build columns were gathered at (the chain:
+    times its levels), ``in_rows`` the capacity the batch came in with.
+    A ``take`` event of no duration and no layer, like ``fold``;
+    ``window_summary`` sums ``rows`` as ``join_gather_rows`` and counts
+    the takes by mode as ``join_takes``."""
+    if core._mode == MODE_OFF:
+        return
+    sp = _span_var.get()
+    tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
+    core.record("take", "join.unique", 0, tid, sid, 0,
+                {"mode": mode, "rows": rows, "in_rows": in_rows})
+
+
 def note_sync(dur_ns: int, is_async: bool) -> None:
     """One device->host read observed by EngineCounters (blocking sync or
     async-window harvest), into the trace counters of the calling
@@ -167,5 +187,5 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
         return None
 
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
-    note_agg_fold = _noop  # noqa: F811
+    note_agg_fold = note_join_take = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

@@ -22,9 +22,10 @@ under it measures the engine without instrumentation (tools/obscheck.py).
 Threading: ``record()`` touches only the calling thread's ring (created
 lazily); the registry of rings is locked ONLY at ring creation and at
 snapshot — never on the event path. Ring memory is bounded two ways:
-each ring holds at most ``ring_capacity`` events, and the registry keeps
-at most ``_MAX_RINGS`` rings, evicting the stalest dead-thread ring
-first (a finished task's recent events stay readable until they age out).
+each ring holds at most ``ring_capacity`` events (a finished thread's
+ring is cut down to the events it recorded), and the registry keeps at
+most ``_MAX_RINGS`` rings, evicting the stalest dead-thread ring first (a
+finished task's recent events stay readable until they age out).
 """
 
 from __future__ import annotations
@@ -33,6 +34,7 @@ import itertools
 import os
 import threading
 import time
+import weakref
 
 MODE_OFF, MODE_RECORDER, MODE_TRACE = 0, 1, 2
 _MODE_NAMES = {"off": MODE_OFF, "recorder": MODE_RECORDER, "trace": MODE_TRACE}
@@ -76,7 +78,13 @@ def set_mode(m: int | str) -> None:
 # per-thread rings
 # ---------------------------------------------------------------------------
 
-_MAX_RINGS = 256
+#: every bridge task runs on a thread of its own, four a query: a window
+#: of the benchmark holds 300 tasks at 0.55 s a query, and a reader of
+#: the window (``window_summary``) is complete only while none of their
+#: rings has been evicted. Finished threads' rings are trimmed to their
+#: events (``_make_ring``), so the bound on memory is the events, not
+#: ``_MAX_RINGS`` whole buffers.
+_MAX_RINGS = 4096
 #: dead-thread rings older than this are pruned at snapshot/creation
 _RETENTION_NS = 300 * 1_000_000_000
 
@@ -93,7 +101,8 @@ def set_ring_capacity(cap: int) -> None:
 
 
 class _Ring:
-    __slots__ = ("buf", "idx", "cap", "tid", "ident", "tname", "last_ns")
+    __slots__ = ("buf", "idx", "cap", "tid", "ident", "tname", "last_ns",
+                 "owner")
 
     def __init__(self, tid: int, cap: int):
         self.buf: list = [None] * cap
@@ -102,6 +111,9 @@ class _Ring:
         self.tid = tid
         t = threading.current_thread()
         self.ident = t.ident
+        # liveness is the thread OBJECT's: the OS hands a finished
+        # thread's ident to the next one started
+        self.owner = weakref.ref(t)
         self.tname = t.name
         self.last_ns = time.perf_counter_ns()
 
@@ -115,23 +127,28 @@ _ring_seq = itertools.count(1)
 _lost_until_ns = 0
 
 
-def _live_idents() -> set:
-    return {t.ident for t in threading.enumerate()}
+def _dead(r: "_Ring") -> bool:
+    t = r.owner()
+    return t is None or not t.is_alive()
 
 
 def _make_ring() -> _Ring:
     with _reg_lock:
-        if len(_rings) >= _MAX_RINGS:
+        dead = [r for r in _rings if _dead(r)]
+        for r in dead:
+            # a finished thread records no more: keep its events, free
+            # the unused rest of its buffer (a task's few hundred events
+            # in 32,768 slots)
+            if r.idx < len(r.buf):
+                del r.buf[r.idx:]
+        if len(_rings) >= _MAX_RINGS and dead:
             # evict the stalest DEAD-thread ring only. A live thread's
             # ring must never leave the registry — its owner would keep
             # recording into an orphan invisible to every export. With
             # no dead rings the registry simply grows: it is bounded by
             # the live thread count, which is a process-level bound
             # already (each thread's ring is just its buffer)
-            live = _live_idents()
-            dead = [r for r in _rings if r.ident not in live]
-            if dead:
-                _drop_locked(min(dead, key=lambda r: r.last_ns))
+            _drop_locked(min(dead, key=lambda r: r.last_ns))
         r = _Ring(next(_ring_seq), _ring_capacity)
         _rings.append(r)
     _tls.ring = r  # auronlint: disable=R7 -- per-THREAD ring is the recorder's design: events buffer by executing thread; TASK attribution rides in the event's trace/span fields, never in this local
@@ -171,9 +188,8 @@ def _drop_locked(r: _Ring) -> None:
 
 
 def _prune_locked(now_ns: int) -> None:
-    live = _live_idents()
     for r in [r for r in _rings
-              if r.ident not in live and now_ns - r.last_ns >= _RETENTION_NS]:
+              if _dead(r) and now_ns - r.last_ns >= _RETENTION_NS]:
         _drop_locked(r)
 
 

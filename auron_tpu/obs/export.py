@@ -117,7 +117,10 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     the host reads; ``sync_sites`` ranks them by seconds as ``[site, n,
     seconds]``. ``agg_fold_rows`` sums the capacities the partial
     aggregate's raw folds ran at (the ``fold`` events that began in the
-    window, ``obs.note_agg_fold``). ``plan_cache_hits`` and
+    window, ``obs.note_agg_fold``); ``join_gather_rows`` sums the
+    capacities the unique-build joins gathered their build columns at and
+    ``join_takes`` counts those takes by mode (the ``take`` events,
+    ``obs.note_join_take``). ``plan_cache_hits`` and
     ``plan_cache_misses`` count the ``serve:plan`` spans that began in the
     window by their ``cache_hit`` argument. ``complete`` is False where
     a ring that may hold events of the window has wrapped, or left the
@@ -128,7 +131,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     layers: dict[str, dict] = {}
     spans: dict[str, dict] = {}
     sites: dict[str, list] = {}
-    d2h = fold_rows = 0
+    d2h = fold_rows = gather_rows = 0
+    takes: dict[str, int] = {}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
 
     def book(table: dict, key: str, dur_ns: int, own_ns: int) -> None:
@@ -142,11 +146,16 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
         # oldest one did
         if ring["wrapped"] and evs[0][0] + evs[0][1] > lo:
             complete = False
-        fold_rows += sum(ev[7]["rows"] for ev in evs
-                         if ev[2] == "fold" and lo <= ev[0] < hi)
         for ev in evs:
-            if ev[8] == "serve" and ev[3] == "plan" and lo <= ev[0] < hi:
+            if not lo <= ev[0] < hi:
+                continue
+            if ev[8] == "serve" and ev[3] == "plan":
                 plans[bool(ev[7]["cache_hit"])] += 1
+            elif ev[2] == "fold":
+                fold_rows += ev[7]["rows"]
+            elif ev[2] == "take":
+                gather_rows += ev[7]["rows"]
+                takes[ev[7]["mode"]] = takes.get(ev[7]["mode"], 0) + 1
         regions = [
             (max(ts, lo), min(ts + dur, hi), layer, name, arg)
             for (ts, dur, _k, name, _t, _s, _p, arg, layer) in evs
@@ -165,6 +174,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
             "agg_fold_rows": fold_rows,
+            "join_gather_rows": gather_rows, "join_takes": takes,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
             "sync_sites": [[k, n, secs] for k, (n, secs) in ranked]}
 

@@ -384,10 +384,11 @@ class ProbePrepLink:
 class ProbePrepPayload:
     """One probe batch's stage-computed prologue results riding to the join
     driver (attached to the Batch as ``_probe_prep``). ``take`` names the
-    eager twin the stage replaced: "probe" (lookup only — the driver's
-    blocking seed path finishes), "gather" / "gather_pred" (build columns
-    gathered at probe width; non-compact emit vs predicted-dense window
-    push), "compact" (the predicted compact-take, ``taken`` =
+    eager twin the stage replaced: "probe" (lookup only — the driver
+    finishes: a stream's seed, or a batch predicted too wide to compact,
+    whose take waits for its own count), "gather" (build columns gathered
+    at probe width: the non-compact emit), "compact" (the predicted
+    compact-take, ``taken`` =
     _unique_compact_take_pred_jit's output tuple), "exists"
     (existence-LUT probe flags)."""
 
@@ -626,8 +627,6 @@ class FusedStageExec(ExecOperator):
         mode from the pipeline's predictor (the SAME predict call the eager
         driver would make), run _stage_program_probe, and wrap the results
         as a ProbePrepPayload for the join driver."""
-        from auron_tpu.columnar.batch import compaction_bucket
-
         kind = anchor["kind"]
         pred_cap = None
         take_tag = None
@@ -640,11 +639,14 @@ class FusedStageExec(ExecOperator):
             pred = pipe.pred if pipe is not None else None
             pred_cap = pred.predict(b.capacity) if pred is not None else None
             if pred_cap is None:
-                # seed/fallback: lookup only — the driver's blocking seed
-                # read finishes the batch exactly as the eager path does
+                # seed/fallback: lookup only — the driver reads the live
+                # count this program returns (eight bytes) and takes at its
+                # bucket, exactly as the eager path does
                 take_prog, take_tag = ("probe",), "probe"
-            elif compaction_bucket(pred_cap, b.capacity) is None:
-                take_prog, take_tag = ("gather",), "gather_pred"
+            elif anchor["take_bucket"](pred_cap, b.capacity) is None:
+                # predicted too wide to pay: lookup only, the driver takes
+                # when the batch's own count has landed
+                take_prog, take_tag = ("probe",), "probe"
             else:
                 take_prog, take_tag = ("compact", pred_cap), "compact"
         key_schema = self.out_stamp or self.children[0].schema
@@ -674,7 +676,7 @@ class FusedStageExec(ExecOperator):
         elif take_prog[0] == "probe":
             bi, ok, sel_out, live = extra
             payload = ProbePrepPayload(
-                build, kind, take_tag, pred_cap=None,
+                build, kind, take_tag, pred_cap=pred_cap,
                 bi=bi, ok=ok, sel_out=sel_out, live=live,
             )
         elif take_prog[0] == "gather":

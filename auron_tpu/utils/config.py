@@ -214,9 +214,12 @@ BATCH_SIZE_BUCKETS = str_conf(
 )
 JOIN_COMPACT_OUTPUT = str_conf(
     "join.compact.output", "auto", "join",
-    "compact sparse unique-join outputs before gathering build columns "
-    "(costs one host sync per probe batch): auto = on for CPU hosts, off "
-    "on accelerators where the sync round-trip outweighs the saved gather",
+    "compact sparse unique-join outputs before gathering build columns: "
+    "on | off | auto = on wherever the boundary costs no blocking read a "
+    "batch, i.e. wherever the selectivity predictor is on (a stream then "
+    "reads one scalar, its seed, and the rest rides the transfer window), "
+    "and on CPU hosts, which can afford the read. Whether a given batch "
+    "compacts is columnar.batch.compaction_bucket's rule over its shapes",
 )
 SELECTIVITY_PREDICTOR_ENABLE = str_conf(
     "exec.selectivity.predictor", "auto", "exec",

@@ -134,6 +134,7 @@ def require_tpu(n_chips: int):
 def resolved_knobs() -> dict:
     """What each backend-dependent ``auto`` resolves to in this process."""
     from auron_tpu import native
+    from auron_tpu.columnar import batch
     from auron_tpu.exec.agg_exec import HashAggExec
     from auron_tpu.exec.joins.driver import _compact_join_output_enabled
     from auron_tpu.jaxenv import is_tpu
@@ -159,6 +160,8 @@ def resolved_knobs() -> dict:
         "exec.fuse.probe": fusion._should_fuse(0, conf, C.FUSE_PROBE),
         "exec.fuse.shuffle": fusion._should_fuse(0, conf, C.FUSE_SHUFFLE),
         "join.compact.output": _compact_join_output_enabled(),
+        "join.compact.rule": ("gathered elements" if batch._gather_bound()
+                              else "a quarter of capacity"),
         "exchange.mode": conf.get(C.EXCHANGE_MODE),
         "memory.budget.bytes": memmgr._auto_budget(),
     }

@@ -459,6 +459,181 @@ def test_probe_prologue_bit_identity(join_type, jump):
         assert ctx.metrics.total("sel_mispredicts") > 0
 
 
+@pytest.fixture(scope="module")
+def dated_star_batches():
+    """The specification-typed tiny star (tests/test_sql_decimal_serve.py:
+    NULL keys that never join, NULL group keys, DECIMAL money) with the
+    fact rows in date order and pruned to the three columns query 42
+    reads, in batches of 8,192 rows; the filtered dimensions as Spark
+    broadcasts them."""
+    import test_sql_decimal_serve as star
+
+    frames = star.make_frames(seed=13, n_fact=60_000)
+    ss = frames["store_sales"].sort_values(
+        "ss_sold_date_sk", na_position="last", kind="stable"
+    ).reset_index(drop=True)[
+        ["ss_sold_date_sk", "ss_item_sk", "ss_ext_sales_price"]]
+    dd = frames["date_dim"]
+    dd = dd[((dd.d_moy == 11) & (dd.d_year == 2000)).fillna(False)]
+    it = frames["item"]
+    it = it[(it.i_manager_id == 1).fillna(False)][
+        ["i_item_sk", "i_category_id", "i_category"]]
+
+    def pruned(table, cols):
+        by_name = {f.name: f for f in star.SCHEMAS[table]}
+        return T.Schema(tuple(by_name[c] for c in cols))
+
+    fact = [Batch.from_pandas(ss.iloc[i:i + 8192],
+                              schema=pruned("store_sales", list(ss.columns)))
+            for i in range(0, len(ss), 8192)]
+    dates = Batch.from_pandas(dd[["d_date_sk", "d_year"]],
+                              schema=pruned("date_dim", ["d_date_sk", "d_year"]))
+    items = Batch.from_pandas(it, schema=pruned("item", list(it.columns)))
+    want = (ss.dropna(subset=["ss_sold_date_sk"])
+              .merge(dd, left_on="ss_sold_date_sk", right_on="d_date_sk")
+              .merge(it, left_on="ss_item_sk", right_on="i_item_sk"))
+    return fact, dates, items, want
+
+
+@pytest.mark.parametrize("chip", [False, True], ids=["cpu_rule", "chip_rule"])
+@pytest.mark.parametrize("compact", ["on", "off"])
+def test_fused_bhj_stages_on_the_tiny_star_are_row_exact(
+        monkeypatch, dated_star_batches, chip, compact):
+    """Two fused probe stages, date_dim then item, over a stream whose
+    first batches pass nothing and whose November batch passes a
+    thousand (seed on an empty batch, mispredict, repair): the stage's
+    in-program take and the eager driver's agree row for row, compaction
+    on or off, under the quarter rule and the chip's rule over shapes."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.columnar import batch as batch_mod
+    from auron_tpu.exec.base import ExecutionContext
+    from auron_tpu.utils.config import JOIN_COMPACT_OUTPUT, active_conf
+
+    fact, dates, items, want = dated_star_batches
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
+
+    def build():
+        scan = MemoryScanExec([list(fact)], fact[0].schema)
+        f1 = FilterExec(scan, [Not(IsNull(Column(1, "ss_item_sk")))])
+        j1 = BroadcastHashJoinExec(
+            f1, MemoryScanExec([[dates]], dates.schema),
+            [Column(0, "ss_sold_date_sk")], [Column(0, "d_date_sk")],
+            "inner", build_side="right", projection=[1, 2, 4])
+        f2 = FilterExec(j1, [Not(IsNull(Column(0, "ss_item_sk")))])
+        return BroadcastHashJoinExec(
+            f2, MemoryScanExec([[items]], items.schema),
+            [Column(0, "ss_item_sk")], [Column(0, "i_item_sk")],
+            "inner", build_side="right", projection=[1, 2, 4, 5])
+
+    conf = active_conf()
+    saved, saved_mode = conf.get(JOIN_COMPACT_OUTPUT), obs.mode()
+    conf.set(JOIN_COMPACT_OUTPUT, compact)
+    obs.set_mode("recorder")
+    try:
+        eager = build().collect().to_pandas()
+        tree = fuse_exec_tree(build(), ON)
+        ctx = ExecutionContext()
+        ctx.metrics.name = tree.name
+        t0 = time.perf_counter()
+        out = list(tree.execute(0, ctx))
+        ws = obs.window_summary(t0, time.perf_counter())
+    finally:
+        conf.set(JOIN_COMPACT_OUTPUT, saved)
+        obs.set_mode(saved_mode)
+    fused = pd.concat([b.to_pandas() for b in out], ignore_index=True)
+    _assert_rows_equal(eager, fused)
+    assert _types(tree).count("FusedStageExec") == 2
+    assert ctx.metrics.total("fused_batches") > 0
+    # against plain pandas: money to the cent, the NULL category kept
+    assert len(fused) == len(want) > 300
+    assert sorted(fused.ss_ext_sales_price.dropna()) == sorted(
+        want.ss_ext_sales_price.dropna())
+    assert fused.i_category.isna().sum() == want.i_category.isna().sum() > 0
+    n = len(fact)
+    if compact == "off":
+        assert ws["join_takes"] == {"dense": 2 * n}
+        assert ws["join_gather_rows"] == 2 * sum(b.capacity for b in fact)
+    else:
+        takes = ws["join_takes"]
+        assert takes["seed"] == 2                 # one a probe stream
+        assert takes.get("repair", 0) >= 1        # nothing, then a thousand
+        assert ctx.metrics.total("sel_mispredicts") == takes["repair"]
+        assert ws["join_gather_rows"] < sum(b.capacity for b in fact) / 2
+
+
+@pytest.mark.parametrize("chip", [False, True], ids=["cpu_rule", "chip_rule"])
+@pytest.mark.parametrize("fused", [False, True], ids=["eager", "stage"])
+def test_batches_behind_a_burst_are_not_gathered_at_capacity(
+        monkeypatch, fused, chip):
+    """One batch of a stream passes half its rows, the batches behind it
+    none. The predictor's bucket stays wide for its shrink patience, and a
+    bucket too wide to pay used to mean a gather of every build column at
+    capacity for batches that hold nothing; now such a batch's take waits
+    in the window for its own count, which compacts it into the least
+    bucket. Only the burst itself is gathered at capacity."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.columnar import batch as batch_mod
+    from auron_tpu.exec.base import ExecutionContext
+    from auron_tpu.obs import core
+    from auron_tpu.utils.config import (
+        JOIN_COMPACT_OUTPUT, TRANSFER_WINDOW_DEPTH, active_conf,
+    )
+
+    cap, live = 8192, [10, 4000, 0, 0, 0, 0]
+    rng = np.random.default_rng(5)
+    frames = []
+    for n in live:
+        k = np.full(cap, 10_000, dtype=np.int64)
+        k[rng.choice(cap, n, replace=False)] = rng.integers(0, 64, n)
+        frames.append(pd.DataFrame({"k": k, "v": rng.integers(0, 1 << 30, cap)}))
+    dim = pd.DataFrame({"id": np.arange(64, dtype=np.int64),
+                        "d": np.arange(64, dtype=np.int64) * 3})
+    probe_b = [Batch.from_pandas(f) for f in frames]
+    dim_b = Batch.from_pandas(dim)
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
+
+    def build():
+        scan = MemoryScanExec([list(probe_b)], probe_b[0].schema)
+        flt = FilterExec(scan, [BinaryOp(
+            "gteq", Column(1, "v"), Literal(0, T.INT64))])
+        return BroadcastHashJoinExec(
+            flt, MemoryScanExec([[dim_b]], dim_b.schema),
+            [Column(0, "k")], [Column(0, "id")], "inner", build_side="right")
+
+    conf = active_conf()
+    saved = (conf.get(JOIN_COMPACT_OUTPUT), conf.get(TRANSFER_WINDOW_DEPTH),
+             obs.mode())
+    conf.set(JOIN_COMPACT_OUTPUT, "on")
+    conf.set(TRANSFER_WINDOW_DEPTH, 1)
+    obs.set_mode("recorder")
+    try:
+        tree = fuse_exec_tree(build(), ON) if fused else build()
+        ctx = ExecutionContext()
+        ctx.metrics.name = tree.name
+        t0 = time.perf_counter_ns()
+        out = list(tree.execute(0, ctx))
+        t1 = time.perf_counter_ns()
+        takes = [ev[7] for _r, evs in core.snapshot_events() for ev in evs
+                 if ev[2] == "take" and t0 <= ev[0] < t1]
+    finally:
+        conf.set(JOIN_COMPACT_OUTPUT, saved[0])
+        conf.set(TRANSFER_WINDOW_DEPTH, saved[1])
+        obs.set_mode(saved[2])
+    got = pd.concat([b.to_pandas() for b in out], ignore_index=True)
+    want = pd.concat(frames).merge(dim, left_on="k", right_on="id")
+    _assert_rows_equal(got[["k", "v", "d"]], want[["k", "v", "d"]])
+    if fused:
+        assert ctx.metrics.total("fused_batches") == len(live)
+    at_capacity = [t for t in takes if t["rows"] == cap]
+    assert [t["mode"] for t in at_capacity] == ["repair"]     # the burst
+    assert sorted(t["rows"] for t in takes if t["rows"] != cap) == [128] * 6
+    assert sum(t["mode"] == "seed" for t in takes) == 1
+
+
 def test_probe_prologue_exists_lut_bit_identity():
     """Duplicate-keyed build probed by semi/anti: the existence-LUT probe
     rides the stage program (payload kind "exists")."""

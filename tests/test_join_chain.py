@@ -359,3 +359,87 @@ def test_chain_window_depth_one_matches(monkeypatch):
     exp.columns = got.columns
     exp = exp.sort_values(list(exp.columns)).reset_index(drop=True)
     pd.testing.assert_frame_equal(got, exp, check_dtype=False)
+
+
+# ---------------------------------------------------------------------------
+# the chain's output boundary on the specification-typed tiny star
+# (tests/test_sql_decimal_serve.py: NULL keys, NULL group keys, DECIMAL money)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def dated_star():
+    """The tiny star with the fact table in date order, as the benchmark's
+    is, in batches of 8,192 rows: November 2000 (texts 42, 52) is one run
+    of rows in the middle of the stream, so the first batches pass nothing
+    and one batch passes a thousand."""
+    import test_sql_decimal_serve as star
+
+    from auron_tpu.serve.server import SqlServer
+
+    frames = star.make_frames(seed=11, n_fact=90_000)
+    ss = frames["store_sales"].sort_values(
+        "ss_sold_date_sk", na_position="last", kind="stable"
+    ).reset_index(drop=True)
+    frames = {**frames, "store_sales": ss}
+    tables = {
+        t: [Batch.from_pandas(df.iloc[i:i + 8192], schema=star.SCHEMAS[t])
+            for i in range(0, len(df), 8192)]
+        for t, df in frames.items()
+    }
+    return star, frames, SqlServer(star.make_catalog(frames), tables, n_parts=1)
+
+
+def _answer_and_takes(star, server, name, compact):
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.utils.config import JOIN_COMPACT_OUTPUT
+
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        t0 = time.perf_counter()
+        rec = server.execute_json({
+            "sql": star._text(name), "tenant": f"c-{compact}",
+            "conf": {JOIN_COMPACT_OUTPUT.key: compact},
+        })
+        ws = obs.window_summary(t0, time.perf_counter())
+    finally:
+        obs.set_mode(saved)
+    return rec["rows"], ws
+
+
+@pytest.mark.parametrize("chip", [False, True], ids=["cpu_rule", "chip_rule"])
+@pytest.mark.parametrize("name, first_batch_empty", [
+    ("q3", False),      # November of either year: live rows from batch 1
+    ("q42", True),      # November 2000 alone: none, none, ..., a thousand
+    ("q55", False),     # November 1999: a thousand early, then none
+])
+def test_chain_compact_and_dense_arms_are_row_exact_twins(
+        monkeypatch, dated_star, chip, name, first_batch_empty):
+    """Through POST /sql's executor (the fused chain): compaction on and
+    off give the reference's rows to the cent, NULL keys never joining and
+    NULL group keys one group, under the quarter rule and under the chip's
+    rule over shapes, through a seed, a mispredict and its repair."""
+    from auron_tpu.columnar import batch as batch_mod
+
+    star, frames, server = dated_star
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
+    want = star.star_reference(frames, name)
+    assert len(want) > 3
+    on, ws_on = _answer_and_takes(star, server, name, "on")
+    off, ws_off = _answer_and_takes(star, server, name, "off")
+    assert on == want
+    assert off == want
+    n_batches = len(server.tables["store_sales"])
+    assert ws_off["join_takes"] == {"dense": n_batches}
+    assert ws_off["join_gather_rows"] == 2 * 8192 * (n_batches - 1) + 2 * \
+        server.tables["store_sales"][-1].capacity
+    takes = ws_on["join_takes"]
+    assert takes["seed"] == 1 and sum(takes.values()) >= n_batches
+    assert ws_on["join_gather_rows"] < ws_off["join_gather_rows"] / 3
+    if first_batch_empty:
+        # seeded on nothing, then a thousand rows arrive: repaired from the
+        # state the window holds, at the count's own bucket or dense
+        assert takes.get("repair", 0) >= 1

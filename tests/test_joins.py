@@ -337,20 +337,184 @@ def test_unique_build_residual_condition_noncompact_emit():
     assert got["rv"].tolist() == want["rv"].tolist()
 
 
-def test_compact_join_output_knob_tri_resolution(monkeypatch):
-    """Tri-state semantics of spark.auron.join.compact.output pinned
-    after the resolve_tri rewrite: on/off force, auto follows the
-    backend (tests run on the CPU backend, where syncs are cheap and
-    auto resolves to compaction ON)."""
+@pytest.mark.parametrize("compact, predictor, tpu, want", [
+    # on | off keep their meaning on every back end
+    ("on", "auto", True, True), ("on", "off", True, True),
+    ("off", "auto", True, False), ("off", "on", False, False),
+    # auto: on wherever the boundary costs no blocking read a batch. The
+    # predictor's own auto is "on where compaction is", so the defaults
+    # compact on the accelerator as on the CPU
+    ("auto", "auto", True, True), ("auto", "auto", False, True),
+    ("auto", "on", True, True),
+    # predictor off: a blocking read a batch, which only a CPU host affords
+    ("auto", "off", True, False), ("auto", "off", False, True),
+])
+def test_compact_join_output_knob_tri_resolution(monkeypatch, compact,
+                                                 predictor, tpu, want):
+    """spark.auron.join.compact.output: on/off force; auto no longer asks
+    which back end this is but whether the predicted, sync-free arm runs."""
+    from auron_tpu import jaxenv
     from auron_tpu.exec import base as exec_base
     from auron_tpu.exec.joins.driver import _compact_join_output_enabled
     from auron_tpu.utils.config import (
-        JOIN_COMPACT_OUTPUT, Configuration, conf_scope,
+        JOIN_COMPACT_OUTPUT, SELECTIVITY_PREDICTOR_ENABLE, Configuration,
+        conf_scope,
     )
 
     # drop the last test's lingering operator context so the gate reads
     # the scoped conf, not a stale task's
     monkeypatch.delattr(exec_base._ctx_local, "ctx", raising=False)
-    for mode, want in (("on", True), ("off", False), ("auto", True)):
-        with conf_scope(Configuration({JOIN_COMPACT_OUTPUT.key: mode})):
-            assert _compact_join_output_enabled() is want, mode
+    monkeypatch.setattr(jaxenv, "is_tpu", lambda: tpu)
+    conf = Configuration({JOIN_COMPACT_OUTPUT.key: compact,
+                          SELECTIVITY_PREDICTOR_ENABLE.key: predictor})
+    with conf_scope(conf):
+        assert _compact_join_output_enabled() is want
+
+
+# ---------------------------------------------------------------------------
+# the output boundary of the unique-build probe: which take each stream of
+# probe batches gets, what the seed reads, and the paths that never compact
+# ---------------------------------------------------------------------------
+
+
+def _takes_of(run):
+    """(result, the window's take events as (mode, rows, in_rows), the
+    window's summary with the probe driver's own host reads as
+    ``join_reads``: (site, bytes)) of ``run()`` under the flight recorder."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.obs import core
+    from auron_tpu.utils.profiling import EngineCounters
+
+    EngineCounters.install()        # the hook that names the host reads
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        t0 = time.perf_counter()
+        out = run()
+        t1 = time.perf_counter()
+        lo, hi = int(t0 * 1e9), int(t1 * 1e9)
+        window = [ev for _r, evs in core.snapshot_events() for ev in evs
+                  if lo <= ev[0] < hi]
+        evs = sorted((ev for ev in window if ev[2] == "take"),
+                     key=lambda ev: ev[0])
+        ws = obs.window_summary(t0, t1)
+        ws["join_reads"] = [(ev[3], ev[7]["bytes"]) for ev in window
+                            if ev[8] == "sync"
+                            and ev[3].startswith("exec/joins/driver.py:")]
+    finally:
+        obs.set_mode(saved)
+    return out, [(e[7]["mode"], e[7]["rows"], e[7]["in_rows"]) for e in evs], ws
+
+
+def _sparse_probe(n=4096, live_from=None, seed=3):
+    """Probe frames of 1,024-row batches over a build of keys 0..63: rows
+    before ``live_from`` carry a key the build lacks."""
+    k = np.full(n, 10_000)
+    k[::97] = np.arange(len(k[::97])) % 64    # a few survivors in every batch
+    if live_from is not None:
+        k[:live_from] = 10_000
+    probe = pd.DataFrame({"k": k.astype(np.int64),
+                          "v": np.arange(n, dtype=np.int64)})
+    dim = pd.DataFrame({"id": np.arange(64, dtype=np.int64),
+                        "d": np.arange(64, dtype=np.int64) * 3})
+    return probe, dim
+
+
+@pytest.mark.parametrize("case, modes", [
+    # a left join keeps every probe row (its seed finds the batch full), a
+    # residual condition needs every pair: both stay dense at capacity,
+    # batch after batch, as before
+    ("outer_probe", ["seed"] + ["dense"] * 3),
+    ("residual", ["dense"] * 4),
+    # a duplicate-key build expands ragged pairs: not this boundary at all
+    ("duplicate_build", []),
+    # the inner unique join: a seed, then the predicted arm
+    ("inner_unique", ["seed", "compact", "compact", "compact"]),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_paths_that_never_compact_still_take_theirs(case, modes):
+    probe, dim = _sparse_probe()
+    jt, cond = INNER, None
+    if case == "outer_probe":
+        jt = LEFT
+    elif case == "residual":
+        cond = BinaryOp("gt", col(3), lit(-1, T.INT64))
+    elif case == "duplicate_build":
+        dim = pd.concat([dim, dim.assign(d=dim.d + 1)], ignore_index=True)
+
+    def run():
+        op = BroadcastHashJoinExec(
+            _mk(probe, 1024), _mk(dim), [col(0)], [col(0)], jt,
+            build_side="right", condition=cond)
+        return op.collect().to_pandas()
+
+    got, takes, ws = _takes_of(run)
+    want = probe.merge(dim, left_on="k", right_on="id",
+                       how="left" if jt == LEFT else "inner")
+    assert len(got) == len(want)
+    assert sorted(got["v"].tolist()) == sorted(want["v"].tolist())
+    assert [m for m, _, _ in takes] == modes
+    assert all(cap == 1024 for _, _, cap in takes)
+    if "dense" in modes:
+        assert [rows for _, rows, _ in takes] == [1024] * 4
+        assert ws["join_gather_rows"] == 4 * 1024
+    assert ws["join_takes"] == {m: modes.count(m) for m in set(modes)}
+
+
+@pytest.mark.parametrize("predictor, live_from, want_modes, want_seed_reads", [
+    # steady: one seed, then predicted compact takes riding the window
+    ("auto", None, ["seed", "compact", "compact", "compact"], 1),
+    # the first batch is empty, the later ones hold two hundred: the seed
+    # compacts into the least bucket, and each batch dispatched at it
+    # before the first harvest (all three: the window is four deep)
+    # repairs at the bucket of its own count
+    ("auto", 1024, ["seed"] + ["compact"] * 3 + ["repair"] * 3, 1),
+    # predictor off: every batch reads its own count and takes exactly
+    ("off", 1024, ["seed"] * 4, 4),
+], ids=["steady", "empty_first_then_jump", "predictor_off"])
+def test_the_seed_reads_one_scalar_and_no_mask(predictor, live_from,
+                                               want_modes, want_seed_reads):
+    """The first batch of a stream has no prediction: the join reads its
+    live count (one scalar), never the selection mask (a byte a row), and
+    takes on the device at that count's bucket."""
+    from auron_tpu.utils.config import (
+        JOIN_COMPACT_OUTPUT, SELECTIVITY_PREDICTOR_ENABLE,
+        TRANSFER_WINDOW_DEPTH, active_conf,
+    )
+
+    probe, dim = _sparse_probe(live_from=live_from)
+    if live_from is not None:       # of the later rows a fifth survives
+        late = probe.index[(probe.index >= live_from) & (probe.index % 5 == 0)]
+        probe.loc[late, "k"] = probe.loc[late, "v"] % 64
+    conf = active_conf()
+    saved = (conf.get(JOIN_COMPACT_OUTPUT),
+             conf.get(SELECTIVITY_PREDICTOR_ENABLE))
+    saved += (conf.get(TRANSFER_WINDOW_DEPTH),)
+    conf.set(JOIN_COMPACT_OUTPUT, "on")
+    conf.set(SELECTIVITY_PREDICTOR_ENABLE, predictor)
+    conf.set(TRANSFER_WINDOW_DEPTH, 4)
+
+    def run():
+        op = BroadcastHashJoinExec(
+            _mk(probe, 1024), _mk(dim), [col(0)], [col(0)], INNER,
+            build_side="right")
+        return op.collect().to_pandas()
+
+    try:
+        got, takes, ws = _takes_of(run)
+    finally:
+        conf.set(JOIN_COMPACT_OUTPUT, saved[0])
+        conf.set(SELECTIVITY_PREDICTOR_ENABLE, saved[1])
+        conf.set(TRANSFER_WINDOW_DEPTH, saved[2])
+    want = probe.merge(dim, left_on="k", right_on="id")
+    assert sorted(got["v"].tolist()) == sorted(want["v"].tolist())
+    assert got["d"].tolist() == (got["k"] * 3).tolist()
+    assert [m for m, _, _ in takes] == want_modes
+    assert all(rows == 256 for m, rows, _ in takes if m == "repair")
+    # the probe's own blocking reads: the seeds, one int64 each, from one
+    # line of the driver; nothing the size of a mask (a byte a row)
+    assert [b for _, b in ws["join_reads"]] == [8] * want_seed_reads
+    assert len({site for site, _ in ws["join_reads"]}) == 1
+
+

@@ -140,6 +140,42 @@ def test_ring_is_bounded_and_wraps():
         core.set_ring_capacity(32768)
 
 
+def test_a_window_of_three_hundred_task_threads_stays_complete():
+    """Every bridge task runs on a thread of its own: a benchmark window
+    of 75 queries is 300 of them. Their rings stay in the registry (the
+    window's summary is complete and holds every thread's events), and a
+    finished thread's ring is cut down to the events it recorded."""
+    import time
+
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        t0 = time.perf_counter()
+
+        def task():
+            obs.note_join_take("compact", 128, 4194304)
+            obs.note_agg_fold(256, 4194304)
+
+        for i in range(300):
+            t = threading.Thread(target=task, name=f"ring-task-{i}")
+            t.start()
+            t.join()
+        ws = obs.window_summary(t0, time.perf_counter())
+        with core._reg_lock:
+            finished = [r for r in core._rings
+                        if r.tname.startswith("ring-task-")]
+    finally:
+        obs.set_mode(saved)
+    assert ws["complete"]
+    assert ws["join_takes"] == {"compact": 300}
+    assert ws["join_gather_rows"] == 300 * 128
+    assert ws["agg_fold_rows"] == 300 * 256
+    assert len(finished) == 300
+    # all but the newest (trimmed when the next ring is made) hold their
+    # events and no more
+    assert sum(len(r.buf) > r.idx for r in finished) <= 1
+
+
 def test_recorder_mode_rings_only_no_per_event_lock():
     """recorder vs trace distinction: recorder records ring events and
     publishes per-task summaries, but never takes the per-event Trace

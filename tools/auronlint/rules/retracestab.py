@@ -52,8 +52,11 @@ from tools.auronlint.core import Rule, SourceModule
 #: this many module-level jit entries tree-wide, and keep proving at
 #: least this many. Raise them as entries are added; a DROP means the
 #: analysis lost sight of real entries (or a key regressed to unproven).
-R13_MIN_COVERED = 51
-R13_MIN_PROVED = 51
+#: 51 -> 48 with the joins' host-index takes and the chain's separate
+#: live count (_unique_compact_take_jit, _chain_take_jit, _sel_count_jit):
+#: three entries deleted with their last caller, none lost from sight.
+R13_MIN_COVERED = 48
+R13_MIN_PROVED = 48
 
 _JIT_RE = re.compile(r"\bjit\b")
 
