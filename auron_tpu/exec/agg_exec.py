@@ -63,7 +63,6 @@ from auron_tpu.utils.config import (
     AGG_INCREMENTAL_FP_BITS,
     AGG_INCREMENTAL_MERGEPATH,
     AGG_INCREMENTAL_PROBE,
-    AGG_PARTIAL_DEFER,
     PARTIAL_AGG_SKIPPING_ENABLE,
     PARTIAL_AGG_SKIPPING_MIN_ROWS,
     PARTIAL_AGG_SKIPPING_RATIO,
@@ -414,10 +413,41 @@ class HashAggExec(ExecOperator):
                 mm.acquire(table, batch_nbytes(sb))
                 table.add(sb, g)
 
+        def skip_or_stage(inter, g):
+            """One counted intermediate of the generic path, blocking or
+            deferred: pass it through in partial-agg skipping mode (yields),
+            else stage it into the table and merge when due."""
+            nonlocal skipping
+            if skipping:
+                yield inter
+                return
+            if (
+                skipping_enabled
+                and seen_rows >= skip_min_rows
+                and seen_groups >= skip_ratio * seen_rows
+                and not table.parked
+            ):
+                # high cardinality: stop accumulating, stream through
+                ctx.metrics.add("partial_agg_skipped", 1)
+                skipping = True
+                yield from table.drain()
+                yield inter
+                return
+            mm.acquire(table, batch_nbytes(inter))
+            table.add(inter, g)
+            # geometric amortization: compacting re-reduces the WHOLE
+            # state, so only do it once the staged rows rival the state
+            # size — otherwise high-cardinality aggs go quadratic in
+            # merge work (measured as the q5-class merge_time blowup)
+            if table.staged_rows >= max(merge_threshold, table.state_capacity()):
+                with ctx.metrics.timer("merge_time"):
+                    table.compact()
+                ctx.metrics.add("num_merges", 1)
+
         def process_generic(b):
             # generic (sort-segmentation) path for ONE batch; yields
             # pass-through output in partial-agg skipping mode
-            nonlocal pending_g, pending_proxy, seen_rows, seen_groups, skipping
+            nonlocal pending_g, pending_proxy, seen_rows, seen_groups
             if self.mode == PARTIAL:
                 # sync the live count FIRST: sparse batches (post-filter/
                 # join output still at input capacity) are compacted
@@ -492,31 +522,7 @@ class HashAggExec(ExecOperator):
             seen_rows += n
             if self.mode != PARTIAL:
                 seen_groups += g
-            if skipping:
-                yield inter
-                return
-            if (
-                skipping_enabled
-                and seen_rows >= skip_min_rows
-                and seen_groups >= skip_ratio * seen_rows
-                and not table.parked
-            ):
-                # high cardinality: stop accumulating, stream through
-                ctx.metrics.add("partial_agg_skipped", 1)
-                skipping = True
-                yield from table.drain()
-                yield inter
-                return
-            mm.acquire(table, batch_nbytes(inter))
-            table.add(inter, g)
-            # geometric amortization: compacting re-reduces the WHOLE
-            # state, so only do it once the staged rows rival the state
-            # size — otherwise high-cardinality aggs go quadratic in
-            # merge work (measured as the q5-class merge_time blowup)
-            if table.staged_rows >= max(merge_threshold, table.state_capacity()):
-                with ctx.metrics.timer("merge_time"):
-                    table.compact()
-                ctx.metrics.add("num_merges", 1)
+            yield from skip_or_stage(inter, g)
 
         def fold_dense(nb, defer: bool = True) -> list | None:
             """Fold one batch through the dense table, driving the
@@ -557,36 +563,27 @@ class HashAggExec(ExecOperator):
         # front, is out of the picture)
         probe = _ProbeScatter(self, ctx, table) if self._probe_eligible() else None
 
-        # deferred PARTIAL counts (exec.agg.partial.defer, docs/fusion.md):
-        # the generic path's steady-state "ONE round-trip per batch" read
-        # (the device_get below at the sync-point(1/batch) site) becomes a
-        # k-deep read through the async transfer window — the upstream
-        # probe/stage pipeline dispatches ahead instead of blocking per
-        # batch (q93-class: 227 blocking syncs / 38s of drain). Compaction
+        # deferred PARTIAL counts (docs/fusion.md): the generic path's
+        # steady-state "ONE round-trip per batch" read (the device_get
+        # above at the sync-point(1/batch) site) becomes a k-deep read
+        # through the async transfer window — the upstream probe/stage
+        # pipeline dispatches ahead instead of blocking per batch
+        # (q93-class: 227 blocking syncs / 38s of drain). Compaction
         # buckets come from the selectivity predictor; a truncating
         # mispredict recomputes the reduce from the still-held batch (bit-
-        # identical, rare: the predictor grows immediately). Gated off when
-        # host aggregates sync internally anyway, or when the sorted-state
-        # probe is active (its direct state folds must not overtake
-        # window-pending batches — the first/first_ignores_null stream-
-        # order contract its spill-park test pins).
+        # identical, rare: the predictor grows immediately). Not armed
+        # when host aggregates sync internally anyway, or when the
+        # sorted-state probe is active (its direct state folds must not
+        # overtake window-pending batches — the first/first_ignores_null
+        # stream-order contract its spill-park test pins).
         defer_win = None
         defer_pred = None
-        if (
-            self.mode == PARTIAL
-            and not self._has_host_aggs
-            and probe is None
-            and resolve_tri(conf.get(AGG_PARTIAL_DEFER), True)
-        ):
-            from auron_tpu.exec.selectivity import (
-                SelectivityPredictor, predictor_enabled,
-            )
+        if self.mode == PARTIAL and not self._has_host_aggs and probe is None:
+            from auron_tpu.exec.selectivity import SelectivityPredictor
             from auron_tpu.runtime.transfer import TransferWindow
 
             defer_win = TransferWindow(conf.get(TRANSFER_WINDOW_DEPTH))
-            defer_pred = (
-                SelectivityPredictor(conf) if predictor_enabled(conf) else None
-            )
+            defer_pred = SelectivityPredictor()
 
         def fold_deferred(bb, in_capacity):
             """One PARTIAL raw fold of the deferred arm (dispatch and
@@ -608,27 +605,21 @@ class HashAggExec(ExecOperator):
             batches in — past the end of a short stream)."""
             from auron_tpu.columnar.batch import compact_batch, compaction_bucket
 
-            pred_cap = None
-            seeded = False
-            if defer_pred is not None:
+            pred_cap = defer_pred.predict(b.capacity)
+            seeded = pred_cap is None
+            if seeded:
+                # auronlint: disable=R9 -- first-batch-only branch: predict() is None exactly once per stream (the observe below seeds it)
+                n_seed = int(jax.device_get(b.device.num_rows()))  # auronlint: sync-point(4/task) -- deferred-agg seed: the stream's first live count, read before its reduce is dispatched
+                defer_pred.observe(n_seed)
+                ctx.metrics.add("sel_seed_reads", 1)
                 pred_cap = defer_pred.predict(b.capacity)
-                if pred_cap is None:
-                    # auronlint: disable=R9 -- first-batch-only branch: predict() is None exactly once per stream (the observe below seeds it)
-                    n_seed = int(jax.device_get(b.device.num_rows()))  # auronlint: sync-point(4/task) -- deferred-agg seed: the stream's first live count, read before its reduce is dispatched
-                    defer_pred.observe(n_seed)
-                    ctx.metrics.add("sel_seed_reads", 1)
-                    seeded = True
-                    pred_cap = defer_pred.predict(b.capacity)
-            used_cap = None
+            used_cap = compaction_bucket(pred_cap, b.capacity)
             bb = b
-            if pred_cap is not None:
-                out_cap = compaction_bucket(pred_cap, b.capacity)
-                if out_cap is not None:
-                    # may truncate on a mispredict — resolve_deferred
-                    # detects n > used_cap and recomputes from ``b``
-                    bb = compact_batch(b, out_cap)
-                    used_cap = out_cap
-                    ctx.metrics.add("agg_compacted_batches", 1)
+            if used_cap is not None:
+                # may truncate on a mispredict — resolve_deferred
+                # detects n > used_cap and recomputes from ``b``
+                bb = compact_batch(b, used_cap)
+                ctx.metrics.add("agg_compacted_batches", 1)
             inter = fold_deferred(bb, b.capacity)
             coll = getattr(inter, "_fp_collision", None)
             scalars = [b.device.num_rows(), inter.device.num_rows()]
@@ -640,10 +631,10 @@ class HashAggExec(ExecOperator):
             """Harvest half, k batches behind dispatch: exact (n, g) land
             together — no pending_g carry — and the intermediate stages at
             its exact group bucket."""
-            nonlocal seen_rows, seen_groups, skipping
+            nonlocal seen_rows, seen_groups
             b, inter, used_cap, has_coll, seeded = state
             n, g = int(resolved[0]), int(resolved[1])
-            if defer_pred is not None and not seeded:
+            if not seeded:
                 # the seed batch was observed at dispatch: the EWMA and the
                 # shrink streak count batches, never one twice
                 defer_pred.observe(n, predicted=used_cap)
@@ -674,26 +665,7 @@ class HashAggExec(ExecOperator):
             seen_rows += n
             seen_groups += g
             inter = self._prefix_slice_meta(inter, bucket_capacity(max(g, 1)))
-            if skipping:
-                yield inter
-                return
-            if (
-                skipping_enabled
-                and seen_rows >= skip_min_rows
-                and seen_groups >= skip_ratio * seen_rows
-                and not table.parked
-            ):
-                ctx.metrics.add("partial_agg_skipped", 1)
-                skipping = True
-                yield from table.drain()
-                yield inter
-                return
-            mm.acquire(table, batch_nbytes(inter))
-            table.add(inter, g)
-            if table.staged_rows >= max(merge_threshold, table.state_capacity()):
-                with ctx.metrics.timer("merge_time"):
-                    table.compact()
-                ctx.metrics.add("num_merges", 1)
+            yield from skip_or_stage(inter, g)
 
         def feed_generic(b):
             """Route one batch to the generic path: through the deferred

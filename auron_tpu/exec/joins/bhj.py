@@ -152,23 +152,20 @@ class BroadcastHashJoinExec(ExecOperator):
             guard = _BuildMemGuard(self, build)
             mm.register(guard, spillable=False)
             probe_child = 1 if self.build_side == "left" else 0
-            # per-partition pipeline for the unique-compact boundary: the
-            # selectivity predictor + transfer window make the steady state
-            # sync-free (driver.UniqueProbePipeline; emissions lag dispatch
-            # by the window depth, drained by finish_probe below)
-            from auron_tpu.exec.joins.driver import UniqueProbePipeline
-
-            pipe = UniqueProbePipeline(ctx.conf)
+            # this partition's compaction boundary (exec/selectivity.py):
+            # emissions lag dispatch by the window depth, drained by
+            # finish_probe below
+            boundary = self.driver.compaction_boundary(ctx)
             if link is not None:
-                self.driver.publish_probe_prep(link, build, pipe, ctx.conf)
+                self.driver.publish_probe_prep(link, build, boundary)
             for pb in self.child_stream(probe_child, partition, ctx):
                 ctx.check_cancelled()
                 # no empty-batch pre-check: it costs a host sync per batch,
                 # and the probe itself already syncs once on the match total
                 with ctx.metrics.timer("probe_time", count=True):
-                    yield from self.driver.probe_batch(build, pb, pipe)
+                    yield from self.driver.probe_batch(build, pb, boundary)
             with ctx.metrics.timer("probe_time"):
-                yield from self.driver.finish_probe(pipe)
+                yield from self.driver.finish_probe(boundary)
             yield from self.driver.finish(build)
         finally:
             if link is not None:

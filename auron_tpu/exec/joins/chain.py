@@ -6,7 +6,6 @@ probe row either survives with exactly one match per dimension or dies.
 Executing the stack operator-at-a-time materializes an intermediate batch
 per level; fused, the chain costs
 
-    one probe program per level (key canon + LUT/binsearch, no gathers)
     ONE probe program for every level (key canon + LUT/binsearch, the
     combined selection and its live count; no gathers)
     ONE take program: the compaction index of the bottom probe stream and
@@ -27,6 +26,7 @@ child join.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Iterator
 
 import jax
@@ -35,13 +35,10 @@ import jax.numpy as jnp
 from auron_tpu import obs
 from auron_tpu.columnar.batch import Batch, compaction_bucket, compaction_index
 from auron_tpu.exec.basic import batch_from_columns
-from auron_tpu.exec.selectivity import SelectivityPredictor, predictor_enabled
+from auron_tpu.exec.selectivity import CompactionBoundary
 from auron_tpu.exprs import ir
 from auron_tpu.exprs.eval import ColumnVal
 from auron_tpu.exec.joins import core
-from auron_tpu.exec.joins.driver import _compact_join_output_enabled
-from auron_tpu.runtime.transfer import TransferWindow
-from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH
 
 
 def clear_chain_memos(top, partition: int, ctx) -> None:
@@ -61,10 +58,6 @@ def try_fused_chain(top, partition: int, ctx) -> Iterator[Batch] | None:
     Returns a batch iterator, or None when the shape doesn't qualify (the
     caller then runs the ordinary per-operator path)."""
     from auron_tpu.exec.joins.bhj import BroadcastHashJoinExec
-
-    # compact off: the chain still fuses — it emits dense outputs with NO
-    # host read at all
-    compact_mode = _compact_join_output_enabled()
 
     # collect the stack of fusable links, top-down
     links = []  # (exec, probe_child_index)
@@ -191,13 +184,12 @@ def try_fused_chain(top, partition: int, ctx) -> Iterator[Batch] | None:
 
     return _run_chain(
         top_ex, bottom, links, builds, key_cols_per_level, out_map,
-        partition, ctx, compact_mode,
+        partition, ctx,
     )
 
 
 def _run_chain(
     top_ex, bottom, links, builds, key_cols_per_level, out_map, partition, ctx,
-    compact_mode: bool = True,
 ) -> Iterator[Batch]:
     d_top = top_ex.driver
     out_schema = d_top.out_schema
@@ -236,17 +228,6 @@ def _run_chain(
     bwords_all = tuple(b.words for b in builds)
     n_lives = tuple(jnp.int32(b.n_live) for b in builds)
 
-    # steady-state pipeline state: EWMA selectivity predictor picks the
-    # compaction bucket ahead of time; the k-deep transfer window carries
-    # each batch's actual live count host-ward while later batches compute
-    # (docs/pipeline.md). The first batch seeds the EWMA from its live
-    # count, read once (eight bytes).
-    pred = (
-        SelectivityPredictor(ctx.conf)
-        if compact_mode and predictor_enabled(ctx.conf)
-        else None
-    )
-    window = TransferWindow(ctx.conf.get(TRANSFER_WINDOW_DEPTH))
     n_levels = len(links)
     build_planes = 2 * sum(len(cs) for cs in bcols_per_level)
     # compacting also takes the probe columns and every level's bi
@@ -257,6 +238,11 @@ def _run_chain(
             n_live, capacity, dense_planes=build_planes,
             taken_planes=taken_planes,
         )
+
+    # the chain's output is ONE compaction boundary (exec/selectivity.py):
+    # it picks each batch's bucket ahead of time and carries the live
+    # count host-ward while later batches compute (docs/pipeline.md)
+    boundary = CompactionBoundary(ctx.conf, bucket_of, ctx.metrics)
 
     def assemble(pb, c_p, c_pm, c_b, c_bm, new_sel) -> Batch:
         """Output batch from gathered arrays; c_p None = probe columns
@@ -282,7 +268,7 @@ def _run_chain(
         out = batch_from_columns(out_cols, out_schema.names, new_sel)
         return Batch(out_schema, out.device, out.dicts)
 
-    def take(mode: str, pb, sel_out, bis, out_cap: int | None):
+    def take(pb, sel_out, bis, mode: str, out_cap: int | None):
         """One take on the device, noted in the rings: compaction index
         and every gather at the static bucket ``out_cap`` in ONE program,
         or (None) the build columns alone gathered at the batch's width.
@@ -302,14 +288,11 @@ def _run_chain(
             out_cap=out_cap,
         )
 
-    def dispatch(pb):
-        """Async half: ALL levels' canon + probe + selection AND + live
-        count as ONE program (single pass over the probe keys), then the
-        compacted (or dense) take at the PREDICTED bucket. No host sync
-        here past a stream's seed — the live count rides the transfer
-        window and is harvested k batches later, overlapping device
-        compute (and, on remote accelerators, hiding link latency).
-        Returns (async-arrays, finish-state)."""
+    def dispatch(pb) -> list[Batch]:
+        """ALL levels' canon + probe + selection AND + live count as ONE
+        program (single pass over the probe keys); the take is the
+        boundary's. Returns the batches ready to emit: FIFO, up to the
+        window's depth behind dispatch."""
         kv_all = tuple(
             tuple(pb.col_values(c) for c in key_cols)
             for key_cols in key_cols_per_level
@@ -323,87 +306,24 @@ def _run_chain(
             luts, lut_bases, bwords_all, n_lives,
             cfgs=level_cfgs,
         )
-        if not compact_mode:
-            return (), ("done", pb, take("dense", pb, sel_out, bis, None))
-        if pred is None:
-            # predictor off, compaction on: the live count rides the
-            # window (so the per-batch read still overlaps k batches of
-            # compute) and the take waits for it
-            return (live,), ("count", pb, sel_out, bis)
-        pred_cap = pred.predict(pb.capacity)
-        if pred_cap is None:
-            # seed: no history yet. Read this batch's live count — one
-            # scalar, once a stream — and take at its own bucket: exact,
-            # so it never repairs and need not ride the (empty) window
-            # auronlint: disable=R9 -- first batch of a stream only: the predictor takes over afterwards (seed read)
-            n_seed = int(jax.device_get(live))  # auronlint: sync-point(2/task) -- chain compaction seed read: the first batch's live count
-            pred.observe(n_seed)
-            out_cap = bucket_of(n_seed, pb.capacity)
-            return (), ("done", pb, take("seed", pb, sel_out, bis, out_cap))
-        out_cap = bucket_of(pred_cap, pb.capacity)
-        if out_cap is None:
-            # predicted too wide to pay. A wrong "dense" costs a whole
-            # capacity of gathers for a batch that may hold nothing (the
-            # batches behind a burst, while the predictor's bucket waits
-            # out its shrink patience), and the batch stays in the window
-            # until its count lands anyway: the count itself decides there
-            return (live,), ("count", pb, sel_out, bis)
-        taken = take("compact", pb, sel_out, bis, out_cap)
-        return (live,), ("pred", pb, sel_out, bis, taken, out_cap)
-
-    def finish(resolved, state) -> Batch:
-        mode, pb = state[:2]
-        if mode == "done":
-            return assemble(pb, *state[2])
-        n_live = int(resolved[0])
-        if mode == "count":
-            _, _, sel_out, bis = state
-            if pred is not None:
-                pred.observe(n_live)
-            out_cap = bucket_of(n_live, pb.capacity)
-            return assemble(pb, *take(
-                "dense" if out_cap is None else "compact",
-                pb, sel_out, bis, out_cap,
-            ))
-        # predicted: the live count was harvested from the window
-        _, _, sel_out, bis, taken, out_cap = state
-        pred.observe(n_live, predicted=out_cap)
-        if n_live > out_cap:
-            # mispredict: the compacted gather truncated rows. Repair from
-            # the still-held device state at the CORRECT bucket — pure
-            # recompute, no extra sync (n_live is already host-side).
-            ctx.metrics.add("sel_mispredicts", 1)
-            taken = take(
-                "repair", pb, sel_out, bis, bucket_of(n_live, pb.capacity)
+        return [
+            assemble(b, *taken)
+            for b, taken in boundary.offer(
+                live, pb.capacity, partial(take, pb, sel_out, bis), pb
             )
-        return assemble(pb, *taken)
+        ]
 
-    # k-deep software pipeline: batch i's live count is harvested while
-    # batches i+1..i+k compute; emission order stays FIFO. A batch whose
-    # take is already settled ("done": compaction off, or the seed, which
-    # only occurs while the window is still empty) is emitted at once
-    # instead of pinning k batches of probe/build-index state.
     for pb in probe_child_stream:
         ctx.check_cancelled()
         with ctx.metrics.timer("probe_time", count=True):
-            arrays, state = dispatch(pb)
-            if state[0] == "done":
-                ready = [finish((), state)]
-            else:
-                ready = [
-                    finish(resolved, st)
-                    for resolved, st in window.push(arrays, state)
-                ]
+            ready = dispatch(pb)
         yield from ready
-    for resolved, state in window.drain():
+    for pb, taken in boundary.drain():
         with ctx.metrics.timer("probe_time"):
-            ready = finish(resolved, state)
+            ready = assemble(pb, *taken)
         yield ready
-    if pred is not None and pred.predictions:
-        ctx.metrics.add("sel_pred_batches", pred.predictions)
-
-
-from functools import partial
+    if boundary.predictions:
+        ctx.metrics.add("sel_pred_batches", boundary.predictions)
 
 
 @partial(jax.jit, static_argnames=("cfgs",))
@@ -437,13 +357,6 @@ def _chain_take_dense_jit(build_vals, build_masks, bis, sel):
         c_b.append(tuple(v[bi] for v in lv_vals))
         c_bm.append(tuple(m[bi] & sel for m in lv_masks))
     return tuple(c_b), tuple(c_bm)
-
-
-@jax.jit
-def _and_all(sel, oks):
-    for ok in oks:
-        sel = sel & ok
-    return sel
 
 
 @partial(jax.jit, static_argnames=("out_cap",))

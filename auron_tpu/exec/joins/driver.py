@@ -8,15 +8,16 @@ auron.proto:457-461 analog); output columns are always (left ++ right).
 
 from __future__ import annotations
 
-from typing import Callable, Iterator
+from functools import partial
+from typing import Iterator
 
-import jax
 import jax.numpy as jnp
 
 from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import Batch, compaction_bucket
 from auron_tpu.exec.basic import batch_from_columns
+from auron_tpu.exec.selectivity import CompactionBoundary
 from auron_tpu.exprs import Evaluator, ir
 from auron_tpu.exprs.eval import ColumnVal
 from auron_tpu.exec.joins import core
@@ -25,51 +26,6 @@ from auron_tpu.exec.joins.core import (
     PreparedBuild, expand_pairs, gather_columns, null_columns, probe_ranges,
     unify_key_dicts, _canon_words, _key_columns,
 )
-
-
-def _compact_join_output_enabled() -> bool:
-    from auron_tpu.exec.base import current_context
-    from auron_tpu.exec.selectivity import predictor_enabled
-    from auron_tpu.jaxenv import is_tpu
-    from auron_tpu.utils.config import (
-        JOIN_COMPACT_OUTPUT, active_conf, resolve_tri,
-    )
-
-    ctx = current_context()
-    conf = ctx.conf if ctx is not None else active_conf()
-    # auto: on wherever the boundary costs no blocking read a batch. With
-    # the predictor a stream reads eight bytes once (its seed) and the rest
-    # rides the transfer window, on any back end; without it every batch
-    # blocks on its live count, which a CPU host can afford and the link
-    # to an accelerator cannot. WHETHER a given batch compacts is then
-    # compaction_bucket's rule over its shapes.
-    return resolve_tri(
-        conf.get(JOIN_COMPACT_OUTPUT), predictor_enabled(conf) or not is_tpu()
-    )
-
-
-class UniqueProbePipeline:
-    """Per-probe-stream state for the sync-free unique-join compaction
-    boundary: a selectivity predictor picking the output bucket ahead of
-    time plus a k-deep async transfer window carrying each batch's actual
-    live count host-ward while later batches compute (docs/pipeline.md).
-
-    Owned by the hash-join exec (one per partition stream — the driver
-    itself is shared across concurrently running partitions) and passed
-    into ``probe_batch``; the exec MUST call ``EquiJoinDriver.finish_probe``
-    after the last probe batch to drain in-flight emissions."""
-
-    def __init__(self, conf):
-        from auron_tpu.exec.selectivity import (
-            SelectivityPredictor, predictor_enabled,
-        )
-        from auron_tpu.runtime.transfer import TransferWindow
-        from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH
-
-        self.pred = (
-            SelectivityPredictor(conf) if predictor_enabled(conf) else None
-        )
-        self.window = TransferWindow(conf.get(TRANSFER_WINDOW_DEPTH))
 
 
 # auronlint: thread-owned -- one driver per join operator instance; its memo fields are touched only by the thread driving that query's probe stream
@@ -160,7 +116,22 @@ class EquiJoinDriver:
         ]
         return proj, pcol_ids, bcol_ids
 
-    def publish_probe_prep(self, link, build: PreparedBuild, pipe, conf) -> bool:
+    @property
+    def _compacts(self) -> bool:
+        """Does the unique-build probe run the compaction boundary? Semi,
+        anti and existence joins emit no pairs and a residual condition
+        needs every pair: those stay on _unique_join_emit_jit."""
+        return self.wants_pairs and self.condition is None
+
+    def compaction_boundary(self, ctx) -> CompactionBoundary:
+        """The boundary of ONE probe stream (the driver itself is shared
+        across concurrently running partitions): the exec makes it, passes
+        it into ``probe_batch`` and drains it through ``finish_probe``."""
+        return CompactionBoundary(ctx.conf, self.take_bucket, ctx.metrics)
+
+    def publish_probe_prep(
+        self, link, build: PreparedBuild, boundary: CompactionBoundary,
+    ) -> bool:
         """Publish the runtime probe anchor into a fused stage's
         ProbePrepLink (plan/fusion.py). Returns False — with the link
         cleared — when this build's shape can't run off stage-prepped
@@ -180,14 +151,8 @@ class EquiJoinDriver:
         need_pairs = self.wants_pairs or self.condition is not None
         if build.unique:
             kind = "unique"
-            compact = (
-                self.wants_pairs
-                and self.condition is None
-                and _compact_join_output_enabled()
-            )
         elif build.exists_lut is not None and not need_pairs:
             kind = "exists"
-            compact = False
         else:
             link.clear()  # general ragged probe: eager only
             return False
@@ -205,9 +170,10 @@ class EquiJoinDriver:
         link.publish(
             build=build,
             kind=kind,
-            compact=compact,
-            take_bucket=self.take_bucket,
-            pipe=pipe,
+            # the stage asks it for each batch's plan; None: never compacts
+            boundary=(
+                boundary if kind == "unique" and self._compacts else None
+            ),
             bcap=bb.capacity,
             use_lut=build.lut is not None,
             lut=build.lut,
@@ -239,13 +205,12 @@ class EquiJoinDriver:
         )
 
     def probe_batch(
-        self, build: PreparedBuild, pb: Batch,
-        pipe: "UniqueProbePipeline | None" = None,
+        self, build: PreparedBuild, pb: Batch, boundary: CompactionBoundary,
     ) -> Iterator[Batch]:
-        """Probe one batch; updates build.matched in place. ``pipe``
-        (optional) enables the sync-free pipelined compaction path on the
-        unique-build fast path — emissions then lag dispatch by up to the
-        window depth, and the caller must drain via ``finish_probe``.
+        """Probe one batch; updates build.matched in place. On the
+        unique-build fast path emissions lag dispatch by up to
+        ``boundary``'s window depth, and the caller must drain via
+        ``finish_probe``.
 
         A batch arriving from a fused probe stage carries a
         ``_probe_prep`` payload (plan/fusion.py): the prologue — key eval,
@@ -257,7 +222,7 @@ class EquiJoinDriver:
         if prep is not None and prep.build is not build:
             prep = None  # stale/foreign anchor: eager prologue
         if prep is not None and prep.kind == "unique" and build.unique:
-            yield from self._probe_batch_unique(build, pb, None, pipe, prep)
+            yield from self._probe_batch_unique(build, pb, None, boundary, prep)
             return
         if (
             prep is not None
@@ -308,7 +273,7 @@ class EquiJoinDriver:
             # must keep the original sort order valid -> it does, because
             # unify_key_dicts maps build codes first (identity order).
         if build.unique:
-            yield from self._probe_batch_unique(build, pb, pvals, pipe)
+            yield from self._probe_batch_unique(build, pb, pvals, boundary)
             if orig_build is not build:
                 orig_build.matched = build.matched
             return
@@ -361,8 +326,7 @@ class EquiJoinDriver:
 
     def _probe_batch_unique(
         self, build: PreparedBuild, pb: Batch, pvals,
-        pipe: "UniqueProbePipeline | None" = None,
-        prep=None,
+        boundary: CompactionBoundary, prep=None,
     ) -> Iterator[Batch]:
         """Unique-build probe: each probe row has <=1 match, so one batch at
         probe capacity covers every join type — probe columns stay as views
@@ -379,16 +343,10 @@ class EquiJoinDriver:
 
         # sparse-output compaction: densify BEFORE gathering build columns,
         # wherever take_bucket's rule says the gathers saved outweigh the
-        # compaction (an outer probe side or a residual condition needs
-        # every probe row: always dense)
-        compact_ok = (
-            self.wants_pairs
-            and self.condition is None
-            and _compact_join_output_enabled()
-        )
-        if compact_ok:
+        # compaction
+        if self._compacts:
             yield from self._emit_unique_compacted(
-                build, pb, pvals, bcol_ids, proj, pipe, prep
+                build, pb, pvals, bcol_ids, proj, boundary, prep
             )
             return
 
@@ -477,8 +435,8 @@ class EquiJoinDriver:
             taken_planes=taken_planes,
         )
 
-    def _take_unique(self, mode, build, pb, pcol_ids, bcol_ids, bi, ok,
-                     sel_out, out_cap):
+    def _take_unique(self, build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
+                     mode, out_cap):
         """One take of the boundary on the device, noted in the rings:
         the build columns gathered at the batch's capacity (``out_cap``
         None: probe columns stay views) or everything taken at the bucket
@@ -499,8 +457,7 @@ class EquiJoinDriver:
 
     def _emit_unique_compacted(
         self, build: PreparedBuild, pb: Batch, pvals, bcol_ids, proj,
-        pipe: "UniqueProbePipeline | None" = None,
-        prep=None,
+        boundary: CompactionBoundary, prep=None,
     ) -> Iterator[Batch]:
         bb = build.batch
         nl = len(self.left_schema)
@@ -526,98 +483,29 @@ class EquiJoinDriver:
             for oi in proj
             if (oi < nl) == self.probe_is_left
         ]
-        pred = pipe.pred if pipe is not None else None
-        # a fused-stage payload already made this batch's predict call (the
-        # SAME predictor instance, at dispatch time — observation order is
-        # identical); calling again would double-count and could disagree
-        pred_cap = (
-            prep.pred_cap if prep is not None
-            else (pred.predict(pb.capacity) if pred is not None else None)
-        )
-        if pred_cap is None:
-            # seed: a stream's first batch has no prediction (nor has any
-            # batch with the predictor off). The probe program already
-            # counted the survivors: read that scalar — eight bytes, not
-            # the mask — seed the predictor and take on the device at the
-            # count's own bucket. Exact, so it never repairs and need not
-            # ride the window (which is still empty: order stays FIFO).
-            # auronlint: disable=R9 -- first batch of a stream (and predictor-off fallback): pred_cap is None only before the first observation
-            n_live = int(jax.device_get(n_live_dev))  # auronlint: sync-point(2/task) -- unique-join compaction seed read: the first batch's live count (and predictor-off fallback)
-            if pred is not None:
-                pred.observe(n_live)
-            taken = self._take_unique(
-                "seed", build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
-                self.take_bucket(n_live, pb.capacity),
-            )
-            yield self._unique_out_batch(
-                pb, bb, proj, pcol_ids, bcol_ids, *taken
-            )
-            return
-        # predicted path: compaction index computed ON DEVICE at the
-        # predicted bucket — no host sync; the actual live count is
-        # harvested from the transfer window k batches later and
-        # mispredicts repair there. With a stage payload the take already
-        # happened inside the fused program — reuse its outputs, push the
-        # same window state. Where the rule says the predicted bucket is
-        # too wide to pay, nothing is taken yet: a wrong "dense" costs a
-        # whole capacity of gathers for a batch that may hold nothing (the
-        # batches behind a burst, while the predictor's bucket waits out
-        # its shrink patience), and the batch stays in the window until
-        # its count lands anyway, so the count itself decides there.
-        out_cap = self.take_bucket(pred_cap, pb.capacity)
-        if out_cap is None:
-            taken = None
-        elif prep is not None and prep.take == "compact":
-            obs.note_join_take("compact", out_cap, pb.capacity)
+        # a fused-stage payload carries the plan the boundary made for
+        # this batch at the stage's dispatch (predicting again would
+        # double-count and could disagree) and, where that plan compacts,
+        # what the stage program took at its bucket
+        plan = prep.plan if prep is not None else None
+        taken = None
+        if prep is not None and prep.take == "compact":
+            obs.note_join_take("compact", plan.cap, pb.capacity)
             taken = prep.taken
-        else:
-            taken = self._take_unique(
-                "compact", build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
-                out_cap,
-            )
-        state = (build, pb, proj, pcol_ids, bcol_ids, taken,
-                 out_cap, bi, ok, sel_out)
-        for resolved, st in pipe.window.push((n_live_dev,), state):
-            yield self._finish_unique_compacted(resolved, st, pred)
-
-    def finish_probe(self, pipe: "UniqueProbePipeline | None") -> Iterator[Batch]:
-        """Drain the pipelined compaction window at end of the probe
-        stream (emissions lag dispatch by the window depth)."""
-        if pipe is None:
-            return
-        for resolved, st in pipe.window.drain():
-            yield self._finish_unique_compacted(resolved, st, pipe.pred)
-
-    def _finish_unique_compacted(self, resolved, state, pred) -> Batch:
-        """Harvest half of the predicted compaction: observe the actual
-        live count, take what the dispatch left to it, repair a too-small
-        bucket by re-taking from the still-held device state (pure
-        recompute — no extra sync)."""
-        from auron_tpu.exec.base import current_context
-
-        (build, pb, proj, pcol_ids, bcol_ids, taken, pred_cap, bi, ok,
-         sel_out) = state
-        n_live = int(resolved[0])
-        if pred is not None:
-            pred.observe(n_live, predicted=pred_cap)
-        if taken is None:
-            # predicted too wide to pay: take at the count's own bucket
-            out_cap = self.take_bucket(n_live, pb.capacity)
-            taken = self._take_unique(
-                "dense" if out_cap is None else "compact",
-                build, pb, pcol_ids, bcol_ids, bi, ok, sel_out, out_cap,
-            )
-        elif n_live > pred_cap:
-            ctx = current_context()
-            if ctx is not None:
-                ctx.metrics.add("sel_mispredicts", 1)
-            taken = self._take_unique(
-                "repair", build, pb, pcol_ids, bcol_ids, bi, ok, sel_out,
-                self.take_bucket(n_live, pb.capacity),
-            )
-        return self._unique_out_batch(
-            pb, build.batch, proj, pcol_ids, bcol_ids, *taken
+        take = partial(
+            self._take_unique, build, pb, pcol_ids, bcol_ids, bi, ok, sel_out
         )
+        state = (pb, bb, proj, pcol_ids, bcol_ids)
+        for st, out in boundary.offer(
+            n_live_dev, pb.capacity, take, state, plan, taken
+        ):
+            yield self._unique_out_batch(*st, *out)
+
+    def finish_probe(self, boundary: CompactionBoundary) -> Iterator[Batch]:
+        """Drain the boundary at end of the probe stream (emissions lag
+        dispatch by the window depth)."""
+        for st, out in boundary.drain():
+            yield self._unique_out_batch(*st, *out)
 
     def _unique_out_batch(
         self, pb, bb, proj, pcol_ids, bcol_ids,

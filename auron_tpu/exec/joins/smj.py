@@ -39,20 +39,18 @@ class SortMergeJoinExec(ExecOperator):
         super().__init__([left, right], self.driver.out_schema)
 
     def _execute(self, partition: int, ctx: ExecutionContext) -> Iterator[Batch]:
-        from auron_tpu.exec.joins.driver import UniqueProbePipeline
-
         with ctx.metrics.timer("build_time"):
             build_batches = list(self.child_stream(1, partition, ctx))
             build = self.driver.prepare(build_batches, conf=ctx.conf)
-        # sync-free pipelined compaction on the unique-build fast path
-        # (same boundary as BHJ; see driver.UniqueProbePipeline)
-        pipe = UniqueProbePipeline(ctx.conf)
+        # the unique-build fast path compacts through the same boundary
+        # as BHJ (exec/selectivity.py)
+        boundary = self.driver.compaction_boundary(ctx)
         for pb in self.child_stream(0, partition, ctx):
             ctx.check_cancelled()
             # no empty-batch pre-check: it costs a host sync per batch, and
             # the probe itself already syncs once on the match total
             with ctx.metrics.timer("probe_time", count=True):
-                yield from self.driver.probe_batch(build, pb, pipe)
+                yield from self.driver.probe_batch(build, pb, boundary)
         with ctx.metrics.timer("probe_time"):
-            yield from self.driver.finish_probe(pipe)
+            yield from self.driver.finish_probe(boundary)
         yield from self.driver.finish(build)

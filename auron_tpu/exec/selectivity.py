@@ -1,50 +1,46 @@
-"""Selectivity prediction for sync-free compaction-bucket choice.
+"""The compaction boundary: sync-free compaction of sparse join outputs.
 
-The compaction boundaries (fused join chain, BHJ unique-compact) used to
-block on ``device_get(sel)`` every batch just to learn the live count and
-pick an output capacity bucket — the dominant host-coordination tax in the
-SF=50 breakdown (PERF_BREAKDOWN_SF50.json: 128 syncs / 0.94 s at the chain
-boundary alone for the q3 class). Steady-state selectivity is highly
-autocorrelated across batches of one stream, so the bucket is *predictable*:
+A join's output boundary used to block on ``device_get(sel)`` every batch
+just to learn the live count and pick an output capacity bucket, the
+dominant host-coordination tax of a selective star join. Steady-state
+selectivity is highly autocorrelated across batches of one stream, so the
+bucket is *predictable*:
 
 - ``SelectivityPredictor`` keeps an EWMA of observed live counts and
   predicts the next batch's compacted capacity bucket with a headroom
   multiplier (absorbs noise) and shrink hysteresis (a bucket only shrinks
   after ``patience`` consecutive low-demand batches, so oscillating
   selectivity doesn't thrash jit shapes);
-- the consumer compacts INTO the predicted bucket entirely on device
-  (``columnar.batch.compaction_index``) and reads the actual live count
-  asynchronously k batches later (``runtime/transfer.TransferWindow``);
-- a mispredict (live count exceeded the bucket: rows were truncated) is
-  detected at harvest time, *before* the batch is emitted downstream, and
-  repaired by re-gathering at the correct bucket from the still-held
-  device state — results are bit-identical to the blocking path.
+- ``CompactionBoundary`` owns one probe stream's predictor, its k-deep
+  ``runtime/transfer.TransferWindow`` and the protocol between them:
+  compact INTO the predicted bucket entirely on device, read the actual
+  live count asynchronously k batches later, repair a too-small bucket at
+  harvest, before the batch is emitted downstream, by re-taking at the
+  correct bucket from the still-held device state: results are
+  bit-identical to a blocking read a batch.
 
-The first batch of a stream has no history and takes the classic blocking
-path (one sync per stream, not per batch): every consumer reads that
-batch's live count itself and ``observe``s it before its first ``predict``
-— waiting for the first harvest instead leaves a stream no longer than
-the transfer window unseeded to its end.
+Every join that compacts (the unique-build probe of BHJ and SMJ, eager or
+behind a fused stage, and the fused star chain) drives one boundary with
+its own ``take`` callback; nothing else calls ``predict``, ``observe``,
+``push`` or ``drain`` for a join (docs/pipeline.md section 1). The partial
+aggregate's deferred arm keeps its own halves (exec/agg_exec.py; ROADMAP
+D2 says why).
 """
 
 from __future__ import annotations
 
+from typing import Any, Callable, Iterator, NamedTuple
+
+import jax
+
 from auron_tpu.columnar.batch import bucket_capacity
-from auron_tpu.utils.config import (
-    JOIN_COMPACT_OUTPUT,
-    SELECTIVITY_EWMA_ALPHA,
-    SELECTIVITY_HEADROOM,
-    SELECTIVITY_PREDICTOR_ENABLE,
-    SELECTIVITY_SHRINK_PATIENCE,
-    resolve_tri,
-)
+from auron_tpu.runtime.transfer import TransferWindow
+from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH
 
-
-def predictor_enabled(conf) -> bool:
-    """Knob resolution: on | off | auto (= on wherever compaction runs —
-    the predictor only exists to unblock the compaction boundary)."""
-    compacting = resolve_tri(conf.get(JOIN_COMPACT_OUTPUT), True)
-    return resolve_tri(conf.get(SELECTIVITY_PREDICTOR_ENABLE), compacting)
+# one value each has ever been in use outside the unit tests
+EWMA_ALPHA = 0.3        # weight of the newest batch's live count
+HEADROOM = 1.5          # bucket margin over the EWMA: absorbs batch noise
+SHRINK_PATIENCE = 4     # consecutive low batches before a bucket shrinks
 
 
 # auronlint: thread-owned -- one predictor per operator instance, driven by the single thread executing that query's batch stream (pump or serving thread, never both at once)
@@ -57,13 +53,11 @@ class SelectivityPredictor:
     Growth is immediate (an overflow already cost a repair — never two);
     shrinking waits out ``patience`` consecutive low batches."""
 
-    def __init__(self, conf=None):
-        from auron_tpu.utils.config import active_conf
-
-        c = conf if conf is not None else active_conf()
-        self.alpha = min(max(c.get(SELECTIVITY_EWMA_ALPHA), 0.01), 1.0)
-        self.headroom = max(c.get(SELECTIVITY_HEADROOM), 1.0)
-        self.patience = max(c.get(SELECTIVITY_SHRINK_PATIENCE), 1)
+    def __init__(self, alpha: float = EWMA_ALPHA, headroom: float = HEADROOM,
+                 patience: int = SHRINK_PATIENCE):
+        self.alpha = alpha
+        self.headroom = headroom
+        self.patience = patience
         self.ewma: float | None = None
         self._bucket: int | None = None
         self._low_streak = 0
@@ -104,3 +98,111 @@ class SelectivityPredictor:
                 self._low_streak = 0
         else:
             self._low_streak = 0
+
+
+class TakePlan(NamedTuple):
+    """What one batch does at dispatch, from the one ``predict`` call it
+    gets: ``seed`` (no observation yet: read the count now), else compact
+    at ``cap`` now, or (``cap`` None) look up only and let the batch's own
+    count decide at harvest."""
+
+    seed: bool
+    cap: int | None
+
+
+class CompactionBoundary:
+    """One probe stream's compaction protocol: predict -> take -> push ->
+    harvest -> observe exactly once -> repair.
+
+    ``bucket_of(n_live, capacity)`` is the join's bucket rule
+    (``columnar.batch.compaction_bucket`` with that join's plane counts).
+    ``offer`` is given the device scalar holding a batch's live count and
+    a ``take(mode, out_cap)`` callback that gathers the batch's output at
+    the bucket ``out_cap`` (None: dense, at the batch's capacity) and
+    notes the take in the rings under ``mode``:
+
+    ==========  ==========================================================
+    ``seed``    no observation yet: the count is read here (eight bytes,
+                once a stream: waiting for the first harvest instead
+                leaves a stream no longer than the window unseeded to its
+                end), observed, and the batch taken at the count's own
+                bucket and emitted at once. Exact, so it never repairs and
+                need not ride the window, which is still empty.
+    ``compact`` the predicted bucket pays: taken at it at dispatch, no
+                host read; or, for a batch that waited, taken at harvest
+                at its own count's bucket.
+    ``dense``   a batch that waited, whose own count stays dense.
+    ``repair``  the count overflowed the predicted bucket (rows were
+                truncated): one re-take at the count's bucket from the
+                state the window held. No extra read.
+    ==========  ==========================================================
+
+    A batch waits where the rule says its predicted bucket is too wide to
+    pay: a wrong "dense" costs a whole capacity of gathers for a batch
+    that may hold nothing (the batches behind a burst, while the
+    predictor's bucket waits out its shrink patience), and the batch
+    stays in the window until its count lands anyway, so the count itself
+    decides there.
+
+    Emission lags dispatch by up to the window's depth and stays FIFO; the
+    consumer MUST ``drain`` after its last batch."""
+
+    def __init__(self, conf, bucket_of: Callable[[int, int], "int | None"],
+                 metrics=None):
+        self._pred = SelectivityPredictor()
+        self._window = TransferWindow(conf.get(TRANSFER_WINDOW_DEPTH))
+        self._bucket_of = bucket_of
+        self._metrics = metrics
+
+    @property
+    def predictions(self) -> int:
+        return self._pred.predictions
+
+    def plan_take(self, capacity: int) -> TakePlan:
+        """This batch's ONE ``predict`` call. A consumer that dispatches
+        upstream of ``offer`` (the fused probe stage, whose program is
+        traced per take) asks here and hands the plan back to ``offer``."""
+        pred_cap = self._pred.predict(capacity)
+        if pred_cap is None:
+            return TakePlan(True, None)
+        return TakePlan(False, self._bucket_of(pred_cap, capacity))
+
+    def offer(self, live, capacity: int, take: Callable, state: Any,
+              plan: TakePlan | None = None, taken: Any = None,
+              ) -> list[tuple[Any, Any]]:
+        """Dispatch one batch; returns the ``(state, taken)`` of every
+        batch that is ready to emit, oldest first. ``plan`` and ``taken``
+        come from a consumer that planned (and, for a ``cap``, took)
+        upstream."""
+        if plan is None:
+            plan = self.plan_take(capacity)
+        if plan.seed:
+            # auronlint: disable=R9 -- first batch of a stream only: plan.seed is true only before the first observation
+            n_live = int(jax.device_get(live))  # auronlint: sync-point(2/task) -- join compaction seed read: the first batch's live count
+            self._pred.observe(n_live)
+            return [(state, take("seed", self._bucket_of(n_live, capacity)))]
+        if plan.cap is not None and taken is None:
+            taken = take("compact", plan.cap)
+        return [
+            self._harvest(resolved, entry)
+            for resolved, entry in self._window.push(
+                (live,), (state, take, capacity, plan.cap, taken))
+        ]
+
+    def drain(self) -> Iterator[tuple[Any, Any]]:
+        """End of stream: resolve what is still in flight, FIFO."""
+        for resolved, entry in self._window.drain():
+            yield self._harvest(resolved, entry)
+
+    def _harvest(self, resolved, entry) -> tuple[Any, Any]:
+        state, take, capacity, cap, taken = entry
+        n_live = int(resolved[0])
+        self._pred.observe(n_live, predicted=cap)
+        if cap is None:
+            out_cap = self._bucket_of(n_live, capacity)
+            taken = take("dense" if out_cap is None else "compact", out_cap)
+        elif n_live > cap:
+            if self._metrics is not None:
+                self._metrics.add("sel_mispredicts", 1)
+            taken = take("repair", self._bucket_of(n_live, capacity))
+        return state, taken

@@ -29,7 +29,7 @@ The speedup floor is SUBSTRATE-RESOLVED, the same measured split as
 every ``auto`` backend knob (``SERVEGATE_MIN_SPEEDUP`` overrides both
 tiers): 2.0 on accelerator backends, 1.4 on the CPU backend. Measured
 basis (24-core box, sf=1, 8 clients — the full trail is in
-docs/serving.md and SERVE_GATE.out): concurrent XLA executions scale
+docs/serving.md): concurrent XLA executions scale
 near-linearly when query work is device-resident (a 6-thread
 device-program A/B scales ~5.6x, and forcing the device sort/fold
 substrates lifts this gate's ratio to 2.73x — at 26% LOWER absolute

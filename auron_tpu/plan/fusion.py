@@ -354,8 +354,8 @@ def _stage_program_shuffle(dev, rr_start, *, steps: tuple, emit: str,
 class ProbePrepLink:
     """Anchor hand-off from a hash-join exec to the fused stage feeding its
     probe side. The join publishes once its build is prepared (device
-    arrays + host ints of the build layout, the per-stream
-    UniqueProbePipeline, and the compact-vs-dense choice); the stage then
+    arrays + host ints of the build layout, and the stream's
+    CompactionBoundary where the join compacts); the stage then
     runs the probe prologue inside its program and attaches a
     ProbePrepPayload to each emitted batch. Same thread-model as
     DensePrepLink: stage and join share the task pump thread, the lock
@@ -390,18 +390,20 @@ class ProbePrepPayload:
     at probe width: the non-compact emit), "compact" (the predicted
     compact-take, ``taken`` =
     _unique_compact_take_pred_jit's output tuple), "exists"
-    (existence-LUT probe flags)."""
+    (existence-LUT probe flags). ``plan`` is what the join's
+    CompactionBoundary said of this batch at dispatch; the driver hands it
+    back to the boundary, which therefore predicts once a batch."""
 
-    __slots__ = ("build", "kind", "take", "pred_cap", "bi", "ok", "sel_out",
+    __slots__ = ("build", "kind", "take", "plan", "bi", "ok", "sel_out",
                  "live", "bvals", "bmasks", "taken", "probe_matched")
 
-    def __init__(self, build, kind, take, pred_cap=None, bi=None, ok=None,
+    def __init__(self, build, kind, take, plan=None, bi=None, ok=None,
                  sel_out=None, live=None, bvals=None, bmasks=None,
                  taken=None, probe_matched=None):
         self.build = build
         self.kind = kind
         self.take = take
-        self.pred_cap = pred_cap
+        self.plan = plan
         self.bi = bi
         self.ok = ok
         self.sel_out = sel_out
@@ -623,32 +625,23 @@ class FusedStageExec(ExecOperator):
         return [nm for nm, _ in self.op_shares]
 
     def _dispatch_probe(self, b: Batch, anchor: dict, node):
-        """One probe-extended program dispatch: resolve the per-batch take
-        mode from the pipeline's predictor (the SAME predict call the eager
-        driver would make), run _stage_program_probe, and wrap the results
-        as a ProbePrepPayload for the join driver."""
+        """One probe-extended program dispatch: ask the join's
+        CompactionBoundary what this batch takes (the program is traced
+        per take), run _stage_program_probe, and wrap the results as a
+        ProbePrepPayload for the join driver."""
         kind = anchor["kind"]
-        pred_cap = None
-        take_tag = None
+        plan = None
         if kind == "exists":
             take_prog = ("exists",)
-        elif not anchor["compact"]:
-            take_prog, take_tag = ("gather",), "gather"
+        elif anchor["boundary"] is None:
+            take_prog = ("gather",)
         else:
-            pipe = anchor["pipe"]
-            pred = pipe.pred if pipe is not None else None
-            pred_cap = pred.predict(b.capacity) if pred is not None else None
-            if pred_cap is None:
-                # seed/fallback: lookup only — the driver reads the live
-                # count this program returns (eight bytes) and takes at its
-                # bucket, exactly as the eager path does
-                take_prog, take_tag = ("probe",), "probe"
-            elif anchor["take_bucket"](pred_cap, b.capacity) is None:
-                # predicted too wide to pay: lookup only, the driver takes
-                # when the batch's own count has landed
-                take_prog, take_tag = ("probe",), "probe"
-            else:
-                take_prog, take_tag = ("compact", pred_cap), "compact"
+            plan = anchor["boundary"].plan_take(b.capacity)
+            # no bucket: lookup only. The driver takes once the batch's
+            # count is read (a seed) or has landed (too wide to pay)
+            take_prog = (
+                ("probe",) if plan.cap is None else ("compact", plan.cap)
+            )
         key_schema = self.out_stamp or self.children[0].schema
         cfg = (self._probe_keys, key_schema, self._probe_kinds,
                anchor["use_lut"], self._probe_outer, anchor["bcap"],
@@ -676,13 +669,13 @@ class FusedStageExec(ExecOperator):
         elif take_prog[0] == "probe":
             bi, ok, sel_out, live = extra
             payload = ProbePrepPayload(
-                build, kind, take_tag, pred_cap=pred_cap,
+                build, kind, "probe", plan=plan,
                 bi=bi, ok=ok, sel_out=sel_out, live=live,
             )
         elif take_prog[0] == "gather":
             bi, ok, sel_out, live, bv, bm = extra
             payload = ProbePrepPayload(
-                build, kind, take_tag, pred_cap=pred_cap,
+                build, kind, "gather",
                 bi=bi, ok=ok, sel_out=sel_out, live=live, bvals=bv, bmasks=bm,
             )
         else:
@@ -690,7 +683,7 @@ class FusedStageExec(ExecOperator):
             # (c_pvals, c_pmasks, bvals, bmasks, new_sel)
             bi, ok, sel_out, live, taken = extra
             payload = ProbePrepPayload(
-                build, kind, take_tag, pred_cap=pred_cap,
+                build, kind, "compact", plan=plan,
                 bi=bi, ok=ok, sel_out=sel_out, live=live, taken=taken,
             )
         return out, payload

@@ -207,44 +207,6 @@ MEM_WAIT_TIMEOUT_S = float_conf(
     "how long a below-fair-share consumer waits for siblings to release "
     "memory before it is forced to spill (auron-memmgr lib.rs WAIT_TIME)",
 )
-# auronlint: disable=R14 -- policy hook: columnar/batch.py hardcodes next_pow2 today; the knob reserves the config surface the paper's bucketing ablation needs
-BATCH_SIZE_BUCKETS = str_conf(
-    "batch.capacity.buckets", "auto", "exec",
-    "capacity bucketing policy for static shapes: auto = next_pow2",
-)
-JOIN_COMPACT_OUTPUT = str_conf(
-    "join.compact.output", "auto", "join",
-    "compact sparse unique-join outputs before gathering build columns: "
-    "on | off | auto = on wherever the boundary costs no blocking read a "
-    "batch, i.e. wherever the selectivity predictor is on (a stream then "
-    "reads one scalar, its seed, and the rest rides the transfer window), "
-    "and on CPU hosts, which can afford the read. Whether a given batch "
-    "compacts is columnar.batch.compaction_bucket's rule over its shapes",
-)
-SELECTIVITY_PREDICTOR_ENABLE = str_conf(
-    "exec.selectivity.predictor", "auto", "exec",
-    "predict the compacted-output capacity bucket from an EWMA of prior "
-    "batches' live counts instead of blocking on a per-batch device_get "
-    "(exec/selectivity.py; mispredicts repair via re-emit): on | off | "
-    "auto = on wherever compaction itself is on",
-)
-SELECTIVITY_EWMA_ALPHA = float_conf(
-    "exec.selectivity.ewma.alpha", 0.3, "exec",
-    "EWMA weight of the newest batch's live count in the selectivity "
-    "predictor (higher = faster tracking, more bucket churn)",
-)
-SELECTIVITY_HEADROOM = float_conf(
-    "exec.selectivity.headroom", 1.5, "exec",
-    "multiplier over the EWMA live count before bucketing the predicted "
-    "capacity — absorbs batch-to-batch selectivity noise without a "
-    "mispredict/repair cycle",
-)
-SELECTIVITY_SHRINK_PATIENCE = int_conf(
-    "exec.selectivity.shrink.patience", 4, "exec",
-    "consecutive batches the demand must sit at half the predicted bucket "
-    "(or less) before the predictor shrinks it — hysteresis so an "
-    "oscillating selectivity doesn't thrash buckets (and jit shapes)",
-)
 TRANSFER_WINDOW_DEPTH = int_conf(
     "runtime.transfer.window.depth", 4, "runtime",
     "depth k of the async device->host transfer window: residual scalar "
@@ -495,8 +457,8 @@ FUSE_PROBE = str_conf(
     "existence hash-map lookup and the build-row pair-gather (incl. the "
     "predicted compact-take) compile into the SAME stage program, so a "
     "probe batch costs one dispatch instead of a chain of eager per-op "
-    "jits. The build side, the UniqueProbePipeline mispredict-repair "
-    "protocol and finish_probe semantics are unchanged. on | off | auto "
+    "jits. The build side, the join's CompactionBoundary (its mispredict "
+    "repair included) and finish_probe semantics are unchanged. on | off | auto "
     "= accelerators always, CPU when the segment cost model fuses "
     "(exec.fuse.min.ops). off restores the eager probe bit-identically",
 )
@@ -510,26 +472,6 @@ FUSE_SHUFFLE = str_conf(
     "(writer.repartition_substrate), so fused and fallback repartition "
     "cannot diverge. on | off | auto = same cost-model split as "
     "exec.fuse.enable. off restores the eager repartition bit-identically",
-)
-AGG_PARTIAL_DEFER = str_conf(
-    "exec.agg.partial.defer", "auto", "agg",
-    "defer the PARTIAL generic path's per-batch (live count, group "
-    "count, collision flag) read through the k-deep async transfer "
-    "window (runtime.transfer.window.depth) instead of blocking one "
-    "device_get per batch: the upstream probe/stage pipeline dispatches "
-    "ahead while counts ride host-ward, compaction buckets are chosen "
-    "by the selectivity predictor and a truncating mispredict recomputes "
-    "the reduce from the still-held batch (row-exact and count-exact; "
-    "float accumulations may re-associate across the re-bucketed "
-    "reduces, the same class of difference as any merge-boundary "
-    "shift). Applies only "
-    "when no host-side aggregates and no sorted-state probe are active "
-    "(the probe path owns its own window and stream-order contract). "
-    "Up to k batches' intermediates ride outside the memory-manager "
-    "accounting while in flight. on | off | auto = on (the stall, not "
-    "the transfer, is the cost on every substrate — the q93-class 38s "
-    "drain at agg_exec.py:427). off restores the eager one-read-per-"
-    "batch protocol bit-identically",
 )
 SERVE_MAX_CONCURRENT = int_conf(
     "serve.admission.max.concurrent", 4, "serve",

@@ -136,7 +136,6 @@ def resolved_knobs() -> dict:
     from auron_tpu import native
     from auron_tpu.columnar import batch
     from auron_tpu.exec.agg_exec import HashAggExec
-    from auron_tpu.exec.joins.driver import _compact_join_output_enabled
     from auron_tpu.jaxenv import is_tpu
     from auron_tpu.memory import memmgr
     from auron_tpu.ops import bitonic, hostscatter, hostsort
@@ -159,7 +158,6 @@ def resolved_knobs() -> dict:
         "exec.fuse.enable": fusion._should_fuse(0, conf, C.FUSE_ENABLE),
         "exec.fuse.probe": fusion._should_fuse(0, conf, C.FUSE_PROBE),
         "exec.fuse.shuffle": fusion._should_fuse(0, conf, C.FUSE_SHUFFLE),
-        "join.compact.output": _compact_join_output_enabled(),
         "join.compact.rule": ("gathered elements" if batch._gather_bound()
                               else "a quarter of capacity"),
         "exchange.mode": conf.get(C.EXCHANGE_MODE),

@@ -31,6 +31,27 @@ def enable_row_metrics(monkeypatch):
     monkeypatch.setenv(env_key, "true")
 
 
+@pytest.fixture()
+def joins_stay_dense(monkeypatch):
+    """A context manager under which ``compaction_bucket``'s rule never
+    pays at a join's output boundary: every batch is gathered at its
+    capacity. The dense twin that row-exactness tests compare the
+    compacting joins with; a rule patched in a test, not an option of the
+    program."""
+    import contextlib
+
+    from auron_tpu.exec.joins import chain, driver
+
+    @contextlib.contextmanager
+    def scope():
+        with monkeypatch.context() as m:
+            for mod in (chain, driver):
+                m.setattr(mod, "compaction_bucket", lambda *a, **k: None)
+            yield
+
+    return scope
+
+
 @pytest.fixture(scope="module")
 def leak_canary():
     """Tier-1 leak canary (R11's dynamic twin): a suite that drives whole

@@ -837,17 +837,15 @@ def test_probe_scatter_spill_park_preserves_first_stream_order(monkeypatch):
 
 
 def test_deferred_partial_counts_k_deep_interleaved_mispredicts():
-    """exec.agg.partial.defer: the PARTIAL generic path's (live count,
-    group count) read rides the k-deep transfer window (mirroring the
-    dense-flag deque of PR 2); interleaved selectivity jumps mean MULTIPLE
-    in-flight batches can be truncated by an under-sized predicted bucket
-    and each must recompute exactly once — counts stay exact vs pandas and
-    vs the blocking protocol at every window depth."""
+    """The deferred arm: the PARTIAL generic path's (live count, group
+    count) read rides the k-deep transfer window (mirroring the dense-flag
+    deque of PR 2); interleaved selectivity jumps mean MULTIPLE in-flight
+    batches can be truncated by an under-sized predicted bucket and each
+    must recompute exactly once — counts stay exact vs pandas at every
+    window depth."""
     import pandas as pd
 
-    from auron_tpu.utils.config import (
-        AGG_PARTIAL_DEFER, TRANSFER_WINDOW_DEPTH, active_conf,
-    )
+    from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH, active_conf
 
     rng = np.random.default_rng(17)
     key_batches = []
@@ -875,11 +873,9 @@ def test_deferred_partial_counts_k_deep_interleaved_mispredicts():
 
     conf = active_conf()
     saved_depth = conf.get(TRANSFER_WINDOW_DEPTH)
-    saved_defer = conf.get(AGG_PARTIAL_DEFER)
 
-    def run(defer, depth):
+    def run(depth):
         conf.set(TRANSFER_WINDOW_DEPTH, depth)
-        conf.set(AGG_PARTIAL_DEFER, defer)
         # spread keys so the dense direct-address table refuses and the
         # GENERIC sort-segmentation path (the deferred read's home) runs
         from auron_tpu.exprs.ir import BinaryOp, Literal
@@ -909,7 +905,7 @@ def test_deferred_partial_counts_k_deep_interleaved_mispredicts():
     try:
         mispredicted = 0
         for depth in (1, 3, 6):
-            got, mis = run("on", depth)
+            got, mis = run(depth)
             mispredicted += mis
             assert got["k"].tolist() == want["k"].tolist(), f"depth={depth}"
             assert got["c"].tolist() == want["c"].tolist(), f"depth={depth}"
@@ -917,15 +913,11 @@ def test_deferred_partial_counts_k_deep_interleaved_mispredicts():
                 pytest.approx(float(x)) for x in want["s"]], f"depth={depth}"
         # teeth: the sparse->dense jumps actually exercised the repair
         assert mispredicted > 0
-        off, _ = run("off", 3)
-        assert off["c"].tolist() == want["c"].tolist()
     finally:
         conf.set(TRANSFER_WINDOW_DEPTH, saved_depth)
-        conf.set(AGG_PARTIAL_DEFER, saved_defer)
 
 
-# live rows of each of the stream's three 4096-row batches; None = default
-# predictor knob. ``want``: seed reads, mispredict repairs, batches
+# live rows of each of the stream's three 4096-row batches. ``want``: seed reads, mispredict repairs, batches
 # compacted at dispatch, and the capacities the raw folds ran at, in order
 # (a repair's fold comes at the drain, after the stream's three).
 _SEED_CASES = {
@@ -933,8 +925,6 @@ _SEED_CASES = {
     "dense": dict(live=(3000, 2900, 3100), want=(1, 0, 0, [4096, 4096, 4096])),
     "growing": dict(live=(60, 600, 60), want=(1, 1, 3, [128, 128, 128, 1024])),
     "empty_first": dict(live=(0, 1000, 0), want=(1, 1, 3, [128, 128, 128, 1024])),
-    "predictor_off": dict(live=(100, 90, 110), predictor="off",
-                          want=(0, 0, 0, [4096, 4096, 4096])),
 }
 
 
@@ -944,7 +934,7 @@ def test_deferred_partial_seeds_the_predictor_on_a_streams_first_batch(case):
     harvests before its drain, so the deferred arm reads the FIRST batch's
     live count (one blocking read a stream) and compacts from batch 1 on:
     the grouped reduce folds live rows, not batch capacity. Row- and
-    count-exact against exec.agg.partial.defer=off in every case."""
+    count-exact against pandas in every case."""
     import os
     import time
 
@@ -953,10 +943,7 @@ def test_deferred_partial_seeds_the_predictor_on_a_streams_first_batch(case):
     from auron_tpu.exec.basic import FilterExec
     from auron_tpu.exprs.ir import IsNotNull
     from auron_tpu.obs import core
-    from auron_tpu.utils.config import (
-        AGG_PARTIAL_DEFER, SELECTIVITY_PREDICTOR_ENABLE,
-        TRANSFER_WINDOW_DEPTH, active_conf,
-    )
+    from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH, active_conf
     from auron_tpu.utils.profiling import EngineCounters
 
     spec = _SEED_CASES[case]
@@ -982,12 +969,9 @@ def test_deferred_partial_seeds_the_predictor_on_a_streams_first_batch(case):
 
     EngineCounters.install()
     conf = active_conf()
-    saved = {k: conf.get(k) for k in (
-        AGG_PARTIAL_DEFER, SELECTIVITY_PREDICTOR_ENABLE, TRANSFER_WINDOW_DEPTH)}
-    saved_mode = obs.mode()
+    saved_depth, saved_mode = conf.get(TRANSFER_WINDOW_DEPTH), obs.mode()
 
-    def run(defer):
-        conf.set(AGG_PARTIAL_DEFER, defer)
+    def run():
         scan = MemoryScanExec.single(
             [Batch(b.schema, b.device, b.dicts) for b in frames])
         flt = FilterExec(scan, [IsNotNull(col(2))])
@@ -1003,18 +987,14 @@ def test_deferred_partial_seeds_the_predictor_on_a_streams_first_batch(case):
     try:
         obs.set_mode("recorder")
         conf.set(TRANSFER_WINDOW_DEPTH, 4)
-        conf.set(SELECTIVITY_PREDICTOR_ENABLE, spec.get("predictor", "auto"))
-        got, metrics, (t0, t1) = run("on")
-        off, _, _ = run("off")
+        got, metrics, (t0, t1) = run()
     finally:
-        for k, v in saved.items():
-            conf.set(k, v)
+        conf.set(TRANSFER_WINDOW_DEPTH, saved_depth)
         obs.set_mode(saved_mode)
 
-    for df in (got, off):
-        assert df["k"].tolist() == want["k"].tolist()
-        assert df["c"].tolist() == want["c"].tolist()
-        assert df["s"].tolist() == want["s"].tolist()
+    assert got["k"].tolist() == want["k"].tolist()
+    assert got["c"].tolist() == want["c"].tolist()
+    assert got["s"].tolist() == want["s"].tolist()
 
     seeds, repairs, compacted, fold_caps = spec["want"]
     assert metrics.total("sel_seed_reads") == seeds

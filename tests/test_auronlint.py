@@ -706,11 +706,14 @@ def test_syncbudget_collects_engine_declarations():
     points = collect_sync_points(REPO_ROOT)
     assert len(points) > 20
     assert all(p.unit in ("batch", "task", "call") for p in points)
-    # the chain seed read (exec/joins/chain.py) must be task-budgeted now —
+    # the joins' seed read (the compaction boundary's, exec/selectivity.py:
+    # the one blocking read of chain and driver) must be task-budgeted —
     # a per-batch budget there would mask the whole tentpole regressing
-    chain_pts = [p for p in points if p.rel.endswith("joins/chain.py")]
-    assert chain_pts and all(p.unit == "task" for p in chain_pts)
-    hit = budget_for_site(f"{chain_pts[0].rel.split('auron_tpu/')[1]}:{chain_pts[0].line}", points)
+    seed_pts = [p for p in points if p.rel.endswith("exec/selectivity.py")]
+    assert len(seed_pts) == 1 and seed_pts[0].unit == "task"
+    assert not [p for p in points if p.rel.endswith(
+        ("joins/chain.py", "joins/driver.py"))]
+    hit = budget_for_site(f"{seed_pts[0].rel.split('auron_tpu/')[1]}:{seed_pts[0].line}", points)
     assert hit is not None and hit.unit == "task"
     assert site_allowlisted("exec/shuffle/writer.py:330")
     assert not site_allowlisted("exec/joins/chain.py:1")

@@ -213,14 +213,7 @@ def test_fused_stage_feeding_join_chain_mispredict(monkeypatch):
             build_side="right",
         )
 
-    from auron_tpu.utils.config import JOIN_COMPACT_OUTPUT, active_conf
-    conf = active_conf()
-    saved = conf.get(JOIN_COMPACT_OUTPUT)
-    conf.set(JOIN_COMPACT_OUTPUT, "on")
-    try:
-        tree = _ab(build, sort_cols=None)
-    finally:
-        conf.set(JOIN_COMPACT_OUTPUT, saved)
+    tree = _ab(build, sort_cols=None)
     assert "FusedStageExec" in _types(tree)
 
 
@@ -498,18 +491,19 @@ def dated_star_batches():
 @pytest.mark.parametrize("chip", [False, True], ids=["cpu_rule", "chip_rule"])
 @pytest.mark.parametrize("compact", ["on", "off"])
 def test_fused_bhj_stages_on_the_tiny_star_are_row_exact(
-        monkeypatch, dated_star_batches, chip, compact):
+        monkeypatch, joins_stay_dense, dated_star_batches, chip, compact):
     """Two fused probe stages, date_dim then item, over a stream whose
     first batches pass nothing and whose November batch passes a
     thousand (seed on an empty batch, mispredict, repair): the stage's
-    in-program take and the eager driver's agree row for row, compaction
-    on or off, under the quarter rule and the chip's rule over shapes."""
+    in-program take and the eager driver's agree row for row, compacting
+    by the rule ("on": the quarter rule and the chip's rule over shapes)
+    or dense whatever it says ("off")."""
+    import contextlib
     import time
 
     from auron_tpu import obs
     from auron_tpu.columnar import batch as batch_mod
     from auron_tpu.exec.base import ExecutionContext
-    from auron_tpu.utils.config import JOIN_COMPACT_OUTPUT, active_conf
 
     fact, dates, items, want = dated_star_batches
     monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
@@ -527,20 +521,19 @@ def test_fused_bhj_stages_on_the_tiny_star_are_row_exact(
             [Column(0, "ss_item_sk")], [Column(0, "i_item_sk")],
             "inner", build_side="right", projection=[1, 2, 4, 5])
 
-    conf = active_conf()
-    saved, saved_mode = conf.get(JOIN_COMPACT_OUTPUT), obs.mode()
-    conf.set(JOIN_COMPACT_OUTPUT, compact)
+    saved_mode = obs.mode()
     obs.set_mode("recorder")
+    rule = joins_stay_dense if compact == "off" else contextlib.nullcontext
     try:
-        eager = build().collect().to_pandas()
-        tree = fuse_exec_tree(build(), ON)
-        ctx = ExecutionContext()
-        ctx.metrics.name = tree.name
-        t0 = time.perf_counter()
-        out = list(tree.execute(0, ctx))
-        ws = obs.window_summary(t0, time.perf_counter())
+        with rule():
+            eager = build().collect().to_pandas()
+            tree = fuse_exec_tree(build(), ON)
+            ctx = ExecutionContext()
+            ctx.metrics.name = tree.name
+            t0 = time.perf_counter()
+            out = list(tree.execute(0, ctx))
+            ws = obs.window_summary(t0, time.perf_counter())
     finally:
-        conf.set(JOIN_COMPACT_OUTPUT, saved)
         obs.set_mode(saved_mode)
     fused = pd.concat([b.to_pandas() for b in out], ignore_index=True)
     _assert_rows_equal(eager, fused)
@@ -553,7 +546,7 @@ def test_fused_bhj_stages_on_the_tiny_star_are_row_exact(
     assert fused.i_category.isna().sum() == want.i_category.isna().sum() > 0
     n = len(fact)
     if compact == "off":
-        assert ws["join_takes"] == {"dense": 2 * n}
+        assert ws["join_takes"] == {"seed": 2, "dense": 2 * n - 2}
         assert ws["join_gather_rows"] == 2 * sum(b.capacity for b in fact)
     else:
         takes = ws["join_takes"]
@@ -579,9 +572,7 @@ def test_batches_behind_a_burst_are_not_gathered_at_capacity(
     from auron_tpu.columnar import batch as batch_mod
     from auron_tpu.exec.base import ExecutionContext
     from auron_tpu.obs import core
-    from auron_tpu.utils.config import (
-        JOIN_COMPACT_OUTPUT, TRANSFER_WINDOW_DEPTH, active_conf,
-    )
+    from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH, active_conf
 
     cap, live = 8192, [10, 4000, 0, 0, 0, 0]
     rng = np.random.default_rng(5)
@@ -605,9 +596,7 @@ def test_batches_behind_a_burst_are_not_gathered_at_capacity(
             [Column(0, "k")], [Column(0, "id")], "inner", build_side="right")
 
     conf = active_conf()
-    saved = (conf.get(JOIN_COMPACT_OUTPUT), conf.get(TRANSFER_WINDOW_DEPTH),
-             obs.mode())
-    conf.set(JOIN_COMPACT_OUTPUT, "on")
+    saved = (conf.get(TRANSFER_WINDOW_DEPTH), obs.mode())
     conf.set(TRANSFER_WINDOW_DEPTH, 1)
     obs.set_mode("recorder")
     try:
@@ -620,9 +609,8 @@ def test_batches_behind_a_burst_are_not_gathered_at_capacity(
         takes = [ev[7] for _r, evs in core.snapshot_events() for ev in evs
                  if ev[2] == "take" and t0 <= ev[0] < t1]
     finally:
-        conf.set(JOIN_COMPACT_OUTPUT, saved[0])
-        conf.set(TRANSFER_WINDOW_DEPTH, saved[1])
-        obs.set_mode(saved[2])
+        conf.set(TRANSFER_WINDOW_DEPTH, saved[0])
+        obs.set_mode(saved[1])
     got = pd.concat([b.to_pandas() for b in out], ignore_index=True)
     want = pd.concat(frames).merge(dim, left_on="k", right_on="id")
     _assert_rows_equal(got[["k", "v", "d"]], want[["k", "v", "d"]])
@@ -671,11 +659,11 @@ def test_fused_probe_deferred_agg_spill_midstream():
     """End-to-end q93 shape under memory pressure: fused probe prologue
     (LEFT join, null-heavy keys) feeding a bool-key partial aggregate on
     the DEFERRED count path, with a tiny MemManager budget forcing table
-    spills mid-stream — fusion + deferral off/on agree row-exactly
-    (counts bit-equal; float sums compared at 1e-9 — predictive
-    compaction re-buckets the reduces, re-associating float adds the
-    same way any merge-boundary shift does). The exactly-once staging
-    contract through spill parks is the teeth here."""
+    spills mid-stream — eager and fused agree with the pandas reference
+    row-exactly (counts bit-equal; float sums compared at 1e-9 —
+    predictive compaction re-buckets the reduces, re-associating float
+    adds the same way any merge-boundary shift does). The exactly-once
+    staging contract through spill parks is the teeth here."""
     from auron_tpu.exec.agg_exec import AggExpr, HashAggExec
     from auron_tpu.memory.memmgr import MemManager
 
@@ -699,25 +687,23 @@ def test_fused_probe_deferred_agg_spill_midstream():
             [(AggExpr("count_star", None), "rows"),
              (AggExpr("sum", Column(1, "s")), "s")], "final")
 
-    from auron_tpu.utils.config import AGG_PARTIAL_DEFER, active_conf
-
-    conf = active_conf()
-    saved = conf.get(AGG_PARTIAL_DEFER)
+    # a left join on a unique build keeps every probe row once
+    probe = pd.concat([b.to_pandas() for b in _probe_frame(
+        11, n=12000, jump=True)], ignore_index=True)
+    want = (probe.assign(k_null=probe.k.isna()).groupby("k_null")
+            .agg(rows=("v", "size"), s=("v", "sum")).reset_index())
     MemManager.init(budget_bytes=64 << 10)  # forces mid-stream spills
     try:
-        conf.set(AGG_PARTIAL_DEFER, "off")
         eager = build().collect().to_pandas()
-        conf.set(AGG_PARTIAL_DEFER, "on")
         fused = fuse_exec_tree(build(), ON).collect().to_pandas()
     finally:
-        conf.set(AGG_PARTIAL_DEFER, saved)
         MemManager.init()
-    eager = eager.sort_values("k_null").reset_index(drop=True)
-    fused = fused.sort_values("k_null").reset_index(drop=True)
-    assert eager["k_null"].tolist() == fused["k_null"].tolist()
-    assert eager["rows"].tolist() == fused["rows"].tolist()  # exactly-once
-    for a, b in zip(eager["s"], fused["s"]):
-        assert a == pytest.approx(b, rel=1e-9)
+    for got in (eager, fused):
+        got = got.sort_values("k_null").reset_index(drop=True)
+        assert got["k_null"].tolist() == want["k_null"].tolist()
+        assert got["rows"].tolist() == want["rows"].tolist()  # exactly-once
+        for a, b in zip(got["s"], want["s"]):
+            assert a == pytest.approx(b, rel=1e-9)
 
 
 def test_writer_stage_counted_and_byte_identical(tmp_path):

@@ -136,36 +136,6 @@ def test_dense_survival_chain_matches_oracle():
     pd.testing.assert_frame_equal(got, exp, check_dtype=False)
 
 
-def test_dense_accelerator_mode_no_sync():
-    # compact off (accelerator default): the chain must still fuse, emitting
-    # dense outputs with no host sync
-    from auron_tpu.utils.config import JOIN_COMPACT_OUTPUT, active_conf
-
-    fact, d1, d2 = _fact_dims(n=300, seed=7)
-    top = _star(fact, [d1, d2], [0, 1])
-    calls = {"fused": 0}
-    orig = chain_mod._run_chain
-
-    def spy(*a, **k):
-        calls["fused"] += 1
-        return orig(*a, **k)
-
-    conf = active_conf()
-    saved_mode = conf.get(JOIN_COMPACT_OUTPUT)
-    conf.set(JOIN_COMPACT_OUTPUT, "off")
-    chain_mod._run_chain = spy
-    try:
-        got = _collect_sorted(top)
-    finally:
-        chain_mod._run_chain = orig
-        conf.set(JOIN_COMPACT_OUTPUT, saved_mode)
-    assert calls["fused"] == 1
-    exp = _oracle(fact, [d1, d2], ["k0", "k1"])
-    exp.columns = got.columns
-    exp = exp.sort_values(list(exp.columns)).reset_index(drop=True)
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False)
-
-
 def test_three_level_chain_with_nulls():
     rng = np.random.default_rng(2)
     n = 400
@@ -204,8 +174,8 @@ class _SpyPredictor:
 
     instances: list = []
 
-    def __new__(cls, conf=None):
-        p = _RealPredictor(conf)
+    def __new__(cls):
+        p = _RealPredictor()
         cls.instances.append(p)
         return p
 
@@ -214,37 +184,23 @@ def _with_spy(monkeypatch):
     import auron_tpu.exec.selectivity as sel_mod
 
     _SpyPredictor.instances = []
-    monkeypatch.setattr(chain_mod, "SelectivityPredictor", _SpyPredictor)
     monkeypatch.setattr(sel_mod, "SelectivityPredictor", _SpyPredictor)
     return _SpyPredictor
 
 
-def _run_both_modes(top_builder):
-    """Collect with predictor on (default) vs off (blocking per-batch
-    sync) — the two must produce identical row sets."""
-    from auron_tpu.utils.config import (
-        JOIN_COMPACT_OUTPUT, SELECTIVITY_PREDICTOR_ENABLE, active_conf,
-    )
-
-    conf = active_conf()
-    saved_c = conf.get(JOIN_COMPACT_OUTPUT)
-    saved_p = conf.get(SELECTIVITY_PREDICTOR_ENABLE)
-    conf.set(JOIN_COMPACT_OUTPUT, "on")
-    try:
-        conf.set(SELECTIVITY_PREDICTOR_ENABLE, "on")
-        got_pred = _collect_sorted(top_builder())
-        conf.set(SELECTIVITY_PREDICTOR_ENABLE, "off")
-        got_sync = _collect_sorted(top_builder())
-    finally:
-        conf.set(JOIN_COMPACT_OUTPUT, saved_c)
-        conf.set(SELECTIVITY_PREDICTOR_ENABLE, saved_p)
-    return got_pred, got_sync
+def _run_both_modes(top_builder, joins_stay_dense):
+    """Collect with the predicted compaction and with every output dense
+    by the rule: the two must produce identical row sets."""
+    got_pred = _collect_sorted(top_builder())
+    with joins_stay_dense():
+        got_dense = _collect_sorted(top_builder())
+    return got_pred, got_dense
 
 
-def test_chain_predictor_forced_mispredict_repair(monkeypatch):
+def test_chain_predictor_forced_mispredict_repair(monkeypatch, joins_stay_dense):
     """Selectivity jumps from ~0 to ~100% mid-stream: the predicted bucket
     is far too small, the repair path must re-emit and the results stay
-    bit-identical to the blocking mode AND the pandas oracle."""
+    bit-identical to the dense twin AND the pandas oracle."""
     spy = _with_spy(monkeypatch)
     n = 6000
     # chunk 0 (1000 rows, capacity 1024): almost nothing survives (seeds a
@@ -264,7 +220,7 @@ def test_chain_predictor_forced_mispredict_repair(monkeypatch):
             )
         return node
 
-    got_pred, got_sync = _run_both_modes(build)
+    got_pred, got_sync = _run_both_modes(build, joins_stay_dense)
     pd.testing.assert_frame_equal(got_pred, got_sync, check_dtype=False)
     exp = _oracle(fact, [d1, d2], ["k0", "k1"])
     exp.columns = got_pred.columns
@@ -275,8 +231,8 @@ def test_chain_predictor_forced_mispredict_repair(monkeypatch):
     assert any(p.predictions > 0 for p in spy.instances)
 
 
-def test_chain_predictor_parity_fuzz(monkeypatch):
-    """Randomized selectivity patterns: predictor-compacted vs blocking
+def test_chain_predictor_parity_fuzz(monkeypatch, joins_stay_dense):
+    """Randomized selectivity patterns: predictor-compacted vs dense
     output row sets are identical (and match pandas) across seeds."""
     spy = _with_spy(monkeypatch)
     for seed in range(4):
@@ -308,7 +264,7 @@ def test_chain_predictor_parity_fuzz(monkeypatch):
                 )
             return node
 
-        got_pred, got_sync = _run_both_modes(build)
+        got_pred, got_sync = _run_both_modes(build, joins_stay_dense)
         pd.testing.assert_frame_equal(got_pred, got_sync, check_dtype=False)
         exp = _oracle(fact, [d1, d2], ["k0", "k1"])
         exp.columns = got_pred.columns
@@ -317,9 +273,9 @@ def test_chain_predictor_parity_fuzz(monkeypatch):
     assert any(p.predictions > 0 for p in spy.instances)
 
 
-def test_bhj_driver_predictor_parity_with_mispredict(monkeypatch):
+def test_bhj_driver_predictor_parity_with_mispredict(monkeypatch, joins_stay_dense):
     """Single unique-build BHJ (driver._emit_unique_compacted path): the
-    pipelined predicted compaction must match the blocking mode and the
+    pipelined predicted compaction must match the dense twin and the
     oracle, including a forced bucket-too-small repair."""
     spy = _with_spy(monkeypatch)
     n = 6000
@@ -334,7 +290,7 @@ def test_bhj_driver_predictor_parity_with_mispredict(monkeypatch):
             build_side="right",
         )
 
-    got_pred, got_sync = _run_both_modes(build)
+    got_pred, got_sync = _run_both_modes(build, joins_stay_dense)
     pd.testing.assert_frame_equal(got_pred, got_sync, check_dtype=False)
     exp = _oracle(fact, [d1], ["k0"])
     exp.columns = got_pred.columns
@@ -390,20 +346,16 @@ def dated_star():
     return star, frames, SqlServer(star.make_catalog(frames), tables, n_parts=1)
 
 
-def _answer_and_takes(star, server, name, compact):
+def _answer_and_takes(star, server, name):
     import time
 
     from auron_tpu import obs
-    from auron_tpu.utils.config import JOIN_COMPACT_OUTPUT
 
     saved = obs.mode()
     obs.set_mode("recorder")
     try:
         t0 = time.perf_counter()
-        rec = server.execute_json({
-            "sql": star._text(name), "tenant": f"c-{compact}",
-            "conf": {JOIN_COMPACT_OUTPUT.key: compact},
-        })
+        rec = server.execute_json({"sql": star._text(name), "tenant": "c"})
         ws = obs.window_summary(t0, time.perf_counter())
     finally:
         obs.set_mode(saved)
@@ -417,9 +369,10 @@ def _answer_and_takes(star, server, name, compact):
     ("q55", False),     # November 1999: a thousand early, then none
 ])
 def test_chain_compact_and_dense_arms_are_row_exact_twins(
-        monkeypatch, dated_star, chip, name, first_batch_empty):
-    """Through POST /sql's executor (the fused chain): compaction on and
-    off give the reference's rows to the cent, NULL keys never joining and
+        monkeypatch, joins_stay_dense, dated_star, chip, name,
+        first_batch_empty):
+    """Through POST /sql's executor (the fused chain): compacting by the
+    rule and dense whatever it says give the reference's rows to the cent, NULL keys never joining and
     NULL group keys one group, under the quarter rule and under the chip's
     rule over shapes, through a seed, a mispredict and its repair."""
     from auron_tpu.columnar import batch as batch_mod
@@ -428,12 +381,13 @@ def test_chain_compact_and_dense_arms_are_row_exact_twins(
     monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
     want = star.star_reference(frames, name)
     assert len(want) > 3
-    on, ws_on = _answer_and_takes(star, server, name, "on")
-    off, ws_off = _answer_and_takes(star, server, name, "off")
+    on, ws_on = _answer_and_takes(star, server, name)
+    with joins_stay_dense():
+        off, ws_off = _answer_and_takes(star, server, name)
     assert on == want
     assert off == want
     n_batches = len(server.tables["store_sales"])
-    assert ws_off["join_takes"] == {"dense": n_batches}
+    assert ws_off["join_takes"] == {"seed": 1, "dense": n_batches - 1}
     assert ws_off["join_gather_rows"] == 2 * 8192 * (n_batches - 1) + 2 * \
         server.tables["store_sales"][-1].capacity
     takes = ws_on["join_takes"]
@@ -443,3 +397,102 @@ def test_chain_compact_and_dense_arms_are_row_exact_twins(
         # seeded on nothing, then a thousand rows arrive: repaired from the
         # state the window holds, at the count's own bucket or dense
         assert takes.get("repair", 0) >= 1
+
+
+# ---------------------------------------------------------------------------
+# one protocol (exec/selectivity.py CompactionBoundary), three consumers
+# ---------------------------------------------------------------------------
+
+
+def _take_modes(tree):
+    """(rows, the ``take`` events' modes in call order) of one run of
+    ``tree`` under the flight recorder."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.exec.base import ExecutionContext
+    from auron_tpu.obs import core
+
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        ctx = ExecutionContext()
+        ctx.metrics.name = tree.name
+        t0 = time.perf_counter_ns()
+        out = [b.to_pandas() for b in tree.execute(0, ctx)]
+        t1 = time.perf_counter_ns()
+        evs = sorted((ev for _r, evs in core.snapshot_events() for ev in evs
+                      if ev[2] == "take" and t0 <= ev[0] < t1),
+                     key=lambda ev: ev[0])
+    finally:
+        obs.set_mode(saved)
+    return pd.concat(out, ignore_index=True), [ev[7]["mode"] for ev in evs], ctx
+
+
+@pytest.mark.parametrize("live, want", [
+    ([300] * 5, ["seed"] + ["compact"] * 4),
+    # most rows survive: no bucket pays, every batch waits for its count
+    ([6000] * 5, ["seed"] + ["dense"] * 4),
+    # PR 29's burst: the two batches dispatched at the seed's bucket, the
+    # burst's repair between them, then the batches that waited
+    ([10, 4000, 0, 0, 0, 0],
+     ["seed", "compact", "compact", "repair", "compact", "compact",
+      "compact"]),
+], ids=["steady", "dense", "burst"])
+def test_chain_bhj_and_fused_stage_take_the_same_modes(live, want):
+    """One sequence of live counts through the three consumers of the
+    compaction boundary (the fused star chain, the eager unique-build BHJ
+    and the BHJ behind a fused probe stage) asks for the same takes in
+    the same order: the protocol is written once."""
+    from auron_tpu import types as T
+    from auron_tpu.exec.basic import FilterExec
+    from auron_tpu.exprs.ir import BinaryOp, Column, Literal
+    from auron_tpu.plan.fusion import fuse_exec_tree
+    from auron_tpu.utils.config import (
+        TRANSFER_WINDOW_DEPTH, Configuration, active_conf,
+    )
+
+    cap = 8192
+    rng = np.random.default_rng(5)
+    frames = []
+    for n in live:
+        k = np.full(cap, 10_000, dtype=np.int64)
+        k[rng.choice(cap, n, replace=False)] = rng.integers(0, 64, n)
+        frames.append(pd.DataFrame({
+            "k": k, "k1": np.arange(cap, dtype=np.int64) % 4,
+            "v": rng.integers(0, 1 << 30, cap)}))
+    probe_b = [Batch.from_pandas(f) for f in frames]
+    d1 = pd.DataFrame({"id": np.arange(64, dtype=np.int64),
+                       "d": np.arange(64, dtype=np.int64) * 3})
+    d2 = pd.DataFrame({"id2": np.arange(4, dtype=np.int64),
+                       "d2": np.arange(4, dtype=np.int64) * 7})
+
+    def bhj(child, dim, key):
+        return BroadcastHashJoinExec(
+            child, _mk(dim), [col(key)], [col(0)], "inner", build_side="right")
+
+    def scan():
+        return MemoryScanExec([list(probe_b)], probe_b[0].schema)
+
+    def staged():
+        flt = FilterExec(scan(), [BinaryOp(
+            "gteq", Column(2, "v"), Literal(0, T.INT64))])
+        return fuse_exec_tree(
+            bhj(flt, d1, 0), Configuration({"exec.fuse.enable": "on"}))
+
+    conf = active_conf()
+    saved = conf.get(TRANSFER_WINDOW_DEPTH)
+    conf.set(TRANSFER_WINDOW_DEPTH, 1)
+    try:
+        got = {
+            "chain": _take_modes(bhj(bhj(scan(), d1, 0), d2, 1)),
+            "eager": _take_modes(bhj(scan(), d1, 0)),
+            "stage": _take_modes(staged()),
+        }
+    finally:
+        conf.set(TRANSFER_WINDOW_DEPTH, saved)
+    assert got["stage"][2].metrics.total("fused_batches") == len(live)
+    assert {name: modes for name, (_, modes, _) in got.items()} == {
+        "chain": want, "eager": want, "stage": want}
+    n_rows = len(pd.concat(frames).merge(d1, left_on="k", right_on="id"))
+    assert [len(rows) for rows, _, _ in got.values()] == [n_rows] * 3

@@ -337,40 +337,6 @@ def test_unique_build_residual_condition_noncompact_emit():
     assert got["rv"].tolist() == want["rv"].tolist()
 
 
-@pytest.mark.parametrize("compact, predictor, tpu, want", [
-    # on | off keep their meaning on every back end
-    ("on", "auto", True, True), ("on", "off", True, True),
-    ("off", "auto", True, False), ("off", "on", False, False),
-    # auto: on wherever the boundary costs no blocking read a batch. The
-    # predictor's own auto is "on where compaction is", so the defaults
-    # compact on the accelerator as on the CPU
-    ("auto", "auto", True, True), ("auto", "auto", False, True),
-    ("auto", "on", True, True),
-    # predictor off: a blocking read a batch, which only a CPU host affords
-    ("auto", "off", True, False), ("auto", "off", False, True),
-])
-def test_compact_join_output_knob_tri_resolution(monkeypatch, compact,
-                                                 predictor, tpu, want):
-    """spark.auron.join.compact.output: on/off force; auto no longer asks
-    which back end this is but whether the predicted, sync-free arm runs."""
-    from auron_tpu import jaxenv
-    from auron_tpu.exec import base as exec_base
-    from auron_tpu.exec.joins.driver import _compact_join_output_enabled
-    from auron_tpu.utils.config import (
-        JOIN_COMPACT_OUTPUT, SELECTIVITY_PREDICTOR_ENABLE, Configuration,
-        conf_scope,
-    )
-
-    # drop the last test's lingering operator context so the gate reads
-    # the scoped conf, not a stale task's
-    monkeypatch.delattr(exec_base._ctx_local, "ctx", raising=False)
-    monkeypatch.setattr(jaxenv, "is_tpu", lambda: tpu)
-    conf = Configuration({JOIN_COMPACT_OUTPUT.key: compact,
-                          SELECTIVITY_PREDICTOR_ENABLE.key: predictor})
-    with conf_scope(conf):
-        assert _compact_join_output_enabled() is want
-
-
 # ---------------------------------------------------------------------------
 # the output boundary of the unique-build probe: which take each stream of
 # probe batches gets, what the seed reads, and the paths that never compact
@@ -379,7 +345,7 @@ def test_compact_join_output_knob_tri_resolution(monkeypatch, compact,
 
 def _takes_of(run):
     """(result, the window's take events as (mode, rows, in_rows), the
-    window's summary with the probe driver's own host reads as
+    window's summary with the compaction boundary's own host reads as
     ``join_reads``: (site, bytes)) of ``run()`` under the flight recorder."""
     import time
 
@@ -402,7 +368,7 @@ def _takes_of(run):
         ws = obs.window_summary(t0, t1)
         ws["join_reads"] = [(ev[3], ev[7]["bytes"]) for ev in window
                             if ev[8] == "sync"
-                            and ev[3].startswith("exec/joins/driver.py:")]
+                            and ev[3].startswith("exec/selectivity.py:")]
     finally:
         obs.set_mode(saved)
     return out, [(e[7]["mode"], e[7]["rows"], e[7]["in_rows"]) for e in evs], ws
@@ -462,37 +428,27 @@ def test_paths_that_never_compact_still_take_theirs(case, modes):
     assert ws["join_takes"] == {m: modes.count(m) for m in set(modes)}
 
 
-@pytest.mark.parametrize("predictor, live_from, want_modes, want_seed_reads", [
+@pytest.mark.parametrize("live_from, want_modes", [
     # steady: one seed, then predicted compact takes riding the window
-    ("auto", None, ["seed", "compact", "compact", "compact"], 1),
+    (None, ["seed", "compact", "compact", "compact"]),
     # the first batch is empty, the later ones hold two hundred: the seed
     # compacts into the least bucket, and each batch dispatched at it
     # before the first harvest (all three: the window is four deep)
     # repairs at the bucket of its own count
-    ("auto", 1024, ["seed"] + ["compact"] * 3 + ["repair"] * 3, 1),
-    # predictor off: every batch reads its own count and takes exactly
-    ("off", 1024, ["seed"] * 4, 4),
-], ids=["steady", "empty_first_then_jump", "predictor_off"])
-def test_the_seed_reads_one_scalar_and_no_mask(predictor, live_from,
-                                               want_modes, want_seed_reads):
+    (1024, ["seed"] + ["compact"] * 3 + ["repair"] * 3),
+], ids=["steady", "empty_first_then_jump"])
+def test_the_seed_reads_one_scalar_and_no_mask(live_from, want_modes):
     """The first batch of a stream has no prediction: the join reads its
     live count (one scalar), never the selection mask (a byte a row), and
     takes on the device at that count's bucket."""
-    from auron_tpu.utils.config import (
-        JOIN_COMPACT_OUTPUT, SELECTIVITY_PREDICTOR_ENABLE,
-        TRANSFER_WINDOW_DEPTH, active_conf,
-    )
+    from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH, active_conf
 
     probe, dim = _sparse_probe(live_from=live_from)
     if live_from is not None:       # of the later rows a fifth survives
         late = probe.index[(probe.index >= live_from) & (probe.index % 5 == 0)]
         probe.loc[late, "k"] = probe.loc[late, "v"] % 64
     conf = active_conf()
-    saved = (conf.get(JOIN_COMPACT_OUTPUT),
-             conf.get(SELECTIVITY_PREDICTOR_ENABLE))
-    saved += (conf.get(TRANSFER_WINDOW_DEPTH),)
-    conf.set(JOIN_COMPACT_OUTPUT, "on")
-    conf.set(SELECTIVITY_PREDICTOR_ENABLE, predictor)
+    saved = conf.get(TRANSFER_WINDOW_DEPTH)
     conf.set(TRANSFER_WINDOW_DEPTH, 4)
 
     def run():
@@ -504,17 +460,15 @@ def test_the_seed_reads_one_scalar_and_no_mask(predictor, live_from,
     try:
         got, takes, ws = _takes_of(run)
     finally:
-        conf.set(JOIN_COMPACT_OUTPUT, saved[0])
-        conf.set(SELECTIVITY_PREDICTOR_ENABLE, saved[1])
-        conf.set(TRANSFER_WINDOW_DEPTH, saved[2])
+        conf.set(TRANSFER_WINDOW_DEPTH, saved)
     want = probe.merge(dim, left_on="k", right_on="id")
     assert sorted(got["v"].tolist()) == sorted(want["v"].tolist())
     assert got["d"].tolist() == (got["k"] * 3).tolist()
     assert [m for m, _, _ in takes] == want_modes
     assert all(rows == 256 for m, rows, _ in takes if m == "repair")
-    # the probe's own blocking reads: the seeds, one int64 each, from one
-    # line of the driver; nothing the size of a mask (a byte a row)
-    assert [b for _, b in ws["join_reads"]] == [8] * want_seed_reads
+    # the boundary's own blocking read: the seed, one int64, from one line
+    # of exec/selectivity.py; nothing the size of a mask (a byte a row)
+    assert [b for _, b in ws["join_reads"]] == [8]
     assert len({site for site, _ in ws["join_reads"]}) == 1
 
 

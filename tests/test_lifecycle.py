@@ -124,9 +124,7 @@ def test_agg_setup_failure_leaks_no_consumers(monkeypatch):
         consumers_before = list(mm._consumers)
     try:
         with pytest.raises(RuntimeError, match="failed"):
-            with api.native_task(_task_bytes(
-                plan, conf={"exec.agg.partial.defer": "on"}
-            )) as h:
+            with api.native_task(_task_bytes(plan)) as h:
                 while api.next_batch(h) is not None:
                     pass
         with mm._lock:

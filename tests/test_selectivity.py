@@ -1,29 +1,20 @@
 """Unit coverage for the sync-free pipeline pieces: the selectivity
-predictor (exec/selectivity.py) and the async transfer window
-(runtime/transfer.py)."""
+predictor and the compaction boundary that drives it (exec/selectivity.py)
+and the async transfer window (runtime/transfer.py)."""
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from auron_tpu.columnar import batch as batch_mod
 from auron_tpu.columnar.batch import compaction_bucket
-from auron_tpu.exec.selectivity import SelectivityPredictor, predictor_enabled
-from auron_tpu.runtime.transfer import TransferWindow, harvest
-from auron_tpu.utils.config import (
-    Configuration,
-    JOIN_COMPACT_OUTPUT,
-    SELECTIVITY_EWMA_ALPHA,
-    SELECTIVITY_HEADROOM,
-    SELECTIVITY_PREDICTOR_ENABLE,
-    SELECTIVITY_SHRINK_PATIENCE,
+from auron_tpu.exec import selectivity as sel_mod
+from auron_tpu.exec.metrics import MetricNode
+from auron_tpu.exec.selectivity import (
+    CompactionBoundary, SelectivityPredictor, TakePlan,
 )
-
-
-def _conf(**kv):
-    c = Configuration()
-    for k, v in kv.items():
-        c.set(k, v)
-    return c
+from auron_tpu.runtime.transfer import TransferWindow, harvest
+from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH, Configuration
 
 
 def test_compaction_bucket_policy():
@@ -73,7 +64,7 @@ def test_compaction_bucket_rule_over_shapes(monkeypatch, chip, capacity,
 
 
 def test_predictor_seeds_then_predicts_and_grows_immediately():
-    p = SelectivityPredictor(_conf())
+    p = SelectivityPredictor()
     assert p.predict(1 << 20) is None              # no history: seed path
     p.observe(100)
     b1 = p.predict(1 << 20)
@@ -85,10 +76,7 @@ def test_predictor_seeds_then_predicts_and_grows_immediately():
 
 
 def test_predictor_shrinks_only_after_patience():
-    c = _conf(**{SELECTIVITY_SHRINK_PATIENCE.key: 3,
-                 SELECTIVITY_EWMA_ALPHA.key: 1.0,
-                 SELECTIVITY_HEADROOM.key: 1.0})
-    p = SelectivityPredictor(c)
+    p = SelectivityPredictor(alpha=1.0, headroom=1.0, patience=3)
     p.observe(10_000)
     big = p.predict(1 << 20)
     p.observe(10)   # 1 low batch
@@ -100,18 +88,9 @@ def test_predictor_shrinks_only_after_patience():
 
 
 def test_predictor_clamped_to_input_capacity():
-    p = SelectivityPredictor(_conf())
+    p = SelectivityPredictor()
     p.observe(1 << 20)
     assert p.predict(1024) <= 1024
-
-
-def test_predictor_enabled_knob_follows_compaction():
-    on = _conf(**{SELECTIVITY_PREDICTOR_ENABLE.key: "on"})
-    off = _conf(**{SELECTIVITY_PREDICTOR_ENABLE.key: "off"})
-    auto_off = _conf(**{JOIN_COMPACT_OUTPUT.key: "off"})
-    assert predictor_enabled(on)
-    assert not predictor_enabled(off)
-    assert not predictor_enabled(auto_off)
 
 
 def test_transfer_window_fifo_and_depth():
@@ -135,13 +114,124 @@ def test_transfer_window_empty_arrays_and_harvest():
     assert list(v) == [0, 1, 2]
 
 
-def test_predictor_enabled_auto_follows_compaction_auto():
-    """The predictor's auto arm resolves through the compaction knob's
-    OWN tri-state (resolve_tri composition, not a manual == chain): with
-    both knobs at auto on the CPU backend, compaction is on, so the
-    predictor is too; forcing compaction on keeps it on."""
-    assert predictor_enabled(_conf())  # both auto -> CPU -> on
-    assert predictor_enabled(_conf(**{JOIN_COMPACT_OUTPUT.key: "on"}))
+class _RecordingPredictor(SelectivityPredictor):
+    """The real predictor, with every call written down."""
+
+    def __init__(self):
+        super().__init__()
+        self.predicts, self.observes = [], []
+
+    def predict(self, in_capacity):
+        self.predicts.append(in_capacity)
+        return super().predict(in_capacity)
+
+    def observe(self, n_live, predicted=None):
+        self.observes.append((n_live, predicted))
+        super().observe(n_live, predicted)
+
+
+K8, K64 = 8192, 65536   # the quarter rule compacts into <= 2048 / <= 16384
+
+# name: (capacity, window depth, live counts, planned upstream,
+#        takes in call order as (batch, mode, out_cap),
+#        batches ready after each submit, batches drained, mispredicts)
+_PROTOCOL = {
+    # no observation yet: read, observe, take at the count's own bucket and
+    # emit at once; nothing enters the window
+    "seed_emits_at_once": (
+        K8, 4, [100], False,
+        [(0, "seed", 128)], [[0]], [], 0),
+    # a bucket that pays is taken at dispatch and rides the window
+    "predicted_rides_the_window": (
+        K8, 2, [100, 100, 100, 100], False,
+        [(0, "seed", 128), (1, "compact", 256), (2, "compact", 256),
+         (3, "compact", 256)],
+        [[0], [], [], [1]], [2, 3], 0),
+    # a bucket too wide to pay: nothing is taken until the batch's own
+    # count lands, which then decides dense or compact
+    "too_wide_waits_for_its_count": (
+        K8, 1, [4000, 4000, 50], False,
+        [(0, "seed", None), (1, "dense", None), (2, "compact", 128)],
+        [[0], [], [1]], [2], 0),
+    # PR 29's finding (1): the batches behind a burst hold nothing while
+    # the bucket waits out its shrink patience. One gather at capacity (the
+    # burst's repair), not one a batch
+    "burst_then_empty": (
+        K8, 1, [10, 4000, 0, 0, 0, 0], False,
+        [(0, "seed", 128), (1, "compact", 128), (2, "compact", 128),
+         (1, "repair", None), (3, "compact", 128), (4, "compact", 128),
+         (5, "compact", 128)],
+        [[0], [], [1], [2], [3], [4]], [5], 1),
+    # an overflow is repaired once a batch, from the state the window held,
+    # and the very next plan is the grown bucket
+    "mispredict_repairs_and_grows": (
+        K64, 1, [100, 3000, 3000, 3000], False,
+        [(0, "seed", 128), (1, "compact", 256), (2, "compact", 256),
+         (1, "repair", 4096), (3, "compact", 4096), (2, "repair", 4096)],
+        [[0], [], [1], [2]], [3], 2),
+    "drain_is_fifo": (
+        K8, 4, [100] * 7, False,
+        [(0, "seed", 128)] + [(i, "compact", 256) for i in range(1, 7)],
+        [[0], [], [], [], [], [1], [2]], [3, 4, 5, 6], 0),
+    # the fused stage plans (and takes) upstream: the boundary predicts
+    # once a batch all the same and takes only what the stage could not
+    "upstream_plan_is_not_predicted_again": (
+        K64, 1, [100, 100, 3000], True,
+        [(0, "seed", 128), (2, "repair", 4096)],
+        [[0], [], [1]], [2], 1),
+}
+
+
+@pytest.mark.parametrize("name", list(_PROTOCOL))
+def test_boundary_protocol(monkeypatch, name):
+    """CompactionBoundary under a fake ``take`` and host scalars for the
+    live counts: which takes it asks for and when, what it emits and in
+    what order, and that every batch is predicted once and observed once."""
+    capacity, depth, live, upstream, want_takes, want_ready, want_drained, \
+        want_mispredicts = _PROTOCOL[name]
+    monkeypatch.setattr(sel_mod, "SelectivityPredictor", _RecordingPredictor)
+    conf = Configuration()
+    conf.set(TRANSFER_WINDOW_DEPTH.key, depth)
+    metrics = MetricNode("join")
+    boundary = CompactionBoundary(conf, compaction_bucket, metrics)
+    takes = []
+
+    def take_of(i):
+        def take(mode, out_cap):
+            takes.append((i, mode, out_cap))
+            return (i, mode, out_cap)
+        return take
+
+    ready, emitted = [], []
+    for i, n in enumerate(live):
+        plan = taken = None
+        if upstream:
+            plan = boundary.plan_take(capacity)
+            assert isinstance(plan, TakePlan)
+            if plan.cap is not None:
+                taken = (i, "stage", plan.cap)
+                takes.append(taken)
+        out = boundary.offer(
+            np.int32(n), capacity, take_of(i), i, plan, taken)
+        emitted += out
+        ready.append([state for state, _ in out])
+    tail = list(boundary.drain())
+    drained = [state for state, _ in tail]
+    # a batch is emitted with what its LAST take returned
+    for state, got in emitted + tail:
+        assert got == [t for t in takes if t[0] == state][-1]
+    takes = [t for t in takes if t[1] != "stage"]
+    assert takes == want_takes
+    assert ready == want_ready
+    assert drained == want_drained
+    assert [s for r in ready for s in r] + drained == list(range(len(live)))
+    assert list(boundary.drain()) == []
+    pred = boundary._pred
+    assert pred.predicts == [capacity] * len(live)       # once a batch
+    assert [n for n, _ in pred.observes] == live         # once, in order
+    assert boundary.predictions == len(live) - 1
+    assert pred.mispredicts == want_mispredicts
+    assert metrics.values.get("sel_mispredicts", 0) == want_mispredicts
 
 
 @pytest.mark.parametrize("n", [128, 1024, 2048, 8192, 1 << 17])

@@ -274,14 +274,24 @@ def test_replay_adds_no_compiles_across_fresh_server_sessions(frames):
 # ---------------------------------------------------------------------------
 
 
-def test_session_conf_rejects_unknown_and_process_global_keys(server):
+@pytest.mark.parametrize("key", [
+    "no.such.key",
+    # process-global: not a session's to set
+    "obs.mode", "http.service.enable", "serve.admission.max.concurrent",
+    # removed with their losing arms (PR 30): the compaction boundary has
+    # no switches, so a session that still names one is refused like any
+    # unknown key (HTTP 400)
+    "join.compact.output", "exec.selectivity.predictor",
+    "exec.agg.partial.defer", "exec.selectivity.ewma.alpha",
+    "exec.selectivity.headroom", "exec.selectivity.shrink.patience",
+    "batch.capacity.buckets",
+])
+def test_session_conf_rejects_unknown_and_process_global_keys(server, key):
     with pytest.raises(QueryError):
-        server.session_conf({"no.such.key": "1"})
-    for denied in ("obs.mode", "http.service.enable",
-                   "serve.admission.max.concurrent"):
-        with pytest.raises(QueryError):
-            server.session_conf({denied: "1"})
-    # a legitimate engine knob is accepted and resolves
+        server.session_conf({key: "1"})
+
+
+def test_session_conf_accepts_an_engine_knob(server):
     conf = server.session_conf({"batch.size": 4096})
     from auron_tpu.utils.config import BATCH_SIZE
 

@@ -353,27 +353,24 @@ def test_op_sync_attribution_follows_the_waiting_operator():
     producer suspended at yield inside an open timer can no longer absorb
     a consumer's stall (the q93 probe_time misattribution)."""
     from auron_tpu.exec.agg_exec import AggExpr, HashAggExec
-    from auron_tpu.utils.config import AGG_PARTIAL_DEFER, active_conf
     from auron_tpu.utils.profiling import EngineCounters
 
     counters = EngineCounters.install()
-    conf = active_conf()
-    saved = conf.get(AGG_PARTIAL_DEFER)
     saved_all = counters.record_all_sites
     counters.record_all_sites = True
     try:
-        conf.set(AGG_PARTIAL_DEFER, "off")  # force the blocking 1/batch read
         rng = np.random.default_rng(3)
         frames = [
             Batch.from_pydict({
                 "k": (rng.integers(0, 50, 800) * 1_000_003).tolist(),
-                "v": [1.0] * 800,
+                "c": [1] * 800,
             })
             for _ in range(6)
         ]
+        # a merge-mode aggregate reads its counts once a batch, blocking
         agg = HashAggExec(
             MemoryScanExec.single(frames), [(col(0), "k")],
-            [(AggExpr("count_star", None), "c")], "partial")
+            [(AggExpr("count_star", None), "c")], "final")
         counters.reset()
         agg.collect()
         snap = counters.snapshot()
@@ -381,7 +378,6 @@ def test_op_sync_attribution_follows_the_waiting_operator():
         assert snap["op_sync"]["HashAggExec"][0] > 0
     finally:
         counters.record_all_sites = saved_all
-        conf.set(AGG_PARTIAL_DEFER, saved)
 
 
 def test_rss_fetch_rides_iter_payloads_raw_bytes(tmp_path):

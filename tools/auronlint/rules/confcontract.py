@@ -54,7 +54,10 @@ from tools.auronlint.core import Rule
 #: whose reads the call-graph closure from plan construction reaches AND
 #: that are PLAN_KNOBS members. Raise as knobs are added; a DROP means
 #: the analysis lost the registry or the plan closure went empty.
-R14_MIN_DECLARED = 70
+#: 70 -> 69: PR 30 deleted seven declarations (the compaction boundary's
+#: six knobs with their losing arms, and batch.capacity.buckets, which
+#: nothing read) and 69 stay visible, none lost from sight.
+R14_MIN_DECLARED = 69
 R14_MIN_PLAN_PROVED = 6
 
 #: where plan construction lives: the closure anchors every function in
