@@ -160,6 +160,59 @@ def _gather_bound() -> bool:
     return is_tpu()
 
 
+#: the static widths a small build's live key list is padded to: a width
+#: is a compile shape of every probe program, so a new seed's 15 or 22
+#: live keys must land on the same one
+COMPARE_WIDTHS = (64, 256, 1024)
+
+
+def lookup_compare_width(n_live: int, search_capacity: int | None = None) -> int | None:
+    """THE lookup policy of the unique-build probe (exec/joins/core.py):
+    the width of the live key list a probe row is COMPARED against, or
+    None where the build keeps its gathered map. A rule over what
+    ``prepare_build`` already holds on the host: the build's live key
+    count, and which map it has. ``search_capacity`` None says a LUT (one
+    gathered element a row, keys compared as int32 offsets of its base);
+    a number says the sorted words of that capacity (a binary search: one
+    gathered element a row for every bit of it, keys compared as 64-bit
+    words).
+
+    Comparing costs one compare-select a row for every slot of the list,
+    on the vector unit. So: compare iff
+    ``width x (a compare-select) < gathers x (a gathered element)``, at
+    the least width of ``COMPARE_WIDTHS`` that holds ``n_live`` keys. The
+    unit costs are the back end's (``_lookup_costs``)."""
+    gather_ns, compare32_ns, compare64_ns = _lookup_costs()
+    if search_capacity is None:
+        map_ns, slot_ns = gather_ns, compare32_ns
+    else:
+        passes = max(search_capacity - 1, 1).bit_length()
+        map_ns, slot_ns = passes * gather_ns, compare64_ns
+    for width in COMPARE_WIDTHS:
+        if n_live <= width:
+            return width if width * slot_ns < map_ns else None
+    return None
+
+
+def _lookup_costs() -> tuple[float, float, float]:
+    """(a gathered element, a compare-select a list slot a row on int32
+    keys, the same on 64-bit words) in ns on this back end. The TPU
+    v5e's are PERF.md section 5's unit costs (PR 31): a LUT probe 7.2-8.5
+    ns a row whatever its width, a list of 1,024 slots 1.0-1.3 ns a row
+    on int32 offsets and 1.2-1.7 ns on 64-bit words, so every width of
+    the ladder pays there, six times over at the widest; the break-even
+    lies beyond it (about 9,000 slots at 1,048,576 rows, under 4,000 at
+    4,194,304, where a slot's cost doubles past 1,024 slots). XLA:CPU
+    gathers an element in 4 ns and compares in 1.4-1.45 ns a slot (this
+    sandbox's CPU, 262,144 rows: a LUT probe 4.5 ns a row, a search over
+    32,768 rows 60 ns, a list of 64 slots 88-93 ns), so there a LUT
+    always stays, and a search gives way only over a build of 2^23 rows
+    and more."""
+    if _gather_bound():
+        return 7.2, 0.0012, 0.0016
+    return 4.0, 1.45, 1.4
+
+
 class DeviceBatch(NamedTuple):
     """The array-only pytree consumed by jitted kernels."""
 

@@ -227,6 +227,8 @@ def _run_chain(
     )
     bwords_all = tuple(b.words for b in builds)
     n_lives = tuple(jnp.int32(b.n_live) for b in builds)
+    key_lists = tuple(b.key_list for b in builds)
+    lookup_kinds = tuple(core.lookup_kind(b) for b in builds)
 
     n_levels = len(links)
     build_planes = 2 * sum(len(cs) for cs in bcols_per_level)
@@ -301,9 +303,11 @@ def _run_chain(
             tuple(pb.col_validity(c) for c in key_cols)
             for key_cols in key_cols_per_level
         )
+        for kind in lookup_kinds:
+            obs.note_join_lookup(kind, pb.capacity)
         sel_out, bis, live = _chain_probe_all_jit(
             kv_all, km_all, pb.device.sel,
-            luts, lut_bases, bwords_all, n_lives,
+            luts, lut_bases, bwords_all, n_lives, key_lists,
             cfgs=level_cfgs,
         )
         return [
@@ -327,21 +331,25 @@ def _run_chain(
 
 
 @partial(jax.jit, static_argnames=("cfgs",))
-def _chain_probe_all_jit(kv_all, km_all, psel, luts, lut_bases, bwords_all, n_lives, cfgs):
+def _chain_probe_all_jit(kv_all, km_all, psel, luts, lut_bases, bwords_all,
+                         n_lives, key_lists, cfgs):
     """Every level's key canonicalization + unique probe + the combined
     selection AND (with its live count) in ONE program: XLA fuses the
-    per-level LUT gathers into a single pass over the probe stream, and no
-    per-level ok/live-count intermediates are materialized."""
+    per-level lookups into a single pass over the probe stream, and no
+    per-level ok/live-count intermediates are materialized. A level's
+    ``key_lists`` entry (None, or a small build's live key list: the
+    pytree's shape is static) picks its map."""
     sel = psel
     bis = []
-    for kv, km, lut, lb, bw, nl, (bcap, use_lut, kinds) in zip(
-        kv_all, km_all, luts, lut_bases, bwords_all, n_lives, cfgs
+    for kv, km, lut, lb, bw, nl, kl, (bcap, use_lut, kinds) in zip(
+        kv_all, km_all, luts, lut_bases, bwords_all, n_lives, key_lists, cfgs
     ):
         words, pvalid = core._canon_words_traced(kv, km, kinds)
         ok_base = psel & (pvalid if pvalid is not None else jnp.ones_like(psel))
-        bi, ok = core._probe_unique_ops(
-            words, ok_base, lut if use_lut else None, lb, bw, nl, bcap
-        )
+        with jax.named_scope("auron.probe.lookup"):
+            bi, ok = core._probe_unique_ops(
+                words, ok_base, lut if use_lut else None, lb, bw, nl, bcap, kl
+            )
         bis.append(bi)
         sel = sel & ok
     return sel, tuple(bis), jnp.sum(sel.astype(jnp.int32))

@@ -34,7 +34,9 @@ from auron_tpu.columnar.batch import (
     Batch,
     DeviceBatch,
     bucket_capacity,
+    compaction_index,
     device_concat,
+    lookup_compare_width,
 )
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exprs import Evaluator, ir
@@ -93,6 +95,13 @@ class PreparedBuild:
     # (no pair enumeration needed): exists_lut[key - lut_base] per probe row
     # replaces the binary search — and lets the build skip its sort.
     exists_lut: jnp.ndarray | None = None
+    # live key list of a SMALL unique build with one key word (the width
+    # is columnar.batch.lookup_compare_width's): (keys[K], rows[K]), the
+    # live keys (int32 offsets of lut_base where the LUT exists, else the
+    # key words) and their build rows, pads -1 in rows. The probe then
+    # COMPARES a probe row against the list instead of gathering from the
+    # LUT or searching the sorted words (_probe_unique_ops)
+    key_list: tuple[jnp.ndarray, jnp.ndarray] | None = None
     # multi-integer-key packing: when set, ``words`` is ONE packed uint64
     # word and probes must pack their key words with the same spec
     pack: "PackSpec | None" = None
@@ -347,6 +356,37 @@ def _scatter_luts_jit(w0, sel, kmin, size: int):
     return row_lut, counts > 0, has_dup
 
 
+@partial(jax.jit, static_argnames=("width",))
+def _key_list_jit(w0, sel, base, *, width: int):
+    """(keys[width], rows[width]) of a unique build's live keys: int32
+    offsets of ``base`` (the LUT's), or the key words where ``base`` is
+    None. A pad's row is -1, so whatever its key it can never match."""
+    idx, live = compaction_index(sel, width)
+    w = w0[idx]
+    keys = w if base is None else (w.view(jnp.int64) - base).astype(jnp.int32)
+    return jnp.where(live, keys, 0), jnp.where(live, idx, -1)
+
+
+def _small_key_list(w0, sel, n_live: int, base):
+    """The live key list of a unique one-word build, where the lookup
+    policy says comparing beats its map: the LUT at ``base``, or (None)
+    the binary search over the sorted words."""
+    width = lookup_compare_width(
+        n_live, None if base is not None else w0.shape[0])
+    if width is None:
+        return None
+    return _key_list_jit(w0, sel, base, width=width)
+
+
+def lookup_kind(build: PreparedBuild) -> str:
+    """How the unique probe maps a key to its build row: ``compare``
+    (the live key list), ``lut`` (one gather) or ``search`` (the sorted
+    words: a gather for every bit of the build's capacity)."""
+    if build.key_list is not None:
+        return "compare"
+    return "lut" if build.lut is not None else "search"
+
+
 def prepare_build(
     batches: list[Batch],
     key_exprs: list[ir.Expr],
@@ -405,6 +445,8 @@ def prepare_build(
                     batch=big, words=[words[0]], n_live=n_live,
                     matched=jnp.zeros(cap, bool), unique=True,
                     lut=row_lut, lut_base=kmin_h, pack=pack,
+                    key_list=_small_key_list(
+                        words[0], sel, n_live, jnp.int64(kmin_h)),
                 )
             if not need_pairs:
                 return PreparedBuild(
@@ -451,12 +493,18 @@ def prepare_build(
         uw, run_starts, n_uniq = _compress_runs_jit(
             tuple(sorted_words), jnp.int32(n_live))
         uniq_words = list(uw)
+    key_list = None
+    if unique and len(sorted_words) == 1 and not has_dict_key:
+        # live rows are a prefix of the clustered build
+        key_list = _small_key_list(
+            sorted_words[0], jnp.arange(cap) < n_live, n_live, None)
     return PreparedBuild(
         batch=clustered,
         words=sorted_words,
         n_live=n_live,
         matched=jnp.zeros(cap, bool),
         unique=unique,
+        key_list=key_list,
         pack=pack,
         uniq_words=uniq_words,
         run_starts=run_starts,
@@ -539,10 +587,26 @@ def _uniq_ranges_jit(uniq_words, run_starts, n_uniq, probe_words, ok):
     return jnp.where(hit, lo, 0), counts
 
 
+def _compare_rows(key, keys, rows):
+    """bi[r] = max over the list's slots j of (rows[j] where key[r] ==
+    keys[j], else -1): the key -> row map of a small unique build by
+    comparing. The list's axis is the MAJOR one, so the reduction is one
+    elementwise pass a slot over row vectors held in registers: no
+    cross-lane reduce and no [K, rows] array in memory."""
+    hit = key[None, :] == keys[:, None]
+    return jnp.max(jnp.where(hit, rows[:, None], jnp.int32(-1)), axis=0)
+
+
 def _probe_unique_ops(
-    probe_words, ok_base, lut, lut_base, bwords, n_live, bcap: int
+    probe_words, ok_base, lut, lut_base, bwords, n_live, bcap: int,
+    key_list=None,
 ):
-    """Traceable core of the unique-build probe (called inside jit)."""
+    """Traceable core of the unique-build probe (called inside jit):
+    (bi, ok) by the build's live key list where it carries one
+    (``key_list``), else by its LUT, else by binary search. The three
+    maps agree on ``ok`` and on ``bi`` wherever ``ok`` (the list and the
+    LUT on every row: an unmatched row's ``bi`` is 0, the search's its
+    insertion point; nothing reads either)."""
     if lut is not None:
         w = probe_words[0]
         size = lut.shape[0]
@@ -550,9 +614,15 @@ def _probe_unique_ops(
         # reinterpret bit-exactly, a value conversion would be UB-ish
         idx = w.view(jnp.int64) - lut_base
         in_range = (idx >= 0) & (idx < size)
-        bi = lut[jnp.clip(idx, 0, size - 1).astype(jnp.int32)]
+        slot = jnp.clip(idx, 0, size - 1).astype(jnp.int32)
+        # in_range stays in ok on both arms: a key clipped into the range
+        # must not alias the live key at its edge
+        bi = lut[slot] if key_list is None else _compare_rows(slot, *key_list)
         ok = ok_base & in_range & (bi >= 0)
         return jnp.clip(bi, 0, bcap - 1), ok
+    if key_list is not None:
+        bi = _compare_rows(probe_words[0], *key_list)
+        return jnp.clip(bi, 0, bcap - 1), ok_base & (bi >= 0)
     lo = binsearch._search(bwords, probe_words, n_live, binsearch._lex_less)
     bi = jnp.clip(lo, 0, bcap - 1)
     eq = lo < n_live
@@ -604,14 +674,17 @@ def key_kind(dtype) -> str:
 
 @partial(jax.jit, static_argnames=("bcap", "use_lut", "probe_outer", "key_kinds"))
 def _unique_probe_jit(
-    key_vals, key_masks, psel, lut, lut_base, bwords, n_live,
+    key_vals, key_masks, psel, lut, lut_base, bwords, n_live, key_list,
     bcap: int, use_lut: bool, probe_outer: bool, key_kinds: tuple,
 ):
-    """Canon + probe in ONE program (no gathers): (bi, ok, sel_out, live)."""
+    """Canon + probe in ONE program (no gathers): (bi, ok, sel_out, live).
+    ``key_list`` (None, or the build's two small arrays: the pytree's
+    shape is the static half) picks the compare map."""
     probe_words, pvalid = _canon_words_traced(key_vals, key_masks, key_kinds)
     ok_base = psel & (pvalid if pvalid is not None else jnp.ones_like(psel))
     bi, ok = _probe_unique_ops(
-        probe_words, ok_base, lut if use_lut else None, lut_base, bwords, n_live, bcap
+        probe_words, ok_base, lut if use_lut else None, lut_base, bwords,
+        n_live, bcap, key_list,
     )
     sel_out = psel if probe_outer else (psel & ok)
     return bi, ok, sel_out, jnp.sum(sel_out.astype(jnp.int32))
@@ -635,8 +708,6 @@ def _unique_compact_take_pred_jit(
     blocking live-count read). Rows beyond ``out_cap`` are truncated — the
     caller harvests the true live count asynchronously and repairs a
     too-small bucket by re-taking (exec/selectivity.py protocol)."""
-    from auron_tpu.columnar.batch import compaction_index
-
     idx, new_sel = compaction_index(sel, out_cap)
     c_pvals = tuple(v[idx] for v in probe_vals)
     c_pmasks = tuple(m[idx] & new_sel for m in probe_masks)
@@ -656,6 +727,7 @@ def _unique_join_emit_jit(
     lut_base,
     bwords,
     n_live,
+    key_list,
     build_vals,
     build_masks,
     bcap: int,
@@ -668,7 +740,8 @@ def _unique_join_emit_jit(
     probe_words, pvalid = _canon_words_traced(key_vals, key_masks, key_kinds)
     ok_base = psel & (pvalid if pvalid is not None else jnp.ones_like(psel))
     bi, ok = _probe_unique_ops(
-        probe_words, ok_base, lut if use_lut else None, lut_base, bwords, n_live, bcap
+        probe_words, ok_base, lut if use_lut else None, lut_base, bwords,
+        n_live, bcap, key_list,
     )
     out_vals = tuple(v[bi] for v in build_vals)
     out_masks = tuple(m[bi] & ok for m in build_masks)

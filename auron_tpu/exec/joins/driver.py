@@ -180,6 +180,7 @@ class EquiJoinDriver:
             lut_base=_jnp.int64(build.lut_base),
             words=tuple(build.words),
             n_live=_jnp.int32(build.n_live),
+            key_list=build.key_list,
             packed=build.pack is not None,
             pack_args=pack_args,
             exists_lut=build.exists_lut,
@@ -341,6 +342,7 @@ class EquiJoinDriver:
         proj, _, bcol_ids = self._unique_probe_cfg()
         import jax.numpy as _jnp
 
+        obs.note_join_lookup(core.lookup_kind(build), pb.capacity)
         # sparse-output compaction: densify BEFORE gathering build columns,
         # wherever take_bucket's rule says the gathers saved outweigh the
         # compaction
@@ -364,6 +366,7 @@ class EquiJoinDriver:
                 _jnp.int64(build.lut_base) if build.lut is not None else None,
                 build.words,
                 _jnp.int32(build.n_live),
+                build.key_list,
                 tuple(bb.col_values(c) for c in bcol_ids),
                 tuple(bb.col_validity(c) for c in bcol_ids),
                 bcap=bb.capacity,
@@ -470,7 +473,7 @@ class EquiJoinDriver:
                 pb.device.sel,
                 build.lut,
                 jnp.int64(build.lut_base) if build.lut is not None else None,
-                build.words, jnp.int32(build.n_live),
+                build.words, jnp.int32(build.n_live), build.key_list,
                 bcap=bb.capacity,
                 use_lut=build.lut is not None,
                 probe_outer=self.probe_outer,

@@ -13,9 +13,10 @@ Public surface:
   conf (R7). A span's ``cat`` is its layer (``LAYERS``), and every span
   is also a region ``auron:<layer>:<name>`` on the profiler's clock.
 - ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` /
-  ``note_agg_fold`` / ``note_join_take`` — the instrumentation facade
-  behind MetricNode.timer, the EngineCounters hooks, the task pump, the
-  partial aggregate and the unique-build join's output boundary.
+  ``note_agg_fold`` / ``note_join_take`` / ``note_join_lookup`` — the
+  instrumentation facade behind MetricNode.timer, the EngineCounters
+  hooks, the task pump, the partial aggregate and the unique-build
+  join's lookup and output boundary.
   Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``window_summary(t0_s, t1_s)`` — where the host's time went between
   two readings of ``time.perf_counter()``, by layer (obs/export.py).
@@ -149,6 +150,23 @@ def note_join_take(mode: str, rows: int, in_rows: int) -> None:
                 {"mode": mode, "rows": rows, "in_rows": in_rows})
 
 
+def note_join_lookup(kind: str, rows: int) -> None:
+    """One lookup of the unique-build join's probe, noted once a probed
+    batch a level where the takes are (the BHJ driver, for its fused stage
+    twin too; the star-join chain): ``kind`` is how the key -> build row
+    map was read, ``compare`` (against a small build's live key list),
+    ``lut`` (one gather a row) or ``search`` (the sorted words); ``rows``
+    the width the lookup ran at, the batch's capacity. A ``lookup`` event
+    of no duration and no layer, like ``take``; ``window_summary`` sums
+    ``rows`` by kind as ``join_lookup_rows``."""
+    if core._mode == MODE_OFF:
+        return
+    sp = _span_var.get()
+    tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
+    core.record("lookup", "join.unique", 0, tid, sid, 0,
+                {"kind": kind, "rows": rows})
+
+
 def note_sync(dur_ns: int, is_async: bool) -> None:
     """One device->host read observed by EngineCounters (blocking sync or
     async-window harvest), into the trace counters of the calling
@@ -187,5 +205,5 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
         return None
 
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
-    note_agg_fold = note_join_take = _noop  # noqa: F811
+    note_agg_fold = note_join_take = note_join_lookup = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

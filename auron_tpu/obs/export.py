@@ -120,7 +120,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     window, ``obs.note_agg_fold``); ``join_gather_rows`` sums the
     capacities the unique-build joins gathered their build columns at and
     ``join_takes`` counts those takes by mode (the ``take`` events,
-    ``obs.note_join_take``). ``plan_cache_hits`` and
+    ``obs.note_join_take``); ``join_lookup_rows`` sums by kind the widths
+    the unique-build probes looked their keys up at (the ``lookup``
+    events, ``obs.note_join_lookup``). ``plan_cache_hits`` and
     ``plan_cache_misses`` count the ``serve:plan`` spans that began in the
     window by their ``cache_hit`` argument. ``complete`` is False where
     a ring that may hold events of the window has wrapped, or left the
@@ -133,6 +135,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     sites: dict[str, list] = {}
     d2h = fold_rows = gather_rows = 0
     takes: dict[str, int] = {}
+    lookups: dict[str, int] = {}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
 
     def book(table: dict, key: str, dur_ns: int, own_ns: int) -> None:
@@ -156,6 +159,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             elif ev[2] == "take":
                 gather_rows += ev[7]["rows"]
                 takes[ev[7]["mode"]] = takes.get(ev[7]["mode"], 0) + 1
+            elif ev[2] == "lookup":
+                kind = ev[7]["kind"]
+                lookups[kind] = lookups.get(kind, 0) + ev[7]["rows"]
         regions = [
             (max(ts, lo), min(ts + dur, hi), layer, name, arg)
             for (ts, dur, _k, name, _t, _s, _p, arg, layer) in evs
@@ -175,6 +181,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
             "agg_fold_rows": fold_rows,
             "join_gather_rows": gather_rows, "join_takes": takes,
+            "join_lookup_rows": lookups,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
             "sync_sites": [[k, n, secs] for k, (n, secs) in ranked]}
 

@@ -230,9 +230,9 @@ def _stage_program_prep(dev: DeviceBatch, bases, his, strides, size, *,
 
 
 @_partial(jax.jit, static_argnames=("steps", "emit", "probe"))
-def _stage_program_probe(dev, lut, lut_base, bwords, n_live, pack_args,
-                         exists_lut, bvals, bmasks, *, steps: tuple,
-                         emit: str, probe: tuple):
+def _stage_program_probe(dev, lut, lut_base, bwords, n_live, key_list,
+                         pack_args, exists_lut, bvals, bmasks, *,
+                         steps: tuple, emit: str, probe: tuple):
     """Stage program variant for segments feeding a hash-join probe: in the
     SAME compiled program as the filter/project work, run the probe
     prologue — key evaluation, canonical-word packing, the unique/existence
@@ -241,19 +241,20 @@ def _stage_program_probe(dev, lut, lut_base, bwords, n_live, pack_args,
     chain (``_pack_probe_jit`` -> ``_unique_probe_jit`` ->
     ``_gather_build_jit`` / ``_unique_compact_take_pred_jit``) bit-for-bit.
 
-    Build-side state (``lut``/``bwords``/``n_live``/pack ranges/build
-    columns) arrives as DEVICE ARGUMENTS published at runtime by the join
-    exec (ProbePrepLink), so a fresh build — even a different one — reuses
-    the compiled program; ``probe`` is the static half:
-    (key_exprs, key_schema, key_kinds, use_lut, probe_outer, bcap, packed,
-    pcol_ids, take) with take one of ("probe",) | ("gather",) |
-    ("compact", out_cap) | ("exists",)."""
+    Build-side state (``lut``/``bwords``/``n_live``/a small build's live
+    ``key_list``/pack ranges/build columns) arrives as DEVICE ARGUMENTS
+    published at runtime by the join exec (ProbePrepLink), so a fresh build
+    — even a different one — reuses the compiled program; ``probe`` is the
+    static half: (key_exprs, key_schema, key_kinds, use_lut, cmp_width,
+    probe_outer, bcap, packed, pcol_ids, take) with cmp_width the key
+    list's width (0: the build carries none) and take one of ("probe",) |
+    ("gather",) | ("compact", out_cap) | ("exists",)."""
     from auron_tpu.columnar.batch import compaction_index
     from auron_tpu.exec.joins import core as jcore
 
     sel, values, validity, _ = _trace_steps(dev, steps)
-    (key_exprs, key_schema, kinds, use_lut, probe_outer, bcap, packed,
-     pcol_ids, take) = probe
+    (key_exprs, key_schema, kinds, use_lut, cmp_width, probe_outer, bcap,
+     packed, pcol_ids, take) = probe
     with jax.named_scope("auron.probe.pack"):
         b = Batch(key_schema, DeviceBatch(sel, values, validity),
                   (None,) * len(key_schema.fields))
@@ -289,7 +290,7 @@ def _stage_program_probe(dev, lut, lut_base, bwords, n_live, pack_args,
     with jax.named_scope("auron.probe.lookup"):
         bi, ok = jcore._probe_unique_ops(
             probe_words, ok_base, lut if use_lut else None, lut_base,
-            list(bwords), n_live, bcap,
+            list(bwords), n_live, bcap, key_list if cmp_width else None,
         )
         sel_out = sel if probe_outer else (sel & ok)
         live = jnp.sum(sel_out.astype(jnp.int32))
@@ -643,15 +644,18 @@ class FusedStageExec(ExecOperator):
                 ("probe",) if plan.cap is None else ("compact", plan.cap)
             )
         key_schema = self.out_stamp or self.children[0].schema
+        key_list = anchor["key_list"]
+        cmp_width = key_list[0].shape[0] if key_list is not None else 0
         cfg = (self._probe_keys, key_schema, self._probe_kinds,
-               anchor["use_lut"], self._probe_outer, anchor["bcap"],
-               anchor["packed"], self._probe_pcols, take_prog)
+               anchor["use_lut"], cmp_width, self._probe_outer,
+               anchor["bcap"], anchor["packed"], self._probe_pcols, take_prog)
         emit = "cols" if self.has_project else "sel"
         if _note_dispatch((self.steps, "probe", cfg), b.capacity):
             node.add("stage_compiles", 1)
         res = _stage_program_probe(
             b.device, anchor["lut"], anchor["lut_base"], anchor["words"],
-            anchor["n_live"], anchor["pack_args"], anchor["exists_lut"],
+            anchor["n_live"], key_list, anchor["pack_args"],
+            anchor["exists_lut"],
             anchor["bvals"], anchor["bmasks"],
             steps=self.steps, emit=emit, probe=cfg,
         )

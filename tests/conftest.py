@@ -52,6 +52,54 @@ def joins_stay_dense(monkeypatch):
     return scope
 
 
+@pytest.fixture()
+def lookups_compare(monkeypatch):
+    """A context manager under which the lookup policy
+    (``columnar.batch.lookup_compare_width``) finds comparing free: every
+    unique one-word build of up to the ladder's widest width carries its
+    live key list and is probed by comparing. XLA:CPU's own break-even is
+    "never", so the tests reach the compare map by patching the rule's
+    unit costs, not through an option of the program."""
+    import contextlib
+
+    from auron_tpu.columnar import batch as batch_mod
+
+    @contextlib.contextmanager
+    def scope():
+        with monkeypatch.context() as m:
+            m.setattr(batch_mod, "_lookup_costs", lambda: (1.0, 0.0, 0.0))
+            yield
+
+    return scope
+
+
+@pytest.fixture()
+def lookup_events():
+    """``lookup_events(run)`` -> (``run()``'s result, the ``lookup`` events
+    it noted under the flight recorder as (kind, rows), in call order)."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.obs import core
+
+    def capture(run):
+        saved = obs.mode()
+        obs.set_mode("recorder")
+        try:
+            t0 = time.perf_counter_ns()
+            out = run()
+            t1 = time.perf_counter_ns()
+            evs = sorted((ev for _r, evs in core.snapshot_events()
+                          for ev in evs
+                          if ev[2] == "lookup" and t0 <= ev[0] < t1),
+                         key=lambda ev: ev[0])
+        finally:
+            obs.set_mode(saved)
+        return out, [(ev[7]["kind"], ev[7]["rows"]) for ev in evs]
+
+    return capture
+
+
 @pytest.fixture(scope="module")
 def leak_canary():
     """Tier-1 leak canary (R11's dynamic twin): a suite that drives whole

@@ -166,6 +166,34 @@ def test_flagship_stage_program_compiles(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+@pytest.mark.parametrize("rows, width, use_lut", [
+    (1 << 20, 1024, True),      # the chain's batch, int32 offsets of the LUT
+    (1 << 22, 256, True),       # the bridge path's batch
+    (1 << 20, 1024, False),     # a build without a LUT: whole 64-bit words
+])
+def test_compare_probe_keeps_no_key_by_row_temporary(one_chip, rows, width,
+                                                     use_lut):
+    """The unique probe against a small build's live key list
+    (``core._compare_rows``): the reduction over the list's slots is fused
+    with the compare and select, so the program's temporaries stay a few
+    row vectors and never a ``[slots, rows]`` array."""
+    from auron_tpu.exec.joins import core
+
+    bcap = 32768
+    key_list = (_sds((width,), jnp.int32 if use_lut else jnp.uint64, one_chip),
+                _sds((width,), jnp.int32, one_chip))
+    compiled = _compile(
+        core._unique_probe_jit,
+        (_sds((rows,), jnp.int64, one_chip),),
+        (_sds((rows,), jnp.bool_, one_chip),), _sds((rows,), jnp.bool_, one_chip),
+        _sds((bcap,), jnp.int32, one_chip) if use_lut else None,
+        _sds((), jnp.int64, one_chip) if use_lut else None,
+        [_sds((bcap,), jnp.uint64, one_chip)], _sds((), jnp.int32, one_chip),
+        key_list, bcap=bcap, use_lut=use_lut, probe_outer=False,
+        key_kinds=("int",))
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows
+
+
 def test_exchange_steps_compile_for_four_chips(mesh4):
     """The ICI shuffle as ONE program across four chips: both mesh
     programs must partition, and the collective must really be there."""

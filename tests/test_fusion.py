@@ -452,6 +452,65 @@ def test_probe_prologue_bit_identity(join_type, jump):
         assert ctx.metrics.total("sel_mispredicts") > 0
 
 
+@pytest.mark.parametrize("join_type", ["inner", "left", "left_semi"])
+@pytest.mark.parametrize("n_live, width", [(64, 64), (100, 256), (1024, 1024),
+                                           (1025, 0)])
+def test_probe_stage_compares_against_a_small_build(
+        monkeypatch, lookups_compare, join_type, n_live, width):
+    """Under the patched lookup rule the fused probe stage carries a small
+    build's live key list (its width a static of the program, 0 one over
+    the ladder: the LUT as ever) and hands the driver the same (bi, ok,
+    sel_out, live) batch for batch as the stage on the LUT alone; the
+    rows are the eager chain's. NULL keys and keys outside the build's
+    range never join."""
+    from auron_tpu.exec.base import ExecutionContext
+
+    dim = pd.DataFrame({"id": np.arange(1, n_live + 1, dtype=np.int64) * 2 - 40,
+                        "b": np.arange(1, n_live + 1) * 2.0})
+    dim_b = [Batch.from_pandas(dim)]
+    jitted = fusion._stage_program_probe
+    calls = []
+
+    def spy(*args, **kw):
+        res = jitted(*args, **kw)
+        calls.append((kw["probe"], res[-1][:4]))
+        return res
+
+    monkeypatch.setattr(fusion, "_stage_program_probe", spy)
+
+    def build():
+        pb = _probe_frame(3)
+        scan = MemoryScanExec([pb], pb[0].schema)
+        flt = FilterExec(scan, [BinaryOp(
+            "gt", Column(1, "v"), Literal(-10.0, T.FLOAT64))])
+        return BroadcastHashJoinExec(
+            flt, MemoryScanExec([list(dim_b)], dim_b[0].schema),
+            [Column(0, "k")], [Column(0, "id")], join_type,
+            build_side="right")
+
+    def staged():
+        calls.clear()
+        ctx = ExecutionContext()
+        out = list(fuse_exec_tree(build(), ON).execute(0, ctx))
+        assert ctx.metrics.total("fused_batches") == 6
+        return pd.concat([b.to_pandas() for b in out], ignore_index=True), \
+            list(calls)
+
+    eager = build().collect().to_pandas()
+    plain, plain_calls = staged()
+    with lookups_compare():
+        fused, fused_calls = staged()
+    _assert_rows_equal(eager, fused)
+    _assert_rows_equal(plain, fused)
+    assert len(fused_calls) == len(plain_calls) == 6
+    # the static half: (..., use_lut, cmp_width, ...)
+    assert {probe[3:5] for probe, _ in plain_calls} == {(True, 0)}
+    assert {probe[3:5] for probe, _ in fused_calls} == {(True, width)}
+    for (_, got), (_, want) in zip(fused_calls, plain_calls):
+        for g, w in zip(got, want):
+            assert (np.asarray(g) == np.asarray(w)).all()
+
+
 @pytest.fixture(scope="module")
 def dated_star_batches():
     """The specification-typed tiny star (tests/test_sql_decimal_serve.py:

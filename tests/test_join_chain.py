@@ -496,3 +496,76 @@ def test_chain_bhj_and_fused_stage_take_the_same_modes(live, want):
         "chain": want, "eager": want, "stage": want}
     n_rows = len(pd.concat(frames).merge(d1, left_on="k", right_on="id"))
     assert [len(rows) for rows, _, _ in got.values()] == [n_rows] * 3
+
+
+# ---------------------------------------------------------------------------
+# the chain's lookups: a small build's live key list beside a level on its LUT
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n1, n2, kinds", [
+    (40, 1025, ["compare", "lut"]),      # the control: one level over the ladder
+    (64, 256, ["compare", "compare"]),
+    (65, 1024, ["compare", "compare"]),
+])
+def test_chain_probes_small_builds_by_comparing(
+        monkeypatch, lookups_compare, lookup_events, n1, n2, kinds):
+    """The fused chain under the patched lookup rule: each level picks its
+    map from its own build's live key count, the probe program returns
+    the same (sel, bis, live) batch for batch as on the LUTs alone, and
+    the rows are the oracle's; NULL and dangling keys never join."""
+    rng = np.random.default_rng(n1 + n2)
+    n = 900
+    fact = pd.DataFrame({
+        "k0": pd.array(rng.integers(-3, n1 + 5, n), dtype="Int64"),
+        "k1": pd.array(rng.integers(-3, n2 + 5, n) * 5 - 100, dtype="Int64"),
+        "amt": rng.integers(0, 1000, n)})
+    fact.loc[rng.random(n) < 0.05, "k0"] = None
+    fact.loc[rng.random(n) < 0.05, "k1"] = None
+    d1 = pd.DataFrame({"id1": np.arange(n1), "d1v": np.arange(n1) * 10})
+    d2 = pd.DataFrame({"id2": np.arange(n2) * 5 - 100, "d2v": np.arange(n2) * 7})
+
+    probed = []
+    jitted = chain_mod._chain_probe_all_jit
+    monkeypatch.setattr(
+        chain_mod, "_chain_probe_all_jit",
+        lambda *a, **k: probed.append(jitted(*a, **k)) or probed[-1])
+
+    def run():
+        probed.clear()
+        rows = _collect_sorted(_star(fact, [d1, d2], [0, 1]))
+        return rows, list(probed)
+
+    (plain_rows, plain_out), plain_evs = lookup_events(run)
+    with lookups_compare():
+        (rows, out), evs = lookup_events(run)
+    n_batches = -(-n // 37)
+    assert len(out) == len(plain_out) == n_batches
+    for (sel, bis, live), (p_sel, p_bis, p_live) in zip(out, plain_out):
+        assert (np.asarray(sel) == np.asarray(p_sel)).all()
+        assert int(live) == int(p_live)
+        for bi, p_bi in zip(bis, p_bis):
+            assert (np.asarray(bi) == np.asarray(p_bi)).all()
+    assert plain_evs == [("lut", 128)] * 2 * n_batches    # a batch's capacity
+    assert evs == [(kind, 128) for kind in kinds] * n_batches
+    want = _oracle(fact.dropna(), [d1, d2], ["k0", "k1"])
+    want = want.astype(rows.dtypes.to_dict())
+    want = want.sort_values(list(want.columns)).reset_index(drop=True)
+    assert len(rows) == len(want) > 100
+    pd.testing.assert_frame_equal(rows, plain_rows)
+    pd.testing.assert_frame_equal(rows, want, check_dtype=False)
+
+
+def test_chain_probe_program_names_its_lookups(monkeypatch):
+    """The lowered text of the chain's probe program holds the scope the
+    fused stage names its lookup by (metadata only)."""
+    fact, d1, d2 = _fact_dims()
+    calls = []
+    jitted = chain_mod._chain_probe_all_jit
+    monkeypatch.setattr(
+        chain_mod, "_chain_probe_all_jit",
+        lambda *a, **k: calls.append((a, k)) or jitted(*a, **k))
+    _star(fact, [d1, d2], [0, 1]).collect_pydict()
+    args, kw = calls[0]
+    text = jitted.lower(*args, **kw).as_text(debug_info=True)
+    assert text.count("auron.probe.lookup") >= 2

@@ -48,9 +48,29 @@ def _rows(df, cols):
 
 @pytest.mark.parametrize("seed", range(12))
 def test_join_fuzz(seed):
+    _fuzz(seed)
+
+
+@pytest.mark.parametrize("seed", range(12, 24))
+def test_join_fuzz_through_the_compare_map(seed, lookups_compare,
+                                           lookup_events):
+    """The same matrix over tables of distinct keys (every build unique)
+    with the lookup rule patched: each unique probe reads its build's
+    live key list, never a LUT or the sorted words."""
+    with lookups_compare():
+        probed, evs = lookup_events(lambda: _fuzz(seed, distinct=True))
+    assert {kind for kind, _ in evs} == ({"compare"} if probed else set())
+
+
+def _fuzz(seed, distinct=False) -> bool:
+    """One random join against its oracle. Returns whether both sides held
+    a row with a key (so that a unique build was probed)."""
     rng = np.random.default_rng(seed + 100)
     ldf = _table(rng, int(rng.integers(0, 120)), int(rng.integers(1, 25)), 0.1)
     rdf = _table(rng, int(rng.integers(0, 120)), int(rng.integers(1, 25)), 0.1)
+    if distinct:
+        ldf = ldf[~ldf.k.duplicated() | ldf.k.isna()].reset_index(drop=True)
+        rdf = rdf[~rdf.k.duplicated() | rdf.k.isna()].reset_index(drop=True)
     rdf = rdf.rename(columns={"k": "k2", "p": "q"})
     jt = str(rng.choice(["inner", "left", "right", "full", "left_semi",
                          "left_anti", "existence"]))
@@ -100,3 +120,4 @@ def test_join_fuzz(seed):
         for _, r in got.iterrows():
             expect = (not pd.isna(r.k)) and int(r.k) in rkeys
             assert bool(r["exists"]) == expect
+    return len(lnn) > 0 and len(rnn) > 0

@@ -472,3 +472,169 @@ def test_the_seed_reads_one_scalar_and_no_mask(live_from, want_modes):
     assert len({site for site, _ in ws["join_reads"]}) == 1
 
 
+# ---------------------------------------------------------------------------
+# the unique probe's lookup: a small build's live key list (compare) against
+# its LUT and the binary search over its sorted words
+# ---------------------------------------------------------------------------
+
+
+def _dim_keys(n_live, base):
+    """``n_live`` distinct ascending keys from ``base`` up that skip 0 (the
+    value a pad slot of the 64-bit list holds) and leave gaps."""
+    keys = base + np.arange(n_live + 8, dtype=np.int64) * 3
+    return keys[keys != 0][:n_live]
+
+
+def _probe_keys(keys, rng, n=2048):
+    """Probe keys around a build's: hits, gaps between them, keys below
+    the LUT's base and above its range (far enough to wrap an int32), a 0
+    (a pad slot's value), and NULLs."""
+    lo, hi = int(keys.min()), int(keys.max())
+    k = rng.choice(keys, n)
+    k[1::7] = rng.integers(lo - 50, hi + 50, len(k[1::7]))
+    k[2::31] = lo - 1 - rng.integers(0, 1 << 40, len(k[2::31]))
+    k[3::31] = hi + 1 + rng.integers(0, 1 << 40, len(k[3::31]))
+    k[4::31] = lo + (1 << 32)              # aliases lo in the low 32 bits
+    k[5::31] = 0
+    k[6::31] = [lo, hi] * (len(k[6::31]) // 2) + [lo] * (len(k[6::31]) % 2)
+    valid = rng.random(n) > 0.05
+    return k, valid
+
+
+@pytest.mark.parametrize("probe_outer", [False, True], ids=["inner", "left"])
+@pytest.mark.parametrize("n_live, base, width", [
+    (1, 7, 64), (63, -90, 64), (64, 1, 64), (65, -1000, 256),
+    (256, 1 << 33, 256), (257, -5, 1024), (1024, -2000, 1024),
+    (1025, 1, None),        # one over the ladder: the build keeps its LUT
+])
+def test_compare_map_is_the_twin_of_the_lut_and_the_search(
+        lookups_compare, n_live, base, width, probe_outer):
+    """On one build and one probe batch the three key -> row maps of the
+    unique probe give the same (bi, ok): the compare map and the LUT bit
+    for bit, the search wherever a row matched (where none did its bi is
+    the insertion point, read by nobody)."""
+    import jax.numpy as jnp
+
+    from auron_tpu.exec.joins import core
+
+    keys = _dim_keys(n_live, base)
+    # in the order of the key WORDS (negative keys after the others), so
+    # that the build's words also serve the search
+    keys = np.concatenate([keys[keys >= 0], keys[keys < 0]])
+    dim = Batch.from_pandas(pd.DataFrame({"id": keys, "d": keys * 3}))
+    with lookups_compare():
+        build = core.prepare_build([dim], [col(0)], dim.schema)
+    assert build.unique and build.lut is not None and build.n_live == n_live
+    if width is None:
+        assert build.key_list is None and core.lookup_kind(build) == "lut"
+        return
+    assert core.lookup_kind(build) == "compare"
+    assert [a.shape for a in build.key_list] == [(width,), (width,)]
+    rows = np.asarray(build.key_list[1])
+    assert (rows[:n_live] == np.arange(n_live)).all() and (rows[n_live:] == -1).all()
+    list64 = core._key_list_jit(
+        build.words[0], dim.device.sel, None, width=width)
+    k, valid = _probe_keys(keys, np.random.default_rng(n_live))
+    psel = np.ones(len(k), bool)
+    psel[::13] = False
+
+    def probe(use_lut, key_list):
+        out = core._unique_probe_jit(
+            (jnp.asarray(k),), (jnp.asarray(valid),), jnp.asarray(psel),
+            build.lut if use_lut else None,
+            jnp.int64(build.lut_base) if use_lut else None,
+            build.words, jnp.int32(n_live), key_list,
+            bcap=dim.capacity, use_lut=use_lut, probe_outer=probe_outer,
+            key_kinds=("int",))
+        return [np.asarray(x) for x in out]
+
+    lut, cmp32 = probe(True, None), probe(True, build.key_list)
+    cmp64, search = probe(False, list64), probe(False, None)
+    want_ok = psel & valid & np.isin(k, keys)
+    assert want_ok.sum() > 100 and (lut[1] == want_ok).all()
+    for got in (cmp32, cmp64, search):
+        assert (got[1] == want_ok).all()                      # ok
+        assert (got[0][want_ok] == lut[0][want_ok]).all()     # bi
+        assert (got[2] == (psel if probe_outer else want_ok)).all()
+        assert got[3] == lut[3]                               # live count
+    assert (cmp32[0] == lut[0]).all()       # unmatched rows too: bit for bit
+    assert (keys[lut[0][want_ok]] == k[want_ok]).all()
+
+
+def test_compare_map_of_a_build_without_a_lut_compares_whole_words(
+        lookups_compare):
+    """Keys too far apart for a LUT: the build is sorted, its list holds
+    the 64-bit words (negative keys as their two's complement) and the
+    rows of the CLUSTERED build, dead and NULL-keyed rows last."""
+    from auron_tpu.exec.joins import core
+
+    keys = np.array([5, -(1 << 45), 1 << 50, -3, 0, 77], dtype=np.int64)
+    df = pd.DataFrame({"id": pd.array(list(keys) + [None], dtype="Int64"),
+                       "d": np.arange(7)})
+    dim = Batch.from_pandas(df)
+    with lookups_compare():
+        build = core.prepare_build([dim], [col(0)], dim.schema)
+        plain = core.prepare_build([dim], [col(0)], dim.schema)
+    assert build.unique and build.lut is None and build.n_live == 6
+    assert core.lookup_kind(build) == "compare"
+    words, rows = (np.asarray(a) for a in build.key_list)
+    assert words.dtype == np.uint64 and words.shape == (64,)
+    assert sorted(words[:6].view(np.int64)) == sorted(keys)
+    assert (rows[:6] == np.arange(6)).all() and (rows[6:] == -1).all()
+    assert (np.asarray(plain.words[0]) == np.asarray(build.words[0])).all()
+
+
+def _small_dim_join(jt, n_live, chunk=512):
+    rng = np.random.default_rng(n_live)
+    keys = _dim_keys(n_live, -20)
+    k, valid = _probe_keys(keys, rng, n=4 * chunk)
+    probe = pd.DataFrame({
+        "k": pd.array([int(x) if ok else None for x, ok in zip(k, valid)],
+                      dtype="Int64"),
+        "v": np.arange(len(k), dtype=np.int64)})
+    dim = pd.DataFrame({"id": keys, "d": keys * 3})
+
+    def run():
+        return BroadcastHashJoinExec(
+            _mk(probe, chunk), _mk(dim), [col(0)], [col(0)], jt,
+            build_side="right").collect().to_pandas()
+
+    want = probe.merge(dim, left_on="k", right_on="id",
+                       how="left" if jt == LEFT else "inner")
+    return run, want
+
+
+@pytest.mark.parametrize("jt", [INNER, LEFT])
+@pytest.mark.parametrize("n_live, kind", [
+    (64, "compare"), (65, "compare"), (1024, "compare"), (1025, "lut")])
+def test_driver_probes_a_small_build_by_comparing(
+        lookups_compare, lookup_events, jt, n_live, kind):
+    """The BHJ driver over a unique build: under the patched rule a build
+    of up to 1,024 live keys is probed by comparing, one over keeps its
+    LUT, and either gives the rows the LUT alone gives and the oracle's;
+    one ``lookup`` event a probed batch at the batch's capacity."""
+    run, want = _small_dim_join(jt, n_live)
+    plain, plain_evs = lookup_events(run)
+    with lookups_compare():
+        got, evs = lookup_events(run)
+    assert plain_evs == [("lut", 512)] * 4     # XLA:CPU's own rule: never
+    assert evs == [(kind, 512)] * 4
+    cols = ["k", "v", "id", "d"]
+    assert _row_multiset(got, cols) == _row_multiset(plain, cols)
+    assert _row_multiset(got, cols) == _row_multiset(want, cols)
+    assert len(got) == len(want) > 500
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_empty_build_under_the_compare_rule(lookups_compare, lookup_events,
+                                            kind):
+    """A build with no live key is not unique: no list, no lookup, and the
+    joins' rows as ever."""
+    empty = RDF.iloc[:0]
+    with lookups_compare():
+        inner, evs = lookup_events(
+            lambda: _join(kind, LDF, empty, INNER, [0], [0]))
+        left = _join(kind, LDF, empty, LEFT, [0], [0])
+    assert len(inner) == 0 and len(left) == len(LDF)
+    assert left["rv"].isna().all()
+    assert evs == []

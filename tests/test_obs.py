@@ -176,6 +176,46 @@ def test_a_window_of_three_hundred_task_threads_stays_complete():
     assert sum(len(r.buf) > r.idx for r in finished) <= 1
 
 
+def test_window_summary_sums_the_joins_lookups_by_kind():
+    """``note_join_lookup`` is an event of no duration and no layer:
+    ``window_summary`` sums its rows by kind as ``join_lookup_rows``
+    (events that began in the window), books it under no layer, and a
+    window with no such event reads an empty table: the benchmark's
+    reader then reports 0 rows looked up by a gather, and None only on a
+    program whose summary lacks the key."""
+    import time
+
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        obs.note_join_lookup("lut", 4194304)              # before the window
+        t0 = time.perf_counter()
+        obs.note_join_lookup("compare", 1048576)
+        obs.note_join_lookup("lut", 1048576)
+        obs.note_join_lookup("compare", 4194304)
+        obs.note_join_lookup("search", 8192)
+        obs.note_join_lookup("lut", 1048576)
+        obs.note_join_take("dense", 1048576, 1048576)     # another event
+        t1 = time.perf_counter()
+        obs.note_join_lookup("lut", 128)                  # after it
+        ws = obs.window_summary(t0, t1)
+        t2 = time.perf_counter()
+        obs.note_join_take("dense", 128, 128)
+        quiet = obs.window_summary(t2, time.perf_counter())
+    finally:
+        obs.set_mode(saved)
+    assert ws["join_lookup_rows"] == {
+        "compare": 1048576 + 4194304, "lut": 2 * 1048576, "search": 8192}
+    assert ws["join_gather_rows"] == 1048576      # takes are not lookups
+    assert "lookup" not in ws["layers"] and not ws["spans"]
+    assert quiet["join_lookup_rows"] == {}
+    obs.set_mode("off")
+    try:
+        obs.note_join_lookup("lut", 128)          # mode off: one flag test
+    finally:
+        obs.set_mode(saved)
+
+
 def test_recorder_mode_rings_only_no_per_event_lock():
     """recorder vs trace distinction: recorder records ring events and
     publishes per-task summaries, but never takes the per-event Trace

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from auron_tpu.columnar import batch as batch_mod
-from auron_tpu.columnar.batch import compaction_bucket
+from auron_tpu.columnar.batch import compaction_bucket, lookup_compare_width
 from auron_tpu.exec import selectivity as sel_mod
 from auron_tpu.exec.metrics import MetricNode
 from auron_tpu.exec.selectivity import (
@@ -61,6 +61,54 @@ def test_compaction_bucket_rule_over_shapes(monkeypatch, chip, capacity,
                                             n_live, dense, taken, want):
     monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
     assert compaction_bucket(n_live, capacity, dense, taken) == want
+
+
+@pytest.mark.parametrize("chip, n_live, search_capacity, want", [
+    # the chip, a build with a LUT: int32 compares against ONE gathered
+    # element a row (PERF.md section 5, unit costs); the ladder's three
+    # widths, and nothing over it
+    (True, 1, None, 64), (True, 18, None, 64), (True, 64, None, 64),
+    (True, 65, None, 256), (True, 180, None, 256), (True, 256, None, 256),
+    (True, 257, None, 1024), (True, 1024, None, 1024),
+    (True, 1025, None, None),
+    (True, 6000, None, None),               # text 3's date level: the LUT
+    # the chip, sorted words: 64-bit compares against a gathered element
+    # for every bit of the build's capacity
+    (True, 30, 131072, 64), (True, 1024, 131072, 1024),
+    (True, 1025, 131072, None),
+    # XLA:CPU gathers cheaply and compares dearly: a LUT always stays, a
+    # search gives way to the least width over a build of 2^23 rows
+    (False, 1, None, None), (False, 18, None, None), (False, 64, None, None),
+    (False, 30, 131072, None), (False, 30, 4194304, None),
+    (False, 30, 8388608, 64), (False, 65, 8388608, None),
+])
+def test_lookup_rule_breaks_even_by_unit_costs(monkeypatch, chip, n_live,
+                                               search_capacity, want):
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
+    assert lookup_compare_width(n_live, search_capacity) == want
+
+
+@pytest.mark.parametrize("costs, n_live, search_capacity, want", [
+    # compare iff width x (a compare-select) < gathers x (a gathered element)
+    ((8.0, 0.125, 0.25), 60, None, None),        # 64 x 0.125 == 8: no gain
+    ((8.0, 0.124, 0.25), 60, None, 64),
+    ((8.0, 0.01, 0.25), 200, None, 256),
+    ((8.0, 0.04, 0.25), 200, None, None),        # 256 x 0.04 > 8
+    ((8.0, 0.01, 0.25), 60, 1024, 64),           # 64 x 0.25 < 10 x 8
+    ((8.0, 0.01, 0.25), 200, 128, None),         # 256 x 0.25 > 7 x 8
+    ((8.0, 0.01, 0.25), 200, 256, None),         # == 8 x 8: no gain
+    ((8.0, 0.01, 0.25), 200, 257, 256),          # nine passes
+])
+def test_lookup_rule_is_one_inequality_over_the_costs(monkeypatch, costs,
+                                                      n_live, search_capacity,
+                                                      want):
+    """The rule itself, on made-up unit costs; and it reads no option."""
+    from auron_tpu.utils.config import generate_doc
+
+    monkeypatch.setattr(batch_mod, "_lookup_costs", lambda: costs)
+    assert lookup_compare_width(n_live, search_capacity) == want
+    assert not [line for line in generate_doc().splitlines()
+                if line.startswith("| `") and "lookup" in line.split("|")[1]]
 
 
 def test_predictor_seeds_then_predicts_and_grows_immediately():
