@@ -11,6 +11,7 @@ tests call ``run_cell`` with the devices they have.
 from __future__ import annotations
 
 import contextlib
+import gc
 import importlib.util
 import json
 import os
@@ -155,6 +156,43 @@ class Tracer:
             shutil.rmtree(self.dir, ignore_errors=True)
 
 
+class Gen2Clock:
+    """The collector's generation-2 passes while it is entered, each with
+    its pause: a full collection holds every thread of the program for about
+    a tenth of a second (PERF.md section 2), one slow query of a window."""
+
+    def __init__(self) -> None:
+        self.pauses: list = []
+        self._t0 = None
+
+    def _note(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.pauses.append(time.perf_counter() - self._t0)
+            self._t0 = None
+
+    def __enter__(self) -> "Gen2Clock":
+        gc.callbacks.append(self._note)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        gc.callbacks.remove(self._note)
+
+
+def latency_summary(records: list) -> dict:
+    """Count, quartiles (``statistics.quantiles``, as the bound's spread is
+    taken) and maximum of the window's latencies."""
+    lat = sorted(r["t1"] - r["t0"] for r in records)
+    if len(lat) < 2:            # no quartiles of fewer than two
+        only = lat[0] if lat else None
+        return {"n": len(lat), "median": only, "max": only}
+    q1, median, q3 = statistics.quantiles(lat, n=4)
+    return {"n": len(lat), "q1": q1, "median": median, "q3": q3, "max": lat[-1]}
+
+
 def memory_peak_bytes(devs) -> int:
     """The peak on the fullest chip, as the backend reports it."""
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devs]
@@ -187,7 +225,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
     batches0 = counters.batches if counters else 0
     setup_s = time.perf_counter() - t_start
     try:
-        records, window_s = driver.window(state, seconds, tracer)
+        with Gen2Clock() as gen2:
+            records, window_s = driver.window(state, seconds, tracer)
     finally:
         tracer.stop()
     window_compile = clock.take()
@@ -207,10 +246,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devs,
         window={"seconds": window_s, "requests": len(records),
                 "compiles": window_compile, "host_syncs": syncs,
                 "batches": batches},
-        latency_s={"n": len(records),
-                   "median": statistics.median(r["t1"] - r["t0"] for r in records)
-                   if records else None,
-                   "max": max((r["t1"] - r["t0"] for r in records), default=None)})
+        latency_s=latency_summary(records),
+        gc2={"count": len(gen2.pauses), "seconds": sum(gen2.pauses)},
+        hash_seed=os.environ.get("PYTHONHASHSEED", "random"))
 
     facts = {"records": records, "window_s": window_s, "trace": reduction,
              "traced": [tracer.t_start, tracer.t_stop],

@@ -9,7 +9,9 @@
   broken underneath, once for each fault a cell can have, and ``correct`` has
   to come out false. The cells hold no state that a step returns and, on one
   chip, no exchange between chips, so those two faults do not apply; the file
-  shuffle stands in for the exchange.
+  shuffle stands in for the exchange. The faults planted in what only the
+  bridge's driver calls (``put_resource``, the block provider, ``call_native``)
+  run over the cells whose configuration's ``driver`` is ``batch_class``.
 """
 
 import copy
@@ -26,6 +28,10 @@ from benchmark import compare, control, harness
 
 with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as _f:
     CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+#: the cells driven through ``bridge.api``: the faults injected there reach
+#: these alone (a serving cell's own faults: ``test_sql_streams.py``)
+BRIDGE_CELLS = [c for c in CELLS
+                if harness.load_cell(c)["config_file"]["driver"] == "batch_class"]
 SEED = 2147483659
 
 
@@ -72,7 +78,7 @@ def test_sound_run_is_correct(name):
 # ---- the timed path broken under bridge.api --------------------------------
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS)     # the serving path collects through it too
 def test_answer_altered_where_it_is_produced(name, monkeypatch):
     from auron_tpu.bridge import api
 
@@ -99,7 +105,7 @@ def test_answer_altered_where_it_is_produced(name, monkeypatch):
     assert out["compared"]["rows_wrong"]["value"] > 0
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", BRIDGE_CELLS)
 def test_half_of_the_rows_left_out(name, monkeypatch):
     from auron_tpu.bridge import api
 
@@ -114,7 +120,7 @@ def test_half_of_the_rows_left_out(name, monkeypatch):
     assert run(tiny(name, sf=0.2))["correct"] is False
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", BRIDGE_CELLS)
 def test_shuffle_of_one_map_task_left_out(name, monkeypatch):
     from auron_tpu.exec.shuffle import reader
 
@@ -124,7 +130,7 @@ def test_shuffle_of_one_map_task_left_out(name, monkeypatch):
     assert run(tiny(name, sf=0.2))["correct"] is False
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", BRIDGE_CELLS)
 def test_failing_query_counts_as_failed_and_not_correct(name, monkeypatch):
     from auron_tpu.bridge import api
 

@@ -44,6 +44,14 @@ def test_every_cell_resolves_to_its_files(spec):
             assert limit in cell["config_file"]["limits"]
 
 
+def test_the_bound_and_the_window_are_those_pr_33_sized(spec):
+    """PERF.md section 2 has the rule and the spreads that set them; only a
+    ``benchmark`` PR that measures every cell again may move them."""
+    bounds = {m["name"]: m["bound"] for m in spec["end_to_end"]}
+    assert bounds == {"batch_query_s": 0.08, "setup_s": 0.25}
+    assert spec["run_seconds"] == 40
+
+
 def test_every_config_has_a_file_of_its_own_and_is_used(spec):
     files = [c["file"] for c in spec["configs"]]
     assert len(set(files)) == len(files)
