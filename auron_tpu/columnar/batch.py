@@ -639,13 +639,19 @@ def _decimal_unscaled(arr: pa.Array, scale: int):
 
 
 def _decimal_from_unscaled(vals: np.ndarray, mask: np.ndarray, dtype: T.DataType) -> pa.Array:
-    pydecs = []
-    import decimal as pydec
-
-    q = pydec.Decimal(1).scaleb(-dtype.scale)
-    for v, m in zip(vals.tolist(), mask.tolist()):
-        pydecs.append(pydec.Decimal(v).scaleb(-dtype.scale).quantize(q) if m else None)
-    return pa.array(pydecs, type=pa.decimal128(dtype.precision, dtype.scale))
+    """``decimal128(p, s)`` of unscaled int64 values, written as the
+    Decimal128 buffer itself (``_decimal_unscaled``'s inverse): a value is
+    two little-endian 64-bit words, the high one the sign extension of the
+    low one. One pass, no Python object a cell: the shuffle writer hands a
+    partial aggregate's DECIMAL sums through here by the 10^5 rows."""
+    n = len(vals)
+    words = np.empty((n, 2), dtype="<i8")
+    words[:, 0] = np.where(mask, vals, 0)
+    words[:, 1] = words[:, 0] >> 63
+    valid = np.packbits(np.asarray(mask, dtype=bool), bitorder="little")
+    return pa.Array.from_buffers(
+        pa.decimal128(dtype.precision, dtype.scale), n,
+        [pa.py_buffer(valid), pa.py_buffer(words)], null_count=int(n - mask.sum()))
 
 
 def host_arrow_cols(cvs) -> list[pa.Array]:

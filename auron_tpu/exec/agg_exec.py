@@ -254,7 +254,7 @@ class HashAggExec(ExecOperator):
             and self._fingerprint_on(conf)
         )
         fp_bits = conf.get(AGG_INCREMENTAL_FP_BITS) if fingerprint else 64
-        if hostsort.use_host_sort(conf):
+        if hostsort.use_host_sort(conf, rows=int(sel.shape[0])):
             return (True, "lax", fingerprint, fp_bits)
         if fingerprint:
             # fixed 3-operand (dead, fp, iota) sort: lax.sort is the right
@@ -410,6 +410,7 @@ class HashAggExec(ExecOperator):
         def drain_dense_into_table():
             sb, g = dense.state_batch_and_count()
             if sb is not None:
+                sb._groups = g
                 mm.acquire(table, batch_nbytes(sb))
                 table.add(sb, g)
 
@@ -419,6 +420,7 @@ class HashAggExec(ExecOperator):
             else stage it into the table and merge when due."""
             nonlocal skipping
             if skipping:
+                self._note_emit(inter)
                 yield inter
                 return
             if (
@@ -430,7 +432,10 @@ class HashAggExec(ExecOperator):
                 # high cardinality: stop accumulating, stream through
                 ctx.metrics.add("partial_agg_skipped", 1)
                 skipping = True
-                yield from table.drain()
+                for held in table.drain():
+                    self._note_emit(held)
+                    yield held
+                self._note_emit(inter)
                 yield inter
                 return
             mm.acquire(table, batch_nbytes(inter))
@@ -448,6 +453,7 @@ class HashAggExec(ExecOperator):
             # generic (sort-segmentation) path for ONE batch; yields
             # pass-through output in partial-agg skipping mode
             nonlocal pending_g, pending_proxy, seen_rows, seen_groups
+            in_capacity = b.capacity
             if self.mode == PARTIAL:
                 # sync the live count FIRST: sparse batches (post-filter/
                 # join output still at input capacity) are compacted
@@ -481,7 +487,7 @@ class HashAggExec(ExecOperator):
                     # merge concat scales with GROUPS, not input
                     # capacity (low-cardinality aggs were paying a
                     # full-capacity concat per staged batch)
-                    table.shrink_last(bucket_capacity(max(gp, 1)))
+                    table.shrink_last(gp)
                     pending_g = None
                 if n == 0:
                     return
@@ -489,6 +495,8 @@ class HashAggExec(ExecOperator):
                     from auron_tpu.columnar.batch import compact_batch
 
                     b = compact_batch(b, bucket_capacity(n))
+                obs.note_agg_fold(b.capacity, in_capacity, path="sort",
+                                  mode=self.mode, live=n)
                 with ctx.metrics.timer("elapsed_compute"):
                     inter = self._to_intermediate(b, ctx)
                 pending_g = (
@@ -500,6 +508,8 @@ class HashAggExec(ExecOperator):
                 # exact count settles one batch later via pending_g
             else:
                 # merge modes never compact: one combined transfer
+                obs.note_agg_fold(b.capacity, in_capacity, path="sort",
+                                  mode=self.mode)
                 with ctx.metrics.timer("elapsed_compute"):
                     inter = self._to_intermediate(b, ctx)
                 coll_dev = getattr(inter, "_fp_collision", None)
@@ -518,7 +528,7 @@ class HashAggExec(ExecOperator):
                 # groups live in a valid prefix and g is exact here:
                 # stage at the group bucket so merge concat scales
                 # with groups, not the input capacity
-                inter = self._prefix_slice_meta(inter, bucket_capacity(max(g, 1)))
+                inter = self._at_group_bucket(inter, g)
             seen_rows += n
             if self.mode != PARTIAL:
                 seen_groups += g
@@ -536,6 +546,8 @@ class HashAggExec(ExecOperator):
             todo = [nb]
             while todo:
                 cur = todo.pop(0)
+                obs.note_agg_fold(cur.capacity, cur.capacity, path="dense",
+                                  mode=self.mode)
                 r = dense.update(cur, defer=defer)
                 if r == "restart":
                     # ranges outgrew the anchored table: drain the
@@ -664,7 +676,7 @@ class HashAggExec(ExecOperator):
                 _note_collision(inter, int(resolved[2]), ctx.metrics)
             seen_rows += n
             seen_groups += g
-            inter = self._prefix_slice_meta(inter, bucket_capacity(max(g, 1)))
+            inter = self._at_group_bucket(inter, g)
             yield from skip_or_stage(inter, g)
 
         def feed_generic(b):
@@ -696,6 +708,9 @@ class HashAggExec(ExecOperator):
                 if probe is not None and not skipping:
                     with ctx.metrics.timer("elapsed_compute", count=True):
                         folded, misses, hit_rows = probe.fold(b)
+                    if folded:
+                        obs.note_agg_fold(b.capacity, b.capacity, path="probe",
+                                          mode=self.mode)
                     # probed hits are rows with ZERO new groups: they must
                     # keep pulling the skip heuristic's cardinality ratio
                     # down (only the generic path updates it otherwise)
@@ -723,6 +738,21 @@ class HashAggExec(ExecOperator):
             if probe is not None:
                 for mb in probe.finish():
                     yield from process_generic(mb)
+            if pending_g is not None:
+                # end of stream: the last blocking PARTIAL fold's group
+                # count has no next batch to ride with. Read it here, so
+                # that the state goes on at its group bucket and not at the
+                # capacity its batch came in with (a 13-group average would
+                # otherwise reach the shuffle writer 131,072 rows wide)
+                g_dev, coll_dev, inter_ref = pending_g
+                scalars = (g_dev,) if coll_dev is None else (g_dev, coll_dev)
+                got = [int(x) for x in jax.device_get(scalars)]  # auronlint: sync-point(4/task) -- end-of-stream settle of the last blocking partial fold's group count (+ fp collision flag)
+                if coll_dev is not None:
+                    _note_collision(inter_ref, got[1], ctx.metrics)
+                seen_groups += got[0]
+                table.adjust_staged(got[0] - pending_proxy)
+                table.shrink_last(got[0])
+                pending_g = None
             # drain the deferred-count window: entries resolve in FIFO
             # order with the same exactly-once staging as the in-stream
             # harvests (a cancellation skips this — the finally below
@@ -749,6 +779,7 @@ class HashAggExec(ExecOperator):
             if self.n_keys == 0:
                 yield self._empty_global_agg(ctx)
             return
+        self._note_emit(state)
         if self.mode == FINAL:
             yield self._finalize(state)
         else:
@@ -901,13 +932,12 @@ class HashAggExec(ExecOperator):
                 # batches behind _FP_FLAG_LOCK)
                 metrics.add("fp_collision_batches", 1)
             merged._fp_collision_host = bool(coll)
-            out = self._prefix_slice_meta(merged, bucket_capacity(max(g, 1)))
+            out = self._at_group_bucket(merged, g)
             if final and coll:
                 # collision arose in THIS fp-ordered merge — same dedup
                 out = self._dedup_full_sort(out, conf)
             return out
-        g = merged.num_rows()
-        return self._prefix_slice_meta(merged, bucket_capacity(max(g, 1)))
+        return self._at_group_bucket(merged, merged.num_rows())
 
     def _dedup_full_sort(self, b: Batch, conf=None) -> Batch:
         """Re-reduce one merged state batch with the legacy FULL-WORD sort:
@@ -921,7 +951,9 @@ class HashAggExec(ExecOperator):
             force_full_sort=True, conf=conf,
         )
         g = merged.num_rows()
-        return prefix_slice(merged, bucket_capacity(max(g, 1)))
+        out = prefix_slice(merged, bucket_capacity(max(g, 1)))
+        out._groups = g
+        return out
 
     def _merge_path(self, parts: list[Batch], metrics, conf=None) -> Batch:
         """Sequential pairwise merge-rank merges: acc ⊕ part is two
@@ -965,7 +997,7 @@ class HashAggExec(ExecOperator):
             merged._fp_collision_host = bool(coll)
             if coll and metrics is not None:
                 metrics.add("fp_collision_batches", 1)
-            acc = self._prefix_slice_meta(merged, bucket_capacity(max(g, 1)))
+            acc = self._at_group_bucket(merged, g)
         return acc
 
     def _resolve_fp_flags(self, parts: list[Batch], metrics) -> bool:
@@ -999,12 +1031,24 @@ class HashAggExec(ExecOperator):
         handle (groups live in the prefix, so sortedness survives)."""
         out = prefix_slice(b, new_cap)
         if out is not b:
-            for attr in ("_fp_order", "_fp_collision", "_fp_collision_host"):
+            for attr in ("_fp_order", "_fp_collision", "_fp_collision_host",
+                         "_groups"):
                 if hasattr(b, attr):
                     setattr(out, attr, getattr(b, attr))
             if hasattr(b, "_inc_fp"):
                 out._inc_fp = b._inc_fp[:new_cap]
         return out
+
+    @staticmethod
+    def _at_group_bucket(b: Batch, g: int) -> Batch:
+        """``b`` sliced to the bucket of its ``g`` settled groups, the count
+        noted on it: what its emission reports (``obs.note_agg_emit``)."""
+        out = HashAggExec._prefix_slice_meta(b, bucket_capacity(max(g, 1)))
+        out._groups = g
+        return out
+
+    def _note_emit(self, b: Batch) -> None:
+        obs.note_agg_emit(getattr(b, "_groups", None), self.mode)
 
     # ------------------------------------------------------------------
 
@@ -1040,6 +1084,10 @@ class HashAggExec(ExecOperator):
             )
             flags = self._sort_flags(sel, force_full_sort=force_full_sort,
                                      conf=conf)
+            obs.note_agg_reduce(
+                int(sel.shape[0]),
+                "mergepath" if merge_cap_a is not None
+                else "hostsort" if flags[0] else "sort")
             # host-sort order computes EAGERLY and enters the jit as data:
             # no pure_callback may live inside the compiled program
             # (concurrent callback-bearing XLA:CPU programs wedge). The
@@ -1113,6 +1161,7 @@ class HashAggExec(ExecOperator):
         # fingerprint knobs from a foreign task's conf
         flags = self._sort_flags(sel, force_full_sort=force_full_sort,
                                  conf=conf)
+        obs.note_agg_reduce(int(sel.shape[0]), "hostsort" if flags[0] else "sort")
         # same invariant as the jit path: segment_by_keys is itself jitted,
         # so the host-sort order must enter it as data (never a callback
         # inside a compiled program — pump threads run concurrently)
@@ -1357,6 +1406,7 @@ class HashAggExec(ExecOperator):
             cols[k].values if len(cols) > k else None,
         ))
         valid = np.asarray(valid_d)
+        obs.note_decimal_host_cells(len(valid), "final")
         # exact totals: vectorized python-int accumulation over k arrays
         total = np.zeros(len(valid), dtype=object)
         base = 1
@@ -1484,20 +1534,18 @@ class _AggTableConsumer:
         with self._lock:
             self.staged_rows = max(0, self.staged_rows + delta)
 
-    def shrink_last(self, new_cap: int) -> None:
-        """Slice the most recently staged intermediate down to its exact
-        group bucket (groups occupy a valid prefix). No-op if a concurrent
-        compact/spill already consumed it."""
-        from auron_tpu.columnar.batch import prefix_slice
+    def shrink_last(self, groups: int) -> None:
+        """Slice the most recently staged intermediate down to the bucket of
+        its ``groups`` settled groups (they occupy a valid prefix), the count
+        noted on it. No-op if a concurrent compact/spill already consumed
+        it."""
         from auron_tpu.exec.sort_exec import batch_nbytes
 
         with self._lock:
             if not self.staged:
                 return
             old = self.staged[-1]
-            if new_cap >= old.capacity:
-                return
-            shrunk = HashAggExec._prefix_slice_meta(old, new_cap)
+            shrunk = HashAggExec._at_group_bucket(old, groups)
             self.staged[-1] = shrunk
             self._staged_bytes += batch_nbytes(shrunk) - batch_nbytes(old)
 
@@ -3101,7 +3149,8 @@ class _ProbeScatter:
                 )
                 ns = Batch(st.schema, dev, st.dicts)
                 ns._inc_fp = state_fp
-                for attr in ("_fp_order", "_fp_collision", "_fp_collision_host"):
+                for attr in ("_fp_order", "_fp_collision", "_fp_collision_host",
+                             "_groups"):
                     if hasattr(st, attr):
                         setattr(ns, attr, getattr(st, attr))
                 # in-place accumulator swap: keys, sel, capacity, bytes all

@@ -42,6 +42,7 @@ from auron_tpu.exec.shuffle.format import (
     decode_block_v2,
     decode_blocks,
     is_v2_payload,
+    iter_block_payloads,
     shuffle_encoding_enabled,
 )
 
@@ -292,6 +293,24 @@ class LocalFileBlockProvider:
         data = self._region(partition)
         if data:
             yield from iter_block_payloads(data)
+
+
+class BroadcastBlockProvider:
+    """A broadcast relation as the host hands it to every task: the
+    length-prefixed blocks an ``IpcWriterExec`` pushed into its channel
+    (Spark's BroadcastExchange collects a small child as IPC bytes and ships
+    them to each executor). Every partition reads all of them."""
+
+    def __init__(self, blocks: list[bytes]):
+        self.blocks = blocks
+
+    def __call__(self, partition: int) -> Iterator[pa.RecordBatch]:
+        for blk in self.blocks:
+            yield from decode_blocks(blk)
+
+    def iter_payloads(self, partition: int) -> Iterator[bytes]:
+        for blk in self.blocks:
+            yield from iter_block_payloads(blk)
 
 
 class MultiMapBlockProvider:

@@ -301,13 +301,16 @@ def cluster_rows_host(pids_np: np.ndarray, sel_np: np.ndarray, n_out: int):
     return order_live, counts
 
 
-def repartition_substrate(conf) -> str:
+def repartition_substrate(conf, rows: int | None = None) -> str:
     """"host" (numpy argsort + host arrow slicing) or "device" (lax.sort
     clustering) — THE substrate decision shared by the eager writer and
-    the fused stage so the two repartition paths cannot diverge."""
+    the fused stage so the two repartition paths cannot diverge. ``rows``
+    is the batch's capacity: a batch too wide for a device sort
+    (``hostsort.DEVICE_SORT_MAX_ROWS``) is clustered on the host, where
+    its rows are headed anyway."""
     from auron_tpu.ops import hostsort
 
-    return "host" if hostsort.use_host_sort(conf) else "device"
+    return "host" if hostsort.use_host_sort(conf, rows=rows) else "device"
 
 
 @partial(jax.jit, static_argnames=("n_out",))
@@ -412,7 +415,7 @@ def stage_partition_batch(
     from auron_tpu.runtime.transfer import start_host_transfer
 
     n_out = partitioning.num_partitions
-    substrate = repartition_substrate(ctx.conf)
+    substrate = repartition_substrate(ctx.conf, b.capacity)
     sp = getattr(b, "_shuffle_prep", None)
     if sp is not None and (sp.n_out != n_out or sp.mode != substrate):
         sp = None  # stale/foreign payload: recompute eagerly

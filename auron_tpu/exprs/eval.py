@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
+from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import Batch
 from auron_tpu.exprs import cast as C
@@ -388,6 +389,7 @@ class Evaluator:
         bound = pydec.Decimal(10) ** (out_t.precision - out_t.scale)
         new_entries: list = []
         ok_tab = np.zeros(max(len(wide.dict), 1), dtype=bool)
+        obs.note_decimal_host_cells(len(wide.dict), "arith")
         with pydec.localcontext() as hp:
             hp.prec = 100
             for i, e in enumerate(wide.dict.to_pylist()):
@@ -441,6 +443,7 @@ class Evaluator:
         bound = pydec.Decimal(10) ** (out_t.precision - out_t.scale)
         entries: list = []
         ok_tab = np.zeros(max(len(uniq), 1), dtype=bool)
+        obs.note_decimal_host_cells(len(uniq), "arith")
         with pydec.localcontext() as hp:
             hp.prec = 100
             for i, (a_raw, b_raw) in enumerate(uniq):
@@ -479,6 +482,7 @@ class Evaluator:
         W, BASE = n_words or self._DEC_WORDS, self._DEC_WORD_BASE
         if cv.dtype.is_wide_decimal:
             entries = cv.dict.to_pylist()
+            obs.note_decimal_host_cells(len(entries), "compare")
             n = max(len(entries), 1)
             tabs = np.zeros((W, n), dtype=np.int64)
             shift = 10 ** (s - cv.dtype.scale)

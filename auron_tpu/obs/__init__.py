@@ -13,10 +13,12 @@ Public surface:
   conf (R7). A span's ``cat`` is its layer (``LAYERS``), and every span
   is also a region ``auron:<layer>:<name>`` on the profiler's clock.
 - ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` /
-  ``note_agg_fold`` / ``note_join_take`` / ``note_join_lookup`` — the
-  instrumentation facade behind MetricNode.timer, the EngineCounters
-  hooks, the task pump, the partial aggregate and the unique-build
-  join's lookup and output boundary.
+  ``note_agg_fold`` / ``note_agg_reduce`` / ``note_agg_emit`` /
+  ``note_decimal_host_cells`` / ``note_join_take`` / ``note_join_lookup``
+  — the instrumentation facade behind MetricNode.timer, the
+  EngineCounters hooks, the task pump, the aggregate's folds, reduces and
+  emissions, the DECIMAL cells the host handles one by one, and the
+  unique-build join's lookup and output boundary.
   Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``window_summary(t0_s, t1_s)`` — where the host's time went between
   two readings of ``time.perf_counter()``, by layer (obs/export.py).
@@ -117,18 +119,67 @@ def note_op(op: str, metric: str, dur_ns: int) -> None:
     core.record("op", metric, dur_ns, tid, sid, 0, op.partition(".")[0])
 
 
-def note_agg_fold(rows: int, in_rows: int) -> None:
-    """One PARTIAL raw fold of the deferred aggregate (exec/agg_exec.py):
-    the capacity its grouped reduce runs at, beside the capacity the batch
-    came in with. A ``fold`` event of no duration and no layer (NOT a
-    region: it takes nothing out of ``pump:batch``'s self time);
-    ``window_summary`` sums the first as ``agg_fold_rows``."""
-    if core._mode == MODE_OFF:
-        return
+def _event(kind: str, name: str, arg: dict) -> None:
+    """An event of no duration and no layer (NOT a region: it takes nothing
+    out of ``pump:batch``'s self time), under the calling thread's span."""
     sp = _span_var.get()
     tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
-    core.record("fold", "agg.partial", 0, tid, sid, 0,
-                {"rows": rows, "in_rows": in_rows})
+    core.record(kind, name, 0, tid, sid, 0, arg)
+
+
+def note_agg_fold(rows: int, in_rows: int, path: str = "deferred",
+                  mode: str = "partial", live: int | None = None) -> None:
+    """One fold of a batch into an aggregate (exec/agg_exec.py): ``path`` is
+    ``dense`` (the direct-address table: one scatter, no sort), ``probe``
+    (the sorted state probed and scatter-updated), ``sort`` (the blocking
+    sort-segmented reduce: every merge-mode fold, and a partial fold outside
+    the deferred arm) or ``deferred`` (the partial aggregate's sync-free
+    arm, a mispredict's repair included); ``rows`` the capacity the fold
+    runs at beside ``in_rows``, the capacity the batch came in with;
+    ``mode`` the aggregate's; ``live`` the batch's live rows where the site
+    has read them (None where reading them would cost a sync). A ``fold``
+    event; ``window_summary`` sums ``rows`` as ``agg_fold_rows`` and by path
+    as ``agg_folds``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("fold", f"agg.{mode}",
+           {"rows": rows, "in_rows": in_rows, "path": path, "live": live})
+
+
+def note_agg_reduce(rows: int, how: str) -> None:
+    """One grouped reduce of the sort-segmented aggregate (``HashAggExec.
+    _group_reduce``: folds, merges of staged state, collision repairs alike):
+    ``how`` is ``sort`` (a device sort at ``rows`` of capacity), ``hostsort``
+    (the host's lexsort, XLA:CPU's arm) or ``mergepath`` (two sorted runs
+    merged by rank, no sort). A ``reduce`` event; ``window_summary`` sums the
+    rows that were sorted as ``agg_sorted_rows`` and counts by ``how`` as
+    ``agg_reduces``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("reduce", "agg.reduce", {"rows": rows, "how": how})
+
+
+def note_agg_emit(groups: int | None, mode: str) -> None:
+    """One emission of an aggregate's groups (its state at the end of its
+    stream, an intermediate passed through while skipping): ``groups`` as
+    the host holds them from the reads the aggregate makes anyway (None
+    where no read has settled them). An ``emit`` event; ``window_summary``
+    sums them as ``agg_groups``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("emit", f"agg.{mode}", {"groups": groups})
+
+
+def note_decimal_host_cells(cells: int, site: str) -> None:
+    """DECIMAL cells the host handled one by one in Python: ``site`` is
+    ``final`` (a wide sum or average rebuilt from its limbs,
+    ``HashAggExec._final_wide``) or ``arith`` / ``compare`` (wide-decimal
+    arithmetic and comparison tables, one cell a dictionary entry or a
+    distinct pair, exprs/eval.py). A ``decimal`` event;
+    ``window_summary`` sums them as ``wide_decimal_host_cells``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("decimal", f"decimal.{site}", {"cells": cells})
 
 
 def note_join_take(mode: str, rows: int, in_rows: int) -> None:
@@ -144,10 +195,7 @@ def note_join_take(mode: str, rows: int, in_rows: int) -> None:
     the takes by mode as ``join_takes``."""
     if core._mode == MODE_OFF:
         return
-    sp = _span_var.get()
-    tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
-    core.record("take", "join.unique", 0, tid, sid, 0,
-                {"mode": mode, "rows": rows, "in_rows": in_rows})
+    _event("take", "join.unique", {"mode": mode, "rows": rows, "in_rows": in_rows})
 
 
 def note_join_lookup(kind: str, rows: int) -> None:
@@ -161,10 +209,7 @@ def note_join_lookup(kind: str, rows: int) -> None:
     ``rows`` by kind as ``join_lookup_rows``."""
     if core._mode == MODE_OFF:
         return
-    sp = _span_var.get()
-    tid, sid = (sp.trace_id, sp.span_id) if sp is not None else (0, 0)
-    core.record("lookup", "join.unique", 0, tid, sid, 0,
-                {"kind": kind, "rows": rows})
+    _event("lookup", "join.unique", {"kind": kind, "rows": rows})
 
 
 def note_sync(dur_ns: int, is_async: bool) -> None:
@@ -206,4 +251,5 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
 
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
     note_agg_fold = note_join_take = note_join_lookup = _noop  # noqa: F811
+    note_agg_reduce = note_agg_emit = note_decimal_host_cells = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

@@ -115,9 +115,16 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ``spans`` (host reads excepted: their names are sites). Thread-seconds,
     not wall: two map pumps run at once. ``d2h_bytes`` sums the bytes of
     the host reads; ``sync_sites`` ranks them by seconds as ``[site, n,
-    seconds]``. ``agg_fold_rows`` sums the capacities the partial
-    aggregate's raw folds ran at (the ``fold`` events that began in the
-    window, ``obs.note_agg_fold``); ``join_gather_rows`` sums the
+    seconds]``. ``agg_fold_rows`` sums the capacities the aggregates' folds
+    ran at (the ``fold`` events that began in the window,
+    ``obs.note_agg_fold``) and ``agg_folds`` holds them by path (``dense``,
+    ``probe``, ``sort``, ``deferred``) as ``{n, rows, live}``;
+    ``agg_sorted_rows`` sums the capacities the grouped reduces sorted and
+    ``agg_reduces`` counts them by ``how`` (the ``reduce`` events,
+    ``obs.note_agg_reduce``); ``agg_groups`` sums the groups the aggregates
+    emitted (``emit``, ``obs.note_agg_emit``); ``wide_decimal_host_cells``
+    the DECIMAL cells the host handled one by one (``decimal``,
+    ``obs.note_decimal_host_cells``); ``join_gather_rows`` sums the
     capacities the unique-build joins gathered their build columns at and
     ``join_takes`` counts those takes by mode (the ``take`` events,
     ``obs.note_join_take``); ``join_lookup_rows`` sums by kind the widths
@@ -133,7 +140,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     layers: dict[str, dict] = {}
     spans: dict[str, dict] = {}
     sites: dict[str, list] = {}
-    d2h = fold_rows = gather_rows = 0
+    d2h = fold_rows = gather_rows = sorted_rows = groups = dec_cells = 0
+    folds: dict[str, dict] = {}
+    reduces: dict[str, dict] = {}
     takes: dict[str, int] = {}
     lookups: dict[str, int] = {}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
@@ -156,6 +165,21 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
                 plans[bool(ev[7]["cache_hit"])] += 1
             elif ev[2] == "fold":
                 fold_rows += ev[7]["rows"]
+                ent = folds.setdefault(ev[7]["path"],
+                                       {"n": 0, "rows": 0, "live": 0})
+                ent["n"] += 1
+                ent["rows"] += ev[7]["rows"]
+                ent["live"] += ev[7]["live"] or 0
+            elif ev[2] == "reduce":
+                ent = reduces.setdefault(ev[7]["how"], {"n": 0, "rows": 0})
+                ent["n"] += 1
+                ent["rows"] += ev[7]["rows"]
+                if ev[7]["how"] != "mergepath":
+                    sorted_rows += ev[7]["rows"]
+            elif ev[2] == "emit":
+                groups += ev[7]["groups"] or 0
+            elif ev[2] == "decimal":
+                dec_cells += ev[7]["cells"]
             elif ev[2] == "take":
                 gather_rows += ev[7]["rows"]
                 takes[ev[7]["mode"]] = takes.get(ev[7]["mode"], 0) + 1
@@ -179,7 +203,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ranked = sorted(sites.items(), key=lambda kv: -kv[1][1])[:top]
     return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
-            "agg_fold_rows": fold_rows,
+            "agg_fold_rows": fold_rows, "agg_folds": folds,
+            "agg_reduces": reduces, "agg_sorted_rows": sorted_rows,
+            "agg_groups": groups, "wide_decimal_host_cells": dec_cells,
             "join_gather_rows": gather_rows, "join_takes": takes,
             "join_lookup_rows": lookups,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],

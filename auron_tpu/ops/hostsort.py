@@ -24,15 +24,30 @@ import numpy as np
 from auron_tpu.utils.config import HOST_SORT_MODE, active_conf, resolve_tri
 
 
-def use_host_sort(conf=None) -> bool:
-    """Trace-time decision: host lexsort or device lax.sort.
+#: the widest sort an accelerator runs as one ``lax.sort`` under ``auto``.
+#: XLA:TPU compiles a sort by its width, not its operands' bytes: the
+#: fingerprint sort's three keys compiled in 6 s at 8,192 rows, 42 s at
+#: 16,384, 157 s at 32,768 and 181 s at 131,072, and one 32-bit key in 7 /
+#: 25 / 32 s (a described v5e, PR 32; PR 22 met 6-7 minutes at 524,288 on
+#: the chip's machine). Past this width the permutation comes from the host
+#: (two arrays down, one up, ``np.lexsort``: milliseconds at 131,072 rows),
+#: as on XLA:CPU, until a device sort whose compile is bounded takes its
+#: place (ROADMAP S8).
+DEVICE_SORT_MAX_ROWS = 1 << 14
+
+
+def use_host_sort(conf=None, rows: int | None = None) -> bool:
+    """Trace-time decision: host lexsort or device lax.sort. Under ``auto``
+    the host sorts on the CPU backend, and on an accelerator where the
+    caller names a width (``rows``) over ``DEVICE_SORT_MAX_ROWS``.
 
     ``conf``: pass the task's own Configuration on any path a
     cross-thread spill can reach — active_conf() is thread-local, so the
     spilling thread would otherwise resolve a foreign task's knob."""
     return resolve_tri(
         (conf if conf is not None else active_conf()).get(HOST_SORT_MODE),
-        jax.default_backend() == "cpu",
+        jax.default_backend() == "cpu"
+        or (rows is not None and rows > DEVICE_SORT_MAX_ROWS),
     )
 
 

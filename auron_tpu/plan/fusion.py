@@ -733,11 +733,13 @@ class FusedStageExec(ExecOperator):
         if self.shuffle is not None:
             from auron_tpu.exec.shuffle.writer import repartition_substrate
 
-            # conf-stable per task: the SAME policy the eager writer
-            # resolves, so fused and fallback repartition cannot diverge
-            shuffle_mode = repartition_substrate(ctx.conf)
             rr_start = jnp.int32(ctx.partition_id % self.shuffle[2])
         for b in self.child_stream(0, partition, ctx):
+            if self.shuffle is not None:
+                # the SAME policy the eager writer resolves, by the task's
+                # conf and the batch's capacity, so fused and fallback
+                # repartition cannot diverge
+                shuffle_mode = repartition_substrate(ctx.conf, b.capacity)
             t_all = time.perf_counter_ns()
             anchor = self.dense_link.snapshot() if self.dense_link else None
             probe_anchor = (

@@ -1003,11 +1003,17 @@ def test_deferred_partial_seeds_the_predictor_on_a_streams_first_batch(case):
     lo, hi = int(t0 * 1e9), int(t1 * 1e9)
     events = sorted((ev for _ring, evs in core.snapshot_events() for ev in evs
                      if lo <= ev[0] < hi), key=lambda ev: ev[0])
-    folds = [ev[7] for ev in events if ev[2] == "fold"]
+    every = [ev[7] for ev in events if ev[2] == "fold"]
+    folds = [f for f in every if f["path"] == "deferred"]
     assert [f["rows"] for f in folds] == fold_caps
     assert all(f["in_rows"] == cap for f in folds)
+    # the FINAL aggregate above it folds the partial's one state on the
+    # blocking path: a fold event of its own (PR 34), in the sum as well
+    final = [f for f in every if f["path"] != "deferred"]
+    assert {f["path"] for f in final} <= {"sort"}
     ws = obs.window_summary(t0, t1)
-    assert ws["agg_fold_rows"] == sum(fold_caps)
+    assert ws["agg_folds"].get("deferred", {"rows": 0})["rows"] == sum(fold_caps)
+    assert ws["agg_fold_rows"] == sum(fold_caps) + sum(f["rows"] for f in final)
     # the arm's blocking reads, by the sync-point lines the hook names:
     # one seed read a stream, one more for each repair, and no other
     src = os.path.join(os.path.dirname(auron_tpu.__file__), "exec/agg_exec.py")

@@ -156,6 +156,27 @@ def test_float64_key_words_compile(monkeypatch, one_chip):
         assert out.dtype == jnp.uint64
 
 
+def test_dense_aggregate_fold_compiles_at_query_65s_batch(one_chip):
+    """The dense table's fold (``agg_exec._dense_update_jit``) as query 65's
+    partial sum gives it: one batch of 4,194,304 rows, two int64 keys packed
+    into 13 x 18,001 lanes of a 262,144-slot table, one DECIMAL sum. One
+    scatter program: seconds to compile where a sort of that width would
+    take minutes (ops/hostsort.py DEVICE_SORT_MAX_ROWS)."""
+    from auron_tpu.exec import agg_exec
+
+    rows, size = 1 << 22, 1 << 18
+    col = _sds((rows,), jnp.int64, one_chip)
+    ok = _sds((rows,), jnp.bool_, one_chip)
+    compiled = _compile(
+        agg_exec._dense_update_jit,
+        (_sds((size,), jnp.int64, one_chip),), (_sds((size,), jnp.bool_, one_chip),),
+        _sds((size,), jnp.bool_, one_chip),
+        _sds((2,), jnp.int64, one_chip), _sds((2,), jnp.int64, one_chip),
+        (col, col), (ok, ok), ok, (((col, ok),),),
+        cfg=(True, (("sum", "decimal(7,2)"),), (14, 18002)), size=size)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 28)
+
+
 def test_flagship_stage_program_compiles(one_chip):
     """``__graft_entry__.entry()``'s fused filter + project + group
     aggregation (a 3-operand lax.sort inside) at its own example shapes."""

@@ -430,14 +430,34 @@ def _get(port: int, path: str) -> bytes:
 SERVE_SPANS = {"request", "admit", "plan", "execute", "collect", "encode"}
 
 
+def _await_request_spans(n: int, since_ns: int) -> None:
+    """Until ``n`` ``serve:request`` spans that began after ``since_ns`` have
+    ENDED: the span closes after the body's last byte is written, so a client
+    that has its answer may read the clock before the handler thread does (a
+    busy machine: 22 ``serve`` regions for 21, seen in whole tier-1 runs)."""
+    deadline = time.perf_counter() + 30.0
+    while time.perf_counter() < deadline:
+        done = sum(1 for _ring, evs in obs.core.snapshot_events() for ev in evs
+                   if ev[8] == "serve" and ev[3] == "request" and ev[0] >= since_ns)
+        if done >= n:
+            return
+        time.sleep(0.002)
+    raise AssertionError(f"{n} serve:request spans did not end")
+
+
 def test_serve_spans_nest_under_the_request_and_are_summed(service):
     obs.set_mode("recorder")
     conn = http.client.HTTPConnection("127.0.0.1", service, timeout=120)
+    began = time.perf_counter_ns()
     _post(conn, _text("q55"), "spans")          # a miss or a hit, before t0
+    # the window holds this test's three requests and nothing of the one
+    # before them: its span has ended before t0, theirs before t1
+    _await_request_spans(1, began)
     t0 = time.perf_counter()
     for _ in range(3):
         status, _body = _post(conn, _text("q55"), "spans")
         assert status == 200
+    _await_request_spans(4, began)
     conn.close()
     t1 = time.perf_counter()
     lo, hi = int(t0 * 1e9), int(t1 * 1e9)
