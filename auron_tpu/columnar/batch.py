@@ -839,24 +839,42 @@ def compaction_index(sel: jnp.ndarray, out_cap: int):
     return idx, sel_out
 
 
-@_partial(jax.jit, static_argnames=("out_cap",))
-def _compact_dev(dev: DeviceBatch, out_cap: int) -> DeviceBatch:
+@_partial(jax.jit, static_argnames=("out_cap", "cols"))
+def _compact_dev(
+    dev: DeviceBatch, out_cap: int, cols: tuple[int, ...] | None = None,
+) -> DeviceBatch:
     """Gather live rows into a dense prefix of a smaller buffer (O(n) +
     O(out log n), no sort). Used when selectivity collapses a batch
     (post-filter/join) so blocking ops (sort-segmentation, exchange pulls)
-    pay for live rows only."""
+    pay for live rows only. ``cols`` names the columns to gather (None:
+    all); any other comes back all NULL, a fill and not a gather."""
     idx, sel_out = compaction_index(dev.sel, out_cap)
-    values = tuple(v[idx] for v in dev.values)
-    validity = tuple(m[idx] & sel_out for m in dev.validity)
+    taken = range(len(dev.values)) if cols is None else cols
+    # values first, then validities: with every column taken the program is
+    # the one compiled before ``cols`` existed (a persistent-cache hit)
+    values = tuple(
+        v[idx] if ci in taken else jnp.zeros((out_cap,) + v.shape[1:], v.dtype)
+        for ci, v in enumerate(dev.values)
+    )
+    validity = tuple(
+        m[idx] & sel_out if ci in taken else jnp.zeros(out_cap, bool)
+        for ci, m in enumerate(dev.validity)
+    )
     return DeviceBatch(sel_out, values, validity)
 
 
-def compact_batch(batch: Batch, out_capacity: int) -> Batch:
+def compact_batch(
+    batch: Batch, out_capacity: int, cols: tuple[int, ...] | None = None,
+) -> Batch:
     """Compact live rows into a batch of ``out_capacity`` slots (must be
-    >= the live count — callers size it from a synced row count)."""
+    >= the live count — callers size it from a synced row count). A
+    consumer that reads only some columns names them in ``cols``: the
+    rest are not gathered and come back all NULL."""
     if out_capacity >= batch.capacity:
         return batch
-    return Batch(batch.schema, _compact_dev(batch.device, out_capacity), batch.dicts)
+    return Batch(
+        batch.schema, _compact_dev(batch.device, out_capacity, cols), batch.dicts
+    )
 
 
 def prefix_slice(batch: Batch, new_capacity: int) -> Batch:

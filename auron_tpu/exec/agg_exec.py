@@ -142,6 +142,13 @@ def _wide_sum_fields(in_t: T.DataType, prefix: str) -> list[T.Field]:
     return fields
 
 
+def _is_64bit_plane(t: T.DataType) -> bool:
+    """Does a column of this type hold 8-byte values on the device? Such a
+    plane costs twice a 32-bit one to gather and about twelve times one to
+    scatter (PERF.md section 5, "unit costs")."""
+    return np.dtype(t.physical_dtype().name).itemsize == 8
+
+
 def intermediate_fields(a: AggExpr, in_t: T.DataType | None, prefix: str) -> list[T.Field]:
     if a.func in ("count", "count_star"):
         return [T.Field(f"{prefix}#count", T.INT64, False)]
@@ -534,20 +541,25 @@ class HashAggExec(ExecOperator):
                 seen_groups += g
             yield from skip_or_stage(inter, g)
 
-        def fold_dense(nb, defer: bool = True) -> list | None:
+        def fold_dense(nb, defer: bool = True, noted: dict | None = None,
+                       ) -> list | None:
             """Fold one batch through the dense table, driving the
             drain/re-anchor protocol (the anchored fold is deferred: its
             in-range flag is read when the NEXT batch arrives, so steady
             state pays no per-batch blocking sync; defer=False resolves
-            synchronously — used at end of stream). Returns None when
+            synchronously — used at end of stream). ``noted`` is what the
+            compaction boundary said of ``nb`` for the fold's ring event
+            (``in_rows``, ``live``, ``take``: obs.note_agg_fold); a held
+            batch folded again after a restart has none. Returns None when
             folded, or — after a permanent fallback (dense set to None) —
             the batches that must flow to the generic path instead."""
             nonlocal dense, skipping_enabled
             todo = [nb]
             while todo:
                 cur = todo.pop(0)
-                obs.note_agg_fold(cur.capacity, cur.capacity, path="dense",
-                                  mode=self.mode)
+                obs.note_agg_fold(cur.capacity, path="dense", mode=self.mode,
+                                  **(noted or {"in_rows": cur.capacity}))
+                noted = None
                 r = dense.update(cur, defer=defer)
                 if r == "restart":
                     # ranges outgrew the anchored table: drain the
@@ -569,6 +581,74 @@ class HashAggExec(ExecOperator):
                     dense = None
                     return left
             return None
+
+        # the dense arm's compaction boundary (exec/selectivity.py, docs/
+        # pipeline.md section 1): a batch behind a selective filter or join
+        # comes in at its input's capacity, and one scatter a row of
+        # capacity is what the fold costs, so the batch is folded at the
+        # bucket of its live rows and one of no rows not at all. The rule
+        # is compaction_bucket's, by shape: staying dense scatters
+        # fold_planes() elements a row of capacity; compacting builds the
+        # index, gathers the planes the fold reads and scatters, a row of
+        # bucket (XLA:CPU: the quarter rule, in front of the host fold too)
+        dense_boundary = None
+        if dense is not None:
+            from auron_tpu.columnar.batch import compact_batch, compaction_bucket
+            from auron_tpu.exec.selectivity import CompactionBoundary
+
+            fold_cols, take_planes = self._fold_columns()
+            fold_planes = dense.fold_planes()
+
+            def dense_bucket_of(n_live: int, capacity: int) -> int | None:
+                return compaction_bucket(
+                    n_live, capacity, dense_planes=fold_planes,
+                    taken_planes=take_planes + fold_planes,
+                )
+
+            dense_boundary = CompactionBoundary(
+                conf, dense_bucket_of, ctx.metrics)
+
+        def take_dense(b, mode: str, out_cap: int | None):
+            """The boundary's take: (mode, the batch to fold), ``b`` itself
+            where it stays dense, else the columns the fold reads compacted
+            into ``out_cap`` rows; nothing where the count just read is 0."""
+            if dense_boundary.live == 0:
+                return mode, None
+            if out_cap is None:
+                return mode, b
+            ctx.metrics.add("agg_compacted_batches", 1)
+            return mode, compact_batch(b, out_cap, cols=fold_cols)
+
+        def offer_dense(b):
+            """One batch into the dense arm's boundary; yields what the
+            generic path passes through of the batches that come out."""
+            dense.waiting_bytes += batch_nbytes(b)
+            for held, taken in dense_boundary.offer(
+                b.device.num_rows(), b.capacity, partial(take_dense, b), b
+            ):
+                yield from fold_taken(held, taken)
+
+        def fold_taken(held, taken):
+            """Fold one batch the boundary emitted (FIFO, up to the window's
+            depth behind its dispatch) at the width it was taken at; one
+            whose count came out 0 leaves its event and no program."""
+            mode, nb = taken
+            live = dense_boundary.live
+            if dense is not None:
+                dense.waiting_bytes -= batch_nbytes(held)
+            if live == 0:
+                obs.note_agg_fold(0, held.capacity, path="dense",
+                                  mode=self.mode, live=0, take="empty")
+                return
+            if dense is None:
+                # a permanent fallback while this batch waited
+                yield from feed_generic(nb)
+                return
+            with ctx.metrics.timer("elapsed_compute", count=True):
+                leftovers = fold_dense(nb, noted={
+                    "in_rows": held.capacity, "live": live, "take": mode})
+            for gb in leftovers or ():
+                yield from feed_generic(gb)
 
         # sorted-state probe/scatter: engages once a compact() has produced
         # an fp-sorted state batch (and the dense table, which runs in
@@ -698,12 +778,7 @@ class HashAggExec(ExecOperator):
             for b in self.child_stream(0, partition, ctx):
                 ctx.check_cancelled()
                 if dense is not None:
-                    with ctx.metrics.timer("elapsed_compute", count=True):
-                        leftovers = fold_dense(b)
-                    if leftovers is None:
-                        continue
-                    for nb in leftovers:
-                        yield from feed_generic(nb)
+                    yield from offer_dense(b)
                     continue
                 if probe is not None and not skipping:
                     with ctx.metrics.timer("elapsed_compute", count=True):
@@ -722,9 +797,17 @@ class HashAggExec(ExecOperator):
                     yield from process_generic(b)
                     continue
                 yield from feed_generic(b)
-            # end of stream: resolve the in-flight deferred dense folds
-            # (up to window-depth of them) via the same protocol,
-            # synchronously (there is no next batch to piggyback on)
+            # end of stream: fold what still waits in the dense arm's
+            # boundary for its count (after a permanent fallback: hand it
+            # to the generic path), THEN resolve the in-flight deferred
+            # dense folds (up to window-depth of them) via the same
+            # protocol, synchronously (there is no next batch to piggyback
+            # on)
+            if dense_boundary is not None:
+                for held, taken in dense_boundary.drain():
+                    yield from fold_taken(held, taken)
+                if dense_boundary.predictions:
+                    ctx.metrics.add("sel_pred_batches", dense_boundary.predictions)
             if dense is not None:
                 for nb in dense.finish_pending():
                     if dense is None:
@@ -813,6 +896,26 @@ class HashAggExec(ExecOperator):
             for grp in self._intermediate_groups(b)
         )
         return keys, per_agg
+
+    def _fold_columns(self) -> tuple[tuple[int, ...] | None, int]:
+        """(the child's columns ``_keys_and_inputs`` reads, the planes a
+        take of them gathers): None where a fold reads every column (the
+        merge modes' intermediate layout). A plane is one array gathered
+        at the take's width, values and validities alike, a 64-bit one
+        counted twice (PERF.md section 5, "unit costs")."""
+        schema = self.children[0].schema
+        cols = None
+        if self.mode == PARTIAL:
+            exprs = [g for g, _ in self.groupings]
+            exprs += [a.expr for a, _ in self.aggs if a.expr is not None]
+            cols = tuple(sorted({
+                n.index for e in exprs for n in ir.walk(e)
+                if isinstance(n, ir.Column)
+            }))
+        planes = 0
+        for ci in (range(len(schema)) if cols is None else cols):
+            planes += 3 if _is_64bit_plane(schema[ci].dtype) else 2
+        return cols, planes
 
     def _state_keys(self, b: Batch) -> list[ColumnVal]:
         """Key-column ColumnVal view of an intermediate-layout batch — THE
@@ -2152,6 +2255,27 @@ def _next_pow2_agg(n: int) -> int:
     return p
 
 
+# What one DEAD row of ``_dense_update_jit`` costs on the TPU (a row routed
+# to the drop segment: what compaction saves; a live row is scattered on
+# either side of the choice), in the unit ``columnar.batch.
+# compaction_bucket`` counts in: one int32 plane gathered by a random
+# index, 7.5 ns a row of output. A scatter of a 64-bit plane (the int64
+# ``segment_sum``; int64 / float64 sums and maxima cost the same) is
+# SCATTER_WIDE of them, a scatter of a 32-bit or bool plane (an int32 /
+# float32 sum or maximum, the ``segment_max`` of the present / valid flags)
+# SCATTER_NARROW. Readings on the v5e (PERF.md section 5, "unit costs", PR
+# 35). Inside query 65's fold, from the traced cell's device seconds: 30 ns
+# a dead row and 112-124 ns a live one (uniform over 262,144 slots) for the
+# int64 sum, 4.1-6.7 ns a row for each flag plane. Alone, 20 calls ending
+# in block_until_ready at 4,194,304 and 524,288 rows into 262,144 slots:
+# 69 ns (every row to one slot) to 124 ns (uniform) for int64 or float64,
+# 6.6 to 8.7 ns for int32, float32 or bool. The constants are the LEAST
+# reading of each: where the fold is cheaper than reckoned, compacting must
+# not be chosen in its place.
+SCATTER_WIDE = 4.0
+SCATTER_NARROW = 0.5
+
+
 def _bincount_i64(idx: np.ndarray, v: np.ndarray, size: int) -> np.ndarray:
     """Exact int64 segment sums via np.bincount: bincount accumulates in
     float64 (exact only to 2^53), so the value splits into four 16-bit
@@ -2231,6 +2355,27 @@ class _DenseAggState:
         # every publication so stale-prepped batches fold via the raw path
         self._link = getattr(exec_, "_dense_prep_link", None)
         self._epoch = 0
+        # bytes of the batches that wait in the arm's compaction boundary
+        # (HashAggExec._execute) for their live counts: up to the transfer
+        # window's depth of them, whole, beside the up to k folded ones
+        # that _pending pins for their flags
+        self.waiting_bytes = 0
+
+    def fold_planes(self) -> float:
+        """What folding one dead row into the table costs, in gathered
+        elements (SCATTER_WIDE / SCATTER_NARROW above): one scatter for ``present``
+        and, for every field of the table, one for its values and one for
+        its validity where it has one. The ``dense_planes`` of the arm's
+        ``compaction_bucket`` rule: what a row of capacity costs where the
+        batch is folded as it came."""
+        ex = self.exec
+        planes = SCATTER_NARROW
+        for (a, _), in_t in zip(ex.aggs, ex._agg_input_types):
+            for f in intermediate_fields(a, in_t if in_t is not None else T.INT64, "x"):
+                planes += SCATTER_WIDE if _is_64bit_plane(f.dtype) else SCATTER_NARROW
+                if f.nullable:
+                    planes += SCATTER_NARROW
+        return planes
 
     def reset(self) -> None:
         """Forget the table (after a drain) so the next update re-anchors.
@@ -2328,7 +2473,9 @@ class _DenseAggState:
         is harvested k batches later from the async window, so the steady
         state has no blocking host round-trip per batch. Table footprint
         is bounded by LIMIT slots x field widths (+ up to k in-flight
-        batches), accounted as an unspillable consumer."""
+        batches, + up to k more that wait in the arm's compaction boundary
+        for their live counts: ``waiting_bytes``), accounted as an
+        unspillable consumer."""
         from auron_tpu.runtime.transfer import harvest, start_host_transfer
 
         if self._host:
@@ -2833,10 +2980,11 @@ class _DenseAggState:
     def mem_used(self) -> int:
         from auron_tpu.exec.sort_exec import batch_nbytes
 
-        # in-flight deferred folds pin their batches until harvest
+        # in-flight deferred folds pin their batches until harvest, and
+        # the batches ahead of them wait in the compaction boundary
         with self._pending_lock:
             pending = list(self._pending)
-        total = sum(batch_nbytes(pb) for pb, _ in pending)
+        total = self.waiting_bytes + sum(batch_nbytes(pb) for pb, _ in pending)
         if self.vals is None:
             return total
         total += self.size  # present bools

@@ -22,9 +22,13 @@ bucket is *predictable*:
 Every join that compacts (the unique-build probe of BHJ and SMJ, eager or
 behind a fused stage, and the fused star chain) drives one boundary with
 its own ``take`` callback; nothing else calls ``predict``, ``observe``,
-``push`` or ``drain`` for a join (docs/pipeline.md section 1). The partial
-aggregate's deferred arm keeps its own halves (exec/agg_exec.py; ROADMAP
-D2 says why).
+``push`` or ``drain`` for a join (docs/pipeline.md section 1). The
+aggregate's dense arm (exec/agg_exec.py ``HashAggExec._execute``) is the
+boundary's fourth client: its ``take`` is ``compact_batch`` of the columns
+the fold reads, and what the boundary emits is folded into the dense
+table, so a batch folds at the bucket of its live rows and an empty one
+not at all. The partial aggregate's deferred arm keeps its own halves
+(ROADMAP D2 says why).
 """
 
 from __future__ import annotations
@@ -61,6 +65,8 @@ class SelectivityPredictor:
         self.ewma: float | None = None
         self._bucket: int | None = None
         self._low_streak = 0
+        # the count observed since the last prediction (None: none yet)
+        self.observed: int | None = None
         # counters surfaced in operator metrics / tests
         self.predictions = 0
         self.mispredicts = 0
@@ -71,6 +77,7 @@ class SelectivityPredictor:
         path once to seed the EWMA). The caller applies the shared
         ``compaction_bucket`` threshold to decide compact-vs-dense — a
         dense prediction still emits WITHOUT a sync."""
+        self.observed = None
         if self._bucket is None:
             return None
         self.predictions += 1
@@ -80,6 +87,7 @@ class SelectivityPredictor:
         """Feed one batch's actual live count. ``predicted`` is the bucket
         the batch was compacted into (None = blocking/dense path) — an
         overflow there counts as a mispredict."""
+        self.observed = n_live
         if predicted is not None and n_live > predicted:
             self.mispredicts += 1
         self.ewma = (
@@ -145,7 +153,12 @@ class CompactionBoundary:
     decides there.
 
     Emission lags dispatch by up to the window's depth and stays FIFO; the
-    consumer MUST ``drain`` after its last batch."""
+    consumer MUST ``drain`` after its last batch.
+
+    ``live`` is the count the boundary has read of the batch it is taking
+    or has just emitted (the seed's read, a harvest), None while a batch
+    is taken at its predicted bucket at dispatch: a consumer that can do
+    without a batch of no rows (the dense aggregate) looks there."""
 
     def __init__(self, conf, bucket_of: Callable[[int, int], "int | None"],
                  metrics=None):
@@ -157,6 +170,10 @@ class CompactionBoundary:
     @property
     def predictions(self) -> int:
         return self._pred.predictions
+
+    @property
+    def live(self) -> int | None:
+        return self._pred.observed
 
     def plan_take(self, capacity: int) -> TakePlan:
         """This batch's ONE ``predict`` call. A consumer that dispatches
@@ -178,7 +195,7 @@ class CompactionBoundary:
             plan = self.plan_take(capacity)
         if plan.seed:
             # auronlint: disable=R9 -- first batch of a stream only: plan.seed is true only before the first observation
-            n_live = int(jax.device_get(live))  # auronlint: sync-point(2/task) -- join compaction seed read: the first batch's live count
+            n_live = int(jax.device_get(live))  # auronlint: sync-point(4/task) -- compaction seed read: the first batch's live count, once a boundary (a task's unique-build joins and its dense aggregate each drive one: query 65's last stage has three and one)
             self._pred.observe(n_live)
             return [(state, take("seed", self._bucket_of(n_live, capacity)))]
         if plan.cap is not None and taken is None:

@@ -128,7 +128,8 @@ def _event(kind: str, name: str, arg: dict) -> None:
 
 
 def note_agg_fold(rows: int, in_rows: int, path: str = "deferred",
-                  mode: str = "partial", live: int | None = None) -> None:
+                  mode: str = "partial", live: int | None = None,
+                  take: str | None = None) -> None:
     """One fold of a batch into an aggregate (exec/agg_exec.py): ``path`` is
     ``dense`` (the direct-address table: one scatter, no sort), ``probe``
     (the sorted state probed and scatter-updated), ``sort`` (the blocking
@@ -137,13 +138,19 @@ def note_agg_fold(rows: int, in_rows: int, path: str = "deferred",
     arm, a mispredict's repair included); ``rows`` the capacity the fold
     runs at beside ``in_rows``, the capacity the batch came in with;
     ``mode`` the aggregate's; ``live`` the batch's live rows where the site
-    has read them (None where reading them would cost a sync). A ``fold``
-    event; ``window_summary`` sums ``rows`` as ``agg_fold_rows`` and by path
-    as ``agg_folds``."""
+    has read them (None where reading them would cost a sync); ``take`` how
+    the dense arm's compaction boundary took the batch: ``seed``,
+    ``compact``, ``dense``, ``repair`` as a join's takes (exec/
+    selectivity.py) or ``empty`` (the count came out 0: ``rows`` 0, no
+    program issued), None off that arm and for a held batch folded again
+    after a restart. A ``fold`` event; ``window_summary`` sums ``rows`` as
+    ``agg_fold_rows`` and by path as ``agg_folds``, and counts the dense
+    arm's by ``take`` as ``agg_dense_folds``."""
     if core._mode == MODE_OFF:
         return
     _event("fold", f"agg.{mode}",
-           {"rows": rows, "in_rows": in_rows, "path": path, "live": live})
+           {"rows": rows, "in_rows": in_rows, "path": path, "live": live,
+            "take": take})
 
 
 def note_agg_reduce(rows: int, how: str) -> None:

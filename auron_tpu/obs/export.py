@@ -118,7 +118,10 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     seconds]``. ``agg_fold_rows`` sums the capacities the aggregates' folds
     ran at (the ``fold`` events that began in the window,
     ``obs.note_agg_fold``) and ``agg_folds`` holds them by path (``dense``,
-    ``probe``, ``sort``, ``deferred``) as ``{n, rows, live}``;
+    ``probe``, ``sort``, ``deferred``) as ``{n, rows, live}``, with
+    ``agg_dense_folds`` counting the dense arm's by how its compaction
+    boundary took the batch (``seed``, ``compact``, ``dense``, ``repair``,
+    ``empty``);
     ``agg_sorted_rows`` sums the capacities the grouped reduces sorted and
     ``agg_reduces`` counts them by ``how`` (the ``reduce`` events,
     ``obs.note_agg_reduce``); ``agg_groups`` sums the groups the aggregates
@@ -142,6 +145,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     sites: dict[str, list] = {}
     d2h = fold_rows = gather_rows = sorted_rows = groups = dec_cells = 0
     folds: dict[str, dict] = {}
+    dense_folds: dict[str, int] = {}
     reduces: dict[str, dict] = {}
     takes: dict[str, int] = {}
     lookups: dict[str, int] = {}
@@ -170,6 +174,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
                 ent["n"] += 1
                 ent["rows"] += ev[7]["rows"]
                 ent["live"] += ev[7]["live"] or 0
+                how = ev[7].get("take")
+                if how is not None:
+                    dense_folds[how] = dense_folds.get(how, 0) + 1
             elif ev[2] == "reduce":
                 ent = reduces.setdefault(ev[7]["how"], {"n": 0, "rows": 0})
                 ent["n"] += 1
@@ -204,6 +211,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
             "agg_fold_rows": fold_rows, "agg_folds": folds,
+            "agg_dense_folds": dense_folds,
             "agg_reduces": reduces, "agg_sorted_rows": sorted_rows,
             "agg_groups": groups, "wide_decimal_host_cells": dec_cells,
             "join_gather_rows": gather_rows, "join_takes": takes,

@@ -532,6 +532,198 @@ def test_dense_agg_deferred_restart_no_double_fold(dense_fold_substrate):
         assert out["mx"].tolist() == exp["mx"].tolist()
 
 
+# the dense arm behind its compaction boundary (exec/selectivity.py), a
+# stream of 4,096-row batches by their live rows on XLA:CPU's quarter rule:
+# (the takes' modes, the widths the folds ran at, repairs, compacted takes)
+_DENSE_BOUNDARY_CASES = {
+    # the head of a date-ordered fact table behind a year's filter
+    "full_tenth_empty_empty": dict(
+        live=(4096, 400, 0, 0),
+        want=(["seed", "compact", "empty", "empty"], [4096, 512, 0, 0], 0, 1)),
+    # taken at 128 rows on the word of two empty batches: re-taken whole
+    "growing": dict(
+        live=(0, 0, 4096),
+        want=(["empty", "empty", "repair"], [0, 0, 4096], 1, 2)),
+    # a stream with nothing to drop loses nothing: folded as it came
+    "all_live": dict(
+        live=(4096, 4096, 4096),
+        want=(["seed", "dense", "dense"], [4096, 4096, 4096], 0, 0)),
+    # shorter than the window: every fold comes out of the boundary's drain
+    "three_sparse": dict(
+        live=(400, 300, 500),
+        want=(["seed", "compact", "compact"], [512, 1024, 1024], 0, 3)),
+}
+
+
+def _sparse_int_frames(lives, cap=4096, key_base=(0,), seed=35):
+    """Batches of ``cap`` rows of which ``lives[i]`` pass ``live IS NOT
+    NULL``; (frames, the live (k, v) rows)."""
+    rng = np.random.default_rng(seed)
+    frames, rows = [], []
+    for i, n_live in enumerate(lives):
+        ks = key_base[i % len(key_base)] + rng.integers(0, 37, cap)
+        alive = np.zeros(cap, dtype=bool)
+        alive[rng.choice(cap, n_live, replace=False)] = True
+        vs = rng.integers(1, 10_000, cap)
+        frames.append(Batch.from_pydict({
+            "k": [int(k) for k in ks],
+            "v": [int(v) for v in vs],
+            "live": [1 if a else None for a in alive],
+        }))
+        rows += [(int(k), int(v)) for k, v, a in zip(ks, vs, alive) if a]
+    return frames, rows
+
+
+def _run_dense_partial(frames):
+    """scan -> filter -> PARTIAL -> FINAL over integer keys with the rings
+    on; (groups, the partial's metrics, its dense fold events in order)."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.exec.basic import FilterExec
+    from auron_tpu.exprs.ir import IsNotNull
+    from auron_tpu.obs import core
+
+    aggs = [(AggExpr("count_star", None), "c"), (AggExpr("sum", col(1)), "s"),
+            (AggExpr("min", col(1)), "mn")]
+    scan = MemoryScanExec.single(
+        [Batch(b.schema, b.device, b.dicts) for b in frames])
+    flt = FilterExec(scan, [IsNotNull(col(2))])
+    p = HashAggExec(flt, [(col(0), "k")], aggs, PARTIAL)
+    f = HashAggExec(p, [(col(0), "k")], aggs, FINAL)
+    assert p._dense_eligible() and p._fold_columns() == ((0, 1), 6)
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        ctx = ExecutionContext()
+        ctx.metrics.name = f.name
+        t0 = time.perf_counter()
+        out = f.collect(ctx=ctx).to_pandas().sort_values("k").reset_index(drop=True)
+        t1 = time.perf_counter()
+        lo, hi = int(t0 * 1e9), int(t1 * 1e9)
+        events = sorted((ev for _ring, evs in core.snapshot_events() for ev in evs
+                         if lo <= ev[0] < hi), key=lambda ev: ev[0])
+        ws = obs.window_summary(t0, t1)
+    finally:
+        obs.set_mode(saved)
+    folds = [ev[7] for ev in events
+             if ev[2] == "fold" and ev[3] == "agg.partial"]
+    assert all(f["path"] == "dense" for f in folds)
+    return out, ctx.metrics, folds, ws
+
+
+def _want_groups(rows):
+    return (
+        pd.DataFrame(rows, columns=["k", "v"])
+        .groupby("k").agg(c=("v", "size"), s=("v", "sum"), mn=("v", "min"))
+        .reset_index().sort_values("k").reset_index(drop=True)
+    )
+
+
+@pytest.mark.parametrize("case", sorted(_DENSE_BOUNDARY_CASES))
+def test_dense_arm_folds_at_the_width_of_its_live_rows(case, dense_fold_substrate):
+    """The dense arm takes its batches through a CompactionBoundary: a batch
+    folds at the bucket of its live rows (the columns the fold reads, and no
+    other, compacted), an empty one leaves its event and folds nothing, a
+    truncating prediction is re-taken from the held batch, an all-live
+    stream folds every batch as it came, and a stream shorter than the
+    window is folded whole by the boundary's drain, which runs before
+    ``finish_pending``. The groups are pandas' in every case, on both fold
+    substrates."""
+    from auron_tpu.utils.config import TRANSFER_WINDOW_DEPTH, active_conf
+
+    spec = _DENSE_BOUNDARY_CASES[case]
+    frames, rows = _sparse_int_frames(spec["live"])
+    conf = active_conf()
+    saved_depth = conf.get(TRANSFER_WINDOW_DEPTH)
+    conf.set(TRANSFER_WINDOW_DEPTH, 4)
+    try:
+        got, metrics, folds, ws = _run_dense_partial(frames)
+    finally:
+        conf.set(TRANSFER_WINDOW_DEPTH, saved_depth)
+    want = _want_groups(rows)
+    for c in ("k", "c", "s", "mn"):
+        assert got[c].tolist() == want[c].tolist(), c
+    modes, widths, repairs, compacted = spec["want"]
+    assert [f["take"] for f in folds] == modes
+    assert [f["rows"] for f in folds] == widths
+    assert all(f["in_rows"] == 4096 for f in folds)
+    # the boundary has read every count by the time a batch is folded
+    assert [f["live"] for f in folds] == list(spec["live"])
+    assert metrics.total("sel_mispredicts") == repairs
+    assert metrics.total("agg_compacted_batches") == compacted
+    # the FINAL aggregate above is dense too: one state, seeded, all live
+    want_modes = {m: modes.count(m) for m in set(modes)}
+    want_modes["seed"] = want_modes.get("seed", 0) + 1
+    assert ws["agg_dense_folds"] == want_modes
+    assert ws["agg_folds"]["dense"]["n"] == len(folds) + 1
+
+
+def test_dense_arm_restart_in_a_compacted_stream_folds_no_batch_twice(
+        dense_fold_substrate):
+    """Sparse batches whose keys jump between far-apart ranges: the restart
+    protocol sees the narrower batches the boundary hands it, folds the held
+    ones again after the re-anchor (events with no take), and the totals
+    stay pandas'."""
+    frames, rows = _sparse_int_frames(
+        (300,) * 9, key_base=(0, 500_000, 0, 900_000))
+    got, metrics, folds, _ws = _run_dense_partial(frames)
+    want = _want_groups(rows)
+    for c in ("k", "c", "s", "mn"):
+        assert got[c].tolist() == want[c].tolist(), c
+    taken = [f for f in folds if f["take"] is not None]
+    assert len(taken) == 9 and all(f["rows"] <= 1024 for f in taken)
+    assert len(folds) > len(taken), "no restart: the test's shape regressed"
+    assert metrics.total("agg_compacted_batches") == 9
+
+
+def test_compact_batch_gathers_the_named_columns_alone():
+    """The dense arm's take: live rows of the columns the fold reads in a
+    prefix of the bucket, every other column all NULL (filled, not
+    gathered), the schema and the dictionaries as they were."""
+    from auron_tpu.columnar.batch import compact_batch
+
+    frames, rows = _sparse_int_frames((100,), cap=1024)
+    from auron_tpu.exec.basic import FilterExec
+    from auron_tpu.exprs.ir import IsNotNull
+
+    (b,) = list(FilterExec(MemoryScanExec.single(frames),
+                           [IsNotNull(col(2))]).execute(0, ExecutionContext()))
+    assert b.capacity == 1024
+    out = compact_batch(b, 128, cols=(0, 1))
+    assert out.capacity == 128 and out.schema == b.schema
+    got = out.to_pandas()
+    assert list(zip(got.k, got.v)) == rows
+    assert got.live.isna().all()
+    whole = compact_batch(b, 128).to_pandas()
+    assert whole.live.notna().all() and whole.k.tolist() == got.k.tolist()
+    assert compact_batch(b, 1024, cols=(0,)) is b
+
+
+def test_dense_arm_rule_at_query_65s_shapes(monkeypatch):
+    """On the TPU the arm's rule counts gathered elements: one int64 sum by
+    two int64 keys folds a dead row for 5 of them (an int64 scatter-add
+    four, the two flag scatters half of one each) and takes 9 planes, so a
+    batch of 4,194,304 compacts at an eighth of its capacity or less."""
+    from auron_tpu.columnar import batch as batch_mod
+    from auron_tpu.exec import agg_exec as agg_mod
+
+    frames, _ = _sparse_int_frames((8,), cap=128)
+    scan = MemoryScanExec.single(frames)
+    p = HashAggExec(scan, [(col(0), "a"), (col(2), "b")],
+                    [(AggExpr("sum", col(1)), "s")], PARTIAL)
+    cols, planes = p._fold_columns()
+    assert (cols, planes) == ((0, 1, 2), 9)
+    fold = agg_mod._DenseAggState(p, ExecutionContext()).fold_planes()
+    assert fold == agg_mod.SCATTER_WIDE + 2 * agg_mod.SCATTER_NARROW == 5.0
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: True)
+    rule = lambda n: batch_mod.compaction_bucket(
+        n, 4194304, dense_planes=fold, taken_planes=planes + fold)
+    assert rule(420_000) == 524288
+    assert rule(600_000) is None
+    assert rule(1) == batch_mod.MIN_CAPACITY
+
+
 def test_dense_agg_sentinel_key_extremes(dense_fold_substrate):
     """A key near the int64 extremes must trigger the dense table's
     re-anchor (then permanent fallback), never fold into a clamped slot:

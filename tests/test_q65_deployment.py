@@ -155,9 +155,19 @@ def test_aggregates_leave_their_folds_and_groups_in_the_rings(q65, monkeypatch):
     finally:
         obs.set_mode(saved)
     pairs, stores = len(q65.pair_revenue(frames)), len(got.attrs["sb"])
+    year = frames["store_sales"].ss_sold_date_sk.between(2450815, 2451179)
     assert ws["complete"]
     assert ws["agg_groups"] == 3 * pairs + PARAMS["n_reduce"] * stores + stores
     assert set(ws["agg_folds"]) == {"dense", "sort"}
+    # the dense arm's compaction boundary (PR 35): of the map side's four
+    # batches the head of the date-ordered fact table holds the year and is
+    # folded as it came, the three behind it are empty and fold nothing
+    # (rows 0); each reduce task's final sum folds its one all-live batch
+    assert ws["agg_dense_folds"] == {"seed": 1 + 2 * PARAMS["n_reduce"],
+                                     "empty": 3}
+    dense = ws["agg_folds"]["dense"]
+    assert dense["n"] == 8 and dense["rows"] == 3 * PARAMS["batch_rows"]
+    assert dense["live"] == int(year.sum()) + 2 * pairs
     assert ws["agg_folds"]["sort"]["n"] == 2 * PARAMS["n_reduce"]   # the averages
     assert ws["agg_reduces"]["sort"]["rows"] == ws["agg_sorted_rows"]
     assert ws["agg_sorted_rows"] == ws["agg_folds"]["sort"]["rows"]
