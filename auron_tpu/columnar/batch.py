@@ -382,6 +382,16 @@ class Batch:
         return Batch(schema or self.schema, dev,
                      dicts if dicts is not None else self.dicts)
 
+    def on_device(self, device) -> "Batch":
+        """This batch with its planes on ``device``: itself where they lie
+        there already, else a copy committed to it: a table's split placed
+        on its chip, a build side's copy a chip, a stage's outputs gathered
+        for the collect stage."""
+        if self.device.sel.devices() == {device}:
+            return self
+        return Batch(self.schema, jax.device_put(self.device, device),
+                     self.dicts)
+
     def prefetch_host(self) -> None:
         """Start non-blocking device->host copies of every array so a later
         ``to_arrow`` finds the data already landed (the task pump calls

@@ -99,6 +99,10 @@ def split_stages(
         which = node.WhichOneof("plan")
         if which == "mesh_exchange":
             ex = node.mesh_exchange
+            if ex.broadcast:
+                raise NotImplementedError(
+                    "a broadcast mesh_exchange has no shuffle-file form: a "
+                    "host engine schedules it as its own BroadcastExchange")
             child_inputs: list[str] = []
             child = rewrite(ex.child, child_inputs)
             ex_id = namespace + (

@@ -29,6 +29,15 @@ Two growth protocols coexist:
 - ``acquire(consumer, additional)`` — cascade protocol used by streaming
   operators: spill the largest *other* spillable consumers first, the
   requester last, so small consumers can grow at dominant ones' expense.
+
+The budget is ONE CHIP's. A consumer belongs to the chip its owner's work
+lands on — JAX's default device on the registering thread, which the mesh
+driver sets to a partition's chip around its pump (parallel/mesh_driver.py)
+— and every share, shortfall and victim list is taken among the consumers
+of that chip alone: a partition that fills its chip spills its own state,
+never a sibling's on a chip with room. Outside a mesh pump (a bridge task,
+the collect stage) a consumer is on the process's first device, so on one
+chip there is the one ledger the manager always had.
 """
 
 from __future__ import annotations
@@ -69,6 +78,15 @@ def _auto_budget() -> int:
         return 8 << 30
 
 
+def _calling_device():
+    """The chip the calling thread's arrays land on: ``jax.default_device``'s
+    value on this thread, the process's first device where none is named."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    return dev if isinstance(dev, jax.Device) else jax.local_devices()[0]
+
+
 class MemConsumer(Protocol):
     name: str
 
@@ -100,6 +118,8 @@ class MemManager:
         self._released = threading.Condition(self._lock)
         self._consumers: list[MemConsumer] = []
         self._spillable: dict[int, bool] = {}
+        # the chip whose ledger each consumer is on
+        self._device: dict[int, object] = {}
         # owning span captured at register(): registration happens on the
         # owning task's thread, so a spill dispatched LATER by a foreign
         # thread still attributes to the owner's trace (obs/span.py)
@@ -127,6 +147,7 @@ class MemManager:
         with self._lock:
             self._consumers.append(consumer)
             self._spillable[id(consumer)] = spillable
+            self._device[id(consumer)] = _calling_device()
             self._owner_spans[id(consumer)] = obs.current_span()
 
     def unregister(self, consumer: MemConsumer) -> None:
@@ -134,6 +155,7 @@ class MemManager:
             if consumer in self._consumers:
                 self._consumers.remove(consumer)
             self._spillable.pop(id(consumer), None)
+            self._device.pop(id(consumer), None)
             self._owner_spans.pop(id(consumer), None)
             # freed capacity: wake waiters blocked on the managed pool
             self._released.notify_all()
@@ -144,9 +166,25 @@ class MemManager:
         with self._lock:
             self._released.notify_all()
 
-    def total_used(self) -> int:
+    def _beside(self, consumer: MemConsumer) -> list:
+        """The registered consumers on ``consumer``'s chip."""
+        dev = self._device.get(id(consumer))
+        if dev is None:     # not registered: the chip its caller works on
+            dev = _calling_device()
+        return [c for c in self._consumers
+                if self._device.get(id(c)) == dev]
+
+    def total_used(self, consumer: MemConsumer | None = None) -> int:
+        """Bytes in use on ``consumer``'s chip; with none named, on the
+        fullest chip (what admission holds against the one-chip budget)."""
         with self._lock:
-            return sum(c.mem_used() for c in self._consumers)
+            if consumer is not None:
+                return sum(c.mem_used() for c in self._beside(consumer))
+            by_dev: dict = {}
+            for c in self._consumers:
+                dev = self._device.get(id(c))
+                by_dev[dev] = by_dev.get(dev, 0) + c.mem_used()
+            return max(by_dev.values(), default=0)
 
     def mem_snapshot(self) -> dict:
         """THE manager snapshot both observability surfaces render
@@ -159,18 +197,19 @@ class MemManager:
                 "budget_bytes": self.budget,
                 "num_spills": self.num_spills,
                 "consumers": [
-                    {"name": c.name, "mem_used": c.mem_used()}
+                    {"name": c.name, "mem_used": c.mem_used(),
+                     "device": getattr(self._device.get(id(c)), "id", None)}
                     for c in self._consumers
                 ],
             }
 
-    def _pool_state(self) -> tuple[int, int, int]:
-        """(total_used, managed_pool, num_spillables) — managed pool =
-        budget minus unspillable usage (lib.rs:355-364)."""
+    def _pool_state(self, consumer: MemConsumer) -> tuple[int, int, int]:
+        """(total_used, managed_pool, num_spillables) of ``consumer``'s chip
+        — managed pool = budget minus unspillable usage (lib.rs:355-364)."""
         total_used = 0
         unspillable = 0
         n_spillables = 0
-        for c in self._consumers:
+        for c in self._beside(consumer):
             u = c.mem_used()
             total_used += u
             if self._spillable.get(id(c), True):
@@ -182,7 +221,7 @@ class MemManager:
     def mem_used_percent(self, consumer: MemConsumer) -> float:
         """Consumer's share of its fair-share maximum (lib.rs:213-225)."""
         with self._lock:
-            _, managed, n = self._pool_state()
+            _, managed, n = self._pool_state(consumer)
             return consumer.mem_used() / max(managed / n, 1)
 
     def _dispatch_spill(self, consumer: MemConsumer) -> int:
@@ -219,7 +258,7 @@ class MemManager:
             return
         with self._lock:
             spillable = self._spillable.get(id(consumer), True)
-            total_used, managed, n = self._pool_state()
+            total_used, managed, n = self._pool_state(consumer)
             consumer_max = managed // n
             consumer_min = consumer_max // 8
             over = total_used > managed or new_used > consumer_max
@@ -230,10 +269,13 @@ class MemManager:
             else:
                 # below min share (or unspillable): wait for the pool
                 self.num_waits += 1
-                ok = self._released.wait_for(
-                    lambda: self._pool_state()[0] <= self._pool_state()[1],
-                    timeout=self._wait_timeout,
-                )
+
+                def pool_has_room() -> bool:
+                    used, managed, _ = self._pool_state(consumer)
+                    return used <= managed
+
+                ok = self._released.wait_for(pool_has_room,
+                                             timeout=self._wait_timeout)
                 if ok or not spillable:
                     return
         # self-spill without holding the manager lock (consumer locks are
@@ -257,13 +299,13 @@ class MemManager:
         chosen under the lock, spilled outside it, and the shortfall
         re-checked per victim."""
         with self._lock:
-            needed = self.total_used() + additional - self.budget
+            needed = self.total_used(consumer) + additional - self.budget
             if needed <= 0:
                 return
             others = sorted(
                 (
                     c
-                    for c in self._consumers
+                    for c in self._beside(consumer)
                     if c is not consumer and self._spillable.get(id(c), True)
                 ),
                 key=lambda c: c.mem_used(),
@@ -279,7 +321,7 @@ class MemManager:
                 # membership: a victim that finished and unregistered in
                 # the meantime must not be spilled (its spill would write
                 # a temp file nothing ever unlinks, ADVICE r4)
-                needed = self.total_used() + additional - self.budget
+                needed = self.total_used(consumer) + additional - self.budget
                 gone = c is not consumer and c not in self._consumers
             if needed <= 0:
                 break

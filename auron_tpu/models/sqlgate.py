@@ -133,18 +133,26 @@ def plan_text(lq: LoweredQuery) -> str:
     return "\n".join(parts) + "\n"
 
 
-def build_resources(lq: LoweredQuery, frames: dict, cache: dict) -> dict:
+def build_resources(lq: LoweredQuery, frames: dict, cache: dict,
+                    devices=None) -> dict:
     """Resource dict for MeshQueryDriver; batch lists cached per
-    (rid, n_parts) so the 25-query gate uploads each view once."""
+    (rid, n_parts) so the 25-query gate uploads each view once. With the
+    mesh's ``devices`` partition ``p``'s batches lie on device ``p`` (a
+    replicated view: one copy a device), as ``serve.SqlServer`` keeps
+    its tables."""
     resources = {}
     for use in lq.tables:
         key = (use.rid, lq.n_parts)
         if key not in cache:
             df = frames[use.table]
             if use.replicated:
-                cache[key] = [tpcds.to_batches(df, 1)[0]] * lq.n_parts
+                view = [tpcds.to_batches(df, 1)[0]] * lq.n_parts
             else:
-                cache[key] = tpcds.to_batches(df, lq.n_parts)
+                view = tpcds.to_batches(df, lq.n_parts)
+            if devices is not None:
+                view = [[b.on_device(d) for b in part]
+                        for part, d in zip(view, devices)]
+            cache[key] = view
         resources[use.rid] = cache[key]
     return resources
 
@@ -158,11 +166,13 @@ def execute(lq: LoweredQuery, frames: dict, mesh, conf=None,
     from auron_tpu.parallel.mesh_driver import MeshQueryDriver
 
     cache = cache if cache is not None else {}
-    resources = build_resources(lq, frames, cache)
     if driver is None:
         driver = MeshQueryDriver(mesh, conf=conf or Configuration())
+    devices = list(driver.mesh.devices.flat)
+    resources = build_resources(lq, frames, cache, devices)
     outs = driver.run(lq.distributed, resources)
-    batches = [b for part in outs for b in part]
+    # the partitions' outputs gathered where the collect stage runs
+    batches = [b.on_device(devices[0]) for part in outs for b in part]
     if lq.collect is None:
         dfs = [b.to_pandas() for b in batches]
     else:

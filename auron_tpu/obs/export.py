@@ -134,7 +134,19 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     the unique-build probes looked their keys up at (the ``lookup``
     events, ``obs.note_join_lookup``). ``plan_cache_hits`` and
     ``plan_cache_misses`` count the ``serve:plan`` spans that began in the
-    window by their ``cache_hit`` argument. ``complete`` is False where
+    window by their ``cache_hit`` argument. ``exchange_bytes`` sums by
+    ``mode`` (``mesh``, ``file``) the ``bytes`` of the ``exchange:write``
+    spans that began in the window, and ``exchange_devices_min`` holds by
+    span name (``write``, ``read``) the least ``devices`` any of them
+    carried: the devices the operands lay on before the send, those of what
+    the read handed out. ``stage_devices_min`` is the least ``devices`` of
+    the window's ``pump:stage`` spans (the distinct single devices a
+    stage's partitions' inputs lay on; None where no stage began), and
+    ``partition_pumps`` the ``pump:partition`` regions: their count ``n``,
+    their ``thread_s``, the seconds ``open_s`` in which at least one was
+    open, and ``width``, the most partitions a stage had: ``thread_s`` over
+    ``width x open_s`` is 1 where a stage's partitions ran side by side
+    and ``1 / width`` where they took turns. ``complete`` is False where
     a ring that may hold events of the window has wrapped, or left the
     registry with events newer than the window's start: the sums are then
     a lower bound and a metric reader reports nothing."""
@@ -150,6 +162,11 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     takes: dict[str, int] = {}
     lookups: dict[str, int] = {}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
+    ex_bytes: dict[str, int] = {}
+    ex_devices: dict[str, int] = {}     # least devices by exchange:<name>
+    stage_devices = None                # least devices over pump:stage spans
+    pumps = {"n": 0, "thread_s": 0.0, "open_s": 0.0, "width": 0}
+    pump_open: list = []                # pump:partition intervals, clipped
 
     def book(table: dict, key: str, dur_ns: int, own_ns: int) -> None:
         ent = table.setdefault(key, {"n": 0, "total_s": 0.0, "self_s": 0.0})
@@ -167,6 +184,17 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
                 continue
             if ev[8] == "serve" and ev[3] == "plan":
                 plans[bool(ev[7]["cache_hit"])] += 1
+            elif ev[8] == "exchange" and isinstance(ev[7], dict):
+                if "mode" in ev[7]:
+                    ex_bytes[ev[7]["mode"]] = (ex_bytes.get(ev[7]["mode"], 0)
+                                               + ev[7].get("bytes", 0))
+                if "devices" in ev[7]:
+                    ex_devices[ev[3]] = min(ex_devices.get(ev[3], 1 << 30),
+                                            ev[7]["devices"])
+            elif ev[8] == "pump" and ev[3] == "stage":
+                pumps["width"] = max(pumps["width"], ev[7]["parts"])
+                stage_devices = (ev[7]["devices"] if stage_devices is None
+                                 else min(stage_devices, ev[7]["devices"]))
             elif ev[2] == "fold":
                 fold_rows += ev[7]["rows"]
                 ent = folds.setdefault(ev[7]["path"],
@@ -200,6 +228,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
         ]
         for (s, e, layer, name, arg), own in self_ns(regions):
             book(layers, layer, e - s, own)
+            if layer == "pump" and name == "partition":
+                pump_open.append((s, e))
             if layer == "sync":
                 d2h += arg.get("bytes", 0) if isinstance(arg, dict) else 0
                 site = sites.setdefault(name, [0, 0.0])
@@ -207,6 +237,12 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
                 site[1] += (e - s) / 1e9
             else:
                 book(spans, f"{layer}:{name}", e - s, own)
+    pumps["n"] = len(pump_open)
+    pumps["thread_s"] = sum(e - s for s, e in pump_open) / 1e9
+    end = 0
+    for s, e in sorted(pump_open):      # the union: seconds with one open
+        pumps["open_s"] += max(e - max(s, end), 0) / 1e9
+        end = max(end, e)
     ranked = sorted(sites.items(), key=lambda kv: -kv[1][1])[:top]
     return {"t0_s": t0_s, "t1_s": t1_s, "complete": complete,
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
@@ -217,6 +253,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             "join_gather_rows": gather_rows, "join_takes": takes,
             "join_lookup_rows": lookups,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
+            "exchange_bytes": ex_bytes, "exchange_devices_min": ex_devices,
+            "stage_devices_min": stage_devices, "partition_pumps": pumps,
             "sync_sites": [[k, n, secs] for k, (n, secs) in ranked]}
 
 

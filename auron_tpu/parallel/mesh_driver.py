@@ -9,9 +9,17 @@ over ICI via ``lax.all_to_all`` with no intermediate files; when they
 don't (or the payload is too large to stay device-resident), the driver
 lowers the SAME node onto the durable file-shuffle pair.
 
+A mesh of width N over N devices is a deployment, and the one-device mesh
+runs the same code: partition ``p`` of every stage runs on mesh device
+``p``, on the table splits the server placed there (serve/server.py) or on
+the shard an exchange delivered there, and a stage's partitions are pumped
+side by side: the first on the driver's thread, each other on a task thread
+of its own (``_pump_stage``).
+
 ``MeshQueryDriver.run`` resolves every ``mesh_exchange`` node bottom-up:
 
-1. run the child sub-plan for each mesh partition (the map stage);
+1. run the child sub-plan for each mesh partition (the map stage), the
+   partitions side by side, each on its own device;
 2. compute per-row destination partition ids with the *same*
    ``Partitioning`` code the file shuffle writer uses — mesh and file
    exchanges route bit-identically (spark-exact murmur3, dict strings,
@@ -20,13 +28,19 @@ lowers the SAME node onto the durable file-shuffle pair.
    (auto = mesh when the estimated per-shard payload fits
    ``exchange.mesh.max.bytes``, else file) — the ICI-vs-file decision rule;
 4. mesh: unify dictionaries across shards, pad every shard to a common
-   capacity bucket, stack to [P, cap], exchange with
+   capacity bucket, assemble the [P, cap] operands from the shards'
+   planes where they lie (no stack on one chip), exchange with
    ``pid_exchange_step`` (slot capacity sized exactly from host-side
    per-(src,dst) counts, so overflow is impossible), and expose each
-   shard's received rows as a memory-scan resource;
+   partition's received shard, on its own device, as a memory-scan
+   resource;
    file: execute a ShuffleWriterExec per shard and expose the blocks
    through IpcReader — byte-identical to the standalone file path;
 5. splice a scan node where the exchange was and continue planning.
+
+A ``mesh_exchange`` marked ``broadcast`` (a derived table on a join's build
+side, sql/lowering.py) runs its child stage the same way and hands every
+partition every row, copied to its chip (``_broadcast``).
 
 Exchange statistics (rows per (src, dst)) are recorded on the driver —
 the same numbers AQE coalescing consumes (parallel/broadcast.py
@@ -37,7 +51,8 @@ from __future__ import annotations
 
 import os
 import tempfile
-from dataclasses import dataclass, field
+import threading
+from dataclasses import dataclass
 from functools import partial
 
 import jax
@@ -55,7 +70,7 @@ from auron_tpu.columnar.batch import (
 )
 from auron_tpu.exec.base import ExecutionContext
 from auron_tpu.parallel.exchange import pid_exchange_step
-from auron_tpu.parallel.mesh import PARTITION_AXIS, shard_rows
+from auron_tpu.parallel.mesh import PARTITION_AXIS, shard_spec
 from auron_tpu.plan.planner import (
     partitioning_from_proto,
     plan_from_proto,
@@ -68,6 +83,7 @@ from auron_tpu.utils.config import (
     EXCHANGE_MESH_MAX_BYTES,
     EXCHANGE_MODE,
     Configuration,
+    conf_scope,
 )
 
 
@@ -76,13 +92,13 @@ class ExchangeStats:
     """Map-output statistics of one resolved exchange (AQE input)."""
 
     exchange_id: str
-    mode: str  # "mesh" | "file"
+    mode: str  # "mesh" | "file" | "broadcast"
     rows: np.ndarray  # [P_src, P_dst] routed row counts
     est_bytes_per_shard: int  # payload of the hottest receiving shard
     coalesced_groups: list | None = None  # AQE partition grouping, if applied
     #: AQE skew-split task table, if applied: [(pid, map_lo, map_hi|None)]
     skew_tasks: list | None = None
-    #: mesh transport: how many devices the exchanged arrays live on
+    #: mesh transport: how many devices the partitions it handed out lie on
     n_devices: int = 0
 
     def partition_sizes(self) -> np.ndarray:
@@ -213,11 +229,9 @@ class MeshQueryDriver:
                 self.local_parts if self.spmd
                 else range(self._reduce_parts or self.n_parts)
             )
-            for p in parts:
-                op = self._plan_stage(resolved)
-                ctx = ExecutionContext(partition_id=p, conf=self.conf.copy(),
-                                       resources=resources)
-                outs[p] = _pump(op, p, ctx)
+            for p, (_, got) in zip(parts, self._pump_stage(resolved, parts,
+                                                          resources)):
+                outs[p] = got
             return outs
         finally:
             self._cleanup_tmp()
@@ -237,6 +251,86 @@ class MeshQueryDriver:
             plan = plan_from_proto(proto)
             with obs.span("fusion", cat="plan"):
                 return fuse_exec_tree(plan, self.conf)
+
+    def _device_of(self, partition: int):
+        """The chip a stage's partition runs on: the mesh's device of that
+        index (a stage that a skew split widened past the mesh wraps)."""
+        devs = self.mesh.devices.flat
+        return devs[partition % len(devs)]
+
+    def _pump_stage(self, proto: pb.PhysicalPlanNode, parts, resources: dict,
+                    concat: bool = False) -> list[tuple]:
+        """Run one stage: its partitions pumped side by side as the
+        bridge's tasks of a stage are, the first on the calling thread and
+        each other on a task thread of its own. Each pump plans its own
+        exec tree (operators keep per-partition state) and runs under the query's conf and span (the R7 hand-off
+        of runtime/task.py) with its partition's chip as JAX's default
+        device: what an operator makes from the host lands beside the
+        partition's batches, and nothing is committed to chip 0 on the way.
+        Returns ``(schema, batches)`` per partition in ``parts``' order
+        (``concat``: the batches as one, an empty batch where there were
+        none). The stage is a ``pump:stage`` span on the calling thread,
+        each partition a ``pump:partition`` span under it that carries its
+        partition and the device its input lies on (docs/observability.md).
+        """
+        parts = list(parts)
+        scans = [rid for kind, rid in self._collect_sources(proto)
+                 if kind == "memory_scan"]
+        parent_arg = {"parts": len(parts), "devices": 0}
+        results: list = [None] * len(parts)
+        errors: list = [None] * len(parts)
+        devices: list = [None] * len(parts)
+
+        with obs.span("stage", cat="pump", arg=parent_arg) as stage:
+
+            def pump_partition(i: int, p: int) -> None:  # auronlint: thread-root(conf-scoped) -- stage partition pump; installs conf_scope(self.conf) before touching engine code
+                try:
+                    arg = {"partition": p, "device": None}
+                    with conf_scope(self.conf), \
+                            jax.default_device(self._device_of(p)), \
+                            obs.span("partition", cat="pump", parent=stage,
+                                     arg=arg):
+                        op = self._plan_stage(proto)
+                        ctx = ExecutionContext(partition_id=p,
+                                               conf=self.conf.copy(),
+                                               resources=resources)
+                        got = _pump(op, p, ctx)
+                        if concat:
+                            got = [device_concat(got) if got
+                                   else Batch.empty(op.schema)]
+                        # the device of what the partition scanned; of what
+                        # it made where it read no resident batch (a file
+                        # exchange's blocks); else the one it ran on
+                        lay = _one_device(
+                            _stage_inputs(scans, resources, p) or got)
+                        arg["device"] = devices[i] = (
+                            self._device_of(p).id if lay is None else lay)
+                        results[i] = (op.schema, got)
+                except BaseException as e:  # noqa: BLE001 -- raised again on the driver's thread, below
+                    errors[i] = e
+
+            # the first partition is pumped here, on the driver's thread,
+            # the others beside it on a task thread each: one path at every
+            # width, and a one-wide mesh hands nothing over (a hand-over to
+            # a thread and back costs two waits for the interpreter's lock,
+            # 10 ms a query with four queries in flight: PERF.md section 6,
+            # PR 36)
+            threads = [
+                threading.Thread(target=pump_partition, args=(i, p),
+                                 daemon=True, name=f"auron-mesh-pump-p{p}")
+                for i, p in enumerate(parts) if i
+            ]
+            for t in threads:
+                t.start()
+            pump_partition(0, parts[0])
+            for t in threads:
+                t.join()
+            parent_arg["devices"] = len(
+                {d for d in devices if d is not None and d >= 0})
+        for e in errors:
+            if e is not None:
+                raise e
+        return results
 
     @staticmethod
     def _collect_sources(plan: pb.PhysicalPlanNode) -> list[tuple[str, str]]:
@@ -425,6 +519,8 @@ class MeshQueryDriver:
     def _execute_exchange(
         self, spec: pb.MeshExchangeNode, child: pb.PhysicalPlanNode, resources: dict
     ) -> pb.PhysicalPlanNode:
+        if spec.broadcast:
+            return self._broadcast(spec, child, resources)
         part = partitioning_from_proto(spec.partitioning)
         assert part.num_partitions == self.n_parts, (
             f"exchange over {part.num_partitions} partitions on a "
@@ -440,27 +536,76 @@ class MeshQueryDriver:
         n_src = self._maybe_coalesce_inputs(child, resources)
         if n_src == self.n_parts and not self.spmd:
             n_src = self._maybe_split_skew(child, resources)
-        op = self._plan_stage(child)
-        schema = op.schema
-        shard_batches: list[Batch] = []
         map_parts = self.local_parts if self.spmd else range(n_src)
-        for p in map_parts:
-            ctx = ExecutionContext(partition_id=p, conf=self.conf.copy(),
-                                   resources=resources)
-            got = _pump(op, p, ctx)
-            shard_batches.append(
-                device_concat(got) if got else Batch.empty(schema))
+        pumped = self._pump_stage(child, map_parts, resources, concat=True)
+        schema = pumped[0][0]
+        shard_batches: list[Batch] = [got[0] for _, got in pumped]
         with obs.span("write", cat="exchange") as sp:
             out = self._route(spec, part, schema, shard_batches, n_src,
                               ex_id, resources)
             if sp is not None:
                 st = self.stats[-1]
                 sp.arg = {"mode": st.mode, "rows": int(st.rows.sum()),
-                          "bytes": int(st.rows.sum()) * _row_width_bytes(schema)}
+                          "bytes": int(st.rows.sum()) * _row_width_bytes(schema),
+                          "devices": _n_devices(shard_batches)}
         if isinstance(out, pb.PhysicalPlanNode):
             return out          # file transport: its readers are IpcReaders
-        with obs.span("read", cat="exchange"):
-            return self._mesh_receive(schema, ex_id, resources, *out)
+        with obs.span("read", cat="exchange") as sp:
+            node = self._mesh_receive(schema, ex_id, resources, *out)
+            if sp is not None:
+                sp.arg = {"devices": self.stats[-1].n_devices}
+            return node
+
+    def _broadcast(self, spec: pb.MeshExchangeNode,
+                   child: pb.PhysicalPlanNode,
+                   resources: dict) -> pb.PhysicalPlanNode:
+        """A broadcast exchange: the child stage runs once at mesh width,
+        and every partition is handed every row of its output, copied to
+        its own chip (a derived table on a join's build side, a few rows:
+        sql/lowering.py). No routing and no collective program: N x N
+        device-to-device copies of the shards that hold rows. One
+        ``exchange:write`` span (``mode`` ``broadcast``, ``bytes`` what all
+        the copies carry) and one ``exchange:read``."""
+        if self.spmd:
+            raise NotImplementedError(
+                "a broadcast exchange in SPMD mode: a peer process's shards "
+                "are not addressable here (it needs a host-level allgather "
+                "of the rows)")
+        ex_id = spec.exchange_id or f"__mesh_exchange_{self._exchange_seq}"
+        self._exchange_seq += 1
+        n_src = self._maybe_coalesce_inputs(child, resources)
+        pumped = self._pump_stage(child, range(n_src), resources, concat=True)
+        schema = pumped[0][0]
+        shards: list[Batch] = [got[0] for _, got in pumped]
+        with obs.span("write", cat="exchange") as sp:
+            # auronlint: sync-point(4/task) -- a broadcast's live rows a shard, read once at the stage boundary; one batched transfer
+            live = np.asarray(jax.device_get(
+                [jnp.sum(b.device.sel, dtype=jnp.int32) for b in shards]),
+                dtype=np.int64)
+            counts = np.repeat(live[:, None], self.n_parts, axis=1)
+            width = _row_width_bytes(schema)
+            self.stats.append(ExchangeStats(
+                ex_id, "broadcast", counts, int(live.sum()) * width))
+            if sp is not None:
+                sp.arg = {"mode": "broadcast", "rows": int(counts.sum()),
+                          "bytes": int(counts.sum()) * width,
+                          "devices": _n_devices(shards)}
+            out_parts = {
+                p: [b.on_device(self._device_of(p))
+                    for b, n in zip(shards, live) if n]
+                for p in range(self.n_parts)
+            }
+        with obs.span("read", cat="exchange") as sp:
+            resources[ex_id] = out_parts
+            self.stats[-1].n_devices = _n_devices(
+                [b for bs in out_parts.values() for b in bs])
+            if sp is not None:
+                sp.arg = {"devices": self.stats[-1].n_devices}
+        return pb.PhysicalPlanNode(
+            memory_scan=pb.MemoryScanNode(
+                schema=schema_to_proto(schema), resource_id=ex_id
+            )
+        )
 
     def _route(self, spec, part, schema: T.Schema, shard_batches: list[Batch],
                n_src: int, ex_id: str, resources: dict):
@@ -665,32 +810,36 @@ class MeshQueryDriver:
             pad = cap - a.shape[0]
             return jnp.pad(a, (0, pad)) if pad else a
 
-        sel = jnp.stack([padded(b.device.sel) for b in batches])
-        pid = jnp.stack([padded(p).astype(jnp.int32) for p in pids])
+        # the [P, cap] operands, sharded over p, assembled from the shards'
+        # planes WHERE THEY LIE: shard p's row is a [1, cap] array on mesh
+        # device p (a plane that is there already does not move), and the
+        # global array is those rows. Nothing is stacked on one chip and
+        # scattered again; in SPMD mode each process brings its own rows
+        devs = [self._device_of(p) for p in self.local_parts]
+        sharding = jax.sharding.NamedSharding(self.mesh, shard_spec())
+
+        def place(planes: list) -> jax.Array:
+            rows = [jax.device_put(padded(a)[None], d)
+                    for a, d in zip(planes, devs)]
+            return jax.make_array_from_single_device_arrays(
+                (self.n_parts,) + rows[0].shape[1:], sharding, rows)
+
+        sel = place([b.device.sel for b in batches])
+        pid = place([p.astype(jnp.int32) for p in pids])
         values = tuple(
-            jnp.stack([
-                padded(remapped[ci][i] if ci in remapped else b.col_values(ci))
-                for i, b in enumerate(batches)
-            ])
+            place([remapped[ci][i] if ci in remapped else b.col_values(ci)
+                   for i, b in enumerate(batches)])
             for ci in range(ncols)
         )
         validity = tuple(
-            jnp.stack([padded(b.col_validity(ci)) for b in batches])
+            place([b.col_validity(ci) for b in batches])
             for ci in range(ncols)
         )
 
         # slot capacity from the exact routing matrix -> overflow impossible
         slot_cap = bucket_capacity(max(int(counts.max()), 1))
         step = pid_exchange_step(self.mesh, slot_cap)
-        if self.spmd:
-            place = partial(_spmd_shard_rows, self.mesh, self.n_parts)
-        else:
-            place = partial(shard_rows, self.mesh)
-        (rvals, rmasks), rsel, overflow = step(
-            jax.tree.map(place, (values, validity)),
-            place(sel),
-            place(pid),
-        )
+        (rvals, rmasks), rsel, overflow = step((values, validity), sel, pid)
         return tuple(dicts), rvals, rmasks, rsel, overflow
 
     def _mesh_receive(self, schema: T.Schema, ex_id: str, resources: dict,
@@ -701,20 +850,22 @@ class MeshQueryDriver:
         result) and each partition's received rows taken out of the
         exchanged arrays."""
         assert int(jax.device_get(overflow)) == 0, "sized from exact counts"  # auronlint: sync-point(4/task) -- one-scalar overflow invariant check per exchange
-        self.stats[-1].n_devices = len(rsel.sharding.device_set)
 
         # expose the addressable partitions (all of them single-process;
         # only this process's shards in SPMD) as a partition-keyed mapping
-        # — ResourceScanExec indexes dicts and lists identically
-        shard = _local_shard if self.spmd else (lambda a, p: a[p])
+        # — ResourceScanExec indexes dicts and lists identically. Partition
+        # p is its shard of the exchanged arrays, on mesh device p: every
+        # later stage runs on the chip that received its rows
         out_parts: dict[int, list[Batch]] = {}
         for p in self.local_parts:
             dev = DeviceBatch(
-                shard(rsel, p),
-                tuple(shard(v, p) for v in rvals),
-                tuple(shard(m, p) for m in rmasks),
+                _local_shard(rsel, p),
+                tuple(_local_shard(v, p) for v in rvals),
+                tuple(_local_shard(m, p) for m in rmasks),
             )
             out_parts[p] = [Batch(schema, dev, dicts)]
+        self.stats[-1].n_devices = _n_devices(
+            [b for bs in out_parts.values() for b in bs])
         resources[ex_id] = out_parts
         return pb.PhysicalPlanNode(
             memory_scan=pb.MemoryScanNode(
@@ -952,19 +1103,6 @@ def _group_maps_by_bytes(per_map: list[int], target: float) -> list[tuple[int, i
     return groups
 
 
-def _spmd_shard_rows(mesh, n_parts: int, local_arr) -> jax.Array:
-    """SPMD placement: this process's stacked local rows [n_local, ...]
-    become its shards of the global [P, ...] array (every process calls
-    this with its own rows; together they form the full array)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    host = np.asarray(jax.device_get(local_arr))  # auronlint: sync-point(4/task) -- SPMD global-array assembly at the stage boundary
-    global_shape = (n_parts,) + tuple(host.shape[1:])
-    return jax.make_array_from_process_local_data(
-        NamedSharding(mesh, P(PARTITION_AXIS)), host, global_shape
-    )
-
-
 def _local_shard(arr: jax.Array, p: int):
     """Shard p of a leading-axis-sharded global array (must be local)."""
     for s in arr.addressable_shards:
@@ -972,6 +1110,43 @@ def _local_shard(arr: jax.Array, p: int):
         if (idx.start or 0) == p:
             return s.data[0]
     raise KeyError(f"partition {p} not addressable on this process")
+
+
+def _devices_of(batches) -> set:
+    """The devices the batches' planes lie on (their selection planes:
+    a batch's planes travel together)."""
+    return {d for b in batches for d in b.device.sel.devices()}
+
+
+def _n_devices(batches) -> int:
+    return len(_devices_of(batches))
+
+
+def _one_device(batches) -> int | None:
+    """The id of the ONE device the batches lie on; -1 where they lie on
+    several (a replicated or sharded input: R-a3's fault), None of none."""
+    devs = _devices_of(batches)
+    if not devs:
+        return None
+    return next(iter(devs)).id if len(devs) == 1 else -1
+
+
+def _stage_inputs(scans: list[str], resources: dict,
+                  partition: int) -> list[Batch]:
+    """The resident batches a stage's partition scans: its memory-scan
+    leaves' (``scans``, their resource ids) entries for that partition (a
+    file exchange's blocks, read from disk, are no device input)."""
+    out: list[Batch] = []
+    for rid in scans:
+        source = resources.get(rid)
+        if isinstance(source, dict):
+            source = source.get(partition)
+        elif isinstance(source, list) and partition < len(source):
+            source = source[partition]
+        else:
+            continue
+        out.extend(b for b in source or () if isinstance(b, Batch))
+    return out
 
 
 @partial(jax.jit, static_argnames=("n_parts",))

@@ -304,6 +304,18 @@ def mesh_exchange(child, partitioning: pb.Partitioning,
         child=child, partitioning=partitioning, exchange_id=exchange_id))
 
 
+def mesh_broadcast(child, n: int,
+                   exchange_id: str = "") -> pb.PhysicalPlanNode:
+    """A ``mesh_exchange`` that hands every one of the mesh's ``n``
+    partitions every row of its child: a derived table on a join's build
+    side computed once at mesh width, then copied to each partition's chip
+    (Spark's BroadcastExchange; parallel/mesh_driver.py)."""
+    return _wrap(mesh_exchange=pb.MeshExchangeNode(
+        child=child, exchange_id=exchange_id, broadcast=True,
+        partitioning=pb.Partitioning(kind=pb.Partitioning.SINGLE,
+                                     num_partitions=n)))
+
+
 def rss_shuffle_writer(child, partitioning: pb.Partitioning,
                        rss_resource_id: str) -> pb.PhysicalPlanNode:
     return _wrap(rss_shuffle_writer=pb.RssShuffleWriterNode(

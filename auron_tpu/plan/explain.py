@@ -122,7 +122,7 @@ PLAN_DETAILS: dict[str, tuple[str, ...]] = {
     "orc_scan": ("fs_resource_id",),
     "orc_sink": ("output_path",),
     "rss_shuffle_writer": ("rss_resource_id",),
-    "mesh_exchange": ("exchange_id",),
+    "mesh_exchange": ("exchange_id", "broadcast"),
     "kafka_scan": ("topic", "format", "startup_mode", "on_error",
                    "source_resource_id"),
 }

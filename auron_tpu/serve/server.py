@@ -19,14 +19,22 @@ One instance serves many concurrent queries (docs/serving.md):
   global resource map.
 
 The server owns the tables as COLUMNAR batches of the catalog's declared
-schemas, resident on the device from construction: a table is handed
-over as a list of ``Batch`` (a host engine's materialised segments), or
-as a pandas frame that is converted once, here, through the same ingest
-(``Batch.from_pandas`` under the catalog's schema). A scanned view — the
-partitioned ``sql:<table>`` or the replicated ``sql:<table>:all`` — is a
-regrouping of those same batches, so every view of every width shares
-one upload: the Flare compile-once/serve-many shape, applied to data
-residency too.
+schemas, resident on the mesh's devices from construction: a table is
+handed over as batches (a list, or any iterable that makes them one at a
+time: a host engine's materialised segments), or as a pandas frame that
+is converted once, here, through the same ingest (``Batch.from_pandas``
+under the catalog's schema). The batches are a scan's splits, dealt to
+the chips round robin in table order (split ``i`` on device ``i mod N``
+of the default mesh), as a scheduler hands splits to free slots: a
+date-ordered fact table's hot year is spread over the chips. A scanned
+view is a regrouping of those same batches: partition ``p`` of the
+partitioned ``sql:<table>`` is the splits on device ``p``, and the
+replicated ``sql:<table>:all`` (a build side) holds one copy of the table
+on every device, made the first time a plan scans it there and kept; a
+partitioned view at another width than the server's copies what lies
+elsewhere for its query alone. On a mesh of one device every view of
+every width shares one upload: the Flare
+compile-once/serve-many shape, applied to data residency too.
 """
 
 from __future__ import annotations
@@ -77,30 +85,47 @@ def _default_base_conf(conf: Optional[Configuration]) -> Configuration:
 TABLE_BATCH_ROWS = 1 << 20
 
 
-def _columnar(name: str, table, schema, n_parts: int) -> list:
-    """One table as the list of batches the server keeps: batches pass
-    through (their schema must be the catalog's), a pandas frame is cut
-    into equal row ranges — at most TABLE_BATCH_ROWS each, the same
-    number for every partition of the default mesh width — and ingested
-    under the catalog's schema."""
+def _columnar(name: str, table, schema, devices) -> list:
+    """One table as the list of batches the server keeps, split ``i`` on
+    ``devices[i mod N]``: batches pass through one at a time (their schema
+    must be the catalog's; ``table`` may be a generator, so that a table
+    larger than one chip never lies on one), a pandas frame is cut into
+    equal row ranges — at most TABLE_BATCH_ROWS each, the same number for
+    every device of the default mesh — and ingested under the catalog's
+    schema."""
+    import jax
+
     from auron_tpu.columnar.batch import Batch
 
+    n_parts = len(devices)
     if isinstance(table, pd.DataFrame):
         n = len(table)
         per_part = max(1, -(-n // (n_parts * TABLE_BATCH_ROWS)))  # batches
         rows = max(1, -(-n // (n_parts * per_part)))
+        out = []
         # an empty frame is one empty batch
-        return [Batch.from_pandas(table.iloc[i:i + rows], schema=schema)
-                for i in range(0, max(n, 1), rows)]
-    batches = list(table)
+        for i, lo in enumerate(range(0, max(n, 1), rows)):
+            with jax.default_device(devices[i % n_parts]):
+                out.append(Batch.from_pandas(table.iloc[lo:lo + rows],
+                                             schema=schema))
+        return out
     want = [(f.name, f.dtype) for f in schema]
-    for b in batches:
+    out = []
+    batches = iter(table)
+    while True:
+        dev = devices[len(out) % n_parts]
+        # a batch that is made as it is asked for lands on its own chip at
+        # once: nothing passes through chip 0 on the way
+        with jax.default_device(dev):
+            b = next(batches, None)
+        if b is None:
+            return out
         got = [(f.name, f.dtype) for f in b.schema]
         if got != want:
             raise ValueError(
                 f"table {name!r}: a batch's columns {got} are not the "
                 f"catalog's {want}")
-    return batches
+        out.append(b.on_device(dev))
 
 
 class SqlServer:
@@ -126,14 +151,25 @@ class SqlServer:
         self.mesh = self._mesh_for(self.n_parts)
         self.plan_cache = PlanCache(self.conf.get(SERVE_PLAN_CACHE_ENTRIES))
         self.admission = AdmissionController(self.conf)
-        # table -> its batches in row order, immutable after this line
-        # (views regroup them; nothing re-uploads). A table the catalog
-        # does not name can never be scanned and is not kept
+        # table -> its batches in row order, split i on device i mod N
+        # of the default mesh, immutable after this line (views regroup
+        # them). A table the catalog does not name can never be scanned
+        # and is not kept
+        devices = list(self.mesh.devices.flat)
         self.tables: dict[str, list] = {
-            name: _columnar(name, t, catalog.schema(name), self.n_parts)
+            name: _columnar(name, t, catalog.schema(name), devices)
             for name, t in tables.items()
             if catalog.schema(name) is not None
         }
+        # (table, device) -> a build side's copy of the whole table on that
+        # device, made the first time a plan scans it there and kept: the
+        # only copies the server pins beyond the resident splits
+        self._replicas: dict[tuple, list] = {}
+        self._replicas_lock = threading.Lock()
+        # the hand-over made a staging copy of every split and freed it:
+        # those pages go back to the system now, not in the middle of some
+        # later query (memory/hostheap.py)
+        _release_freed_heap()
         self._stats_lock = threading.Lock()
         self.queries_ok = 0
         self.queries_err = 0
@@ -201,20 +237,35 @@ class SqlServer:
                 self._meshes[n_parts] = mesh
             return mesh
 
+    def _view(self, table: str, n_parts: int, replicated: bool) -> list:
+        """A table's batches by partition of an ``n_parts``-wide mesh, each
+        partition's on its own device. Partitioned: the splits dealt round
+        robin (partition ``p`` scans splits ``p, p + N, ...``); at the
+        server's own width those are the resident splits and nothing is
+        copied, at another width a split that lies elsewhere is copied for
+        this query alone and freed with it (a fact table is never pinned
+        twice). Replicated (a build side): the whole table on every
+        device, each device's copy made once and kept."""
+        batches = self.tables[table]
+        devices = list(self._mesh_for(n_parts).devices.flat)
+        if not replicated:
+            return [[b.on_device(dev) for b in batches[p::n_parts]]
+                    for p, dev in enumerate(devices)]
+        view = []
+        with self._replicas_lock:
+            for dev in devices:
+                copy = self._replicas.get((table, dev))
+                if copy is None:
+                    copy = self._replicas[table, dev] = [
+                        b.on_device(dev) for b in batches]
+                view.append(copy)
+        return view
+
     def _build_resources(self, lq) -> dict:
-        """Batch lists for every table the plan scans: per partition a
-        contiguous run of the table's batches, or all of them on every
-        partition for a replicated (build-side) view."""
-        out = {}
-        for use in lq.tables:
-            batches = self.tables[use.table]
-            if use.replicated:
-                out[use.rid] = [batches] * lq.n_parts
-            else:
-                per = -(-len(batches) // lq.n_parts)
-                out[use.rid] = [batches[p * per:(p + 1) * per]
-                                for p in range(lq.n_parts)]
-        return out
+        """Batch lists for every table the plan scans, partition ``p``'s
+        on mesh device ``p``."""
+        return {use.rid: self._view(use.table, lq.n_parts, use.replicated)
+                for use in lq.tables}
 
     def _execute(self, lq, conf: Configuration) -> pd.DataFrame:
         """Run one lowered query under ``conf``: distributed stage on the
@@ -226,7 +277,10 @@ class SqlServer:
             resources = self._build_resources(lq)
             driver = MeshQueryDriver(self._mesh_for(lq.n_parts), conf=conf)
             outs = driver.run(lq.distributed, resources)
-        batches = [b for part in outs for b in part]
+        # the partitions' outputs are gathered on the mesh's first device:
+        # the collect stage is one task, and the answer leaves from there
+        first = driver.mesh.devices.flat[0]
+        batches = [b.on_device(first) for part in outs for b in part]
         with obs.span("collect", cat="serve"):
             return self._collect(lq, conf, batches)
 
@@ -240,7 +294,7 @@ class SqlServer:
         from auron_tpu.sql.lowering import STAGE_RID
 
         if lq.collect is None:
-            dfs = [b.to_pandas() for b in batches]
+            dfs = [_frame(b.to_arrow()) for b in batches]
         else:
             # stage barrier, as in models/sqlgate.execute: retire the
             # distributed stage's async arrays before the collect task
@@ -256,7 +310,7 @@ class SqlServer:
             dfs = []
             try:
                 while (rb := api.next_batch(h)) is not None:
-                    dfs.append(rb.to_pandas())
+                    dfs.append(_frame(rb))
             except BaseException:
                 # a failing per-query collect must not leak its runtime
                 # (handle in api._runtimes, pump thread blocked on the
@@ -312,6 +366,11 @@ class SqlServer:
                 rec["wall_s"] = round(time.perf_counter() - t_arrive, 4)
                 with self._stats_lock:
                     self.queries_ok += 1
+                if not hit:
+                    # this query traced, lowered and compiled (or loaded)
+                    # its programs: what that freed is handed back in the
+                    # query that was slow anyway
+                    _release_freed_heap()
                 return df, rec
         except Exception:
             with self._stats_lock:
@@ -354,6 +413,25 @@ class SqlServer:
             "admission": self.admission.stats(),
             "tables_resident": len(self.tables),
         }
+
+
+def _release_freed_heap() -> None:
+    """``serve:release``: the allocator's freed pages back to the system
+    (argument ``bytes``: what the process's resident set shrank by)."""
+    from auron_tpu.memory.hostheap import release_freed_heap
+
+    with obs.span("release", cat="serve", arg={"bytes": 0}) as sp:
+        freed = release_freed_heap()
+        if sp is not None:
+            sp.arg["bytes"] = freed
+
+
+def _frame(rb) -> pd.DataFrame:
+    """An Arrow batch of the answer as a frame; an integer column that holds
+    a NULL keeps its integers (objects, ``None`` for NULL) where pandas'
+    default would turn the column into floats: a key answers as ``7``, never
+    ``7.0``, and an int64 past 2**53 stays exact."""
+    return rb.to_pandas(integer_object_nulls=True)
 
 
 def _json_rows(df: pd.DataFrame) -> list[list]:

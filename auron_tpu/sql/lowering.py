@@ -29,6 +29,15 @@ Replicated subplans never contain a ``mesh_exchange`` (each partition
 holds a full copy; exchanging copies would merge duplicates), so grouped
 aggregation there chains partial -> final in-task.
 
+One exception, by what the catalog says: a derived table on a build side
+that reads a relation AS LARGE AS the probe seed (TPC-DS query 65's ``sb``:
+an average over the same fact table the query partitions) is not computed
+once a partition over a copy of that relation a chip — the copy is what
+partitioning exists to avoid, and past one chip's memory it cannot be made.
+It is lowered as a partitioned subplan of its own (its probe seed reads the
+partitioned view, its aggregates exchange) under a BROADCAST
+``mesh_exchange`` that hands every partition every row of its result.
+
 Anything the rules cannot lower EXACTLY raises
 :class:`~auron_tpu.sql.diagnostics.SqlUnsupported` with the construct
 name and source position — never a silently wrong plan. Determinism is
@@ -273,6 +282,16 @@ def _widen_pair(lk: ir.Expr, lt: T.DataType, rk: ir.Expr, rt: T.DataType,
             rk = ir.Cast(rk, common)
         return lk, rk
     raise SqlUnsupported(f"{what} types {lt} and {rt}", "", pos)
+
+
+def _agg_depth(node: pb.PhysicalPlanNode) -> int:
+    """How many grouped aggregations are nested on the deepest path of a
+    (replicated) subplan: its FINAL-mode ``hash_agg`` nodes."""
+    from auron_tpu.plan.protowalk import child_nodes
+
+    below = max((_agg_depth(c) for c in child_nodes(node)), default=0)
+    return below + (node.WhichOneof("plan") == "hash_agg"
+                    and node.hash_agg.mode == pb.AGG_FINAL)
 
 
 def _scan_rids(node: pb.PhysicalPlanNode) -> set:
@@ -527,8 +546,12 @@ class _Lowering:
         joined: set[int] = set()
         for gi in order:
             for e in items[gi]:
-                base = self._elem_plan(e, probe=(not repl and not joined),
-                                       scope=scope, ctes=ctes)
+                base = self._elem_plan(
+                    e, probe=(not repl and not joined), scope=scope,
+                    ctes=ctes,
+                    # what the enclosing select partitions, where it does
+                    seed_est=(None if repl else
+                              max(x.est for x in items[order[0]])))
                 if current is None:
                     current = base
                     joined.add(e.index)
@@ -683,21 +706,25 @@ class _Lowering:
                          sub=sub, subquery=rel.query, est=sub.est)
         raise SqlUnsupported(type(rel).__name__, "relation kind", _pos(rel))
 
-    def _elem_plan(self, e: _Elem, probe: bool, scope: Scope,
-                   ctes: dict) -> pb.PhysicalPlanNode:
+    def _elem_plan(self, e: _Elem, probe: bool, scope: Scope, ctes: dict,
+                   seed_est: Optional[int] = None) -> pb.PhysicalPlanNode:
         if e.table:
             rid = self._use(e.table, replicated=not probe)
             plan = B.memory_scan(e.schema, rid)
-        elif probe:
-            # re-lower the probe subquery partitioned (phase 1 lowered it
-            # replicated to learn its schema)
+        elif probe or (seed_est is not None and e.est >= seed_est
+                       and e.subquery.limit is None):
+            # re-lower the subquery partitioned (phase 1 lowered it
+            # replicated to learn its schema): the probe seed, and a build
+            # side that reads a relation as large as the probe seed, whose
+            # rows are then broadcast (module docstring)
             env = dict(ctes)
             if isinstance(e.rel, A.TableName):
                 env.pop(e.rel.name.lower(), None)
             sub = self.lower_subquery(e.subquery, scope, False, env)
             assert [f.dtype for f in sub.fields] == \
-                [f.dtype for f in e.schema], "probe re-lowering drifted"
-            plan = sub.plan
+                [f.dtype for f in e.schema], "subquery re-lowering drifted"
+            plan = sub.plan if probe else B.mesh_broadcast(sub.plan,
+                                                           self.n_parts)
         else:
             plan = e.sub.plan
         if e.pushed:
@@ -713,7 +740,13 @@ class _Lowering:
         if n == 1:
             return [0]
         ests = [max(e.est for e in group) for group in items]
-        seed = max(range(n), key=lambda i: (ests[i], -i))
+        # the seed is partitioned, the rest end up on build sides: of two
+        # derived tables over relations of one size, the one that has been
+        # grouped fewer times over keeps more rows (query 65's pair sums
+        # against its per-store average of them) and is the one to partition
+        depth = [max(_agg_depth(e.sub.plan) if e.sub else 0 for e in group)
+                 for group in items]
+        seed = max(range(n), key=lambda i: (ests[i], -depth[i], -i))
         order = [seed]
         placed = {e.index for e in items[seed]}
         remaining = [i for i in range(n) if i != seed]

@@ -544,9 +544,13 @@ def test_mesh_driver_run_has_pump_and_exchange_regions(server, mode):
         # the wait for the collective and the received shards; the file
         # transport's reads are its IpcReaders' own
         assert spans["exchange:read"]["n"] == 1
-    # the stages were planned under the spans a bridge task opens: the map
-    # stage once, the residual stage once a reduce partition (AQE may
-    # coalesce the file transport's two into one)
+    # the stages were planned under the spans a bridge task opens: once a
+    # partition's task thread, the map stage's two and the residual stage's
+    # one a reduce partition (AQE may coalesce the file transport's two
+    # into one)
     assert spans["plan:task"]["n"] == spans["plan:fusion"]["n"]
-    assert spans["plan:task"]["n"] in (2, 3)
+    assert spans["plan:task"]["n"] in (3, 4)
+    # each stage is a span, each of its partitions one under it
+    assert spans["pump:stage"]["n"] == 2
+    assert spans["pump:partition"]["n"] == spans["plan:task"]["n"]
     assert spans["exchange:write"]["self_s"] > 0
