@@ -144,8 +144,12 @@ def _wide_sum_fields(in_t: T.DataType, prefix: str) -> list[T.Field]:
 
 def _is_64bit_plane(t: T.DataType) -> bool:
     """Does a column of this type hold 8-byte values on the device? Such a
-    plane costs twice a 32-bit one to gather and about twelve times one to
-    scatter (PERF.md section 5, "unit costs")."""
+    plane costs twice a 32-bit one to gather and eight to nineteen times one
+    to scatter (PERF.md section 5, "unit costs"), which is why the dense
+    fold sums an integer plane as ``k`` int32 limbs (``k`` from
+    ``ops/segments.py limb_plan``: the type's bits and the batch's rows)
+    and prices it ``k`` narrow scatters; a float sum, a minimum or a
+    maximum of such a plane keeps its one wide scatter."""
     return np.dtype(t.physical_dtype().name).itemsize == 8
 
 
@@ -558,6 +562,8 @@ class HashAggExec(ExecOperator):
             while todo:
                 cur = todo.pop(0)
                 obs.note_agg_fold(cur.capacity, path="dense", mode=self.mode,
+                                  scatters=(None if dense._host else
+                                            dense.fold_scatters(cur.capacity)),
                                   **(noted or {"in_rows": cur.capacity}))
                 noted = None
                 r = dense.update(cur, defer=defer)
@@ -588,18 +594,22 @@ class HashAggExec(ExecOperator):
         # capacity is what the fold costs, so the batch is folded at the
         # bucket of its live rows and one of no rows not at all. The rule
         # is compaction_bucket's, by shape: staying dense scatters
-        # fold_planes() elements a row of capacity; compacting builds the
-        # index, gathers the planes the fold reads and scatters, a row of
-        # bucket (XLA:CPU: the quarter rule, in front of the host fold too)
+        # fold_planes(capacity) elements a row of capacity; compacting
+        # builds the index, gathers the planes the fold reads and scatters,
+        # a row of bucket (XLA:CPU: the quarter rule, in front of the host
+        # fold too)
         dense_boundary = None
         if dense is not None:
             from auron_tpu.columnar.batch import compact_batch, compaction_bucket
             from auron_tpu.exec.selectivity import CompactionBoundary
 
             fold_cols, take_planes = self._fold_columns()
-            fold_planes = dense.fold_planes()
+            # bound now: ``dense`` goes to None on a permanent fallback while
+            # the boundary still sizes the batches it holds
+            fold_planes_at = dense.fold_planes
 
             def dense_bucket_of(n_live: int, capacity: int) -> int | None:
+                fold_planes = fold_planes_at(capacity)
                 return compaction_bucket(
                     n_live, capacity, dense_planes=fold_planes,
                     taken_planes=take_planes + fold_planes,
@@ -2099,8 +2109,37 @@ _reduce_arrays_jit = _jax.jit(
 # ---------------------------------------------------------------------------
 
 
-def _seg_sum(vals, ids, nseg):
-    return jax.ops.segment_sum(vals, ids, num_segments=nseg)
+#: (two's-complement bits a summed plane's values occupy by their TYPE, sign
+#: included; whether that is a promise the fold must check): a row count's
+#: 0 / 1 flags, and the int64 ``#count`` field a merge sums
+_ROW_COUNT = (2, False)
+_MERGED_COUNT = (64, False)
+
+
+def _sum_bits(raw: bool, in_t: T.DataType) -> tuple[int, bool] | None:
+    """``(bits, checked)`` of the plane a dense ``sum`` / ``avg`` scatters, by
+    the aggregate's input type alone, or None where it is no integer (a
+    float sum is not exact under regrouping and keeps its one scatter). A
+    raw fold reads the input cast to ``sum_type`` (the same values); a merge
+    reads the ``#sum`` field, which holds ``sum_type``'s digits. DECIMAL(p):
+    ``ceil(log2(10^p))`` bits and a sign, a promise the int64 plane of
+    unscaled values does not keep by itself, so ``checked``; the physical
+    integers hold their width and no more."""
+    if in_t.kind == T.TypeKind.DECIMAL:
+        p = in_t.precision if raw else sum_type(in_t).precision
+        return (10 ** p - 1).bit_length() + 1, True
+    if not in_t.is_integer:
+        return None
+    return (8 * in_t.physical_dtype().itemsize if raw else 64), False
+
+
+def _seg_sum(vals, ids, nseg, bits: tuple[int, bool] | None):
+    """One value plane of the dense fold summed by slot: an integer plane by
+    int32 limbs (``ops/segments.py seg_sum_limbs``; ``bits`` as ``_sum_bits``
+    gives them), any other by one scatter at its own width."""
+    if bits is None:
+        return jax.ops.segment_sum(vals, ids, num_segments=nseg)
+    return S.seg_sum_limbs(vals, ids, nseg, *bits)
 
 
 def _seg_any(flags, ids, nseg):
@@ -2121,7 +2160,10 @@ def _dense_update_jit(
     dead rows route to segment ``size`` (dropped). No sort, no
     segmentation — the whole per-batch aggregation is segment_* scatters
     at O(rows + size), the dense analog of the reference's integer-keyed
-    agg hash map (agg/agg_hash_map.rs)."""
+    agg hash map (agg/agg_hash_map.rs). The TPU has no 64-bit integer
+    scatter, so integer and DECIMAL sums and every count are scattered as
+    int32 limbs whose widths follow from ``cfg``'s types and the batch's
+    rows, and carried into the int64 table once a batch (``_seg_sum``)."""
     raw, funcs, dims = cfg
     nseg = size + 1
     # in-table guard, fused with the fold: if ANY live key falls outside
@@ -2167,19 +2209,17 @@ def _dense_update_jit(
     out_vals = []
     out_valids = []
     fi = 0
-    for (func, _), ins in zip(funcs, agg_ins):
+    for (func, in_t), ins in zip(funcs, agg_ins):
         if func in ("count", "count_star"):
             if not raw:
                 # merge: SUM the intermediate #count field
                 v, _ = ins[0]
-                contrib = _seg_sum(jnp.where(sel, v, 0).astype(jnp.int64), idx, nseg)[:size]
+                contrib = _seg_sum(jnp.where(sel, v, 0), idx, nseg, _MERGED_COUNT)[:size]
             elif func == "count_star":
-                contrib = _seg_sum(
-                    jnp.where(sel, jnp.int64(1), jnp.int64(0)), idx, nseg
-                )[:size]
+                contrib = _seg_sum(sel, idx, nseg, _ROW_COUNT)[:size]
             else:
                 _, m = ins[0]
-                contrib = _seg_sum((m & sel).astype(jnp.int64), idx, nseg)[:size]
+                contrib = _seg_sum(m & sel, idx, nseg, _ROW_COUNT)[:size]
             out_vals.append(state_vals[fi] + contrib)
             out_valids.append(None)
             fi += 1
@@ -2187,17 +2227,19 @@ def _dense_update_jit(
         if func in ("sum", "avg"):
             v, m = ins[0]
             ok = m & sel
-            s = _seg_sum(jnp.where(ok, v, jnp.zeros_like(v)), idx, nseg)[:size]
+            s = _seg_sum(
+                jnp.where(ok, v, jnp.zeros_like(v)), idx, nseg, _sum_bits(raw, in_t)
+            )[:size]
             sv = _seg_any(ok, idx, nseg)[:size]
             out_vals.append(state_vals[fi] + s)
             out_valids.append(state_valids[fi] | sv)
             fi += 1
             if func == "avg":
                 if raw:
-                    c = _seg_sum(ok.astype(jnp.int64), idx, nseg)[:size]
+                    c = _seg_sum(ok, idx, nseg, _ROW_COUNT)[:size]
                 else:
                     cv, _ = ins[1]
-                    c = _seg_sum(jnp.where(sel, cv, 0).astype(jnp.int64), idx, nseg)[:size]
+                    c = _seg_sum(jnp.where(sel, cv, 0), idx, nseg, _MERGED_COUNT)[:size]
                 out_vals.append(state_vals[fi] + c)
                 out_valids.append(None)
                 fi += 1
@@ -2255,25 +2297,38 @@ def _next_pow2_agg(n: int) -> int:
     return p
 
 
-# What one DEAD row of ``_dense_update_jit`` costs on the TPU (a row routed
-# to the drop segment: what compaction saves; a live row is scattered on
-# either side of the choice), in the unit ``columnar.batch.
-# compaction_bucket`` counts in: one int32 plane gathered by a random
-# index, 7.5 ns a row of output. A scatter of a 64-bit plane (the int64
-# ``segment_sum``; int64 / float64 sums and maxima cost the same) is
-# SCATTER_WIDE of them, a scatter of a 32-bit or bool plane (an int32 /
-# float32 sum or maximum, the ``segment_max`` of the present / valid flags)
-# SCATTER_NARROW. Readings on the v5e (PERF.md section 5, "unit costs", PR
-# 35). Inside query 65's fold, from the traced cell's device seconds: 30 ns
-# a dead row and 112-124 ns a live one (uniform over 262,144 slots) for the
-# int64 sum, 4.1-6.7 ns a row for each flag plane. Alone, 20 calls ending
-# in block_until_ready at 4,194,304 and 524,288 rows into 262,144 slots:
-# 69 ns (every row to one slot) to 124 ns (uniform) for int64 or float64,
-# 6.6 to 8.7 ns for int32, float32 or bool. The constants are the LEAST
-# reading of each: where the fold is cheaper than reckoned, compacting must
-# not be chosen in its place.
-SCATTER_WIDE = 4.0
-SCATTER_NARROW = 0.5
+def _dense_fold_scatters(raw: bool, funcs: tuple, rows: int) -> tuple[int, int]:
+    """(narrow, wide): the 32-bit and the 64-bit planes ``_dense_update_jit``
+    scatters for one batch of ``rows`` rows under the static ``cfg`` members
+    ``raw`` and ``funcs``: the ``present`` flags, for every nullable field its
+    valid flags, and each value plane as the program takes it: an integer
+    sum or a count by ``S.limb_plan``'s limbs (the very function the
+    program asks), a float sum, a minimum or a maximum at its own width."""
+    narrow, wide = 1, 0
+
+    def plane(bits, t=None):
+        nonlocal narrow, wide
+        plan = S.limb_plan(bits[0], rows) if bits is not None else None
+        if plan is not None:
+            narrow += plan.limbs
+        elif bits is not None or _is_64bit_plane(t):
+            wide += 1
+        else:
+            narrow += 1
+
+    for func, in_t in funcs:
+        count = _ROW_COUNT if raw else _MERGED_COUNT
+        if func in ("count", "count_star"):
+            plane(count)
+            continue
+        narrow += 1
+        if func in ("sum", "avg"):
+            plane(_sum_bits(raw, in_t), sum_type(in_t))
+            if func == "avg":
+                plane(count)
+        else:
+            plane(None, in_t)
+    return narrow, wide
 
 
 def _bincount_i64(idx: np.ndarray, v: np.ndarray, size: int) -> np.ndarray:
@@ -2333,7 +2388,7 @@ class _DenseAggState:
         self._base_cfg = (
             exec_.mode == PARTIAL,
             tuple(
-                (a.func, str(t)) for (a, _), t in
+                (a.func, t) for (a, _), t in
                 zip(exec_.aggs, exec_._agg_input_types)
             ),
         )
@@ -2361,21 +2416,21 @@ class _DenseAggState:
         # that _pending pins for their flags
         self.waiting_bytes = 0
 
-    def fold_planes(self) -> float:
-        """What folding one dead row into the table costs, in gathered
-        elements (SCATTER_WIDE / SCATTER_NARROW above): one scatter for ``present``
-        and, for every field of the table, one for its values and one for
-        its validity where it has one. The ``dense_planes`` of the arm's
+    def fold_scatters(self, rows: int) -> tuple[int, int]:
+        """(narrow, wide) planes one fold of a batch of ``rows`` rows
+        scatters (``_dense_fold_scatters``): what its ``fold`` event says."""
+        return _dense_fold_scatters(*self._base_cfg, rows)
+
+    def fold_planes(self, rows: int) -> float:
+        """What folding one dead row of a batch of ``rows`` rows into the
+        table costs, in gathered elements (``S.SCATTER_NARROW`` a 32-bit
+        plane, ``S.SCATTER_WIDE`` a 64-bit one): the ``present`` and valid
+        flags, and every value plane as the program scatters it, a limbed
+        sum as its ``k`` narrow planes. The ``dense_planes`` of the arm's
         ``compaction_bucket`` rule: what a row of capacity costs where the
         batch is folded as it came."""
-        ex = self.exec
-        planes = SCATTER_NARROW
-        for (a, _), in_t in zip(ex.aggs, ex._agg_input_types):
-            for f in intermediate_fields(a, in_t if in_t is not None else T.INT64, "x"):
-                planes += SCATTER_WIDE if _is_64bit_plane(f.dtype) else SCATTER_NARROW
-                if f.nullable:
-                    planes += SCATTER_NARROW
-        return planes
+        narrow, wide = self.fold_scatters(rows)
+        return narrow * S.SCATTER_NARROW + wide * S.SCATTER_WIDE
 
     def reset(self) -> None:
         """Forget the table (after a drain) so the next update re-anchors.

@@ -129,7 +129,8 @@ def _event(kind: str, name: str, arg: dict) -> None:
 
 def note_agg_fold(rows: int, in_rows: int, path: str = "deferred",
                   mode: str = "partial", live: int | None = None,
-                  take: str | None = None) -> None:
+                  take: str | None = None,
+                  scatters: tuple[int, int] | None = None) -> None:
     """One fold of a batch into an aggregate (exec/agg_exec.py): ``path`` is
     ``dense`` (the direct-address table: one scatter, no sort), ``probe``
     (the sorted state probed and scatter-updated), ``sort`` (the blocking
@@ -143,14 +144,20 @@ def note_agg_fold(rows: int, in_rows: int, path: str = "deferred",
     ``compact``, ``dense``, ``repair`` as a join's takes (exec/
     selectivity.py) or ``empty`` (the count came out 0: ``rows`` 0, no
     program issued), None off that arm and for a held batch folded again
-    after a restart. A ``fold`` event; ``window_summary`` sums ``rows`` as
-    ``agg_fold_rows`` and by path as ``agg_folds``, and counts the dense
-    arm's by ``take`` as ``agg_dense_folds``."""
+    after a restart; ``scatters`` the (32-bit, 64-bit) planes the dense
+    table's device fold scatters at ``rows`` (flags, limbs of the integer
+    sums and counts, whole float sums and extrema: known from the types and
+    the shape, no read; None off that fold and on its host substrate), the
+    event's ``narrow`` and ``wide``. A ``fold`` event; ``window_summary``
+    sums ``rows`` as ``agg_fold_rows`` and by path as ``agg_folds``, counts
+    the dense arm's by ``take`` as ``agg_dense_folds`` and sums ``rows`` x
+    planes as ``agg_dense_scatter_rows``."""
     if core._mode == MODE_OFF:
         return
+    narrow, wide = scatters or (None, None)
     _event("fold", f"agg.{mode}",
            {"rows": rows, "in_rows": in_rows, "path": path, "live": live,
-            "take": take})
+            "take": take, "narrow": narrow, "wide": wide})
 
 
 def note_agg_reduce(rows: int, how: str) -> None:

@@ -121,7 +121,9 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ``probe``, ``sort``, ``deferred``) as ``{n, rows, live}``, with
     ``agg_dense_folds`` counting the dense arm's by how its compaction
     boundary took the batch (``seed``, ``compact``, ``dense``, ``repair``,
-    ``empty``);
+    ``empty``) and ``agg_dense_scatter_rows`` summing, over the dense
+    table's device folds, ``rows`` x the planes each scattered, ``narrow``
+    (32-bit: flags, limbs) and ``wide`` (64-bit) apart;
     ``agg_sorted_rows`` sums the capacities the grouped reduces sorted and
     ``agg_reduces`` counts them by ``how`` (the ``reduce`` events,
     ``obs.note_agg_reduce``); ``agg_groups`` sums the groups the aggregates
@@ -158,6 +160,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     d2h = fold_rows = gather_rows = sorted_rows = groups = dec_cells = 0
     folds: dict[str, dict] = {}
     dense_folds: dict[str, int] = {}
+    scatter_rows = {"narrow": 0, "wide": 0}
     reduces: dict[str, dict] = {}
     takes: dict[str, int] = {}
     lookups: dict[str, int] = {}
@@ -205,6 +208,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
                 how = ev[7].get("take")
                 if how is not None:
                     dense_folds[how] = dense_folds.get(how, 0) + 1
+                for width in scatter_rows:
+                    scatter_rows[width] += ev[7]["rows"] * (ev[7].get(width) or 0)
             elif ev[2] == "reduce":
                 ent = reduces.setdefault(ev[7]["how"], {"n": 0, "rows": 0})
                 ent["n"] += 1
@@ -248,6 +253,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             "layers": layers, "spans": spans, "d2h_bytes": d2h,
             "agg_fold_rows": fold_rows, "agg_folds": folds,
             "agg_dense_folds": dense_folds,
+            "agg_dense_scatter_rows": scatter_rows,
             "agg_reduces": reduces, "agg_sorted_rows": sorted_rows,
             "agg_groups": groups, "wide_decimal_host_cells": dec_cells,
             "join_gather_rows": gather_rows, "join_takes": takes,

@@ -421,3 +421,124 @@ def _min_identity(dtype):
     if dtype == jnp.bool_:
         return False
     return jnp.iinfo(dtype).min
+
+
+# ---------------------------------------------------------------------------
+# exact integer segment sums by int32 limbs (the dense aggregate's scatters)
+# ---------------------------------------------------------------------------
+
+# What one DEAD row of a scatter costs on the TPU (a row routed to the drop
+# segment: what compaction saves; a live row is scattered on either side of
+# the choice), in the unit ``columnar.batch.compaction_bucket`` counts in:
+# one int32 plane gathered by a random index, 7.5 ns a row of output. A
+# scatter of a 64-bit plane (an int64 or float64 ``segment_sum``, minimum or
+# maximum) is SCATTER_WIDE of them, a scatter of a 32-bit or bool plane (an
+# int32 / float32 sum or maximum, one LIMB of an integer sum, the
+# ``segment_max`` of the present / valid flags) SCATTER_NARROW. Readings on
+# the v5e (PERF.md section 5, "unit costs", PR 37; ``tools/scatter_costs.py``
+# reads them again). Alone, 20 calls ending in block_until_ready at
+# 4,194,304 rows into 262,144 slots: a 64-bit plane 69.0 ns a row with every
+# row to the drop segment, 75.1 ns with a tenth live, 123.8 ns uniform; a
+# 32-bit one 8.8 / 7.9 / 6.6 ns: a dead row is the DEAREST of a narrow
+# scatter (they all hit one slot) and the cheapest of a wide one. Inside
+# query 65's fold: all dead 8.8 ns a narrow plane and 69 ns the int64 sum;
+# in the traced cell 8.4 ns a dead row between live ones a narrow plane.
+# The constants are the least DEAD-row reading of each (8.4 and 69.0 ns),
+# rounded down: where the fold is cheaper than reckoned, compacting must
+# not be chosen in its place. (PR 35 held 0.5 and 4, from a traced cell's
+# seconds shared out over live and dead rows: with 0.5 query 65's
+# tenth-live batch stayed dense and the query read 0.04 s more, PR 37.)
+SCATTER_WIDE = 9.0
+SCATTER_NARROW = 1.0
+#: the most int32 limbs an integer sum is split into; one that would need
+#: more keeps its 64-bit scatter. k planes scattered one by one cost k
+#: narrow scatters (PR 37, alone as above, 3 / 5 / 7 / 8 limbs: 19.9 / 33.2
+#: / 46.6 / 53.1 ns a row uniform, 26.3 / 43.8 / 61.3 / 70.0 ns with every
+#: row to one slot, against 123.8 and 69.0 ns for the 64-bit scatter; in
+#: query 65's fold 3 limbs and the flags 33.6 ns a live row where the
+#: int64 sum and the flags read 137.4): eight break even on a batch of dead
+#: rows alone, which the arm's compaction takes first, and pay 2.3 times
+#: over on live ones. An int64 needs seven up to 4,194,304 rows, eight at
+#: 8,388,608. (ONE scatter of [rows, k] windows reads 16.2-17.0 ns a row
+#: whatever k, but XLA lays the windows out 128 lanes wide: 2.3 GB of
+#: temporaries at 4,194,304 rows and 24-27 s to compile. Not taken.)
+LIMBS_PAY_UP_TO = 8
+
+
+class LimbPlan(NamedTuple):
+    """How an integer plane is summed by 32-bit scatters (``limb_plan``)."""
+
+    bits: int   # b: the width of a low limb
+    limbs: int  # k: the planes scattered
+    cover: int  # two's-complement bits of a value the limbs hold, <= 64
+
+
+def limb_plan(value_bits: int, rows: int) -> LimbPlan | None:
+    """The limbs an exact segment sum of ``rows`` integers of ``value_bits``
+    two's-complement bits (sign included) is taken by, or None where one
+    64-bit scatter is cheaper. A rule over a type's width and a shape.
+
+    The worst slot receives every row, and a limb's sum must fit its int32
+    accumulator: a low limb is ``b`` unsigned bits with ``rows <= 2^(31-b)``
+    (``rows x (2^b - 1) < 2^31``); the TOP limb is what the arithmetic shift
+    leaves, sign and all, and may hold ``b + 1`` bits (``rows x 2^b <=
+    2^31`` in magnitude, and -2^31 itself fits). So ``k`` limbs hold
+    ``k x b + 1`` bits: ``k = ceil((value_bits - 1) / b)``. A DECIMAL(7,2)
+    sum (24 bits and a sign) at 4,194,304 rows: b = 9, k = 3; a merge of
+    DECIMAL(17,2) (57 bits and a sign) at 131,072 rows: b = 14, k = 5; an
+    int64 at 4,194,304 rows: k = 7, recombined mod 2^64, which is the
+    wrapping sum a 64-bit scatter gives."""
+    b = 31 - max(rows - 1, 1).bit_length()
+    if b < 1:
+        return None
+    k = max(-(-(value_bits - 1) // b), 1)
+    if k > LIMBS_PAY_UP_TO:
+        return None
+    return LimbPlan(b, k, min(k * b + 1, 64))
+
+
+def split_limbs(vals, b: int, k: int) -> list:
+    """An integer plane as ``k`` int32 planes: ``k - 1`` unsigned limbs of
+    ``b`` bits from the low end, and what the arithmetic shift leaves above
+    them, sign and all (``limb_plan`` says when that fits 32 bits)."""
+    v = vals.astype(jnp.int64)
+    mask = jnp.int64((1 << b) - 1)
+    limbs = [((v >> (i * b)) & mask).astype(jnp.int32) for i in range(k - 1)]
+    return limbs + [(v >> ((k - 1) * b)).astype(jnp.int32)]
+
+
+def seg_sum_limbs(vals, ids, nseg: int, value_bits: int, checked: bool):
+    """Exact int64 segment sums of an integer plane through int32 scatters.
+
+    ``vals`` holds 0 in every row that must not count (dead, NULL); ``ids``
+    routes those to a drop segment or anywhere. ``value_bits`` is what the
+    column's TYPE says its values occupy (sign included): the limbs follow
+    from it and from the rows (``limb_plan``), each is scattered as an int32
+    plane, and the limb sums are recombined at the table's width, shifted
+    into place and added mod 2^64. ``checked`` says that width is a promise
+    and not the plane's physical one (a DECIMAL's precision over its int64
+    plane): the program then checks, in the pass that splits the values,
+    that every one lies inside the bits the limbs hold, and takes the
+    64-bit scatter where one does not (both arms compiled, one run), so a
+    plane that breaks its declared precision is still summed exactly."""
+    plan = limb_plan(value_bits, vals.shape[0])
+    if plan is None:
+        return jax.ops.segment_sum(vals.astype(jnp.int64), ids, num_segments=nseg)
+    b, k, cover = plan
+
+    def narrow(v):
+        out = None
+        for i, limb in enumerate(split_limbs(v, b, k)):
+            part = jax.ops.segment_sum(limb, ids, num_segments=nseg)
+            part = part.astype(jnp.int64) << (i * b)
+            out = part if out is None else out + part
+        return out
+
+    if not checked or cover >= 64:
+        return narrow(vals)
+    v64 = vals.astype(jnp.int64)
+    half = jnp.int64(1 << (cover - 1))
+    fits = jnp.all((v64 >= -half) & (v64 < half))
+    return lax.cond(
+        fits, narrow,
+        lambda v: jax.ops.segment_sum(v, ids, num_segments=nseg), v64)

@@ -659,6 +659,31 @@ def test_dense_arm_folds_at_the_width_of_its_live_rows(case, dense_fold_substrat
     assert ws["agg_folds"]["dense"]["n"] == len(folds) + 1
 
 
+def test_dense_fold_events_say_how_the_sums_were_scattered(dense_fold_substrate):
+    """The ``fold`` event of a device fold of the dense table carries the
+    32-bit and the 64-bit planes it scattered at its width (count(*) one
+    int32 plane, the int64 sum ``limb_plan``'s limbs, the int64 minimum one
+    wide plane, three flag planes), ``window_summary`` sums rows x planes;
+    the host substrate scatters nothing on the device and says so."""
+    from auron_tpu.ops.segments import limb_plan
+
+    frames, _rows = _sparse_int_frames((4096, 400))
+    _got, _metrics, folds, ws = _run_dense_partial(frames)
+    assert [f["rows"] for f in folds] == [4096, 512]
+    total = ws["agg_dense_scatter_rows"]
+    if dense_fold_substrate == "auto":
+        assert all(f["narrow"] is None and f["wide"] is None for f in folds)
+        assert total == {"narrow": 0, "wide": 0}
+        return
+    assert [limb_plan(64, r).limbs for r in (4096, 512)] == [4, 3]
+    assert [(f["narrow"], f["wide"]) for f in folds] == [(3 + 1 + 4, 1), (3 + 1 + 3, 1)]
+    # the FINAL aggregate above folds once, merging: both counts by limbs
+    final_rows = ws["agg_folds"]["dense"]["rows"] - 4096 - 512
+    assert total["wide"] == 4096 + 512 + final_rows
+    assert total["narrow"] == 4096 * 8 + 512 * 7 + final_rows * (
+        3 + 2 * limb_plan(64, final_rows).limbs)
+
+
 def test_dense_arm_restart_in_a_compacted_stream_folds_no_batch_twice(
         dense_fold_substrate):
     """Sparse batches whose keys jump between far-apart ranges: the restart
@@ -701,27 +726,125 @@ def test_compact_batch_gathers_the_named_columns_alone():
 
 
 def test_dense_arm_rule_at_query_65s_shapes(monkeypatch):
-    """On the TPU the arm's rule counts gathered elements: one int64 sum by
-    two int64 keys folds a dead row for 5 of them (an int64 scatter-add
-    four, the two flag scatters half of one each) and takes 9 planes, so a
-    batch of 4,194,304 compacts at an eighth of its capacity or less."""
+    """On the TPU the arm's rule counts gathered elements, and the fold's
+    price is what the program really scatters at the batch's capacity: a
+    DECIMAL(7,2) sum by two int64 keys folds a dead row of a 4,194,304-row
+    batch for 5 of them (three 9-bit int32 limbs and the two flag scatters,
+    an element each: 8.4-8.8 ns a dead row a plane on the v5e; one int64
+    scatter-add would be nine) and takes 9 planes, so the batch compacts at
+    an eighth of its capacity or less: query 65's tenth-live batch is taken
+    at 524,288 rows, where the sum is two 12-bit limbs."""
     from auron_tpu.columnar import batch as batch_mod
     from auron_tpu.exec import agg_exec as agg_mod
+    from auron_tpu.ops import segments as seg_mod
 
     frames, _ = _sparse_int_frames((8,), cap=128)
-    scan = MemoryScanExec.single(frames)
+    (b,) = frames
+    priced = Batch(T.Schema.of(
+        T.Field("k", T.INT64), T.Field("v", T.decimal(7, 2)),
+        T.Field("live", T.INT64)), b.device, b.dicts)
+    scan = MemoryScanExec.single([priced])
     p = HashAggExec(scan, [(col(0), "a"), (col(2), "b")],
                     [(AggExpr("sum", col(1)), "s")], PARTIAL)
     cols, planes = p._fold_columns()
     assert (cols, planes) == ((0, 1, 2), 9)
-    fold = agg_mod._DenseAggState(p, ExecutionContext()).fold_planes()
-    assert fold == agg_mod.SCATTER_WIDE + 2 * agg_mod.SCATTER_NARROW == 5.0
+    state = agg_mod._DenseAggState(p, ExecutionContext())
+    assert state.fold_scatters(4194304) == (5, 0)
+    fold = state.fold_planes(4194304)
+    assert fold == 5 * seg_mod.SCATTER_NARROW == 5.0
+    # taken at 524,288 rows the same sum is two 12-bit limbs
+    assert state.fold_scatters(524288) == (4, 0)
+    # the same sum of a physical int64 column: seven limbs, none wide; the
+    # int64 minimum beside it keeps its 64-bit scatter
+    q = HashAggExec(MemoryScanExec.single(frames), [(col(0), "a"), (col(2), "b")],
+                    [(AggExpr("sum", col(1)), "s"), (AggExpr("min", col(1)), "m")],
+                    PARTIAL)
+    wider = agg_mod._DenseAggState(q, ExecutionContext())
+    assert wider.fold_scatters(4194304) == (1 + 1 + 7 + 1, 1)
+    assert wider.fold_planes(4194304) == 10 * seg_mod.SCATTER_NARROW + seg_mod.SCATTER_WIDE
     monkeypatch.setattr(batch_mod, "_gather_bound", lambda: True)
     rule = lambda n: batch_mod.compaction_bucket(
         n, 4194304, dense_planes=fold, taken_planes=planes + fold)
     assert rule(420_000) == 524288
     assert rule(600_000) is None
     assert rule(1) == batch_mod.MIN_CAPACITY
+
+
+def _priced_frames(cents, keys, alive, valid, dtype):
+    """One batch a list of (k, v, live) rows: ``v`` the int64 plane of
+    ``cents`` under the declared ``dtype`` (Arrow would refuse a DECIMAL
+    outside its precision: the plane is labelled, not converted), NULL where
+    ``valid`` is False; ``live`` NULL on the rows a filter drops."""
+    out = []
+    for c, k, a, ok in zip(cents, keys, alive, valid):
+        b = Batch.from_pydict({
+            "k": [int(x) for x in k],
+            "v": [int(x) if o else None for x, o in zip(c, ok)],
+            "live": [1 if x else None for x in a],
+        })
+        out.append(Batch(T.Schema.of(
+            T.Field("k", T.INT64), T.Field("v", dtype), T.Field("live", T.INT64)),
+            b.device, b.dicts))
+    return out
+
+
+_LIMB_FOLD_CASES = {
+    # dtype, the values a row draws from
+    "dec7_at_its_precision": (T.decimal(7, 2), [10 ** 7 - 1, -(10 ** 7 - 1), 1, -1, 0]),
+    "dec7_on_limb_edges": (T.decimal(7, 2), [
+        (1 << 12) - 1, 1 << 12, -(1 << 12), (1 << 24) - 1, -(1 << 24), 1 << 23]),
+    "dec7_outside_its_precision": (T.decimal(7, 2), [
+        10 ** 7, -(10 ** 9), 1 << 40, -(1 << 55), 5]),
+    "int64_extremes": (T.INT64, [
+        np.iinfo(np.int64).max, np.iinfo(np.int64).min, -1, 1, 1 << 62]),
+}
+
+
+@pytest.mark.parametrize("one_slot", [False, True], ids=["spread", "one_slot"])
+@pytest.mark.parametrize("case", sorted(_LIMB_FOLD_CASES))
+def test_dense_sums_are_exact_at_every_width(case, one_slot, dense_fold_substrate):
+    """sum, avg's sum and count, count and count(*) through the dense table,
+    raw and merged (PARTIAL then PARTIAL_MERGE), with NULLs and dead rows:
+    the ``#sum`` and ``#count`` planes are numpy's wrapping int64 sums on
+    both substrates (the device fold takes them by int32 limbs, the host
+    fold by ``_bincount_i64``), also where a DECIMAL plane breaks its
+    declared precision (the limbs' guard) and where int64 sums wrap."""
+    from auron_tpu.exec.basic import FilterExec
+    from auron_tpu.exprs.ir import IsNotNull
+
+    dtype, pool = _LIMB_FOLD_CASES[case]
+    rng = np.random.default_rng(len(case) + one_slot)
+    n_batches, cap = 3, 512
+    cents = [rng.choice(np.asarray(pool, np.int64), cap) for _ in range(n_batches)]
+    keys = [np.zeros(cap, np.int64) + 4 if one_slot else rng.integers(0, 9, cap)
+            for _ in range(n_batches)]
+    alive = [rng.random(cap) < 0.8 for _ in range(n_batches)]
+    valid = [rng.random(cap) < 0.9 for _ in range(n_batches)]
+    aggs = [(AggExpr("sum", col(1)), "s"), (AggExpr("avg", col(1)), "a"),
+            (AggExpr("count", col(1)), "c"), (AggExpr("count_star", None), "n")]
+    flt = FilterExec(MemoryScanExec.single(
+        _priced_frames(cents, keys, alive, valid, dtype)), [IsNotNull(col(2))])
+    p = HashAggExec(flt, [(col(0), "k")], aggs, PARTIAL)
+    m = HashAggExec(p, [(col(0), "k")], aggs, PARTIAL_MERGE)
+    assert p._dense_eligible() and m._dense_eligible()
+    got = {}
+    for b in m.execute(0, ExecutionContext()):
+        sel = np.asarray(b.device.sel)
+        planes = [np.asarray(v)[sel] for v in b.device.values]
+        for row in zip(*planes):
+            got[int(row[0])] = tuple(int(x) for x in row[1:])
+    want = {}
+    with np.errstate(over="ignore"):
+        for c, k, a, ok in zip(cents, keys, alive, valid):
+            for ci, ki, ai, oki in zip(c, k, a, ok):
+                if not ai:
+                    continue
+                s, cnt, n = want.get(int(ki), (np.int64(0), 0, 0))
+                want[int(ki)] = (s + ci if oki else s, cnt + bool(oki), n + 1)
+    assert sorted(got) == sorted(want)
+    for k, (s, cnt, n) in want.items():
+        # s#sum, a#sum, a#count, c#count, n#count
+        assert got[k] == (int(s), int(s), cnt, cnt, n), k
 
 
 def test_dense_agg_sentinel_key_extremes(dense_fold_substrate):

@@ -156,25 +156,62 @@ def test_float64_key_words_compile(monkeypatch, one_chip):
         assert out.dtype == jnp.uint64
 
 
-def test_dense_aggregate_fold_compiles_at_query_65s_batch(one_chip):
-    """The dense table's fold (``agg_exec._dense_update_jit``) as query 65's
-    partial sum gives it: one batch of 4,194,304 rows, two int64 keys packed
-    into 13 x 18,001 lanes of a 262,144-slot table, one DECIMAL sum. One
-    scatter program: seconds to compile where a sort of that width would
-    take minutes (ops/hostsort.py DEVICE_SORT_MAX_ROWS)."""
-    from auron_tpu.exec import agg_exec
+def _scatter_results(hlo: str) -> list[list[str]]:
+    """The element types every scatter of a compiled program's text
+    accumulates into, one list a scatter: ``["s32"]`` a 32-bit one,
+    ``["u32", "u32"]`` the variadic scatter the TPU's 64-bit rewrite leaves
+    of an int64 one (low and high words scattered as a pair)."""
+    import re
 
-    rows, size = 1 << 22, 1 << 18
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r"= (\(.*?\)|\S+) scatter\(", line)
+        if m:
+            out.append(re.findall(r"\b([a-z]+\d+|pred)\[", m.group(1)))
+    return out
+
+
+@pytest.mark.parametrize("rows, raw, limbs", [
+    (1 << 22, True, 3),     # the map side: DECIMAL(7,2) prices, 9-bit limbs
+    (1 << 17, False, 5),    # the reduce side: a merge of DECIMAL(17,2) sums
+])
+def test_dense_aggregate_fold_compiles_at_query_65s_batch(one_chip, rows, raw, limbs):
+    """The dense table's fold (``agg_exec._dense_update_jit``) as query 65's
+    sums give it: a batch of 4,194,304 rows (the partial sum) or 131,072
+    (the final sum's merge), two int64 keys packed into 13 x 18,001 lanes of
+    a 262,144-slot table, one DECIMAL sum. One scatter program: seconds to
+    compile where a sort of that width would take minutes (ops/hostsort.py
+    DEVICE_SORT_MAX_ROWS). The sum goes by int32 limbs: no scatter of the
+    program has a 64-bit operand (the v5e has no 64-bit integer scatter; its
+    rewrite shows as one scatter of a PAIR of 32-bit words),
+    except the one the map side's guard keeps for a plane that breaks its
+    declared precision, in the other arm of its conditional."""
+    from auron_tpu import types as T
+    from auron_tpu.exec import agg_exec
+    from auron_tpu.ops.segments import limb_plan
+
+    size = 1 << 18
     col = _sds((rows,), jnp.int64, one_chip)
     ok = _sds((rows,), jnp.bool_, one_chip)
+    in_t = T.decimal(7, 2)
+    plan = limb_plan(agg_exec._sum_bits(raw, in_t)[0], rows)
+    assert plan.limbs == limbs
+    guarded = plan.cover < 64
     compiled = _compile(
         agg_exec._dense_update_jit,
         (_sds((size,), jnp.int64, one_chip),), (_sds((size,), jnp.bool_, one_chip),),
         _sds((size,), jnp.bool_, one_chip),
         _sds((2,), jnp.int64, one_chip), _sds((2,), jnp.int64, one_chip),
         (col, col), (ok, ok), ok, (((col, ok),),),
-        cfg=(True, (("sum", "decimal(7,2)"),), (14, 18002)), size=size)
+        cfg=(raw, (("sum", in_t),), (14, 18002)), size=size)
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 28)
+    scatters = _scatter_results(compiled.as_text())
+    wide = [ts for ts in scatters
+            if len(ts) > 1 or ts[0] in ("s64", "u64", "f64")]
+    # the limbs and the two flag scatters, one 32-bit accumulator each
+    assert len(scatters) - len(wide) == limbs + 2, scatters
+    assert len(wide) == (1 if guarded else 0), scatters
+    assert ("conditional(" in compiled.as_text()) == guarded
 
 
 def test_flagship_stage_program_compiles(one_chip):
