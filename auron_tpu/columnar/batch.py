@@ -133,13 +133,17 @@ def compaction_bucket(
     columns and the build index) at the bucket where it compacts. On the
     TPU a gathered element costs 7-10 ns whatever the table's size and
     the gather's width (an int64 plane twice that), and
-    ``compaction_index`` is a pass over the mask plus one such gather at
-    the bucket's width for every bit of ``in_capacity`` (PERF.md section
-    5, "unit costs": measured on the v5e), so the rule there counts
+    ``compaction_index``'s search is a pass over the mask plus one such
+    gather at the bucket's width for every bit of ``in_capacity`` (PERF.md
+    section 5, "unit costs": measured on the v5e), so the rule there counts
     gathered elements: compact iff
     ``bucket * (index passes + taken_planes) <= in_capacity * dense_planes``
     — at two to four build planes a bucket of a sixteenth of capacity or
-    less. XLA:CPU keeps the break-even measured there, a quarter of
+    less. The rule prices the search even where the index takes its other
+    form (``compaction_index_form``: one scatter, a write a row of
+    capacity, where the bucket is wide), so which batches compact does not
+    depend on the form; pricing the scatter is a later change of this
+    rule. XLA:CPU keeps the break-even measured there, a quarter of
     capacity. A caller that names no ``dense_planes`` (the aggregate, for
     which staying dense means a sort-segmented reduce at capacity, tens of
     gathers a row) keeps the quarter rule on both back ends."""
@@ -213,6 +217,26 @@ def _lookup_costs() -> tuple[float, float, float]:
     if _gather_bound():
         return 7.2, 0.0012, 0.0016
     return 4.0, 1.45, 1.4
+
+
+def compaction_index_form(cap: int, out_cap: int) -> str:
+    """THE form of ``compaction_index`` from ``cap`` rows into ``out_cap``:
+    ``scatter`` where one write a row of capacity costs less than the
+    search's gathered element a row of bucket for every bit of capacity,
+    else ``search``. A rule over the static shapes alone, so the host that
+    launches a program knows the form its trace took. The TPU v5e's costs
+    over the running count both pay (PERF.md section 5,
+    ``tools/scatter_costs.py``, at 1,048,576 to 4,194,304 rows): the
+    search 7.4-7.6 ns a row of bucket a bit (41.4 ms from 2,097,152 rows
+    into 262,144, 86.2 ms from 4,194,304 into 524,288), the scatter
+    4.62-4.63 ns a row of capacity whatever the bucket (9.7 / 19.4 ms);
+    the break-even is a bucket of about a 34th of capacity at 2^21 rows
+    (into 65,536 the two read 10.86 / 10.26 ms with the count). XLA:CPU
+    lowers a scatter to a serial loop: there the search always stays."""
+    if not _gather_bound():
+        return "search"
+    bits = max(cap - 1, 1).bit_length()
+    return "scatter" if out_cap * bits * 7.5 > cap * 4.6 else "search"
 
 
 class DeviceBatch(NamedTuple):
@@ -837,18 +861,41 @@ def _running_count(sel: jnp.ndarray) -> jnp.ndarray:
 
 
 def compaction_index(sel: jnp.ndarray, out_cap: int):
-    """(idx[out_cap], sel_out[out_cap]): positions of the live rows, via
-    cumsum + branchless binary search. Gather-based on purpose — XLA:CPU
-    lowers scatters to serial loops (the platform even advertises
-    prefer-no-scatter), while the log2(cap) searchsorted passes vectorize."""
+    """(idx[out_cap], sel_out[out_cap]): positions of the live rows, in
+    order, after a running count of the mask, in one of two forms with the
+    same output, the one ``compaction_index_form`` gives the shapes:
+
+    - ``search``: a branchless binary search of the count for every output
+      slot, one gathered element a slot for every bit of the capacity.
+      XLA:CPU lowers scatters to serial loops (the platform advertises
+      prefer-no-scatter), while these passes vectorize;
+    - ``scatter``: ONE int32 scatter, live row ``i`` writing ``i`` at its
+      rank ``count[i] - 1``: a write a row of capacity, whatever the bucket.
+
+    Output slots past the live count hold ``cap - 1``, as the clipped search
+    gives them; a bucket smaller than the live count keeps the first
+    ``out_cap`` live rows (the boundary repairs such a mispredict)."""
     cap = sel.shape[0]
     pos = _running_count(sel)
-    idx = jnp.searchsorted(
-        pos, jnp.arange(1, out_cap + 1, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
-    idx = jnp.clip(idx, 0, cap - 1)
+    if compaction_index_form(cap, out_cap) == "scatter":
+        idx = (
+            jnp.full(out_cap, cap - 1, jnp.int32)
+            .at[jnp.where(sel, pos - 1, out_cap)]
+            .set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
+        )
+    else:
+        idx = jnp.searchsorted(
+            pos, jnp.arange(1, out_cap + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
+        idx = jnp.clip(idx, 0, cap - 1)
     sel_out = jnp.arange(out_cap, dtype=jnp.int32) < pos[-1]
     return idx, sel_out
+
+
+def note_index(cap: int, out_cap: int) -> None:
+    """Note on the host one program launched with ``compaction_index`` from
+    ``cap`` rows into ``out_cap``, under the form its shapes choose."""
+    obs.note_compaction_index(compaction_index_form(cap, out_cap), cap, out_cap)
 
 
 @_partial(obs.jit, static_argnames=("out_cap", "cols"))
@@ -856,10 +903,11 @@ def _compact_dev(
     dev: DeviceBatch, out_cap: int, cols: tuple[int, ...] | None = None,
 ) -> DeviceBatch:
     """Gather live rows into a dense prefix of a smaller buffer (O(n) +
-    O(out log n), no sort). Used when selectivity collapses a batch
-    (post-filter/join) so blocking ops (sort-segmentation, exchange pulls)
-    pay for live rows only. ``cols`` names the columns to gather (None:
-    all); any other comes back all NULL, a fill and not a gather."""
+    O(out log n) by the index's search, O(n) by its scatter; no sort).
+    Used when selectivity collapses a batch (post-filter/join) so blocking
+    ops (sort-segmentation, exchange pulls) pay for live rows only. ``cols``
+    names the columns to gather (None: all); any other comes back all NULL,
+    a fill and not a gather."""
     idx, sel_out = compaction_index(dev.sel, out_cap)
     taken = range(len(dev.values)) if cols is None else cols
     # values first, then validities: with every column taken the program is
@@ -884,6 +932,7 @@ def compact_batch(
     rest are not gathered and come back all NULL."""
     if out_capacity >= batch.capacity:
         return batch
+    note_index(batch.capacity, out_capacity)
     return Batch(
         batch.schema, _compact_dev(batch.device, out_capacity, cols), batch.dicts
     )

@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 
 from auron_tpu import obs
-from auron_tpu.columnar.batch import Batch, compaction_bucket, compaction_index
+from auron_tpu.columnar.batch import (
+    Batch, compaction_bucket, compaction_index, note_index,
+)
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exec.selectivity import CompactionBoundary
 from auron_tpu.exprs import ir
@@ -283,6 +285,7 @@ def _run_chain(
                 bvals_all, bmasks_all, tuple(bis), sel_out
             )
             return None, None, c_b, c_bm, sel_out
+        note_index(pb.capacity, out_cap)
         return _chain_take_pred_jit(
             tuple(pb.col_values(c) for c in probe_cols),
             tuple(pb.col_validity(c) for c in probe_cols),

@@ -38,6 +38,7 @@ from auron_tpu.columnar.batch import (
     compaction_index,
     device_concat,
     lookup_compare_width,
+    note_index,
 )
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exprs import Evaluator, ir
@@ -377,6 +378,7 @@ def _small_key_list(w0, sel, n_live: int, base):
         n_live, None if base is not None else w0.shape[0])
     if width is None:
         return None
+    note_index(w0.shape[0], width)
     return _key_list_jit(w0, sel, base, width=width)
 
 

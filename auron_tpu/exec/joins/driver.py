@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from auron_tpu import obs
 from auron_tpu import types as T
-from auron_tpu.columnar.batch import Batch, compaction_bucket
+from auron_tpu.columnar.batch import Batch, compaction_bucket, note_index
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exec.selectivity import CompactionBoundary
 from auron_tpu.exprs import Evaluator, ir
@@ -452,6 +452,7 @@ class EquiJoinDriver:
         if out_cap is None:
             bv, bm = core._gather_build_jit(bvals, bmasks, bi, ok)
             return (None, None, bv, bm, sel_out)
+        note_index(pb.capacity, out_cap)
         return core._unique_compact_take_pred_jit(
             tuple(pb.col_values(c) for c in pcol_ids),
             tuple(pb.col_validity(c) for c in pcol_ids),

@@ -15,10 +15,11 @@ Public surface:
 - ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` /
   ``note_agg_fold`` / ``note_agg_reduce`` / ``note_agg_emit`` /
   ``note_decimal_host_cells`` / ``note_join_take`` / ``note_join_lookup``
-  — the instrumentation facade behind MetricNode.timer, the
-  EngineCounters hooks, the task pump, the aggregate's folds, reduces and
-  emissions, the DECIMAL cells the host handles one by one, and the
-  unique-build join's lookup and output boundary.
+  / ``note_compaction_index`` — the instrumentation facade behind
+  MetricNode.timer, the EngineCounters hooks, the task pump, the
+  aggregate's folds, reduces and emissions, the DECIMAL cells the host
+  handles one by one, the unique-build join's lookup and output boundary,
+  and every program launched with a compaction index.
   Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``jit`` — ``jax.jit`` for every program of the engine: each call is a
   device launch, counted and timed beside the primitives JAX runs eagerly
@@ -232,6 +233,22 @@ def note_join_lookup(kind: str, rows: int) -> None:
     _event("lookup", "join.unique", {"kind": kind, "rows": rows})
 
 
+def note_compaction_index(form: str, in_rows: int, out_rows: int) -> None:
+    """One program launched with a compaction index (``columnar/batch.py
+    compaction_index``: the aggregate's drains and boundary, the joins'
+    takes, a small build's key list), noted by the host that launches it:
+    ``form`` the index's, ``search`` or ``scatter``
+    (``compaction_index_form`` of the static shapes), ``in_rows`` the
+    capacity it reads, ``out_rows`` the bucket it fills. An ``index`` event
+    of no duration and no layer, like ``take``; ``window_summary`` counts
+    them by form as ``compaction_index``, with ``in_rows`` summed as
+    ``rows`` and ``out_rows`` as ``out_rows``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("index", "compaction", {"form": form, "in_rows": in_rows,
+                                   "out_rows": out_rows})
+
+
 def note_sync(dur_ns: int, is_async: bool) -> None:
     """One device->host read observed by EngineCounters (blocking sync or
     async-window harvest), into the trace counters of the calling
@@ -271,5 +288,6 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
 
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
     note_agg_fold = note_join_take = note_join_lookup = _noop  # noqa: F811
+    note_compaction_index = _noop  # noqa: F811
     note_agg_reduce = note_agg_emit = note_decimal_host_cells = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

@@ -134,7 +134,11 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ``join_takes`` counts those takes by mode (the ``take`` events,
     ``obs.note_join_take``); ``join_lookup_rows`` sums by kind the widths
     the unique-build probes looked their keys up at (the ``lookup``
-    events, ``obs.note_join_lookup``). ``plan_cache_hits`` and
+    events, ``obs.note_join_lookup``). ``compaction_index`` holds by form
+    (``search``, ``scatter``) the programs launched with a compaction index
+    as ``{n, rows, out_rows}``: their count, the capacities the indexes
+    read and the buckets they filled (the ``index`` events,
+    ``obs.note_compaction_index``). ``plan_cache_hits`` and
     ``plan_cache_misses`` count the ``serve:plan`` spans that began in the
     window by their ``cache_hit`` argument. ``exchange_bytes`` sums by
     ``mode`` (``mesh``, ``file``) the ``bytes`` of the ``exchange:write``
@@ -172,6 +176,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     reduces: dict[str, dict] = {}
     takes: dict[str, int] = {}
     lookups: dict[str, int] = {}
+    indexes = {form: {"n": 0, "rows": 0, "out_rows": 0}
+               for form in ("search", "scatter")}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
     ex_bytes: dict[str, int] = {}
     ex_devices: dict[str, int] = {}     # least devices by exchange:<name>
@@ -235,6 +241,11 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             elif ev[2] == "lookup":
                 kind = ev[7]["kind"]
                 lookups[kind] = lookups.get(kind, 0) + ev[7]["rows"]
+            elif ev[2] == "index":
+                ent = indexes[ev[7]["form"]]
+                ent["n"] += 1
+                ent["rows"] += ev[7]["in_rows"]
+                ent["out_rows"] += ev[7]["out_rows"]
             elif ev[2] == "launch":
                 for kind, family, layer, n, ns in ev[7]:
                     ent = launches.setdefault((family, kind, layer), [0, 0])
@@ -288,7 +299,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             "agg_reduces": reduces, "agg_sorted_rows": sorted_rows,
             "agg_groups": groups, "wide_decimal_host_cells": dec_cells,
             "join_gather_rows": gather_rows, "join_takes": takes,
-            "join_lookup_rows": lookups,
+            "join_lookup_rows": lookups, "compaction_index": indexes,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
             "exchange_bytes": ex_bytes, "exchange_devices_min": ex_devices,
             "stage_devices_min": stage_devices, "partition_pumps": pumps,

@@ -56,7 +56,7 @@ import numpy as np
 
 from auron_tpu import obs
 from auron_tpu import types as T
-from auron_tpu.columnar.batch import Batch, DeviceBatch
+from auron_tpu.columnar.batch import Batch, DeviceBatch, note_index
 from auron_tpu.exec.base import ExecOperator, ExecutionContext
 from auron_tpu.exprs import Evaluator, ir
 from auron_tpu.utils.config import (
@@ -652,6 +652,8 @@ class FusedStageExec(ExecOperator):
         emit = "cols" if self.has_project else "sel"
         if _note_dispatch((self.steps, "probe", cfg), b.capacity):
             node.add("stage_compiles", 1)
+        if take_prog[0] == "compact":
+            note_index(b.capacity, plan.cap)
         res = _stage_program_probe(
             b.device, anchor["lut"], anchor["lut_base"], anchor["words"],
             anchor["n_live"], key_list, anchor["pack_args"],

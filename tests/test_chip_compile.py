@@ -214,6 +214,34 @@ def test_dense_aggregate_fold_compiles_at_query_65s_batch(one_chip, rows, raw, l
     assert ("conditional(" in compiled.as_text()) == guarded
 
 
+@pytest.mark.parametrize("cap, out_cap", [
+    (1 << 21, 1 << 18),     # query 65's map-side drain of its dense table
+    (1 << 22, 1 << 19),     # its tenth-live batch through the dense boundary
+])
+def test_compaction_by_scatter_compiles_at_query_65s_shapes(
+        monkeypatch, one_chip, cap, out_cap):
+    """``_compact_dev`` as the TPU builds it at query 65's widest takes: the
+    index by ONE scatter (``compaction_index_form``), no search loop left,
+    four int64 columns gathered at the bucket. The temporaries stay under
+    one int32 plane of the capacity: no windowed layout 128 lanes wide."""
+    from auron_tpu.columnar import batch as batch_mod
+
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: True)
+    assert batch_mod.compaction_index_form(cap, out_cap) == "scatter"
+    # a jit of its own: no trace another test left under XLA:CPU's form
+    compact = jax.jit(batch_mod._compact_dev.__wrapped__,
+                      static_argnames=("out_cap", "cols"))
+    planes = range(4)
+    dev = batch_mod.DeviceBatch(
+        _sds((cap,), jnp.bool_, one_chip),
+        tuple(_sds((cap,), jnp.int64, one_chip) for _ in planes),
+        tuple(_sds((cap,), jnp.bool_, one_chip) for _ in planes))
+    compiled = _compile(compact, dev, out_cap=out_cap)
+    text = compiled.as_text()
+    assert len(_scatter_results(text)) == 1 and " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * cap
+
+
 def test_flagship_stage_program_compiles(one_chip):
     """``__graft_entry__.entry()``'s fused filter + project + group
     aggregation (a 3-operand lax.sort inside) at its own example shapes."""

@@ -175,6 +175,47 @@ def test_aggregates_leave_their_folds_and_groups_in_the_rings(q65, monkeypatch):
     assert wrong_rows(q65, got, frames)["sb"] == 0
 
 
+def test_scatter_built_index_answers_as_the_search(q65, monkeypatch):
+    """Under the TPU's rules (``_gather_bound``), query 65's drains and takes
+    build their compaction index by one scatter where the shapes say it pays:
+    the answer, the stores' averages included, is the one the search gives
+    everywhere, and the rings say which form each launch took."""
+    import time
+
+    import jax
+
+    from auron_tpu import obs
+    from auron_tpu.columnar import batch as batch_mod
+
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: True)
+    frames = datagen_store.tpcds_store(SF, SEEDS[2])
+    rule = batch_mod.compaction_index_form
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    forms = {}
+    try:
+        for name, form in (("search", lambda cap, out_cap: "search"), ("shape", rule)):
+            monkeypatch.setattr(batch_mod, "compaction_index_form", form)
+            jax.clear_caches()      # a program traced under the other form
+            t0 = time.perf_counter()
+            got, _ = run(q65, frames)
+            forms[name] = got, obs.window_summary(t0, time.perf_counter())
+    finally:
+        obs.set_mode(saved)
+        jax.clear_caches()
+    (searched, ws_search), (shaped, ws_shape) = forms["search"], forms["shape"]
+    assert ws_search["compaction_index"]["scatter"]["n"] == 0
+    assert ws_shape["compaction_index"]["scatter"]["n"] > 0
+    assert (ws_shape["compaction_index"]["scatter"]["n"]
+            + ws_shape["compaction_index"]["search"]["n"]
+            == ws_search["compaction_index"]["search"]["n"])
+    assert shaped.equals(searched)
+    by_store = lambda sb: sb.sort_values("ss_store_sk").reset_index(drop=True)
+    assert by_store(shaped.attrs["sb"]).equals(by_store(searched.attrs["sb"]))
+    assert wrong_rows(q65, shaped, frames) == {"answer": 0, "sb": 0, "rows": 100,
+                                               "stores": 13}
+
+
 def test_decimal128_writer_equals_the_cell_by_cell_loop():
     """``_decimal_from_unscaled`` writes the Decimal128 buffer itself; the
     loop it replaced (a ``decimal.Decimal`` a cell) is the reference."""

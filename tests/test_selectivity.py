@@ -301,3 +301,56 @@ def test_running_count_equals_the_flat_cumsum(n):
         live = np.flatnonzero(mask)[:128]
         np.testing.assert_array_equal(np.asarray(idx)[:len(live)], live)
         assert int(np.asarray(sel_out).sum()) == len(live)
+
+
+@pytest.mark.parametrize("chip, cap, out_cap, want", [
+    # query 65: the map-side drains of its 2^21-slot table, the reduce
+    # side's, its tenth-live batch through the dense boundary
+    (True, 1 << 21, 1 << 18, "scatter"),
+    (True, 1 << 21, 1 << 17, "scatter"),
+    (True, M4, 1 << 19, "scatter"),
+    # the star cells' takes: a few thousand live rows of 4 M or 1 M
+    (True, M4, 1 << 14, "search"),
+    (True, M1, 1 << 13, "search"),
+    # a small build's live key list, at every width of the ladder, from
+    # the star cells' builds (item: 18,000 rows; date_dim: 73,049)
+    *[(True, cap, width, "search") for width in batch_mod.COMPARE_WIDTHS
+      for cap in (1 << 15, 1 << 17)],
+    # XLA:CPU lowers a scatter to a serial loop: the search whatever the shape
+    (False, 1 << 21, 1 << 18, "search"),
+    (False, M4, 1 << 19, "search"),
+    (False, 1 << 10, 1 << 9, "search"),
+])
+def test_compaction_index_form_by_shape(monkeypatch, chip, cap, out_cap, want):
+    """The index's form is a rule over the static shapes: the scatter where
+    a write a row of capacity costs less than the search's gathered element
+    a row of bucket for every bit of capacity (the v5e's unit costs)."""
+    monkeypatch.setattr(batch_mod, "_gather_bound", lambda: chip)
+    assert batch_mod.compaction_index_form(cap, out_cap) == want
+
+
+@pytest.mark.parametrize("density", [0.0, 0.003, 0.5, 1.0])
+@pytest.mark.parametrize("cap", [128, 1024, 1 << 17])
+def test_scatter_index_equals_the_search(monkeypatch, cap, density):
+    """compaction_index's two forms give the same bits, ``idx`` and
+    ``sel_out``, into a bucket below, at and above the live count: the
+    first ``out_cap`` live rows in order, every slot past them ``cap - 1``
+    (where the clipped search puts them), and with no live row at all."""
+    rng = np.random.default_rng(cap)
+    mask = rng.random(cap) < density
+    n_live = int(mask.sum())
+    for out_cap in sorted({max(n_live // 2, 1), max(n_live, 1), n_live + 64}):
+        got = {}
+        for form in ("search", "scatter"):
+            monkeypatch.setattr(batch_mod, "compaction_index_form",
+                                lambda c, o, form=form: form)
+            got[form] = [np.asarray(a) for a in batch_mod.compaction_index(
+                jnp.asarray(mask), out_cap)]
+        for a, b in zip(got["search"], got["scatter"]):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        idx, sel_out = got["scatter"]
+        live = np.flatnonzero(mask)[:out_cap]
+        np.testing.assert_array_equal(idx[:len(live)], live)
+        assert (idx[len(live):] == cap - 1).all()
+        np.testing.assert_array_equal(sel_out, np.arange(out_cap) < n_live)
