@@ -219,6 +219,29 @@ def _lookup_costs() -> tuple[float, float, float]:
     return 4.0, 1.45, 1.4
 
 
+def lookup_form(n_live: int, cap: int) -> str:
+    """THE map of a unique one-word build of ``n_live`` live keys in
+    ``cap`` rows that no LUT serves (``exec/joins/core.py
+    prepare_build``): ``compare`` where ``lookup_compare_width`` gives its
+    live key list a width, else ``hash`` (ops/hashmap.py) where the hash
+    map's probe costs less a row than the binary search over the sorted
+    words, a gathered 64-bit element for every bit of ``cap``, else
+    ``search``. A rule over what the host holds, with no knob. The TPU
+    v5e's costs (PERF.md section 5, ``tools/hash_costs.py``, 4,194,304
+    probe words a tenth of them build keys): the map's probe 48.9-53.1 ns
+    a row at 2^20 to 2^21 keys (65.2 ns in a table twice as wide), the
+    search 502-522 ns a row at 20 and 21 bits, 24-25 ns a bit; so the map
+    pays from three bits of capacity up, which is every build past the key
+    list's widths. XLA:CPU lowers scatters to serial loops and its search
+    stays: there the map is never built."""
+    if lookup_compare_width(n_live, cap) is not None:
+        return "compare"
+    if not _gather_bound():
+        return "search"
+    passes = max(cap - 1, 1).bit_length()
+    return "hash" if 53.1 < passes * 24.0 else "search"
+
+
 def compaction_index_form(cap: int, out_cap: int) -> str:
     """THE form of ``compaction_index`` from ``cap`` rows into ``out_cap``:
     ``scatter`` where one write a row of capacity costs less than the

@@ -229,7 +229,7 @@ def _run_chain(
     )
     bwords_all = tuple(b.words for b in builds)
     n_lives = tuple(jnp.int32(b.n_live) for b in builds)
-    key_lists = tuple(b.key_list for b in builds)
+    tables = tuple(b.probe_table for b in builds)
     lookup_kinds = tuple(core.lookup_kind(b) for b in builds)
 
     n_levels = len(links)
@@ -310,7 +310,7 @@ def _run_chain(
             obs.note_join_lookup(kind, pb.capacity)
         sel_out, bis, live = _chain_probe_all_jit(
             kv_all, km_all, pb.device.sel,
-            luts, lut_bases, bwords_all, n_lives, key_lists,
+            luts, lut_bases, bwords_all, n_lives, tables,
             cfgs=level_cfgs,
         )
         return [
@@ -335,17 +335,17 @@ def _run_chain(
 
 @partial(obs.jit, static_argnames=("cfgs",))
 def _chain_probe_all_jit(kv_all, km_all, psel, luts, lut_bases, bwords_all,
-                         n_lives, key_lists, cfgs):
+                         n_lives, tables, cfgs):
     """Every level's key canonicalization + unique probe + the combined
     selection AND (with its live count) in ONE program: XLA fuses the
     per-level lookups into a single pass over the probe stream, and no
     per-level ok/live-count intermediates are materialized. A level's
-    ``key_lists`` entry (None, or a small build's live key list: the
-    pytree's shape is static) picks its map."""
+    ``tables`` entry (None, a small build's live key list or a wide
+    build's hash map: the pytree's shape is static) picks its map."""
     sel = psel
     bis = []
     for kv, km, lut, lb, bw, nl, kl, (bcap, use_lut, kinds) in zip(
-        kv_all, km_all, luts, lut_bases, bwords_all, n_lives, key_lists, cfgs
+        kv_all, km_all, luts, lut_bases, bwords_all, n_lives, tables, cfgs
     ):
         words, pvalid = core._canon_words_traced(kv, km, kinds)
         ok_base = psel & (pvalid if pvalid is not None else jnp.ones_like(psel))

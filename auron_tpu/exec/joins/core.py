@@ -38,13 +38,14 @@ from auron_tpu.columnar.batch import (
     compaction_index,
     device_concat,
     lookup_compare_width,
+    lookup_form,
     note_index,
 )
 from auron_tpu.exec.basic import batch_from_columns
 from auron_tpu.exprs import Evaluator, ir
 from auron_tpu.exprs.eval import ColumnVal
 from auron_tpu.obs.launch import ejnp
-from auron_tpu.ops import binsearch
+from auron_tpu.ops import binsearch, hashmap
 from auron_tpu.ops.floatbits import f64_equality_word
 from auron_tpu.ops import segments as S
 
@@ -116,6 +117,16 @@ class PreparedBuild:
     run_starts: jnp.ndarray | None = None  # [cap+1]; run i is rows
     # [run_starts[i], run_starts[i+1]) of the sorted build
     n_uniq: "jnp.ndarray | int" = 0  # device scalar (never synced)
+    # the hash map of a unique one-word build too wide for a LUT or a key
+    # list (ops/hashmap.py, where columnar.batch.lookup_form says so): the
+    # build then stays unsorted, ``batch`` and ``words`` in arrival order
+    hash_table: hashmap.HashTable | None = None
+
+    @property
+    def probe_table(self):
+        """What the unique probe is handed beside the LUT: the hash map,
+        the live key list, or None (the sorted words)."""
+        return self.hash_table if self.hash_table is not None else self.key_list
 
 
 def _key_columns(batch: Batch, key_exprs: list[ir.Expr]) -> list[ColumnVal]:
@@ -384,8 +395,11 @@ def _small_key_list(w0, sel, n_live: int, base):
 
 def lookup_kind(build: PreparedBuild) -> str:
     """How the unique probe maps a key to its build row: ``compare``
-    (the live key list), ``lut`` (one gather) or ``search`` (the sorted
-    words: a gather for every bit of the build's capacity)."""
+    (the live key list), ``lut`` (one gather), ``hash`` (the hash map: two
+    slots and a row gathered) or ``search`` (the sorted words: a gather for
+    every bit of the build's capacity)."""
+    if build.hash_table is not None:
+        return "hash"
     if build.key_list is not None:
         return "compare"
     return "lut" if build.lut is not None else "search"
@@ -419,7 +433,8 @@ def prepare_build(
         packed, _ = _pack_probe_jit(tuple(words), None, pack)
         words = [packed]
 
-    # ---- sort-free LUT path: single integer-like key, small value range
+    # ---- sort-free maps: one integer-like word (a key or a packed key),
+    # by a LUT over a small value range, else by a hash map
     if (
         cap > 0
         and len(words) == 1
@@ -434,17 +449,30 @@ def prepare_build(
         # the scatter pass (and its sync) instead of building tables that the
         # duplicates+pairs fallthrough would discard
         cannot_be_unique = n_live > kmax_h - kmin_h + 1
-        if (
+        lut = (
             n_live > 0
             and 0 <= kmax_h - kmin_h < min(max(4 * cap, 1 << 16), 1 << 22)
             and not (need_pairs and cannot_be_unique)
-        ):
+        )
+        # a one-word key too wide for a LUT: the hash map where its probe
+        # pays (columnar.batch.lookup_form), unless duplicates are certain
+        hashed = (
+            not lut
+            and n_live > 0
+            and not cannot_be_unique
+            and lookup_form(n_live, cap) == "hash"
+        )
+        if lut:
             size = bucket_capacity(int(kmax_h - kmin_h) + 1)
-            row_lut, exists, has_dup_d = _scatter_luts_jit(
+            row_lut, exists, flags_d = _scatter_luts_jit(
                 words[0], sel, jnp.int64(kmin_h), size=size
             )
-            has_dup = bool(jax.device_get(has_dup_d))  # auronlint: sync-point(8/task) -- one-scalar duplicate probe per build
-            if not has_dup:
+        elif hashed:
+            table, flags_d = hashmap.build(words[0], sel, n_live)
+        if lut or hashed:
+            flags = jax.device_get(flags_d)  # auronlint: sync-point(8/task) -- one read per build: the LUT's duplicate flag, or the hash map's (unplaced, duplicate, rounds)
+        if lut:
+            if not bool(flags):
                 return PreparedBuild(
                     batch=big, words=[words[0]], n_live=n_live,
                     matched=jnp.zeros(cap, bool), unique=True,
@@ -459,6 +487,17 @@ def prepare_build(
                     exists_lut=exists, lut_base=kmin_h, pack=pack,
                 )
             # duplicates + pair output -> fall through to the sorted map
+        elif hashed:
+            left, dup, rounds = (int(x) for x in flags)
+            obs.note_join_hash_build(n_live, table.rows.shape[0], rounds,
+                                     bool(left or dup))
+            if not (left or dup):
+                return PreparedBuild(
+                    batch=big, words=[words[0]], n_live=n_live,
+                    matched=jnp.zeros(cap, bool), unique=True,
+                    hash_table=table, pack=pack,
+                )
+            # a duplicate key, or a row left unplaced: the sorted map
     # presorted pre-check: SMJ build sides arrive straight from SortExec,
     # already clustered with live rows in a prefix — detecting that on
     # device (one tiny sync) skips the whole sort + all-column permute
@@ -468,10 +507,11 @@ def prepare_build(
         stats = stats0
         sorted_words = list(words)
     else:
-        if hostsort.use_host_sort(conf):
-            order = S.host_order(words, sel)
+        if hostsort.use_host_sort(conf, rows=cap):
+            order = S.host_order(words, sel, where="join_build")
             device_sort = False
         else:
+            obs.note_sort(cap, "join_build", "device")
             order, device_sort = None, True
         row_sel_s, sorted_words, values_s, validity_s, stats = _prepare_build_jit(
             sel, dev.sel, tuple(words), dev.values, dev.validity, order,
@@ -603,14 +643,18 @@ def _compare_rows(key, keys, rows):
 
 def _probe_unique_ops(
     probe_words, ok_base, lut, lut_base, bwords, n_live, bcap: int,
-    key_list=None,
+    table=None,
 ):
     """Traceable core of the unique-build probe (called inside jit):
-    (bi, ok) by the build's live key list where it carries one
-    (``key_list``), else by its LUT, else by binary search. The three
-    maps agree on ``ok`` and on ``bi`` wherever ``ok`` (the list and the
-    LUT on every row: an unmatched row's ``bi`` is 0, the search's its
-    insertion point; nothing reads either)."""
+    (bi, ok) by the build's hash map where ``table`` is one
+    (``PreparedBuild.probe_table``), by its live key list where ``table``
+    is that, else by its LUT, else by binary search. The four maps agree
+    on ``ok`` and on ``bi`` wherever ``ok`` (the list and the LUT on every
+    row: an unmatched row's ``bi`` is 0, the search's its insertion point,
+    the map's a row or 0; nothing reads any of them)."""
+    if isinstance(table, hashmap.HashTable):
+        bi, found = hashmap.probe(table, probe_words[0])
+        return jnp.clip(bi, 0, bcap - 1), ok_base & found
     if lut is not None:
         w = probe_words[0]
         size = lut.shape[0]
@@ -621,11 +665,11 @@ def _probe_unique_ops(
         slot = jnp.clip(idx, 0, size - 1).astype(jnp.int32)
         # in_range stays in ok on both arms: a key clipped into the range
         # must not alias the live key at its edge
-        bi = lut[slot] if key_list is None else _compare_rows(slot, *key_list)
+        bi = lut[slot] if table is None else _compare_rows(slot, *table)
         ok = ok_base & in_range & (bi >= 0)
         return jnp.clip(bi, 0, bcap - 1), ok
-    if key_list is not None:
-        bi = _compare_rows(probe_words[0], *key_list)
+    if table is not None:
+        bi = _compare_rows(probe_words[0], *table)
         return jnp.clip(bi, 0, bcap - 1), ok_base & (bi >= 0)
     lo = binsearch._search(bwords, probe_words, n_live, binsearch._lex_less)
     bi = jnp.clip(lo, 0, bcap - 1)
@@ -678,17 +722,18 @@ def key_kind(dtype) -> str:
 
 @partial(obs.jit, static_argnames=("bcap", "use_lut", "probe_outer", "key_kinds"))
 def _unique_probe_jit(
-    key_vals, key_masks, psel, lut, lut_base, bwords, n_live, key_list,
+    key_vals, key_masks, psel, lut, lut_base, bwords, n_live, table,
     bcap: int, use_lut: bool, probe_outer: bool, key_kinds: tuple,
 ):
     """Canon + probe in ONE program (no gathers): (bi, ok, sel_out, live).
-    ``key_list`` (None, or the build's two small arrays: the pytree's
-    shape is the static half) picks the compare map."""
+    ``table`` (``PreparedBuild.probe_table``: None, or the build's two
+    arrays of its key list or its hash map; the pytree's shape is the
+    static half) picks the compare or the hash map."""
     probe_words, pvalid = _canon_words_traced(key_vals, key_masks, key_kinds)
     ok_base = psel & (pvalid if pvalid is not None else jnp.ones_like(psel))
     bi, ok = _probe_unique_ops(
         probe_words, ok_base, lut if use_lut else None, lut_base, bwords,
-        n_live, bcap, key_list,
+        n_live, bcap, table,
     )
     sel_out = psel if probe_outer else (psel & ok)
     return bi, ok, sel_out, jnp.sum(sel_out.astype(jnp.int32))
@@ -731,7 +776,7 @@ def _unique_join_emit_jit(
     lut_base,
     bwords,
     n_live,
-    key_list,
+    table,
     build_vals,
     build_masks,
     bcap: int,
@@ -745,7 +790,7 @@ def _unique_join_emit_jit(
     ok_base = psel & (pvalid if pvalid is not None else jnp.ones_like(psel))
     bi, ok = _probe_unique_ops(
         probe_words, ok_base, lut if use_lut else None, lut_base, bwords,
-        n_live, bcap, key_list,
+        n_live, bcap, table,
     )
     out_vals = tuple(v[bi] for v in build_vals)
     out_masks = tuple(m[bi] & ok for m in build_masks)

@@ -180,7 +180,7 @@ class EquiJoinDriver:
             lut_base=_jnp.int64(build.lut_base),
             words=tuple(build.words),
             n_live=_jnp.int32(build.n_live),
-            key_list=build.key_list,
+            table=build.probe_table,
             packed=build.pack is not None,
             pack_args=pack_args,
             exists_lut=build.exists_lut,
@@ -366,7 +366,7 @@ class EquiJoinDriver:
                 _jnp.int64(build.lut_base) if build.lut is not None else None,
                 build.words,
                 _jnp.int32(build.n_live),
-                build.key_list,
+                build.probe_table,
                 tuple(bb.col_values(c) for c in bcol_ids),
                 tuple(bb.col_validity(c) for c in bcol_ids),
                 bcap=bb.capacity,
@@ -474,7 +474,7 @@ class EquiJoinDriver:
                 pb.device.sel,
                 build.lut,
                 jnp.int64(build.lut_base) if build.lut is not None else None,
-                build.words, jnp.int32(build.n_live), build.key_list,
+                build.words, jnp.int32(build.n_live), build.probe_table,
                 bcap=bb.capacity,
                 use_lut=build.lut is not None,
                 probe_outer=self.probe_outer,

@@ -126,7 +126,9 @@ class SortExec(ExecOperator):
         from auron_tpu.ops import hostsort
 
         with ctx.metrics.timer("sort_time"):
-            if hostsort.use_host_sort(ctx.conf):
+            host = hostsort.use_host_sort(ctx.conf, rows=cap)
+            obs.note_sort(cap, "sort_exec", "host" if host else "device")
+            if host:
                 order = hostsort.order_by_words((live, *ops))
                 sorted_ops = (None, *(o[order] for o in ops), order)
             else:

@@ -15,11 +15,13 @@ Public surface:
 - ``note_op`` / ``note_sync`` / ``note_compile`` / ``note_pump_batch`` /
   ``note_agg_fold`` / ``note_agg_reduce`` / ``note_agg_emit`` /
   ``note_decimal_host_cells`` / ``note_join_take`` / ``note_join_lookup``
-  / ``note_compaction_index`` — the instrumentation facade behind
-  MetricNode.timer, the EngineCounters hooks, the task pump, the
-  aggregate's folds, reduces and emissions, the DECIMAL cells the host
-  handles one by one, the unique-build join's lookup and output boundary,
-  and every program launched with a compaction index.
+  / ``note_join_hash_build`` / ``note_compaction_index`` / ``note_sort``
+  — the instrumentation facade behind MetricNode.timer, the
+  EngineCounters hooks, the task pump, the aggregate's folds, reduces and
+  emissions, the DECIMAL cells the host handles one by one, the
+  unique-build join's lookup, hash-map build and output boundary, every
+  program launched with a compaction index, and the sorts of plans,
+  joins' builds and the host's lexsort.
   Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``jit`` — ``jax.jit`` for every program of the engine: each call is a
   device launch, counted and timed beside the primitives JAX runs eagerly
@@ -224,13 +226,44 @@ def note_join_lookup(kind: str, rows: int) -> None:
     batch a level where the takes are (the BHJ driver, for its fused stage
     twin too; the star-join chain): ``kind`` is how the key -> build row
     map was read, ``compare`` (against a small build's live key list),
-    ``lut`` (one gather a row) or ``search`` (the sorted words); ``rows``
+    ``lut`` (one gather a row), ``hash`` (the hash map, ops/hashmap.py:
+    two slots and a row gathered) or ``search`` (the sorted words); ``rows``
     the width the lookup ran at, the batch's capacity. A ``lookup`` event
     of no duration and no layer, like ``take``; ``window_summary`` sums
     ``rows`` by kind as ``join_lookup_rows``."""
     if core._mode == MODE_OFF:
         return
     _event("lookup", "join.unique", {"kind": kind, "rows": rows})
+
+
+def note_join_hash_build(rows: int, buckets: int, rounds: int,
+                         fallback: bool) -> None:
+    """One build of a join's hash map (``exec/joins/core.py
+    prepare_build``, ops/hashmap.py): ``rows`` the live keys it placed,
+    ``buckets`` the table's slots, ``rounds`` the rounds of placement it
+    ran, ``fallback`` True where it met a duplicate key or left a row
+    unplaced and the build took its sorted path instead. A ``hashbuild``
+    event of no duration and no layer, like ``take``; ``window_summary``
+    sums them as ``join_hash_build`` ``{n, rows, rounds, fallbacks}``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("hashbuild", "join.unique",
+           {"rows": rows, "buckets": buckets, "rounds": rounds,
+            "fallback": fallback})
+
+
+def note_sort(rows: int, where: str, substrate: str) -> None:
+    """One sort of ``rows`` rows of capacity: ``where`` is ``sort_exec``
+    (a plan's Sort, exec/sort_exec.py), ``join_build`` (a join's sorted
+    build, ``exec/joins/core.py prepare_build``) or ``hostsort`` (an
+    aggregate's order from the host's lexsort, ``ops/segments.py
+    host_order``); ``substrate`` ``host`` or ``device``. A ``sort`` event
+    of no duration and no layer, like ``take``; ``window_summary`` sums
+    ``rows`` by ``where`` and substrate as ``sort_rows``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("sort", f"sort.{where}", {"rows": rows, "where": where,
+                                     "substrate": substrate})
 
 
 def note_compaction_index(form: str, in_rows: int, out_rows: int) -> None:
@@ -288,6 +321,7 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
 
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
     note_agg_fold = note_join_take = note_join_lookup = _noop  # noqa: F811
+    note_join_hash_build = note_sort = _noop  # noqa: F811
     note_compaction_index = _noop  # noqa: F811
     note_agg_reduce = note_agg_emit = note_decimal_host_cells = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

@@ -134,7 +134,12 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     ``join_takes`` counts those takes by mode (the ``take`` events,
     ``obs.note_join_take``); ``join_lookup_rows`` sums by kind the widths
     the unique-build probes looked their keys up at (the ``lookup``
-    events, ``obs.note_join_lookup``). ``compaction_index`` holds by form
+    events, ``obs.note_join_lookup``) and ``join_hash_build`` the joins'
+    hash-map builds as ``{n, rows, rounds, fallbacks}`` (``hashbuild``,
+    ``obs.note_join_hash_build``); ``sort_rows`` sums by ``where`` and
+    substrate, as ``{where: {substrate: rows}}``, the capacities the plans'
+    sorts, the joins' sorted builds and the host's lexsorts ordered (the
+    ``sort`` events, ``obs.note_sort``). ``compaction_index`` holds by form
     (``search``, ``scatter``) the programs launched with a compaction index
     as ``{n, rows, out_rows}``: their count, the capacities the indexes
     read and the buckets they filled (the ``index`` events,
@@ -176,6 +181,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     reduces: dict[str, dict] = {}
     takes: dict[str, int] = {}
     lookups: dict[str, int] = {}
+    hash_builds = {"n": 0, "rows": 0, "rounds": 0, "fallbacks": 0}
+    sorts: dict[str, dict] = {}
     indexes = {form: {"n": 0, "rows": 0, "out_rows": 0}
                for form in ("search", "scatter")}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
@@ -241,6 +248,15 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             elif ev[2] == "lookup":
                 kind = ev[7]["kind"]
                 lookups[kind] = lookups.get(kind, 0) + ev[7]["rows"]
+            elif ev[2] == "hashbuild":
+                hash_builds["n"] += 1
+                hash_builds["rows"] += ev[7]["rows"]
+                hash_builds["rounds"] += ev[7]["rounds"]
+                hash_builds["fallbacks"] += bool(ev[7]["fallback"])
+            elif ev[2] == "sort":
+                ent = sorts.setdefault(ev[7]["where"], {})
+                sub = ev[7]["substrate"]
+                ent[sub] = ent.get(sub, 0) + ev[7]["rows"]
             elif ev[2] == "index":
                 ent = indexes[ev[7]["form"]]
                 ent["n"] += 1
@@ -299,7 +315,8 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             "agg_reduces": reduces, "agg_sorted_rows": sorted_rows,
             "agg_groups": groups, "wide_decimal_host_cells": dec_cells,
             "join_gather_rows": gather_rows, "join_takes": takes,
-            "join_lookup_rows": lookups, "compaction_index": indexes,
+            "join_lookup_rows": lookups, "join_hash_build": hash_builds,
+            "sort_rows": sorts, "compaction_index": indexes,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
             "exchange_bytes": ex_bytes, "exchange_devices_min": ex_devices,
             "stage_devices_min": stage_devices, "partition_pumps": pumps,

@@ -257,11 +257,15 @@ def segment_by_keys(
         return _finish_segmentation(order, sorted_words, sel_sorted, cap)
 
 
-def host_order(words: list[jnp.ndarray], sel: jnp.ndarray) -> jnp.ndarray:
+def host_order(words: list[jnp.ndarray], sel: jnp.ndarray,
+               where: str = "hostsort") -> jnp.ndarray:
     """EAGER host lexsort order for segment_by_keys(host_sort=True):
     identical tie semantics to the in-trace callback (dead rows last,
-    stable). Call OUTSIDE jit; pass the result as ``order``."""
+    stable). Call OUTSIDE jit; pass the result as ``order``. ``where``
+    names the sort in its ring event (``obs.note_sort``)."""
     import numpy as np
+
+    obs.note_sort(int(sel.shape[0]), where, "host")
 
     # auronlint: sync-point(2/batch) -- documented eager host boundary ("call OUTSIDE jit"); one batched transfer
     dead_d, words_d = jax.device_get(
@@ -289,6 +293,7 @@ def host_order_fp(
     consumes it as data instead of hashing the words a second time."""
     import numpy as np
 
+    obs.note_sort(int(sel.shape[0]), "hostsort", "host")
     dead_dev, fp_dev = _fp_dead_jit(tuple(words), sel, fp_bits)
     # auronlint: sync-point(2/batch) -- fingerprint host-sort boundary: 2 fixed arrays per batch regardless of key count (vs 2+K for host_order)
     dead_d, fp_d = jax.device_get((dead_dev, fp_dev))

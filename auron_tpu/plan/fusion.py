@@ -230,7 +230,7 @@ def _stage_program_prep(dev: DeviceBatch, bases, his, strides, size, *,
 
 
 @_partial(obs.jit, static_argnames=("steps", "emit", "probe"))
-def _stage_program_probe(dev, lut, lut_base, bwords, n_live, key_list,
+def _stage_program_probe(dev, lut, lut_base, bwords, n_live, table,
                          pack_args, exists_lut, bvals, bmasks, *,
                          steps: tuple, emit: str, probe: tuple):
     """Stage program variant for segments feeding a hash-join probe: in the
@@ -241,13 +241,14 @@ def _stage_program_probe(dev, lut, lut_base, bwords, n_live, key_list,
     chain (``_pack_probe_jit`` -> ``_unique_probe_jit`` ->
     ``_gather_build_jit`` / ``_unique_compact_take_pred_jit``) bit-for-bit.
 
-    Build-side state (``lut``/``bwords``/``n_live``/a small build's live
-    ``key_list``/pack ranges/build columns) arrives as DEVICE ARGUMENTS
-    published at runtime by the join exec (ProbePrepLink), so a fresh build
-    — even a different one — reuses the compiled program; ``probe`` is the
-    static half: (key_exprs, key_schema, key_kinds, use_lut, cmp_width,
-    probe_outer, bcap, packed, pcol_ids, take) with cmp_width the key
-    list's width (0: the build carries none) and take one of ("probe",) |
+    Build-side state (``lut``/``bwords``/``n_live``/the ``table`` of
+    ``PreparedBuild.probe_table``/pack ranges/build columns) arrives as
+    DEVICE ARGUMENTS published at runtime by the join exec (ProbePrepLink),
+    so a fresh build — even a different one — reuses the compiled program;
+    ``probe`` is the static half: (key_exprs, key_schema, key_kinds,
+    use_lut, cmp_width, probe_outer, bcap, packed, pcol_ids, take) with
+    cmp_width the width of the table's first array, the key list's or the
+    hash map's (0: the build carries none) and take one of ("probe",) |
     ("gather",) | ("compact", out_cap) | ("exists",)."""
     from auron_tpu.columnar.batch import compaction_index
     from auron_tpu.exec.joins import core as jcore
@@ -290,7 +291,7 @@ def _stage_program_probe(dev, lut, lut_base, bwords, n_live, key_list,
     with jax.named_scope("auron.probe.lookup"):
         bi, ok = jcore._probe_unique_ops(
             probe_words, ok_base, lut if use_lut else None, lut_base,
-            list(bwords), n_live, bcap, key_list if cmp_width else None,
+            list(bwords), n_live, bcap, table if cmp_width else None,
         )
         sel_out = sel if probe_outer else (sel & ok)
         live = jnp.sum(sel_out.astype(jnp.int32))
@@ -644,8 +645,8 @@ class FusedStageExec(ExecOperator):
                 ("probe",) if plan.cap is None else ("compact", plan.cap)
             )
         key_schema = self.out_stamp or self.children[0].schema
-        key_list = anchor["key_list"]
-        cmp_width = key_list[0].shape[0] if key_list is not None else 0
+        table = anchor["table"]
+        cmp_width = table[0].shape[0] if table is not None else 0
         cfg = (self._probe_keys, key_schema, self._probe_kinds,
                anchor["use_lut"], cmp_width, self._probe_outer,
                anchor["bcap"], anchor["packed"], self._probe_pcols, take_prog)
@@ -656,7 +657,7 @@ class FusedStageExec(ExecOperator):
             note_index(b.capacity, plan.cap)
         res = _stage_program_probe(
             b.device, anchor["lut"], anchor["lut_base"], anchor["words"],
-            anchor["n_live"], key_list, anchor["pack_args"],
+            anchor["n_live"], table, anchor["pack_args"],
             anchor["exists_lut"],
             anchor["bvals"], anchor["bmasks"],
             steps=self.steps, emit=emit, probe=cfg,

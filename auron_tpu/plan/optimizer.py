@@ -335,6 +335,10 @@ for _p in _PASSTHROUGH:
 # the only operator in the IR whose OUTPUT depends on its input's row order
 # (head-N). Sort/TakeOrdered/Window establish their own order internally.
 _ORDER_SENSITIVE = ("limit",)
+# operators that neither need their input's order nor keep it in a way an
+# ancestor could read: an order beneath them lives on only as far as they do
+_ORDER_FREE = ("project", "filter")
+_WRITERS = ("shuffle_writer", "rss_shuffle_writer")
 
 
 def elide_smj_input_sorts(
@@ -343,18 +347,24 @@ def elide_smj_input_sorts(
     """Drop SortExec children feeding a sort-merge join.
 
     The host engine plans Sort->SMJ because ITS merge-join streams two
-    ordered cursors; this engine's SMJ clusters the build side itself
-    (joins/core.prepare_build) and probes with order-independent binary
-    searches, so explicit input sorts are pure overhead — at perf-gate
-    scale each one is a full materialized lexsort of a fact partition.
+    ordered cursors; this engine's SMJ builds its right side into a map
+    (joins/core.prepare_build: a LUT, a hash map or sorted words) and
+    probes with order-independent lookups, so explicit input sorts are
+    pure overhead — at perf-gate scale each one is a full materialized
+    lexsort of a fact partition.
 
     ``mode`` controls how aggressive the rewrite is:
 
-    - "build" (default): elide only the BUILD-side (right) sort. The join's
-      output order follows the probe side, so this NEVER changes the output
-      ordering — safe even when the host relied on the SMJ's output
-      ordering to satisfy a downstream requirement invisible in this task
-      plan (Spark's EnsureRequirements plants no sort above a join whose
+    - "build" (default): elide the BUILD-side (right) sort, and the probe
+      side's (left) where the task plan itself proves the join's order
+      dies: the path from the join to the task's root passes only
+      order-free operators (projection, filter, a hash join's probe side)
+      and ends at a hash aggregate or a hash-partitioning shuffle writer,
+      neither of which reads or keeps its input's order. The join's output
+      order follows the probe side, so elsewhere the probe's sort stays —
+      safe even when the host relied on the SMJ's output ordering to
+      satisfy a downstream requirement invisible in this task plan
+      (Spark's EnsureRequirements plants no sort above a join whose
       outputOrdering already satisfies the parent).
     - "full": elide both sides. Only the host can know no ancestor outside
       the converted section needs the order; it asserts that by setting
@@ -369,18 +379,36 @@ def elide_smj_input_sorts(
         return plan
     new = pb.PhysicalPlanNode()
     new.CopyFrom(plan)
-    _elide(new, order_sensitive=False, full=(mode == "full"))
+    _elide(new, order_sensitive=False, full=(mode == "full"), dies=False)
     return new
 
 
-def _elide(node: "pb.PhysicalPlanNode", order_sensitive: bool, full: bool) -> None:
+def _order_dies_below(node: "pb.PhysicalPlanNode", dies: bool) -> tuple:
+    """For each child of ``node`` (``protowalk.child_nodes``' order), does
+    its output's order die on the way to the task's root (``dies``: it
+    dies above ``node``)?"""
+    which = node.WhichOneof("plan")
+    if which == "hash_agg":
+        return (True,)
+    if which in _WRITERS:
+        return (getattr(node, which).partitioning.kind == pb.Partitioning.HASH,)
+    if which in _ORDER_FREE:
+        return (dies,)
+    if which == "hash_join":
+        probe_left = node.hash_join.build_side == pb.BUILD_RIGHT
+        return (dies and probe_left, dies and not probe_left)
+    return ()
+
+
+def _elide(node: "pb.PhysicalPlanNode", order_sensitive: bool, full: bool,
+           dies: bool) -> None:
     from auron_tpu.plan.protowalk import child_nodes
 
     which = node.WhichOneof("plan")
     sensitive = order_sensitive or which in _ORDER_SENSITIVE
     if which == "sort_merge_join" and not sensitive:
         j = node.sort_merge_join
-        sides = ("left", "right") if full else ("right",)
+        sides = ("left", "right") if (full or dies) else ("right",)
         for side in sides:
             child = getattr(j, side)
             if (
@@ -390,5 +418,6 @@ def _elide(node: "pb.PhysicalPlanNode", order_sensitive: bool, full: bool) -> No
                 grand = pb.PhysicalPlanNode()
                 grand.CopyFrom(child.sort.child)
                 getattr(j, side).CopyFrom(grand)
-    for c in child_nodes(node):
-        _elide(c, sensitive, full)
+    below = _order_dies_below(node, dies)
+    for i, c in enumerate(child_nodes(node)):
+        _elide(c, sensitive, full, i < len(below) and below[i])

@@ -280,6 +280,42 @@ def test_compare_probe_keeps_no_key_by_row_temporary(one_chip, rows, width,
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows
 
 
+def test_hash_map_build_and_probe_compile_at_query_93s_shapes(one_chip):
+    """The unique build's hash map (ops/hashmap.py) at a partition of query
+    93: its build of 1,048,576 live keys in as many rows (every placement
+    stage and the tail's loop), and the unique probe's program looking up
+    4,194,304 probe rows in it. The map's arrays are planes, never a
+    ``[rows, k]`` window laid out 128 lanes wide: the build's temporaries
+    stay within its table and a few row planes, the probe's within a few
+    planes of its rows."""
+    from auron_tpu.exec.joins import core
+    from auron_tpu.ops import hashmap
+
+    n = 1 << 20
+    size = hashmap.table_size(n)
+    compiled = _compile(
+        hashmap._build_jit, _sds((n,), jnp.uint64, one_chip),
+        _sds((n,), jnp.bool_, one_chip), size=size,
+        widths=hashmap.stage_widths(n, n))
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 * size + 64 * n
+
+    rows = 1 << 22
+    table = hashmap.HashTable(_sds((size,), jnp.uint32, one_chip),
+                              _sds((size,), jnp.uint32, one_chip),
+                              _sds((size,), jnp.int32, one_chip))
+    compiled = _compile(
+        core._unique_probe_jit,
+        (_sds((rows,), jnp.int64, one_chip),),
+        (_sds((rows,), jnp.bool_, one_chip),), _sds((rows,), jnp.bool_, one_chip),
+        None, None, [_sds((n,), jnp.uint64, one_chip)],
+        _sds((), jnp.int32, one_chip), table, bcap=n, use_lut=False,
+        probe_outer=False, key_kinds=("int",))
+    # 28 B a row on the compiler of this writing: the mixed word, its two
+    # slots and the two 64-bit slots read; a window 128 lanes wide would be
+    # hundreds
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * rows
+
+
 def test_exchange_steps_compile_for_four_chips(mesh4):
     """The ICI shuffle as ONE program across four chips: both mesh
     programs must partition, and the collective must really be there."""

@@ -12,6 +12,7 @@ from auron_tpu.exprs.ir import BinaryOp, col, lit
 from auron_tpu.plan import builders as B
 from auron_tpu.plan.optimizer import prune_columns
 from auron_tpu.plan.planner import plan_from_proto
+from auron_tpu.proto import plan_pb2 as pb
 
 
 def _mk_batch(df):
@@ -122,3 +123,99 @@ def _run_plan_nosort(plan, fact, dim):
     op = plan_from_proto(plan)
     df = op.collect(ctx=ctx).to_pandas()
     return df.sort_values(list(df.columns)).reset_index(drop=True)
+
+
+# ---------------------------------------------------------------------------
+# the Sorts under a sort-merge join (elide_smj_input_sorts)
+# ---------------------------------------------------------------------------
+
+FACT = T.Schema(tuple(T.Field(c, T.INT64, True) for c in
+                      ("item", "ticket", "customer", "quantity", "price")))
+RETURNS = T.Schema(tuple(T.Field(c, T.INT64, True) for c in
+                         ("sr_item", "sr_ticket", "sr_reason", "sr_quantity")))
+REASON = T.Schema((T.Field("r_reason_sk", T.INT64, False),))
+
+
+def _by_line(child):
+    from auron_tpu.ops.sortkeys import SortSpec
+
+    return B.sort(child, [(col(0), SortSpec()), (col(1), SortSpec())])
+
+
+def _line_smj(left, right):
+    return B.sort_merge_join(left, right, [col(0), col(1)], [col(0), col(1)],
+                             "inner")
+
+
+def _acts(smj):
+    """Query 93's stage 3 above its join: BHJ reason on the probe side,
+    the customer and the act_sales' operands projected."""
+    joined = B.hash_join(smj, B.memory_scan(REASON, "reason"), [col(7)], [col(0)],
+                         "inner", build_side="right")
+    return B.project(joined, [(col(2), "customer"), (col(3), "quantity")])
+
+
+def _stage3(root, smj=None):
+    smj = smj or _line_smj(_by_line(B.ipc_reader(FACT, "ex1")),
+                           _by_line(B.ipc_reader(RETURNS, "ex2")))
+    return root(_acts(smj))
+
+
+def _partial_sum(node):
+    return B.hash_agg(node, [(col(0), "customer")], [("sum", col(1), "s")],
+                      "partial")
+
+
+def _writer(node, part=None):
+    return B.shuffle_writer(node, part or B.hash_partitioning([col(0)], 2),
+                            "d", "i")
+
+
+def _smj_inputs(plan) -> list:
+    """(left, right) node kinds of every sort-merge join, root first."""
+    from auron_tpu.plan.protowalk import child_nodes
+
+    out, todo = [], [plan]
+    while todo:
+        node = todo.pop(0)
+        if node.WhichOneof("plan") == "sort_merge_join":
+            j = node.sort_merge_join
+            out.append((j.left.WhichOneof("plan"), j.right.WhichOneof("plan")))
+        todo.extend(child_nodes(node))
+    return out
+
+
+@pytest.mark.parametrize("name, plan, mode, want", [
+    # query 93's stage 3: the writer hashes, the partial sum has no order
+    ("q93_stage3", lambda: _stage3(lambda n: _writer(_partial_sum(n))), "build",
+     [("ipc_reader", "ipc_reader")]),
+    ("ends_at_the_aggregate", lambda: _stage3(_partial_sum), "build",
+     [("ipc_reader", "ipc_reader")]),
+    # a head-N above: nothing is rewritten
+    ("under_limit", lambda: _stage3(lambda n: _writer(_partial_sum(B.limit(n, 10)))),
+     "build", [("sort", "sort")]),
+    # the task's root hands its rows on in order (a broadcast, the driver)
+    ("root_keeps_order", lambda: _stage3(lambda n: B.ipc_writer(n, "out")), "build",
+     [("sort", "ipc_reader")]),
+    ("range_partitioned", lambda: _stage3(lambda n: _writer(
+        n, pb.Partitioning(kind=pb.Partitioning.RANGE, num_partitions=2))),
+     "build", [("sort", "ipc_reader")]),
+    # SMJ -> SMJ on the same keys: the inner join's order is what the outer
+    # one could read, so only the outer one's probe Sort goes
+    ("smj_over_smj", lambda: _stage3(
+        lambda n: _writer(_partial_sum(n)),
+        smj=_line_smj(_line_smj(_by_line(B.ipc_reader(FACT, "ex1")),
+                                _by_line(B.ipc_reader(RETURNS, "ex2"))),
+                      _by_line(B.ipc_reader(RETURNS, "ex3")))),
+     "build", [("sort_merge_join", "ipc_reader"), ("sort", "ipc_reader")]),
+    # the host's own word: full drops both anywhere, off none
+    ("full_keeps_its_meaning", lambda: _stage3(lambda n: B.ipc_writer(n, "out")),
+     "full", [("ipc_reader", "ipc_reader")]),
+    ("off_keeps_its_meaning", lambda: _stage3(lambda n: _writer(_partial_sum(n))),
+     "off", [("sort", "sort")]),
+])
+def test_probe_sort_goes_only_where_the_plan_proves_its_order_dies(name, plan,
+                                                                   mode, want):
+    from auron_tpu.plan.optimizer import elide_smj_input_sorts
+
+    assert _smj_inputs(elide_smj_input_sorts(plan(), mode=mode)) == want

@@ -184,3 +184,38 @@ def test_f64_key_words_order_and_equality(monkeypatch, on_tpu):
         same_val = (vals[:, None] == vals[None, :]) | (
             np.isnan(vals)[:, None] & np.isnan(vals)[None, :])
         assert np.array_equal(same_word, same_val)
+
+
+def test_wide_sorts_take_their_order_from_the_host_on_a_tpu(monkeypatch):
+    """ops/hostsort's width rule reaches a plan's Sort and a join's sorted
+    build: on an accelerator (the back end patched to name itself "tpu") a
+    sort of 10^5 rows and a duplicate-keyed build of as many take the
+    host's lexsort, where a device sort of that width compiles for minutes
+    on XLA:TPU; the answers are the same."""
+    import time
+
+    import jax
+
+    from auron_tpu import obs
+    from auron_tpu.exec.joins import core
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.default_rng(17)
+    df = pd.DataFrame({"k": rng.integers(0, 10**12, 100_000)})
+    dup = pd.DataFrame({"k": np.concatenate([df.k.to_numpy()[:-1], df.k[:1]])})
+    before = obs.mode()
+    obs.set_mode(obs.MODE_RECORDER)
+    t0 = time.perf_counter()
+    try:
+        got = _sort([Batch.from_pandas(df)], [col(0)], [SortSpec()])
+        build = core.prepare_build([Batch.from_pandas(dup)], [col(0)],
+                                   T.Schema.from_arrow(pa.Schema.from_pandas(dup)))
+        s = obs.window_summary(t0, time.perf_counter())
+    finally:
+        obs.set_mode(before)
+    assert got.k.tolist() == sorted(df.k.tolist())
+    assert not build.unique and build.n_live == 100_000
+    words = np.asarray(build.words[0])[:100_000].view(np.int64)
+    assert (np.diff(words) >= 0).all()
+    assert s["sort_rows"]["sort_exec"] == {"host": 131072}
+    assert s["sort_rows"]["join_build"] == {"host": 131072}
