@@ -29,6 +29,7 @@ import pyarrow as pa
 from auron_tpu import obs
 from auron_tpu import types as T
 from auron_tpu.columnar.batch import (
+    MIN_CAPACITY,
     Batch,
     _arrow_to_host,
     aligned_empty,
@@ -75,8 +76,10 @@ class IpcReaderExec(ExecOperator):
             pending.append(rb)
             pending_rows += rb.num_rows
             if pending_rows >= target:
-                yield _combine(pending, self.schema)
-                pending, pending_rows = [], 0
+                n = seal_rows(pending_rows)
+                head, pending = _split_batches(pending, n)
+                pending_rows -= n
+                yield _combine(head, self.schema, carried=pending_rows)
         if pending:
             yield _combine(pending, self.schema)
 
@@ -104,7 +107,7 @@ class IpcReaderExec(ExecOperator):
             if asm.rows >= target:
                 with ctx.metrics.timer("decode_time"), \
                         obs.span("read", cat="exchange", arg=_EMIT):
-                    b = asm.emit()
+                    b = asm.emit(seal_rows(asm.rows))
                 if b is not None:
                     yield b
         if asm.rows:
@@ -115,18 +118,56 @@ class IpcReaderExec(ExecOperator):
                 yield b
 
 
+def seal_rows(rows: int) -> int:
+    """How many of ``rows`` assembled rows (``rows > 0``, in stream order)
+    the reader seals into its next batch: THE seal rule of both decode
+    paths. A batch's capacity is ``bucket_capacity`` of its rows, the next
+    power of two, so rows that pass a power of two ``C`` by a sliver would
+    ride in ``2C`` at little over half live. Where the overshoot is at most
+    ``C / 8``, seal exactly ``C`` rows (the prefix) and carry the rest (the
+    suffix) into the next batch; otherwise seal them all. The carry then
+    opens the next batch or, at the end of the stream, is sealed in a
+    bucket of its own of at most ``C / 8``: less than the ``2C`` that
+    sealing all would have cost. A read that passes ``C`` by
+    more (query 65's 234 k rows over 131,072) is sealed whole: cut there,
+    its carry would fill a bucket of ``C`` again and save nothing. A rule
+    over the row counts alone: no option, no conf key (docs/pipeline.md,
+    section 1)."""
+    c = 1 << (rows.bit_length() - 1)
+    if MIN_CAPACITY <= c < rows <= c + c // 8:
+        return c
+    return rows
+
+
+def _split_batches(batches: list[pa.RecordBatch], n: int) -> tuple[list, list]:
+    """The first ``n`` rows of ``batches`` and the rest, both in order."""
+    head: list[pa.RecordBatch] = []
+    for i, rb in enumerate(batches):
+        if n >= rb.num_rows:
+            head.append(rb)
+            n -= rb.num_rows
+            continue
+        if n:
+            head.append(rb.slice(0, n))
+        return head, [rb.slice(n), *batches[i + 1:]]
+    return head, []
+
+
 class _BucketAssembler:
     """Accumulates decoded column chunks and seals them into one Batch.
 
     Chunks per column are (vals np[n], valid np[n] | None, dict | None)
     in the ENGINE's physical plane layout (the _arrow_to_host contract);
     emit() concatenates them into aligned capacity-bucket host buffers and
-    ships the whole pytree in one (aliasing) device transfer."""
+    ships the whole pytree in one (aliasing) device transfer. Every column
+    holds one chunk per block added, so ``sizes`` (the chunks' rows) is
+    every column's chunk boundaries."""
 
     def __init__(self):
         self.schema: T.Schema | None = None
         self.rows = 0
         self.chunks: list[list] = []  # per column
+        self.sizes: list[int] = []
 
     def _bind_schema(self, arrow_schema: pa.Schema) -> None:
         if self.schema is None:
@@ -175,6 +216,7 @@ class _BucketAssembler:
                 v, m, d = _arrow_to_host(arr, f.dtype, n)
                 self.chunks[i].append((v, m[:n], d))
         self.rows += n
+        self.sizes.append(n)
 
     def add_arrow(self, rb: pa.RecordBatch) -> None:
         self._bind_schema(rb.schema)
@@ -185,15 +227,40 @@ class _BucketAssembler:
             v, m, d = _arrow_to_host(rb.column(i), f.dtype, n)
             self.chunks[i].append((v, m[:n], d))
         self.rows += n
+        self.sizes.append(n)
 
-    def emit(self) -> Batch | None:
-        import jax
+    def _take_head(self, n: int) -> list[list]:
+        """Remove the first ``n`` rows' chunks and return them per column;
+        a chunk that straddles ``n`` is sliced, its suffix kept first."""
+        k, rows = 0, 0
+        while rows < n and rows + self.sizes[k] <= n:
+            rows += self.sizes[k]
+            k += 1
+        cut = n - rows           # rows of chunk k that go with the head
+        head = []
+        for i, chunks in enumerate(self.chunks):
+            head.append(chunks[:k])
+            rest = chunks[k:]
+            if cut:
+                vals, valid, d = rest[0]
+                head[i].append((vals[:cut], None if valid is None else valid[:cut], d))
+                rest[0] = (vals[cut:], None if valid is None else valid[cut:], d)
+            self.chunks[i] = rest
+        self.sizes = self.sizes[k:]
+        if cut:
+            self.sizes[0] -= cut
+        self.rows -= n
+        return head
 
+    def emit(self, n: int | None = None) -> Batch | None:
+        """Seal the first ``n`` rows (all of them by default) into one
+        Batch; the rest stay, in order, for the next."""
         from auron_tpu.columnar.batch import _seal_batch
 
         if self.schema is None or self.rows == 0:
             return None
-        rows = self.rows
+        rows = self.rows if n is None else n
+        chunks = self._take_head(rows)
         cap = bucket_capacity(rows)
         values, validity, dicts = [], [], []
         for i, f in enumerate(self.schema):
@@ -204,11 +271,11 @@ class _BucketAssembler:
             if f.dtype.is_dict_encoded:
                 entry_lists = [
                     (dct.to_pylist() if dct is not None else [])
-                    for _, _, dct in self.chunks[i]
+                    for _, _, dct in chunks[i]
                 ]
                 d, remaps = merge_vocab(entry_lists, f.dtype)
                 pos = 0
-                for (codes, valid, _), r in zip(self.chunks[i], remaps):
+                for (codes, valid, _), r in zip(chunks[i], remaps):
                     k = len(codes)
                     remap = r if len(r) else np.zeros(1, np.int32)
                     out[pos : pos + k] = remap[np.clip(codes, 0, len(remap) - 1)]
@@ -219,7 +286,7 @@ class _BucketAssembler:
                     pos += k
             else:
                 pos = 0
-                for vals, valid, _ in self.chunks[i]:
+                for vals, valid, _ in chunks[i]:
                     k = len(vals)
                     out[pos : pos + k] = vals
                     if valid is None:
@@ -234,12 +301,12 @@ class _BucketAssembler:
             dicts.append(d)
         batch = _seal_batch(self.schema, values, validity, dicts, rows, cap,
                             zc=True)
-        self.rows = 0
-        self.chunks = [[] for _ in self.schema]
+        obs.note_exchange_read(rows, cap, self.rows)
         return batch
 
 
-def _combine(batches: list[pa.RecordBatch], schema: T.Schema) -> Batch:
+def _combine(batches: list[pa.RecordBatch], schema: T.Schema,
+             carried: int = 0) -> Batch:
     tbl = pa.Table.from_batches(align_dict_batches(batches))
     if any(pa.types.is_dictionary(f.type) for f in tbl.schema):
         # dictionary-preserving blocks: each block carries its own dict;
@@ -247,7 +314,9 @@ def _combine(batches: list[pa.RecordBatch], schema: T.Schema) -> Batch:
         tbl = tbl.unify_dictionaries()
     tbl = tbl.combine_chunks()
     rb = tbl.to_batches()[0] if tbl.num_rows else pa.RecordBatch.from_pylist([], schema=tbl.schema)
-    return Batch.from_arrow(rb)
+    batch = Batch.from_arrow(rb)
+    obs.note_exchange_read(rb.num_rows, batch.capacity, carried)
+    return batch
 
 
 class LocalFileBlockProvider:

@@ -16,12 +16,13 @@ Public surface:
   ``note_agg_fold`` / ``note_agg_reduce`` / ``note_agg_emit`` /
   ``note_decimal_host_cells`` / ``note_join_take`` / ``note_join_lookup``
   / ``note_join_hash_build`` / ``note_compaction_index`` / ``note_sort``
-  — the instrumentation facade behind MetricNode.timer, the
-  EngineCounters hooks, the task pump, the aggregate's folds, reduces and
-  emissions, the DECIMAL cells the host handles one by one, the
-  unique-build join's lookup, hash-map build and output boundary, every
-  program launched with a compaction index, and the sorts of plans,
-  joins' builds and the host's lexsort.
+  / ``note_exchange_read`` — the instrumentation facade behind
+  MetricNode.timer, the EngineCounters hooks, the task pump, the
+  aggregate's folds, reduces and emissions, the DECIMAL cells the host
+  handles one by one, the unique-build join's lookup, hash-map build and
+  output boundary, every program launched with a compaction index, the
+  sorts of plans, joins' builds and the host's lexsort, and the batches
+  the shuffle reader seals.
   Each checks ``core._mode`` first; in mode off a call is one flag test.
 - ``jit`` — ``jax.jit`` for every program of the engine: each call is a
   device launch, counted and timed beside the primitives JAX runs eagerly
@@ -282,6 +283,19 @@ def note_compaction_index(form: str, in_rows: int, out_rows: int) -> None:
                                    "out_rows": out_rows})
 
 
+def note_exchange_read(rows: int, capacity: int, carried: int) -> None:
+    """One batch the shuffle reader sealed (``exec/shuffle/reader.py``, both
+    decode paths): ``rows`` its live rows, ``capacity`` its bucket,
+    ``carried`` the rows the seal rule (``seal_rows``) left for the next
+    batch, 0 where it sealed all it held. A ``seal`` event of no duration
+    and no layer, like ``take``; ``window_summary`` sums them as
+    ``exchange_read`` ``{batches, rows, capacity, carried}``."""
+    if core._mode == MODE_OFF:
+        return
+    _event("seal", "exchange.read", {"rows": rows, "capacity": capacity,
+                                     "carried": carried})
+
+
 def note_sync(dur_ns: int, is_async: bool) -> None:
     """One device->host read observed by EngineCounters (blocking sync or
     async-window harvest), into the trace counters of the calling
@@ -322,6 +336,6 @@ if core.KILLED:  # no-obs baseline (make obscheck): rebind facade to no-ops
     note_op = note_sync = note_compile = note_pump_batch = _noop  # noqa: F811
     note_agg_fold = note_join_take = note_join_lookup = _noop  # noqa: F811
     note_join_hash_build = note_sort = _noop  # noqa: F811
-    note_compaction_index = _noop  # noqa: F811
+    note_compaction_index = note_exchange_read = _noop  # noqa: F811
     note_agg_reduce = note_agg_emit = note_decimal_host_cells = _noop  # noqa: F811
     apply_conf = _noop  # noqa: F811

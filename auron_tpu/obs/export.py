@@ -143,7 +143,10 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     (``search``, ``scatter``) the programs launched with a compaction index
     as ``{n, rows, out_rows}``: their count, the capacities the indexes
     read and the buckets they filled (the ``index`` events,
-    ``obs.note_compaction_index``). ``plan_cache_hits`` and
+    ``obs.note_compaction_index``). ``exchange_read`` sums the batches the
+    shuffle reader sealed as ``{batches, rows, capacity, carried}``: their
+    count, live rows, capacities and the rows each carried into the next
+    (the ``seal`` events, ``obs.note_exchange_read``). ``plan_cache_hits`` and
     ``plan_cache_misses`` count the ``serve:plan`` spans that began in the
     window by their ``cache_hit`` argument. ``exchange_bytes`` sums by
     ``mode`` (``mesh``, ``file``) the ``bytes`` of the ``exchange:write``
@@ -185,6 +188,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
     sorts: dict[str, dict] = {}
     indexes = {form: {"n": 0, "rows": 0, "out_rows": 0}
                for form in ("search", "scatter")}
+    reads = {"batches": 0, "rows": 0, "capacity": 0, "carried": 0}
     plans = [0, 0]          # serve:plan spans: [misses, hits]
     ex_bytes: dict[str, int] = {}
     ex_devices: dict[str, int] = {}     # least devices by exchange:<name>
@@ -262,6 +266,10 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
                 ent["n"] += 1
                 ent["rows"] += ev[7]["in_rows"]
                 ent["out_rows"] += ev[7]["out_rows"]
+            elif ev[2] == "seal":
+                reads["batches"] += 1
+                for key in ("rows", "capacity", "carried"):
+                    reads[key] += ev[7][key]
             elif ev[2] == "launch":
                 for kind, family, layer, n, ns in ev[7]:
                     ent = launches.setdefault((family, kind, layer), [0, 0])
@@ -317,6 +325,7 @@ def window_summary(t0_s: float, t1_s: float, top: int = 10) -> dict:
             "join_gather_rows": gather_rows, "join_takes": takes,
             "join_lookup_rows": lookups, "join_hash_build": hash_builds,
             "sort_rows": sorts, "compaction_index": indexes,
+            "exchange_read": reads,
             "plan_cache_misses": plans[0], "plan_cache_hits": plans[1],
             "exchange_bytes": ex_bytes, "exchange_devices_min": ex_devices,
             "stage_devices_min": stage_devices, "partition_pumps": pumps,

@@ -515,3 +515,134 @@ def test_rss_push_relay_aborts_on_failure():
     with pytest.raises(RuntimeError, match="fetch died"):
         push_payloads(ExplodingProvider(), Writer(), 2)
     assert ("abort",) in events and ("flush",) not in events
+
+
+# --- the reader's seal rule: a block that passes a power of two by a sliver
+# seals at that power and carries the rest into the next batch ----------
+
+_SEAL_C = 1024      # the power of two the blocks straddle (batch.size too)
+
+
+def _seal_blocks(sizes: list[int], encoding: str) -> tuple:
+    """Blocks of ``sizes`` rows (a row id, a nullable int, a dict-encoded
+    string) as the writer encodes them, and the rows they hold in order."""
+    from auron_tpu.exec.shuffle.format import decode_blocks
+    from auron_tpu.exec.shuffle.writer import encode_shuffle_block
+    from auron_tpu.utils.config import SHUFFLE_ENCODING, Configuration
+
+    conf = Configuration().set(SHUFFLE_ENCODING, encoding)
+    schema = T.Schema.of(T.Field("id", T.INT64), T.Field("v", T.INT64),
+                         T.Field("s", T.STRING))
+    blocks, start = [], 0
+    for n in sizes:
+        ids = np.arange(start, start + n)
+        b = Batch.from_pydict(
+            {"id": ids.tolist(),
+             "v": [None if i % 7 == 0 else int(i * 3) for i in ids],
+             "s": [f"s{i % (5 + len(blocks))}" for i in ids]},
+            schema=schema)
+        blocks.append(encode_shuffle_block([b.to_arrow()], conf=conf))
+        start += n
+    rows = pd.concat([Batch.from_arrow(rb).to_pandas() for blk in blocks
+                      for rb in decode_blocks(blk)]).reset_index(drop=True)
+    return schema, blocks, rows
+
+
+def _seal_read(schema, provider, encoding: str) -> tuple:
+    """Read partition 0 at batch.size 1,024: the batches' (rows, capacity),
+    their rows concatenated, and ``window_summary``'s ``exchange_read``."""
+    import time
+
+    from auron_tpu import obs
+    from auron_tpu.utils.config import BATCH_SIZE, SHUFFLE_ENCODING, Configuration
+
+    conf = (Configuration().set(SHUFFLE_ENCODING, encoding)
+            .set(BATCH_SIZE, _SEAL_C))
+    saved = obs.mode()
+    obs.set_mode("recorder")
+    try:
+        t0 = time.perf_counter()
+        ctx = ExecutionContext(conf=conf)
+        ctx.resources["blocks"] = provider
+        out = list(IpcReaderExec(schema, "blocks").execute(0, ctx))
+        summary = obs.window_summary(t0, time.perf_counter())
+    finally:
+        obs.set_mode(saved)
+    shapes = [(b.num_rows(), b.capacity) for b in out]
+    rows = pd.concat([b.to_pandas() for b in out]).reset_index(drop=True)
+    return shapes, rows, summary["exchange_read"]
+
+
+#: the three ways a reader assembles a batch: v2 blocks and v1 IPC blocks
+#: through the bucketed decode (a provider with ``iter_payloads``), and the
+#: Arrow path (a provider of RecordBatches alone)
+_SEAL_PATHS = [("on", "on", True), ("off", "on", True), ("on", "off", False)]
+
+
+def _seal_provider(blocks: list[bytes], raw: bool):
+    from auron_tpu.exec.shuffle.reader import BroadcastBlockProvider
+
+    prov = BroadcastBlockProvider(blocks)
+    return prov if raw else (lambda partition: prov(partition))
+
+
+@pytest.mark.parametrize("write_enc,read_enc,raw", _SEAL_PATHS)
+@pytest.mark.parametrize("sizes,want,carried", [
+    # a map batch's twin blocks C+d and C-d, then C+d again and a tail: every
+    # batch seals at C, the carry rides in the next one
+    ([_SEAL_C + 40, _SEAL_C - 30, _SEAL_C + 25, 300],
+     [(1024, 1024), (1024, 1024), (1024, 1024), (335, 512)], [40, 10, 35, 0]),
+    # the carry left at the end of the stream is sealed on its own
+    ([_SEAL_C + 40], [(1024, 1024), (40, 128)], [40, 0]),
+    # a read that passes C by far (query 65's 234 k rows over 2^17) is
+    # sealed whole, as before
+    ([915, 915], [(1830, 2048)], [0]),
+    # blocks under the target gather until they reach it: 1,050 rows pass
+    # 1,024 by 26
+    ([400, 400, 250, 300], [(1024, 1024), (326, 512)], [26, 0]),
+])
+def test_reader_seals_a_sliver_over_a_power_of_two_at_that_power(
+        sizes, want, carried, write_enc, read_enc, raw):
+    schema, blocks, rows = _seal_blocks(sizes, write_enc)
+    shapes, got, reads = _seal_read(schema, _seal_provider(blocks, raw), read_enc)
+    assert shapes == want
+    # bit-identical rows, in stream order: the batch a prefix, the carry a
+    # suffix
+    pd.testing.assert_frame_equal(got, rows)
+    assert reads == {"batches": len(shapes), "rows": sum(sizes),
+                     "capacity": sum(c for _, c in shapes),
+                     "carried": sum(carried)}
+
+
+@pytest.mark.parametrize("rows,want", [
+    (1024, 1024), (1025, 1024), (1152, 1024), (1153, 1153),
+    (2_097_152 + 1_000, 2_097_152), (234_000, 234_000), (100, 100),
+    (129, 128), (145, 145),
+])
+def test_seal_rows_carries_only_a_sliver_of_at_most_an_eighth(rows, want):
+    from auron_tpu.exec.shuffle.reader import seal_rows
+
+    assert seal_rows(rows) == want
+
+
+def test_file_shuffle_of_two_maps_seals_at_the_power_the_blocks_straddle(tmp_path):
+    """Two map tasks whose one block each passes and misses 1,024 rows, read
+    through the files they wrote (``MultiMapBlockProvider``): 1,024 and
+    1,024, the last 10 rows on their own, the rows in map order."""
+    from auron_tpu.utils.config import BATCH_SIZE, Configuration
+
+    pairs, frames = [], []
+    for mid, n in enumerate((_SEAL_C + 40, _SEAL_C - 30)):
+        df = pd.DataFrame({"id": np.arange(n) + 10_000 * mid,
+                           "v": np.arange(n) * 0.5})
+        frames.append(df)
+        b = Batch.from_arrow(pa.RecordBatch.from_pandas(df, preserve_index=False))
+        pairs.append(_write(tmp_path, [b], SinglePartitioning(), map_id=mid))
+    ctx = ExecutionContext(conf=Configuration().set(BATCH_SIZE, _SEAL_C))
+    ctx.resources["blocks"] = MultiMapBlockProvider(pairs)
+    out = list(IpcReaderExec(b.schema, "blocks").execute(0, ctx))
+    assert [(o.num_rows(), o.capacity) for o in out] == [
+        (1024, 1024), (1024, 1024), (10, 128)]
+    got = pd.concat([o.to_pandas() for o in out]).reset_index(drop=True)
+    pd.testing.assert_frame_equal(
+        got, pd.concat(frames).reset_index(drop=True), check_dtype=False)
