@@ -17,6 +17,7 @@ their exact columns, which are the grouping keys and so unique.
 
 from __future__ import annotations
 
+import decimal
 import math
 
 import numpy as np
@@ -116,6 +117,83 @@ def head(df: pd.DataFrame, order: tuple, ascending: tuple, limit) -> pd.DataFram
         if len(tie.astype(str).drop_duplicates()) > 1:
             raise TieError("non-identical rows tie at the LIMIT boundary")
     return df.iloc[idx[:limit]].reset_index(drop=True)
+
+
+def _holds_money(s: pd.Series) -> bool:
+    """DECIMAL or floating-point cells, or a column of objects with no cell
+    to tell by."""
+    arrow = getattr(s.dtype, "pyarrow_dtype", None)
+    if arrow is not None:
+        import pyarrow as pa
+
+        return pa.types.is_decimal(arrow) or pa.types.is_floating(arrow)
+    if pd.api.types.is_float_dtype(s.dtype):
+        return True
+    if s.dtype != object:
+        return False
+    cells = s.dropna()
+    return len(cells) == 0 or any(
+        isinstance(v, (decimal.Decimal, float, np.floating)) for v in cells)
+
+
+def money_columns(answer: pd.DataFrame) -> list:
+    """The columns of a reference's answer, and of each part it hangs on its
+    frame (``attrs``, as ``part.column``), that hold money: DECIMAL or
+    floating-point cells, or no cell at all to tell by."""
+    parts = [("", answer)] + [(f"{k}.", v) for k, v in answer.attrs.items()
+                              if isinstance(v, pd.DataFrame)]
+    return [p + str(c) for p, df in parts for c in df.columns
+            if _holds_money(df[c])]
+
+
+def halved(table: str, frames: dict) -> dict:
+    """Every other row of ``table`` dropped, the other tables as they are."""
+    if table not in frames:
+        raise ValueError(f"no table {table!r} to halve: the tables are {sorted(frames)}")
+    return {**frames, table: frames[table].iloc[::2].reset_index(drop=True)}
+
+
+def _same(a: pd.DataFrame, b: pd.DataFrame) -> bool:
+    """The two answers hold the same cells, and so do the parts they hang on
+    their frames (``attrs``)."""
+    return a.equals(b) and a.attrs.keys() == b.attrs.keys() and all(
+        not isinstance(v, pd.DataFrame) or v.equals(b.attrs[k])
+        for k, v in a.attrs.items())
+
+
+def control_frames(traffic: dict, queries: dict, params: dict, frames: dict,
+                   schemas: dict) -> dict:
+    """The data of the control that the traffic file names, for the
+    references to run over in the program's place.
+
+    ``control_money`` names the lower precision that every DECIMAL column is
+    rounded to (``money_in``). ``null`` there, with the reason in
+    ``control_why``, says that no lower precision moves the answer; the
+    control then breaks the guarantee that every row is read, dropping every
+    other row of the table that ``control_halved`` names. The null is an
+    error where a reference's answer, or a part it hangs on its frame, holds
+    money, and where the references over money in bfloat16 answer otherwise
+    than over exact money (a count filtered on a price): a precision control
+    exists there and has to be named."""
+    precision = traffic["control_money"]
+    if precision is not None:
+        return money_in(precision, frames, schemas)
+    if not traffic.get("control_why") or not traffic.get("control_halved"):
+        raise ValueError("control_money is null: control_why gives no reason "
+                         "or control_halved names no table")
+    low = money_in("bfloat16", frames, schemas)
+    for name, q in queries.items():
+        exact = q.reference(frames, params)
+        money = money_columns(exact)
+        if money:
+            raise ValueError(
+                f"control_money is null, but the answer of {name} holds money "
+                f"in {money}: name the lower precision its control computes in")
+        if not _same(exact, q.reference(low, params)):
+            raise ValueError(
+                f"control_money is null, but money in bfloat16 moves the answer "
+                f"of {name}: name the lower precision its control computes in")
+    return halved(traffic["control_halved"], frames)
 
 
 def money_in(precision: str, frames: dict, schemas: dict) -> dict:

@@ -1,15 +1,19 @@
 """Driver of the batch cells whose tables come from a generator module of
-their own: ``batch_class``'s closed loop (its ``window``, ``finish`` and
-``check``, imported), with the tables made by the module that the
-configuration names.
+their own: ``batch_class``'s closed loop (its ``window`` and ``finish``,
+imported), with the tables made by the module that the configuration names.
 
 ``batch_class.setup`` asks ``datagen.make`` for the frames, and that looks a
 generator up in ``datagen.py``'s own globals; a table that ``datagen.py`` does
 not make comes from a module beside it (``data.module``, for example
-``datagen_store``) that brings ``make(config, seed)`` and ``schemas()``. So a
-further table is a generator module, a schema file and a configuration that
-names them, and a further plan is a ``queries/<name>.py`` with a ``run`` of
-its own: no file that is there is edited.
+``datagen_store``) that brings ``make(config, seed)`` and ``schemas()``. The
+module's tables are whatever the deployment reads, ``store_sales`` among them
+or not: nothing here names a table. So a further table is a generator module,
+a schema file and a configuration that names them, a further plan is a
+``queries/<name>.py`` with a ``run`` of its own, and an answer that holds no
+money is a traffic file that says so (``control_money: null`` with its
+``control_why``, and the table whose every other row its control drops in
+``control_halved``; ``compare.control_frames`` refuses the null where money
+moves an answer): no file that is there is edited.
 """
 
 from __future__ import annotations
@@ -56,8 +60,8 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
         with tempfile.TemporaryDirectory(prefix="bench_q_") as wd:
             q.run(resident[name], params, wd, span)
         warm[name] = time.perf_counter() - t0
-    say(phase="setup", fact_rows=len(frames["store_sales"]), generate_s=gen_s,
-        ingest_s=ingest_s, warmup_s=warm)
+    say(phase="setup", rows={t: len(df) for t, df in frames.items()},
+        generate_s=gen_s, ingest_s=ingest_s, warmup_s=warm)
     return state
 
 
@@ -108,13 +112,14 @@ def check(state: dict, records: list, limits: dict) -> dict:
 
 def control(config: dict, traffic: dict, seed: int) -> tuple:
     """``batch_class.control`` over this configuration's generator: the
-    references in the program's place with money in the lower precision that
-    the traffic file names; ``check`` has to find them not correct."""
+    references in the program's place over the control's data that the
+    traffic file names; ``check`` has to find them not correct."""
     gen = _generator(config)
     frames = gen.make(config, seed)
     queries = _queries(traffic)
     params = {**config["sizes"], **traffic["params"]}
-    low = compare.money_in(traffic["control_money"], frames, gen.schemas())
+    low = compare.control_frames(traffic, queries, params, frames,
+                                 gen.schemas())
     records = [{"ok": True, "name": n, "answer": _want(q, low, params)}
                for n, q in queries.items()]
     return {"frames": frames, "queries": queries, "params": params}, records

@@ -19,6 +19,7 @@ names the mix and gives its parameters.
 from __future__ import annotations
 
 import gc
+import importlib
 import tempfile
 import time
 
@@ -27,6 +28,13 @@ from benchmark.harness import load_module
 
 
 def _queries(traffic: dict) -> dict:
+    """The query classes the traffic names: ``benchmark/queries/<name>.py``,
+    or ``<name>`` in the package that ``query_package`` names under
+    ``benchmark`` (a test's fixture deployment)."""
+    package = traffic.get("query_package")
+    if package:
+        return {name: importlib.import_module(f"benchmark.{package}.{name}")
+                for name in traffic["queries"]}
     return {name: load_module("queries", name) for name in traffic["queries"]}
 
 
@@ -53,8 +61,8 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
         with tempfile.TemporaryDirectory(prefix="bench_q_") as wd:
             q.run(resident[name], params, wd, span)
         warm[name] = time.perf_counter() - t0
-    say(phase="setup", fact_rows=len(frames["store_sales"]), generate_s=gen_s,
-        ingest_s=ingest_s, warmup_s=warm)
+    say(phase="setup", rows={t: len(df) for t, df in frames.items()},
+        generate_s=gen_s, ingest_s=ingest_s, warmup_s=warm)
     return state
 
 
@@ -124,13 +132,14 @@ def check(state: dict, records: list, limits: dict) -> dict:
 
 
 def control(config: dict, traffic: dict, seed: int) -> tuple:
-    """The references put in the program's place with money in the lower
-    precision that the traffic file names (``control_money``): ``(state,
+    """The references put in the program's place over the control's data
+    that the traffic file names (``compare.control_frames``): ``(state,
     records)`` for ``check``, which has to find them not correct."""
     frames = datagen.make(config, seed)
     queries = _queries(traffic)
     params = {**config["sizes"], **traffic["params"]}
-    low = compare.money_in(traffic["control_money"], frames, datagen.schemas())
+    low = compare.control_frames(traffic, queries, params, frames,
+                                 datagen.schemas())
     records = [{"ok": True, "name": n, "answer": _want(q, low, params)}
                for n, q in queries.items()]
     return {"frames": frames, "queries": queries, "params": params}, records

@@ -141,8 +141,8 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
                                    f"{body[:2000].decode(errors='replace')}")
     finally:
         conn.close()
-    say(phase="setup", fact_rows=len(frames["store_sales"]), generate_s=gen_s,
-        ingest_s=ingest_s, warmup_s=warm, serve=server.stats(),
+    say(phase="setup", rows={t: len(df) for t, df in frames.items()},
+        generate_s=gen_s, ingest_s=ingest_s, warmup_s=warm, serve=server.stats(),
         resident_bytes_by_chip=resident,
         peak_bytes_by_chip=_peaks(server))
     return state
@@ -243,14 +243,15 @@ def check(state: dict, records: list, limits: dict) -> dict:
 
 
 def control(config: dict, traffic: dict, seed: int) -> tuple:
-    """The references put in the program's place with money in the lower
-    precision that the traffic file names, as answers a client would have
-    parsed; ``check`` has to find them not correct."""
+    """The references put in the program's place over the control's data
+    that the traffic file names, as answers a client would have parsed;
+    ``check`` has to find them not correct."""
     gen = _generator(config)
     frames = gen.make(config, seed)
     queries = _queries(traffic)
     params = {**config["sizes"], **traffic["params"]}
-    low = compare.money_in(traffic["control_money"], frames, gen.schemas())
+    low = compare.control_frames(traffic, queries, params, frames,
+                                 gen.schemas())
     records = [{"ok": True, "name": n, "answer": to_answer(w)}
                for n, w in wants(queries, low, params).items()]
     return {"frames": frames, "queries": queries, "params": params}, records

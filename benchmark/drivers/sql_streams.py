@@ -121,8 +121,8 @@ def setup(config: dict, traffic: dict, seed: int, span, say) -> dict:
                                    f"{body[:2000].decode(errors='replace')}")
     finally:
         conn.close()
-    say(phase="setup", fact_rows=len(frames["store_sales"]), generate_s=gen_s,
-        ingest_s=ingest_s, warmup_s=warm, serve=server.stats())
+    say(phase="setup", rows={t: len(df) for t, df in frames.items()},
+        generate_s=gen_s, ingest_s=ingest_s, warmup_s=warm, serve=server.stats())
     return state
 
 
@@ -278,14 +278,15 @@ def to_answer(df: pd.DataFrame) -> dict:
 
 
 def control(config: dict, traffic: dict, seed: int) -> tuple:
-    """The references put in the program's place with money in the lower
-    precision that the traffic file names (``control_money``), as answers a
+    """The references put in the program's place over the control's data
+    that the traffic file names (``compare.control_frames``), as answers a
     client would have parsed: ``(state, records)`` for ``check``, which has
     to find them not correct."""
     frames = datagen.make(config, seed)
     queries = _queries(traffic)
     params = {**config["sizes"], **traffic["params"]}
-    low = compare.money_in(traffic["control_money"], frames, datagen.schemas())
+    low = compare.control_frames(traffic, queries, params, frames,
+                                 datagen.schemas())
     records = [{"ok": True, "name": n, "answer": to_answer(_want(q, low, params))}
                for n, q in queries.items()]
     return {"frames": frames, "queries": queries, "params": params}, records

@@ -6,6 +6,10 @@ under ``configs/``, its traffic mix under ``traffic/``, its driver under
 metric under ``metrics/``), and drives one run: set-up, window, peak memory,
 the comparison with the reference, the metrics. ``run.py`` is the command;
 tests call ``run_cell`` with the devices they have.
+
+A test's fixture deployment, which is no cell of ``BENCHMARK.json``, keeps its
+traffic mix under a root of its own: ``load_cell`` reads it from there, and
+the mix names the package of its queries (``query_package``).
 """
 
 from __future__ import annotations
@@ -52,9 +56,10 @@ def load_module(kind: str, name: str):
     raise FileNotFoundError(f"no benchmark/{kind}/{name}.py")
 
 
-def load_cell(workload: str, spec: dict | None = None) -> dict:
-    """The cell's entry of ``BENCHMARK.json`` with its configuration and its
-    traffic mix read from their own files."""
+def load_cell(workload: str, spec: dict | None = None, root: str = HERE) -> dict:
+    """The cell's entry of ``BENCHMARK.json`` (or of ``spec``) with its
+    configuration and its traffic mix read from their own files, the mix
+    from ``<root>/traffic/``."""
     if spec is None:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             spec = json.load(f)
@@ -66,7 +71,8 @@ def load_cell(workload: str, spec: dict | None = None) -> dict:
     files = {c["name"]: c["file"] for c in spec["configs"]}
     with open(os.path.join(ROOT, files[cell["config"]])) as f:
         cell["config_file"] = json.load(f)
-    cell["traffic_file"] = load_json("traffic", cell["traffic"] + ".json")
+    with open(os.path.join(root, "traffic", cell["traffic"] + ".json")) as f:
+        cell["traffic_file"] = json.load(f)
 
     def reports(m: dict) -> bool:
         return "workloads" not in m or workload in m["workloads"]

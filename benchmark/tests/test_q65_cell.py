@@ -1,16 +1,16 @@
 """The cell ``batch_q65_sf8``: its files are found by name, the ``store``
 generator keeps to ``schemas/tpcds_store.json`` and to a history-keeping
 dimension's shape, the three readers this cell brings read the program's rings,
-and the driver's comparison holds the stores' averages beside the top 100."""
+and the driver's comparison holds the stores' averages beside the top 100.
+The bridge's faults run over this cell as cases of the parametrised tests of
+``test_control_and_faults.py``."""
 
-import copy
 import decimal
 import json
 import os
 import time
 
 import pandas as pd
-import pyarrow as pa
 import pytest
 
 from auron_tpu import obs
@@ -41,7 +41,7 @@ def test_cell_resolves_to_its_files_by_name():
             assert hasattr(mod, name), name
     assert {m["name"] for m in cell["end_to_end"]} == {"batch_query_s", "setup_s"}
     names = [m["name"] for m in cell["per_layer"]]
-    assert len(names) == 18 and all(n.endswith(".agg") for n in names)
+    assert len(names) == 20 and all(n.endswith(".agg") for n in names)
     assert set(NEW_READERS) <= set(names)
     for n in names:
         assert callable(harness.load_module("metrics", n).read)
@@ -218,97 +218,3 @@ def test_check_holds_the_averages_beside_the_top_100(monkeypatch):
     monkeypatch.setattr(q, "reference", lambda frames, params=None: _answer([], sb))
     assert wrong(_answer([], sb.copy())) == 0
     assert wrong(_answer([], off)) == 1
-
-
-# ---- the cell's own faults, planted where they bite query 65 ----------------
-#
-# ``test_control_and_faults.py`` plants the bridge's faults for the cells whose
-# driver is ``batch_class``; these are the same four, placed where query 65
-# feels them. A whole run at a tiny size on the CPU (everything of
-# ``run_cell``); ``correct`` has to come out false.
-
-
-def _tiny(sf: float = 0.2) -> dict:
-    cell = copy.deepcopy(harness.load_cell(CELL))
-    cell["config_file"]["data"]["sf"] = sf
-    cell["config_file"]["sizes"]["batch_rows"] = 1 << 14
-    return cell
-
-
-def _run(cell: dict, seconds: float = 1.0) -> dict:
-    import jax
-
-    return harness.run_cell(cell, SEED, seconds, False, jax.devices()[:1],
-                            time.perf_counter())
-
-
-def test_fault_a_cent_on_revenue(monkeypatch):
-    """One cent on one ``revenue`` of each batch stage 4 hands over: the row
-    of the batch that the ORDER BY puts first, so the top 100 holds it."""
-    from auron_tpu.bridge import api
-
-    real, altered = api.next_batch, []
-
-    def cent(h):
-        rb = real(h)
-        if rb is None or rb.num_rows == 0 or "revenue" not in rb.schema.names:
-            return rb
-        df = rb.to_pandas()
-        k = df.sort_values(["s_store_name", "i_item_desc"]).revenue.first_valid_index()
-        df.loc[k, "revenue"] = df.loc[k, "revenue"] + decimal.Decimal("0.01")
-        altered.append(k)
-        return pa.RecordBatch.from_pandas(df, schema=rb.schema, preserve_index=False)
-
-    monkeypatch.setattr(api, "next_batch", cent)
-    out = _run(_tiny())
-    assert altered
-    assert out["correct"] is False
-    assert out["compared"]["rows_wrong"]["value"] > 0
-
-
-def test_fault_half_of_the_years_rows_left_out(monkeypatch):
-    """The table is in date order and the year that query 65 reads lies whole
-    in map task 0's range: every other batch of that range is left out."""
-    from auron_tpu.bridge import api
-
-    real = api.put_resource
-
-    def half(rid, value, *a, **kw):
-        if rid.endswith("_fact"):
-            value = [value[0][::2]] + list(value[1:])
-        return real(rid, value, *a, **kw)
-
-    monkeypatch.setattr(api, "put_resource", half)
-    out = _run(_tiny())
-    assert out["correct"] is False
-    assert out["compared"]["rows_wrong"]["value"] > 0
-
-
-def test_fault_shuffle_of_one_map_task_left_out(monkeypatch):
-    """Both exchanges lose their second writer's blocks: half the pairs'
-    partial sums and one reduce task's partial averages never arrive."""
-    from auron_tpu.exec.shuffle import reader
-
-    real = reader.MultiMapBlockProvider
-    monkeypatch.setattr(reader, "MultiMapBlockProvider",
-                        lambda pairs: real(pairs[:1]))
-    assert _run(_tiny())["correct"] is False
-
-
-def test_fault_call_native_refused_after_the_first_querys_calls(monkeypatch):
-    """Query 65 makes n_map + 3 x n_reduce = 8 calls: the warm-up's and the
-    window's first query's go through, every later one is refused."""
-    from auron_tpu.bridge import api
-
-    real, calls = api.call_native, []
-
-    def refuse(task, *a, **kw):
-        calls.append(1)
-        if len(calls) > 16:
-            raise RuntimeError("refused by the test")
-        return real(task, *a, **kw)
-
-    monkeypatch.setattr(api, "call_native", refuse)
-    out = _run(_tiny(sf=0.05), seconds=3.0)
-    assert len(calls) > 16
-    assert out["failed"] > 0 and out["correct"] is False

@@ -47,7 +47,7 @@ def test_cell_resolves_to_its_files_by_name():
         assert os.path.exists(os.path.join(harness.HERE, "sql", q + ".sql"))
     assert {m["name"] for m in cell["end_to_end"]} == {"batch_query_s", "setup_s"}
     names = [m["name"] for m in cell["per_layer"]]
-    assert len(names) == 20 and all(n.endswith(".mesh") for n in names)
+    assert len(names) == 22 and all(n.endswith(".mesh") for n in names)
     assert set(NEW_READERS) <= set(names)
     for n in names:
         assert callable(harness.load_module("metrics", n).read)
@@ -57,9 +57,9 @@ def test_cell_resolves_to_its_files_by_name():
 def test_benchmark_json_gained_one_configuration_and_one_four_chip_cell():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
-    entry = spec["configs"][-1]
+    entry = next(c for c in spec["configs"] if c["name"] == "tpcds_sql_mesh4_sf24")
     cfg = harness.load_cell(CELL)["config_file"]
-    assert entry["name"] == "tpcds_sql_mesh4_sf24" and entry["source"] == cfg["source"]
+    assert entry["source"] == cfg["source"]
     assert len(entry["source"]) <= 200
     assert cfg["reduced"] == entry["reduced"] == ["sf", "tables", "queries"]
     assert set(cfg["reduced_why"]) == set(cfg["reduced"])
@@ -69,10 +69,9 @@ def test_benchmark_json_gained_one_configuration_and_one_four_chip_cell():
     for key in ("generator", "dimensions", "splits", "tie_break", "second_text",
                 "types", "dms"):
         assert key in cfg["assumed"], key
-    assert spec["workloads"][-1]["name"] == CELL
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] == [CELL]
     e2e = {m["name"]: m for m in spec["end_to_end"]}
-    assert e2e["batch_query_s"]["workloads"][-1] == CELL
+    assert CELL in e2e["batch_query_s"]["workloads"]
     assert e2e["batch_query_s"]["bound"] == 0.08 and spec["run_seconds"] == 40
 
 

@@ -3,7 +3,8 @@ and ``reason`` generator keeps to ``schemas/tpcds_returns.json`` and to the
 specification's shapes (a return is a sold line and never more than it), the
 three readers this cell brings read the program's rings and the trace, the
 driver's comparison holds every customer's sum beside the top 100, and the
-cell's own faults come out not correct."""
+cell's own fault comes out not correct (the bridge's faults run over this cell
+as cases of the parametrised tests of ``test_control_and_faults.py``)."""
 
 import copy
 import decimal
@@ -12,7 +13,6 @@ import os
 import time
 
 import pandas as pd
-import pyarrow as pa
 import pytest
 
 from auron_tpu import obs
@@ -271,29 +271,6 @@ def test_sound_run_is_correct():
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] >= 1
 
 
-def test_fault_a_cent_on_the_first_rows_sumsales(monkeypatch):
-    """One cent on the ``sumsales`` of each batch stage 4 hands over, in the
-    row that the ORDER BY puts first among those with a sum."""
-    from auron_tpu.bridge import api
-
-    real, altered = api.next_batch, []
-
-    def cent(h):
-        rb = real(h)
-        if rb is None or rb.num_rows == 0 or "sumsales" not in rb.schema.names:
-            return rb
-        df = rb.to_pandas()
-        k = df.sort_values(["sumsales", "ss_customer_sk"]).sumsales.first_valid_index()
-        df.loc[k, "sumsales"] = df.loc[k, "sumsales"] + decimal.Decimal("0.01")
-        altered.append(k)
-        return pa.RecordBatch.from_pandas(df, schema=rb.schema, preserve_index=False)
-
-    monkeypatch.setattr(api, "next_batch", cent)
-    out = _run(_tiny())
-    assert altered
-    assert out["correct"] is False and out["compared"]["rows_wrong"]["value"] > 0
-
-
 def test_fault_a_map_tasks_returns_left_out(monkeypatch):
     """Map task 1 of stage 2 scans none of its returns: the sales they were
     made from join nothing, and their customers' sums move."""
@@ -311,25 +288,6 @@ def test_fault_a_map_tasks_returns_left_out(monkeypatch):
     out = _run(_tiny())
     assert out["failed"] == 0
     assert out["correct"] is False and out["compared"]["rows_wrong"]["value"] > 0
-
-
-def test_fault_call_native_refused_after_the_first_querys_calls(monkeypatch):
-    """Query 93 makes 2 x n_map + 2 x n_reduce = 8 calls: the warm-up's and
-    the window's first query's go through, every later one is refused."""
-    from auron_tpu.bridge import api
-
-    real, calls = api.call_native, []
-
-    def refuse(task, *a, **kw):
-        calls.append(1)
-        if len(calls) > 16:
-            raise RuntimeError("refused by the test")
-        return real(task, *a, **kw)
-
-    monkeypatch.setattr(api, "call_native", refuse)
-    out = _run(_tiny(sf=0.05), seconds=3.0)
-    assert len(calls) > 16
-    assert out["failed"] > 0 and out["correct"] is False
 
 
 def test_control_fails_in_almost_every_customers_sum():
